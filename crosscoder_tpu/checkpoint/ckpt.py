@@ -604,8 +604,11 @@ class Checkpointer:
                     f"checkpoint save {v} under {vdir} failed checksum "
                     "verification (corrupt or truncated artifact)"
                 )
-        template = init_train_state(jax.random.key(cfg.seed), cfg, tx,
-                                    n_data=n_data)
+        # shapes, dtypes and paths only: a concrete template would be one
+        # more full TrainState on the device while the checkpoint's lands
+        template = jax.eval_shape(
+            lambda key: init_train_state(key, cfg, tx, n_data=n_data),
+            jax.random.key(cfg.seed))
         pathed, treedef = jax.tree_util.tree_flatten_with_path(template)
         with np.load(vdir / f"{v}_train_state.npz") as z:
             positional = all(k.startswith("leaf_") for k in z.files)
@@ -640,7 +643,7 @@ class Checkpointer:
                 if key not in z.files:
                     if _is_ef(key):
                         respec_resets.append(key)
-                        loaded.append(leaf)
+                        loaded.append(jax.numpy.zeros(leaf.shape, leaf.dtype))
                         continue
                     raise ValueError(
                         f"checkpoint is missing state leaf {key!r}; optimizer "
@@ -657,7 +660,7 @@ class Checkpointer:
                     raw = raw.view(want)
                 if _is_ef(key) and raw.shape != leaf.shape:
                     respec_resets.append(key)
-                    loaded.append(leaf)
+                    loaded.append(jax.numpy.zeros(leaf.shape, leaf.dtype))
                     continue
                 arr = jax.numpy.asarray(raw, dtype=leaf.dtype)
                 # force an XLA-OWNED buffer: on the CPU backend

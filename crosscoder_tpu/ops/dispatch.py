@@ -1,12 +1,15 @@
 """Shared hardware-dispatch gate for the opt-in Pallas kernels.
 
-Every kernel module in ops/ ships interpret-verified but
-hardware-unmeasured (this environment cannot Mosaic-compile), so real-TPU
-dispatch is an explicit opt-in env var per kernel family — one rule,
-stated once: the interpreter (CPU tests) always may run, hardware only
-with the opt-in. Flip a kernel's conservative default here-adjacent (its
-call site) once a real-TPU A/B lands; the GATE shape itself is shared so
-a policy change (new backend, global kill-switch) lands in one place.
+The kernel families gated here are interpret-verified and have never been
+timed on a chip, so real-TPU dispatch is an explicit opt-in env var per
+family — one rule, stated once: the interpreter (CPU tests) always may
+run, hardware only with the opt-in. The chip's compiler has been asked:
+tests/test_chip_compile.py compiles one case per family for a described
+v5e at production shapes (all accepted except ops/sparse_grad, which it
+refuses — a strict xfail there). Flip a kernel's conservative default
+here-adjacent (its call site) once a real-TPU A/B lands; the GATE shape
+itself is shared so a policy change (new backend, global kill-switch)
+lands in one place.
 
 Gate resolution (first ``hw_kernel_enabled`` call logs the full table to
 stderr, once per process, so a run's kernel posture is always in its

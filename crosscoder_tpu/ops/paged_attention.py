@@ -28,10 +28,9 @@ Two implementations, one dispatch (the ops/quant.py discipline):
   it (tests/test_paged_attention.py).
 
 Hardware dispatch is gated on ``CROSSCODER_PAGED_ATTN_PALLAS=1``
-(conservative default, mirroring ops/sparse_grad.py: this environment
-cannot Mosaic-compile, so the kernel ships interpret-verified but
-hardware-unmeasured; the page-table structure, not the constant, is the
-load-bearing part).
+(conservative default: interpret-verified, compiles for a v5e at
+Gemma-2-2B heads — tests/test_chip_compile.py — never timed on one; the
+page-table structure, not the constant, is the load-bearing part).
 """
 
 from __future__ import annotations

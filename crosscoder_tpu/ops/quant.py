@@ -191,10 +191,10 @@ def quantize_rows(x: jax.Array, block: int) -> tuple[jax.Array, jax.Array]:
     identical either way (the tests assert it in interpret mode).
 
     The TPU kernel dispatch is gated on ``CROSSCODER_QUANT_PALLAS=1``
-    (conservative default: this environment cannot Mosaic-compile, so the
-    kernel ships interpret-verified but hardware-unmeasured; flip the
-    default once a real-TPU A/B lands — the XLA lowering is a correct
-    two-pass fallback either way)."""
+    (conservative default: the kernel is interpret-verified and compiles
+    for a v5e — tests/test_chip_compile.py — but has never been timed on
+    one; flip the default once a real-TPU A/B lands — the XLA lowering is
+    a correct two-pass fallback either way)."""
     from crosscoder_tpu.ops.dispatch import hw_kernel_enabled
 
     use_kernel = hw_kernel_enabled("CROSSCODER_QUANT_PALLAS", _INTERPRET)

@@ -40,10 +40,10 @@ f32), and one write of the [H, nd] f32 output (2.4 GB — the output
 write is irreducible for a dense-layout gradient and is the same bytes
 the dense matmul writes); vs the dense path's 2·B·H·nd ≈ 5 TFLOP
 matmul. Hardware dispatch is gated on ``CROSSCODER_SPARSE_GRAD_PALLAS=1``
-(conservative default, mirroring ops/quant.py: this environment cannot
-Mosaic-compile, so the kernel ships interpret-verified but
-hardware-unmeasured; flip the default once a real-TPU A/B lands — the
-sorted-pair structure, not the constant, is the load-bearing part).
+and must stay off: the kernel is interpret-verified only — the chip's
+compiler REFUSES it as written (dynamic scalar reads of the pair list
+from VMEM; tests/test_chip_compile.py holds the refusal as a strict
+xfail and says why scalar-prefetching the list is not a local fix).
 """
 
 from __future__ import annotations

@@ -809,9 +809,8 @@ topk.defvjp(_topk_vjp_fwd, _topk_vjp_bwd)
 # threshold kernelizes so much more cheaply than per-row TopK).
 #
 # Hardware dispatch is gated on ``CROSSCODER_BATCHTOPK_PALLAS=1``
-# (conservative default, the ops/quant.py precedent: this environment
-# cannot Mosaic-compile, so the kernel ships interpret-verified but
-# hardware-unmeasured).
+# (conservative default, the ops/quant.py precedent: interpret-verified,
+# compiles for a v5e — tests/test_chip_compile.py — never timed on one).
 
 # thresholds per bisection pass: matches activations._BATCHTOPK_T so the
 # kernel and the dense oracle take the same pass schedule (bf16's 15-bit

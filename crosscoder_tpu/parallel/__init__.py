@@ -8,22 +8,6 @@ the required collectives (psum/all-gather/reduce-scatter) onto ICI within a
 slice and DCN across slices. There is no hand-written transport.
 """
 
-def shard_map_compat(f, **kwargs):
-    """``jax.shard_map`` across the jax versions this repo meets: newer
-    releases export it at the top level with a ``check_vma`` flag, older
-    ones (e.g. 0.4.x) keep it in ``jax.experimental.shard_map`` and call
-    the same knob ``check_rep``. Every shard_map in the repo comes through
-    here so a jax upgrade is a one-line change, not a grep."""
-    try:
-        from jax import shard_map as _sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
-
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-    return _sm(f, **kwargs)
-
-
 from crosscoder_tpu.parallel.mesh import (  # noqa: F401
     batch_sharding,
     make_mesh,

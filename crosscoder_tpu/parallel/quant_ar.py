@@ -124,15 +124,13 @@ def quantized_pmean_fn(mesh, block: int, axis_name: str = "data"):
     the same global-mean gradient."""
     from jax.sharding import PartitionSpec as P
 
-    from crosscoder_tpu.parallel import shard_map_compat
-
     n_dev = mesh.shape[axis_name]
 
     def local(gl, ef):
         out, new_ef = _quantized_pmean_leaf(gl[0], ef, axis_name, n_dev, block)
         return out[None], new_ef
 
-    return jax.jit(shard_map_compat(
+    return jax.jit(jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axis_name), P(axis_name)),
         out_specs=(P(axis_name), P(axis_name)), check_vma=False,

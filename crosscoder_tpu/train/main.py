@@ -75,7 +75,7 @@ def main(argv: list[str] | None = None) -> Any:
     from crosscoder_tpu.parallel import multihost
     from crosscoder_tpu.utils import compile_cache
 
-    compile_cache.enable()   # warm restarts/resumes skip remote recompiles
+    compile_cache.enable()   # warm restarts/resumes skip recompiles
 
     distributed = multihost.initialize()   # no-op single-process
     cfg = CrossCoderConfig.from_cli(argv)

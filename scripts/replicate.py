@@ -292,7 +292,11 @@ def main(argv=None):
     ap.add_argument("--demo-lm-steps", type=_positive_int, default=400)
     ap.add_argument("--demo-cc-steps", type=_positive_int, default=1500)
     ap.add_argument("--out", type=str, default="replicate_out")
-    ap.add_argument("--platform", type=str, default=None, choices=("cpu", "tpu"))
+    ap.add_argument(
+        "--platform", type=str, default=None, choices=("cpu", "tpu"),
+        help="force a jax backend (default: cpu for --demo — a toy-sized "
+        "self-check that needs no accelerator — else the platform default)",
+    )
     args = ap.parse_args(argv)
 
     platform = args.platform or ("cpu" if args.demo else None)

@@ -132,3 +132,30 @@ def test_bench_compact_summary_is_small_and_gated():
     out2 = bench._compact(headline, {
         "e2e": {"error": "RuntimeError: " + "x" * 290}})
     assert len(out2["e2e"]["error"]) <= 120
+
+
+def test_bench_exits_nonzero_when_a_section_raised(tmp_path, monkeypatch,
+                                                   capsys):
+    """A section's exception is caught so the rest can run and the summary
+    line is still written — but the run has failed, and its exit code says
+    so (it used to exit 0)."""
+    sys.path.insert(0, str(_ROOT))
+    try:
+        import bench
+    finally:
+        sys.path.pop(0)
+
+    headline = {"metric": "m", "value": None, "unit": "u",
+                "vs_baseline": None, "compile_cache": "cold"}
+    results = {"step": {"acts_per_sec_chip": 1.0},
+               "e2e": {"error": "RuntimeError: boom"},
+               "configs": [{"config": "a", "acts_per_sec_chip": 1.0},
+                           {"config": "b", "error": "ValueError: x"}]}
+    assert bench._failed_sections(results) == ["e2e", "configs"]
+    monkeypatch.setenv("BENCH_ARTIFACT", str(tmp_path / "detail.json"))
+    monkeypatch.setattr(bench, "_run_sections", lambda: (headline, results))
+    with pytest.raises(SystemExit) as exc:
+        bench.main()
+    assert exc.value.code == 1
+    lines = capsys.readouterr().out.strip().splitlines()
+    assert len(lines) == 1 and json.loads(lines[0])["e2e"]["error"]

@@ -628,3 +628,23 @@ def test_mesh_buffer_through_trainer(lm_pair, tokens):
         assert float(jax.device_get(mh["loss"])) == float(jax.device_get(md["loss"]))
     t_host.close()
     t_dev.close()
+
+
+def test_mesh_buffer_serves_without_device_to_device_transfers(lm_pair, tokens):
+    """LM weights handed over uncommitted on the default device (what
+    ``lm.from_hf`` without shardings leaves) are committed to the mesh ONCE,
+    at construction; a serve + refill cycle then makes no implicit
+    device-to-device transfer — each one would be a re-replication per
+    dispatch on a real multi-chip host (weights, gather indices, scalars)."""
+    from crosscoder_tpu.data.buffer import make_buffer
+
+    lm_cfg, params = lm_pair
+    assert not params[0]["embed"].committed
+    mesh, sh = _data_mesh()
+    dev = make_buffer(make_cfg(buffer_device="hbm"), lm_cfg, params, tokens,
+                      batch_sharding=sh)
+    for leaf in jax.tree_util.tree_leaves(dev.model_params):
+        assert leaf.committed and len(leaf.sharding.device_set) == mesh.size
+    with jax.transfer_guard_device_to_device("disallow"):
+        for _ in range(20):                      # crosses one refill cycle
+            dev.next_raw()

@@ -230,10 +230,21 @@ def test_paged_full_length_bit_parity(lm_pair):
     np.testing.assert_array_equal(got, want)
 
 
+# Paged and padded harvest are two different compiled programs (packed
+# token plane + per-document attention vs one padded batch), so XLA is
+# free to fuse and associate their float32 reductions differently:
+# agreement is to a few ulps, not bitwise (observed max |diff| 3.0e-6 on
+# O(1) activations through 4 layers, jax 0.9.0 CPU). 2e-5 is ~100 float32
+# ulps at that magnitude and ~200x below one bfloat16 rounding step
+# (2^-8 of the value, ~4e-3), so a silent precision drop still fails.
+_TWO_LOWERINGS_ATOL = 2e-5
+
+
 def test_paged_mixed_length_parity(lm_pair):
     """Mixed-length chunk incl. a single-token and a max-length document:
-    hook activations at valid positions are bitwise equal to the padded
-    forward; pad positions come back zeroed (the valid-length mask)."""
+    hook activations at valid positions equal the padded forward's to
+    float32 round-off (``_TWO_LOWERINGS_ATOL``); pad positions come back
+    exactly zero (the valid-length mask)."""
     cfg, params = lm_pair
     rng = np.random.default_rng(4)
     lengths = np.array([1, SEQ, 7, 3, 9, 5])
@@ -245,8 +256,9 @@ def test_paged_mixed_length_parity(lm_pair):
     got = np.asarray(lm.run_with_cache_multi_paged(
         params, tokens, lengths, cfg, HOOKS, page_size=8), np.float32)
     for d, ln in enumerate(lengths):
-        np.testing.assert_array_equal(
-            got[d, :ln], want[d, :ln], err_msg=f"doc {d}"
+        np.testing.assert_allclose(
+            got[d, :ln], want[d, :ln], rtol=0, atol=_TWO_LOWERINGS_ATOL,
+            err_msg=f"doc {d}"
         )
         assert np.all(got[d, ln:] == 0.0)
 

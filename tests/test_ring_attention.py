@@ -7,7 +7,6 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from crosscoder_tpu.parallel import shard_map_compat as shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from crosscoder_tpu.models import lm
@@ -50,7 +49,7 @@ def test_ring_attention_matches_dense(is_local):
     v = jax.random.normal(ks[2], (B, S, KV, hd))
     scale, softcap, window = 0.35, 50.0, 16
 
-    ring = shard_map(
+    ring = jax.shard_map(
         lambda q, k, v: ring_attention(
             q, k, v, axis_name="data", n_shards=n, scale=scale,
             softcap=softcap, sliding_window=window, is_local=is_local,
@@ -76,7 +75,7 @@ def _lowered_text(n_shards: int) -> str:
     q = jax.random.normal(ks[0], (B, S, H, hd))
     k = jax.random.normal(ks[1], (B, S, KV, hd))
     v = jax.random.normal(ks[2], (B, S, KV, hd))
-    ring = shard_map(
+    ring = jax.shard_map(
         lambda q, k, v: ring_attention(
             q, k, v, axis_name="data", n_shards=n_shards, scale=0.5,
             softcap=30.0, sliding_window=8, is_local=False,
@@ -111,7 +110,7 @@ def test_ring_attention_single_shard_degenerates():
     q = jax.random.normal(ks[0], (B, S, H, hd))
     k = jax.random.normal(ks[1], (B, S, KV, hd))
     v = jax.random.normal(ks[2], (B, S, KV, hd))
-    ring = shard_map(
+    ring = jax.shard_map(
         lambda q, k, v: ring_attention(
             q, k, v, axis_name="data", n_shards=1, scale=0.5),
         mesh=mesh, in_specs=(P(), P(), P()), out_specs=P(), check_vma=False,

@@ -65,10 +65,21 @@ def test_served_bitwise_parity_mixed_lengths(stack):
         assert r.idx.dtype == np.int32 and r.vals.shape == (cfg.topk_k,)
 
 
+# Bucket 4 and bucket 1 are two different compiled programs (different
+# batch shapes), so the float32 matmul/reduction association may differ:
+# the served activations agree to round-off, not bitwise (observed max
+# |diff| 3.6e-7 on O(0.5) values, jax 0.9.0 CPU). 5e-6 is ~100 float32
+# ulps there and ~400x below one bfloat16 rounding step (~2e-3), so a
+# precision drop — or a pad row leaking into a real row — still fails.
+# The selected latents and their diff scores stay exact.
+_TWO_BUCKETS_ATOL = 5e-6
+
+
 def test_bucket_padding_invisible(stack):
     """A partial batch rides a padded bucket (3 requests → bucket 4 with
-    one dummy row); each request's result is bitwise what the request
-    gets served alone — pad rows never leak into real rows."""
+    one dummy row); each request's result is what the request gets
+    served alone (same latents, activations to float32 round-off) —
+    pad rows never leak into real rows."""
     eng, cfg, lm_cfg, _, _ = stack
     rng = np.random.default_rng(1)
     docs = _docs(rng, lm_cfg, [5, SEQ, 2])
@@ -77,7 +88,8 @@ def test_bucket_padding_invisible(stack):
     for doc, r in zip(docs, together):
         solo = serve_batch(eng, [doc])[0]
         assert solo.bucket == 1
-        np.testing.assert_array_equal(r.vals, solo.vals)
+        np.testing.assert_allclose(r.vals, solo.vals, rtol=0,
+                                   atol=_TWO_BUCKETS_ATOL)
         np.testing.assert_array_equal(r.idx, solo.idx)
         np.testing.assert_array_equal(r.diff, solo.diff)
 
