@@ -735,7 +735,7 @@ def section_refill_overlap() -> dict:
     refill"): the ``e2e`` harvest→buffer→train leg run with
     ``refill_overlap`` off vs on, at fine (SEG_LAYERS=3) and coarse
     (SEG_LAYERS=14) harvest segmentation. Per leg: the measured refill
-    bubble fraction (obs ``take_blocked_s() / wall`` — exactly what
+    bubble fraction (obs ``refill_wait`` span total / wall — exactly what
     ``perf/refill_bubble_frac`` logs), the max/median step ratio (the
     refresh spike), and acts/s/chip. Gate (ISSUE 14 acceptance): with
     overlap ON, bubble_frac <= 0.10 AND acts/s no worse than overlap-off
@@ -802,7 +802,7 @@ def section_refill_overlap() -> dict:
                 _sync(m["loss"])
                 m = trainer.step(full_metrics=False)
                 _sync(m["loss"])
-                trainer._obs.take_blocked_s()   # reset the accumulator
+                trainer._obs.tracer.take_interval()     # reset the span totals
                 # per-step sync on every step of both arms: its cost
                 # cancels in the A/B, and per-step times expose the
                 # refresh spike as max - median
@@ -814,7 +814,8 @@ def section_refill_overlap() -> dict:
                     _sync(m["loss"])
                     times.append(1000 * (time.perf_counter() - t1))
                 wall = time.perf_counter() - t0
-                blocked = trainer._obs.take_blocked_s()
+                blocked = trainer._obs.tracer.take_interval().get(
+                    "refill_wait", (0.0, 0))[0]
                 trainer.close()
                 median_ms = sorted(times)[len(times) // 2]
                 leg = {
@@ -1017,7 +1018,7 @@ def section_obs() -> dict:
     round so tracer cost can never silently regress:
 
     - **spans/s**: raw SpanTracer record throughput (enter + exit +
-      event append + registry EMA);
+      event append + per-name total);
     - **per-step overhead**: the Trainer stepped with obs off vs on at the
       reference shape on a fixed pre-generated batch (so both arms time
       step dispatch + telemetry, not synthetic-data generation). Gate:

@@ -497,17 +497,22 @@ class CrossCoderConfig:
                                     # overhead is 4/quant_block bytes/elem
     # --- observability (crosscoder_tpu/obs; docs/OBSERVABILITY.md) ---
     # Everything off by default and ZERO-COST off: with obs="off" the
-    # compiled train step is byte-identical to a build without the plane
-    # and no additional host↔device transfer happens anywhere (asserted
-    # in tests/test_obs.py).
-    obs: str = "off"                # "on": span tracer (Chrome trace-event
+    # compiled train step does not depend on these knobs, nothing is
+    # constructed and no additional host↔device transfer happens anywhere
+    # (asserted in tests/test_obs.py).
+    obs: str = "off"                # "on": ONE telemetry plane for the job,
+                                    # created by whichever of make_buffer
+                                    # and Trainer runs first: span tracer on
+                                    # time.perf_counter (Chrome trace-event
                                     # JSON under obs_dir, Perfetto-viewable,
                                     # host spans wrapped in jax.profiler
-                                    # TraceAnnotations), perf/* + comm/*
-                                    # registry metrics in the log stream
-                                    # (incl. perf/refill_bubble_frac),
-                                    # compile-event reporting, SIGUSR1
-                                    # profiler windows
+                                    # TraceAnnotations; set-up included),
+                                    # perf/* + comm/* metrics in the log
+                                    # stream — span time as per-log-interval
+                                    # totals perf/span/<name>_s|_n with
+                                    # perf/interval_s|_steps, and
+                                    # perf/refill_bubble_frac — compile-event
+                                    # reporting, SIGUSR1 profiler windows
     obs_dir: str = ""               # telemetry output dir; default
                                     # <checkpoint_dir>/obs (trace.json,
                                     # profile/ windows)

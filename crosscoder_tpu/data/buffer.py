@@ -52,7 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from crosscoder_tpu import native
+from crosscoder_tpu import native, obs
 from crosscoder_tpu.config import CrossCoderConfig
 from crosscoder_tpu.models import lm
 from crosscoder_tpu.obs import trace
@@ -292,7 +292,8 @@ class PairedActivationBuffer:
         if not lazy:
             # lazy=True defers calibration+fill to load_state_dict() so a
             # resumed run doesn't harvest the whole buffer twice
-            self.normalisation_factor = self._estimate_norm_scaling_factors()
+            with trace.span("calibrate"):
+                self.normalisation_factor = self._estimate_norm_scaling_factors()
             self.refresh()
 
     def _alloc_store(self) -> None:
@@ -452,12 +453,16 @@ class PairedActivationBuffer:
         ``buffer.py:121-122``) becomes a sub-batch-sized bubble.
         """
         self._quiesce_dispatch()
-        num_batches = (
-            self.buffer_batches if self.first else self._refill_batches()
-        )
-        self.first = False
-        self._begin_cycle(num_batches)
-        self._finish_cycle()
+        first, self.first = self.first, False
+        if not first:
+            self._begin_cycle(self._refill_batches())
+            self._finish_cycle()
+            return
+        # span site (docs/OBSERVABILITY.md): the synchronous whole-buffer
+        # fill — the largest part of set-up the program can shorten
+        with trace.span("first_fill", batches=self.buffer_batches):
+            self._begin_cycle(self.buffer_batches)
+            self._finish_cycle()
 
     # -- incremental refill cycle ---------------------------------------
     #
@@ -696,11 +701,12 @@ class PairedActivationBuffer:
         # drain work — on the refill-dispatch thread when offloaded, on
         # the serve thread when pumped inline (multi-process)
         with trace.span("refill_dispatch", credit=credit):
-            while credit > 0:
-                used = self._dispatch_quanta(credit)
-                if used == 0:
-                    break
-                credit -= used
+            with trace.span("harvest_dispatch", credit=credit):
+                while credit > 0:
+                    used = self._dispatch_quanta(credit)
+                    if used == 0:
+                        break
+                    credit -= used
             while self._head_drainable():
                 with trace.span("harvest"):
                     self._drain_one()
@@ -750,8 +756,11 @@ class PairedActivationBuffer:
             return
         with pipeline.sharded_program_guard():
             credit = self._cyc_segs_per_serve
-            while credit > 0 and self._step_job():
-                credit -= 1
+            # span site: this serve's paced harvest dispatches — where the
+            # producer parks when the device's launch queue pushes back
+            with trace.span("harvest_dispatch", credit=credit):
+                while credit > 0 and self._step_job():
+                    credit -= 1
             while self._head_drainable():
                 # span site (docs/OBSERVABILITY.md): one harvest chunk
                 # landing (device fetch + store scatter) — a no-op unless
@@ -776,8 +785,9 @@ class PairedActivationBuffer:
                 pipeline.sharded_program_guard():
             while (self._cyc_seq_done < self._cyc_batches
                    or self._cyc_job is not None):
-                advanced = (self._dispatch_quanta(1 << 30) if self._cyc_shadow
-                            else self._step_job())
+                with trace.span("harvest_dispatch"):
+                    advanced = (self._dispatch_quanta(1 << 30) if self._cyc_shadow
+                                else self._step_job())
                 if not advanced:            # depth window full: free a slot
                     with trace.span("harvest"):
                         self._drain_one()
@@ -846,8 +856,9 @@ class PairedActivationBuffer:
         factors applied (reference ``buffer.py:115-125``). Gather, upcast,
         and scale run as one fused native pass when the C++ kernels are
         available (:mod:`crosscoder_tpu.native`)."""
-        idx = self._next_idx()
-        out = native.gather_scale_f32(self._store, idx, self.normalisation_factor)
+        with trace.span("serve_gather"):
+            idx = self._next_idx()
+            out = native.gather_scale_f32(self._store, idx, self.normalisation_factor)
         self._after_serve()
         return out
 
@@ -861,8 +872,9 @@ class PairedActivationBuffer:
         which is numerically identical to the reference's host-side
         ``acts.float() * factor`` (reference ``buffer.py:123-124``).
         """
-        idx = self._next_idx()
-        out = native.gather_rows(self._store, idx)
+        with trace.span("serve_gather"):
+            idx = self._next_idx()
+            out = native.gather_rows(self._store, idx)
         self._after_serve()
         return out
 
@@ -1106,6 +1118,10 @@ def make_buffer(cfg: CrossCoderConfig, lm_cfg, model_params, tokens,
                    else DevicePairedActivationBuffer)
     elif cfg.quant_buffer:
         cls = QuantPairedActivationBuffer
+    # the job's telemetry plane is born here when the buffer is built first
+    # (every entry point does): calibration and the first fill run inside
+    # the constructor, and the Trainer adopts — and closes — the same plane
+    obs.acquire(cfg)
     return cls(cfg, lm_cfg, model_params, tokens, **kwargs)
 
 
@@ -1114,11 +1130,13 @@ def make_buffer(cfg: CrossCoderConfig, lm_cfg, model_params, tokens,
 
 
 @jax.jit
+@jax.named_scope("store/gather")
 def _dev_gather(store: jax.Array, idx: jax.Array) -> jax.Array:
     return store[idx]
 
 
 @functools.partial(jax.jit, donate_argnums=0)
+@jax.named_scope("store/scatter")
 def _dev_scatter(store: jax.Array, positions: jax.Array, acts: jax.Array) -> jax.Array:
     """In-place (donated) row scatter of one harvest chunk.
 
@@ -1226,7 +1244,7 @@ class DevicePairedActivationBuffer(PairedActivationBuffer):
         """fp32 normalized batch, DEVICE-resident."""
         # the serve gather is a sharded program too (mesh variant:
         # psum_scatter) — same XLA:CPU concurrency guard as the refill
-        with pipeline.sharded_program_guard():
+        with trace.span("serve_gather"), pipeline.sharded_program_guard():
             out = self._gather_rows(self._next_idx())
             out = out.astype(jnp.float32) * jnp.asarray(
                 self.normalisation_factor
@@ -1238,7 +1256,7 @@ class DevicePairedActivationBuffer(PairedActivationBuffer):
     def next_raw(self) -> jax.Array:
         """Raw bf16 batch, DEVICE-resident (the trainer's fast path — the
         step applies the norm factors on device)."""
-        with pipeline.sharded_program_guard():
+        with trace.span("serve_gather"), pipeline.sharded_program_guard():
             out = self._gather_rows(self._next_idx())
             pipeline.finish_on_cpu(out)
         self._after_serve()
@@ -1273,6 +1291,7 @@ def _mesh_store_ops(mesh, rows_local: int, acts_sharded: bool):
 
     acts_spec = P("data", None, None, None) if acts_sharded else P()
 
+    @jax.named_scope("store/scatter")
     def scatter(store, positions, acts):
         rows = acts[:, 1:].reshape(-1, acts.shape[2], acts.shape[3])
         if acts_sharded:
@@ -1290,6 +1309,7 @@ def _mesh_store_ops(mesh, rows_local: int, acts_sharded: bool):
             rows.astype(store.dtype), mode="drop", unique_indices=True
         )
 
+    @jax.named_scope("store/gather")
     def gather(store, idx):
         my = jax.lax.axis_index("data")
         li = idx - my * rows_local
@@ -1453,6 +1473,7 @@ def _quant_chunk(acts: jax.Array, block: int) -> tuple[jax.Array, jax.Array]:
 
 
 @functools.partial(jax.jit, static_argnums=(4,), donate_argnums=(0, 1))
+@jax.named_scope("store/scatter")
 def _dev_scatter_quant(
     store_q: jax.Array, store_s: jax.Array, positions: jax.Array,
     acts: jax.Array, block: int,
@@ -1470,6 +1491,7 @@ def _dev_scatter_quant(
 
 
 @jax.jit
+@jax.named_scope("store/gather")
 def _dev_gather_dequant(
     store_q: jax.Array, store_s: jax.Array, idx: jax.Array
 ) -> jax.Array:
@@ -1530,15 +1552,17 @@ class QuantPairedActivationBuffer(PairedActivationBuffer):
         )
 
     def next(self) -> np.ndarray:
-        idx = self._next_idx()
-        out = self._gather_dequant(idx, np.float32)
-        out *= self.normalisation_factor[None, :, None]
+        with trace.span("serve_gather"):
+            idx = self._next_idx()
+            out = self._gather_dequant(idx, np.float32)
+            out *= self.normalisation_factor[None, :, None]
         self._after_serve()
         return out
 
     def next_raw(self) -> np.ndarray:
-        idx = self._next_idx()
-        out = self._gather_dequant(idx, _BF16)
+        with trace.span("serve_gather"):
+            idx = self._next_idx()
+            out = self._gather_dequant(idx, _BF16)
         self._after_serve()
         return out
 
@@ -1605,6 +1629,7 @@ def _mesh_store_ops_quant(mesh, rows_local: int, acts_sharded: bool, block: int)
 
     acts_spec = P("data", None, None, None) if acts_sharded else P()
 
+    @jax.named_scope("store/scatter")
     def scatter(store_q, store_s, positions, acts):
         rows = acts[:, 1:].reshape(-1, acts.shape[2], acts.shape[3])
         q, s = quant.quantize_rows(rows, block)
@@ -1620,6 +1645,7 @@ def _mesh_store_ops_quant(mesh, rows_local: int, acts_sharded: bool, block: int)
         store_s = store_s.at[local].set(s, mode="drop", unique_indices=True)
         return store_q, store_s
 
+    @jax.named_scope("store/gather")
     def gather(store_q, store_s, idx):
         my = jax.lax.axis_index("data")
         li = idx - my * rows_local

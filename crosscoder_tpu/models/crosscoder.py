@@ -99,6 +99,7 @@ def init_params(key: jax.Array, cfg: CrossCoderConfig, dtype: jnp.dtype | None =
     return params
 
 
+@jax.named_scope("cc/encode")
 def pre_acts(params: Params, x: jax.Array) -> jax.Array:
     """Encoder pre-activations: ``x @ W_enc + b_enc`` summed over sources.
 
@@ -155,6 +156,7 @@ def calibrate_batchtopk_threshold(
     return float(np.mean(vals))
 
 
+@jax.named_scope("cc/decode")
 def decode(params: Params, f: jax.Array) -> jax.Array:
     """Reconstruction ``[..., n_sources, d_in]`` from latents ``[..., d_hidden]``
     (reference ``crosscoder.py:82-89``)."""
@@ -281,6 +283,24 @@ _sparse_decode_product.defvjp(_sparse_decode_fwd, _sparse_decode_bwd)
 # reference crosscoder.py:82-89).
 
 
+def _select_decode(
+    h: jax.Array, W_dec: jax.Array, k: int
+) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """The factored tiers' shared forward tail: kernel mask → sparsify →
+    k-row decode. Returns ``(f [B,H], vals [B,k], idx [B,k], recon [B,n,d]
+    f32 without b_dec)``; the scopes name the two halves in a device trace."""
+    from crosscoder_tpu.ops import topk_pallas
+
+    with jax.named_scope("cc/select"):
+        f = topk_pallas.topk(h, k)
+        vals, idx = topk_pallas.sparsify(f, k)
+    with jax.named_scope("cc/decode"):
+        w = jnp.take(W_dec, idx, axis=0)                   # [B, k, n, d]
+        recon = jnp.einsum("bk,bknd->bnd", vals, w,
+                           preferred_element_type=jnp.float32)
+    return f, vals, idx, recon
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def _factored_topk_forward(
     h: jax.Array, W_dec: jax.Array, k: int
@@ -294,22 +314,12 @@ def _factored_topk_forward(
     when nothing differentiable consumes them (the dispatch in get_losses
     guarantees l1_coeff == 0 on this path; metric-only uses are fine).
     """
-    from crosscoder_tpu.ops import topk_pallas
-
-    f = topk_pallas.topk(h, k)
-    vals, idx = topk_pallas.sparsify(f, k)
-    w = jnp.take(W_dec, idx, axis=0)                       # [B, k, n, d]
-    recon = jnp.einsum("bk,bknd->bnd", vals, w, preferred_element_type=jnp.float32)
+    _, vals, idx, recon = _select_decode(h, W_dec, k)
     return recon, vals, idx
 
 
 def _factored_topk_fwd(h, W_dec, k):
-    from crosscoder_tpu.ops import topk_pallas
-
-    f = topk_pallas.topk(h, k)
-    vals, idx = topk_pallas.sparsify(f, k)
-    w = jnp.take(W_dec, idx, axis=0)                       # [B, k, n, d]
-    recon = jnp.einsum("bk,bknd->bnd", vals, w, preferred_element_type=jnp.float32)
+    f, vals, idx, recon = _select_decode(h, W_dec, k)
     # f is the residual: both backward matmuls consume the masked [B,H]
     # activations (dW_dec contraction + the straight-through mask on df)
     return (recon, vals, idx), (f, W_dec)
@@ -319,15 +329,17 @@ def _factored_topk_bwd(k, res, g):
     f, W_dec = res
     g_recon = g[0].astype(jnp.float32)                     # [B, n, d]
     # cotangents g[1], g[2] (vals, idx) are ignored — see docstring
-    dW_dec = jnp.einsum(
-        "bh,bnd->hnd", f.astype(jnp.float32), g_recon,
-        preferred_element_type=jnp.float32,
-    ).astype(W_dec.dtype)
-    df = jnp.einsum(
-        "bnd,hnd->bh", g_recon, W_dec.astype(jnp.float32),
-        preferred_element_type=jnp.float32,
-    )
-    dh = jnp.where(f > 0, df, 0.0).astype(f.dtype)
+    with jax.named_scope("cc/decode"):      # a custom vjp: JAX names nothing here
+        dW_dec = jnp.einsum(
+            "bh,bnd->hnd", f.astype(jnp.float32), g_recon,
+            preferred_element_type=jnp.float32,
+        ).astype(W_dec.dtype)
+        df = jnp.einsum(
+            "bnd,hnd->bh", g_recon, W_dec.astype(jnp.float32),
+            preferred_element_type=jnp.float32,
+        )
+    with jax.named_scope("cc/select"):
+        dh = jnp.where(f > 0, df, 0.0).astype(f.dtype)
     return dh, dW_dec
 
 
@@ -376,16 +388,11 @@ def _sparse_topk_step(
     """``(recon [B,n,d] f32 (no b_dec), vals [B,k], idx [B,k])`` from the
     batch ``x [B,n,d]`` — encode + TopK + factored decode in one
     custom-vjp scope so the backward never leaves factored form."""
-    from crosscoder_tpu.ops import topk_pallas
-
-    hf = jnp.einsum("bnd,ndh->bh", x, W_enc,
-                    preferred_element_type=jnp.float32)
-    h = (hf + b_enc.astype(jnp.float32)).astype(x.dtype)
-    f = topk_pallas.topk(h, k)
-    vals, idx = topk_pallas.sparsify(f, k)
-    w = jnp.take(W_dec, idx, axis=0)                       # [B, k, n, d]
-    recon = jnp.einsum("bk,bknd->bnd", vals, w,
-                       preferred_element_type=jnp.float32)
+    with jax.named_scope("cc/encode"):
+        hf = jnp.einsum("bnd,ndh->bh", x, W_enc,
+                        preferred_element_type=jnp.float32)
+        h = (hf + b_enc.astype(jnp.float32)).astype(x.dtype)
+    _, vals, idx, recon = _select_decode(h, W_dec, k)
     return recon, vals, idx
 
 
@@ -628,13 +635,7 @@ def _sparse_topk_from_h(
     replaced by the scatter/gather pair. ``dh`` is materialized [B, H]
     (one scatter) because ``h`` has other consumers on this path (the
     AuxK ranking) — the full-step variant above avoids even that."""
-    from crosscoder_tpu.ops import topk_pallas
-
-    f = topk_pallas.topk(h, k)
-    vals, idx = topk_pallas.sparsify(f, k)
-    w = jnp.take(W_dec, idx, axis=0)
-    recon = jnp.einsum("bk,bknd->bnd", vals, w,
-                       preferred_element_type=jnp.float32)
+    _, vals, idx, recon = _select_decode(h, W_dec, k)
     return recon, vals, idx
 
 
@@ -929,11 +930,12 @@ def get_losses(
         f = act_ops.apply(h, cfg, params)
         recon = decode(params, f)
 
-    xf = x.astype(jnp.float32)
-    rf = recon.astype(jnp.float32)
-    err2 = jnp.square(rf - xf)                            # [B, n, d]
-    l2_per_row = jnp.sum(err2, axis=(-2, -1))             # [B]
-    l2_loss = jnp.mean(l2_per_row)
+    with jax.named_scope("cc/loss"):
+        xf = x.astype(jnp.float32)
+        rf = recon.astype(jnp.float32)
+        err2 = jnp.square(rf - xf)                        # [B, n, d]
+        l2_per_row = jnp.sum(err2, axis=(-2, -1))         # [B]
+        l2_loss = jnp.mean(l2_per_row)
 
     # L1 is an objective term only when l1_coeff != 0 (TopK-style runs set it
     # to 0 and control sparsity structurally); off log-steps
@@ -941,19 +943,20 @@ def get_losses(
     # [H, n] decoder-norm reduce plus a full [B, H] weighted sweep, ~2-3 ms
     # of the bare TopK step at dict 2^15 — so it is gated exactly like the
     # other metric-only reductions and returns 0 in that slot.
-    need_l1 = with_metrics or cfg.l1_coeff != 0
-    if need_l1:
-        dec_norms = jnp.linalg.norm(params["W_dec"].astype(jnp.float32), axis=-1)  # [H, n]
-        total_dec_norm = jnp.sum(dec_norms, axis=-1)      # [H]
-    if not need_l1:
-        l1_loss = jnp.zeros((), jnp.float32)
-    elif sparse:
-        # identical to the dense weighted L1: inactive latents contribute 0
-        w_active = jnp.take(total_dec_norm, idx)          # [B, k]
-        l1_loss = jnp.mean(jnp.sum(vals.astype(jnp.float32) * w_active, axis=-1))
-    else:
-        ff = f.astype(jnp.float32)
-        l1_loss = jnp.mean(jnp.sum(ff * total_dec_norm[None, :], axis=-1))
+    with jax.named_scope("cc/loss"):
+        need_l1 = with_metrics or cfg.l1_coeff != 0
+        if need_l1:
+            dec_norms = jnp.linalg.norm(params["W_dec"].astype(jnp.float32), axis=-1)  # [H, n]
+            total_dec_norm = jnp.sum(dec_norms, axis=-1)      # [H]
+        if not need_l1:
+            l1_loss = jnp.zeros((), jnp.float32)
+        elif sparse:
+            # identical to the dense weighted L1: inactive latents contribute 0
+            w_active = jnp.take(total_dec_norm, idx)          # [B, k]
+            l1_loss = jnp.mean(jnp.sum(vals.astype(jnp.float32) * w_active, axis=-1))
+        else:
+            ff = f.astype(jnp.float32)
+            l1_loss = jnp.mean(jnp.sum(ff * total_dec_norm[None, :], axis=-1))
 
     # --- AuxK (cfg.aux_k > 0; Gao et al. 2024 "Scaling and evaluating
     # sparse autoencoders", the standard TopK-SAE dead-latent recipe; no
@@ -1050,21 +1053,22 @@ def get_losses(
             fired=fired,
         )
 
-    eps = 1e-8
-    centered = xf - jnp.mean(xf, axis=0, keepdims=True)
-    tot_var = jnp.sum(jnp.square(centered), axis=(-2, -1))  # [B]
-    explained_variance = 1.0 - l2_per_row / (tot_var + eps)
+    with jax.named_scope("cc/loss"):
+        eps = 1e-8
+        centered = xf - jnp.mean(xf, axis=0, keepdims=True)
+        tot_var = jnp.sum(jnp.square(centered), axis=(-2, -1))  # [B]
+        explained_variance = 1.0 - l2_per_row / (tot_var + eps)
 
-    # per-source EV (reference computes _A and _B separately,
-    # crosscoder.py:115-121); vectorized over the source axis here
-    l2_per_source = jnp.sum(err2, axis=-1)                # [B, n]
-    var_per_source = jnp.sum(jnp.square(centered), axis=-1)  # [B, n]
-    ev_per_source = 1.0 - l2_per_source / (var_per_source + eps)  # [B, n]
+        # per-source EV (reference computes _A and _B separately,
+        # crosscoder.py:115-121); vectorized over the source axis here
+        l2_per_source = jnp.sum(err2, axis=-1)                # [B, n]
+        var_per_source = jnp.sum(jnp.square(centered), axis=-1)  # [B, n]
+        ev_per_source = 1.0 - l2_per_source / (var_per_source + eps)  # [B, n]
 
-    if sparse:
-        l0_loss = jnp.mean(jnp.sum((vals > 0).astype(jnp.float32), axis=-1))
-    else:
-        l0_loss = jnp.mean(jnp.sum((f > 0).astype(jnp.float32), axis=-1))
+        if sparse:
+            l0_loss = jnp.mean(jnp.sum((vals > 0).astype(jnp.float32), axis=-1))
+        else:
+            l0_loss = jnp.mean(jnp.sum((f > 0).astype(jnp.float32), axis=-1))
 
     return LossOutput(
         l2_loss=l2_loss,

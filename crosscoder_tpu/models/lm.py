@@ -259,6 +259,7 @@ def _attn_core(
     )
 
 
+@jax.named_scope("harvest/block/attn")
 def _attention(
     x: jax.Array, lp: Mapping[str, jax.Array], cfg: LMConfig, is_local: jax.Array
 ) -> jax.Array:
@@ -270,6 +271,7 @@ def _attention(
     return jnp.einsum("bsq,qd->bsd", out, lp["wo"], preferred_element_type=jnp.float32).astype(x.dtype)
 
 
+@jax.named_scope("harvest/block/mlp")
 def _mlp(x: jax.Array, lp: Mapping[str, jax.Array]) -> jax.Array:
     """GeGLU: gelu_tanh(x·W_gate) ⊙ (x·W_up) · W_down."""
     gate = jnp.einsum("bsd,df->bsf", x, lp["w_gate"], preferred_element_type=jnp.float32)
@@ -438,7 +440,8 @@ def _forward_impl(
     if n_scan is None:
         n_scan = cfg.n_layers
 
-    resid = params["embed"][tokens].astype(dt) * jnp.asarray(math.sqrt(D), dt)
+    with jax.named_scope("harvest/embed"):
+        resid = params["embed"][tokens].astype(dt) * jnp.asarray(math.sqrt(D), dt)
 
     n_cap = len(capture)
     cap_arr = jnp.asarray([l for l, _ in capture], dtype=jnp.int32) if n_cap else None
@@ -620,7 +623,8 @@ def ce_loss(
 def _seg_start_impl(params: LMParams, tokens: jax.Array, cfg: LMConfig, n_cap: int):
     B, S = tokens.shape
     dt = dtype_of(cfg.dtype)
-    resid = params["embed"][tokens].astype(dt) * jnp.asarray(math.sqrt(cfg.d_model), dt)
+    with jax.named_scope("harvest/embed"):
+        resid = params["embed"][tokens].astype(dt) * jnp.asarray(math.sqrt(cfg.d_model), dt)
     buf = jnp.zeros((n_cap, B, S, cfg.d_model), dt)
     return resid, buf
 

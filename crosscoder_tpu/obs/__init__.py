@@ -1,12 +1,20 @@
 """Unified telemetry plane (``cfg.obs``; docs/OBSERVABILITY.md).
 
 One object — :class:`Observability` — owns the three telemetry channels
-and their lifecycle:
+and their lifecycle. It belongs to the JOB, not to the Trainer:
+:func:`acquire` is called by whichever of ``make_buffer`` and
+``Trainer.__init__`` runs first with ``cfg.obs == "on"`` and creates the
+plane (keyed by the resolved ``obs_dir``); the later caller gets the same
+object, so calibration and the first fill are traced into the same file
+as the loop. ``Trainer.close()`` closes it.
 
 - a :class:`~crosscoder_tpu.obs.trace.SpanTracer` installed as the
   process-global tracer, so the span sites in the buffer, checkpointer,
   and watchdog light up without those objects growing constructor
-  parameters; spans feed per-name EMA timers into the registry;
+  parameters; every span is stamped with ``time.perf_counter_ns`` (one
+  clock with any harness around the job), and span time reaches the log
+  stream as per-log-interval totals (:meth:`Observability.publish_interval`:
+  ``perf/span/<name>_s``/``_n``, ``perf/interval_s``/``_steps``);
 - a :class:`~crosscoder_tpu.obs.registry.MetricsRegistry` whose snapshot
   the Trainer merges into the metrics stream (``perf/*`` and ``comm/*``
   keys) exactly like the resilience counters — the resilience channel is
@@ -21,8 +29,8 @@ and their lifecycle:
   drift between the PR-2 wire-byte model and the program actually running
   is visible in every log line.
 
-Off by default: with ``cfg.obs == "off"`` the Trainer never constructs
-this object, every library span site hits the shared
+Off by default: with ``cfg.obs == "off"`` nothing constructs this
+object, every library span site hits the shared
 :class:`~crosscoder_tpu.obs.trace.NullTracer` no-op, the compiled step
 HLO is byte-identical to a build without the plane, and zero additional
 host↔device transfers occur (regression-tested in tests/test_obs.py).
@@ -39,10 +47,36 @@ from crosscoder_tpu.obs.registry import MetricsRegistry
 from crosscoder_tpu.obs.trace import NullTracer, SpanTracer
 
 
+def resolved_dir(cfg: Any) -> str:
+    """Where the job's telemetry lands: ``cfg.obs_dir``, or ``obs/`` under
+    the checkpoint directory."""
+    return cfg.obs_dir or os.path.join(cfg.checkpoint_dir, "obs")
+
+
+# the live planes by resolved directory: one per job
+_PLANES: dict[str, "Observability"] = {}
+
+
+def acquire(cfg: Any, mesh: Any | None = None) -> "Observability | None":
+    """The job's telemetry plane: created by the first caller, returned to
+    every later one with the same resolved ``obs_dir`` until it is closed;
+    None (and nothing constructed) unless ``cfg.obs == "on"``. ``mesh``
+    (the comm accounting's) is taken from the first caller that has one."""
+    if cfg.obs != "on":
+        return None
+    key = resolved_dir(cfg)
+    plane = _PLANES.get(key)
+    if plane is None:
+        plane = _PLANES[key] = Observability(cfg, mesh=mesh)
+    elif plane.mesh is None:
+        plane.mesh = mesh
+    return plane
+
+
 class Observability:
     def __init__(self, cfg: Any, mesh: Any | None = None) -> None:
         self.cfg = cfg
-        self.out_dir = cfg.obs_dir or os.path.join(cfg.checkpoint_dir, "obs")
+        self.out_dir = resolved_dir(cfg)
         self.registry = MetricsRegistry()
         # per-process trace file: on a multi-host pod with a shared
         # checkpoint_dir, every process traces its own host threads
@@ -53,26 +87,34 @@ class Observability:
         except Exception:
             idx = 0
         name = "trace.json" if idx == 0 else f"trace.p{idx}.json"
-        self.tracer = SpanTracer(
-            os.path.join(self.out_dir, name), registry=self.registry
-        )
+        self.tracer = SpanTracer(os.path.join(self.out_dir, name))
         self._prev_tracer = trace.set_tracer(self.tracer)
         self.mesh = mesh
-        # refill-wait accumulator: nanoseconds the train loop spent blocked
-        # on batch production since the last log point (the numerator of
-        # perf/refill_bubble_frac)
-        self._blocked_ns = 0
+        # the last published log interval's perf/span/* and perf/interval_*
+        # keys; replaced whole at every log step, so a span name that did
+        # not occur in an interval is absent from its line, not stale
+        self._interval: dict[str, float] = {}
         self._closed = False
 
-    # -- refill-bubble accounting (trainer hot path) --------------------
-    def add_blocked_ns(self, ns: int) -> None:
-        self._blocked_ns += ns
+    # -- span time per log interval --------------------------------------
+    def publish_interval(self, t0_ns: int, t1_ns: int, steps: int) -> None:
+        """Close the log interval ``[t0_ns, t1_ns]`` of ``steps`` steps:
+        record its ``log_interval`` span and turn the span totals since
+        the last log step into the keys the next :meth:`snapshot` carries.
+        ``perf/refill_bubble_frac`` is ``refill_wait`` over the interval."""
+        self.tracer.complete("log_interval", t0_ns, t1_ns, steps=steps)
+        totals = self.tracer.take_interval()
+        wall_s = max(totals.pop("log_interval")[0], 1e-9)
+        out = {"perf/interval_s": wall_s, "perf/interval_steps": float(steps)}
+        for name, (s, n) in totals.items():
+            out[f"perf/span/{name}_s"] = s
+            out[f"perf/span/{name}_n"] = float(n)
+        out["perf/refill_bubble_frac"] = totals.get("refill_wait", (0.0, 0))[0] / wall_s
+        self._interval = out
 
-    def take_blocked_s(self) -> float:
-        """Blocked-on-refill seconds since the last call (log-interval
-        reset)."""
-        ns, self._blocked_ns = self._blocked_ns, 0
-        return ns / 1e9
+    def snapshot(self) -> dict[str, float]:
+        """The registry's scalars plus the last published interval's."""
+        return {**self.registry.snapshot(), **self._interval}
 
     # -- compile/comm observability -------------------------------------
     def observe_step(self, key: str, jit_fn: Any, *,
@@ -138,12 +180,15 @@ class Observability:
         if self._closed:
             return
         self._closed = True
+        if _PLANES.get(self.out_dir) is self:
+            del _PLANES[self.out_dir]
         trace.set_tracer(self._prev_tracer)
         self.tracer.close()
 
 
 __all__ = [
     "Observability",
+    "acquire",
     "MetricsRegistry",
     "NullTracer",
     "SpanTracer",

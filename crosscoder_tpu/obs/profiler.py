@@ -25,6 +25,11 @@ device round-trip on the fast path.
 While a window closes, per-device HBM stats (``jax.local_devices()``
 ``memory_stats``) land in the registry as ``perf/hbm_*`` gauges — absent
 on backends that report none (CPU), populated on TPU.
+
+Two spans put the window on the span clock (docs/OBSERVABILITY.md):
+``profile_window`` from ``start_trace`` to the return of ``stop_trace``,
+and ``profile_stop`` around the sync and ``stop_trace`` themselves — the
+seconds the stop holds the loop are a named span, not a long step.
 """
 
 from __future__ import annotations
@@ -32,7 +37,10 @@ from __future__ import annotations
 import os
 import signal
 import threading
+import time
 from typing import Any, Callable
+
+from crosscoder_tpu.obs import trace
 
 
 def parse_profile_steps(spec: str) -> tuple[int, int] | None:
@@ -72,6 +80,7 @@ class ProfilerWindow:
         self._resolved: tuple[int, int] | None = self._window
         self._pending_sig = 0           # SIGUSR1-requested steps
         self._active = False
+        self._t_start_ns = 0            # perf_counter_ns at start_trace
         self.windows_captured = 0
         self._prev_handler: Any = None
 
@@ -112,6 +121,7 @@ class ProfilerWindow:
         if self._resolved is not None and step == self._resolved[0]:
             import jax
 
+            self._t_start_ns = time.perf_counter_ns()
             jax.profiler.start_trace(self.out_dir)
             self._active = True
 
@@ -132,9 +142,12 @@ class ProfilerWindow:
     def _stop(self, sync: Callable[[], Any] | None) -> None:
         import jax
 
-        if sync is not None:
-            sync()              # device execution must have LANDED in the trace
-        jax.profiler.stop_trace()
+        with trace.span("profile_stop"):
+            if sync is not None:
+                sync()          # device execution must have LANDED in the trace
+            jax.profiler.stop_trace()
+        trace.get_tracer().complete(
+            "profile_window", self._t_start_ns, time.perf_counter_ns())
         self._active = False
         self.windows_captured += 1
         if self.registry is not None:

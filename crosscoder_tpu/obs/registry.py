@@ -1,7 +1,7 @@
-"""Typed metrics registry: counters, gauges, EMA timers, histograms.
+"""Typed metrics registry: counters, gauges, histograms.
 
 Generalizes :class:`crosscoder_tpu.utils.logging.ResilienceCounters` (a
-lock + monotone int dict) to the four shapes performance telemetry needs,
+lock + monotone int dict) to the three shapes performance telemetry needs,
 under the same two contracts that made the resilience channel safe to
 merge into the reference's metric stream:
 
@@ -22,11 +22,13 @@ Shapes and their snapshot forms:
 
 - ``count(k)``: monotone counter → ``{k: int}`` (zero counts are dropped);
 - ``gauge(k, v)``: last-value gauge → ``{k: float}``;
-- ``ema(k, v)``: exponential moving average (the cheap "typical duration"
-  for per-span timings — O(1) state, outlier-resistant) → ``{k: float}``;
 - ``observe(k, v)``: bounded histogram (last ``HIST_CAP`` observations)
   → ``{k_p50, k_p99, k_max, k_n}`` — the tail-attribution shape for
-  bubble/stall hunting, where an EMA would average the spike away.
+  bubble/stall hunting, where a mean would average the spike away.
+
+Span durations are not recorded here: the tracer keeps them as additive
+per-log-interval totals (``obs/trace.py`` ``take_interval``), which the
+plane publishes beside this registry's snapshot.
 """
 
 from __future__ import annotations
@@ -36,13 +38,11 @@ import threading
 
 class MetricsRegistry:
     HIST_CAP = 4096     # observations kept per histogram (ring buffer)
-    EMA_ALPHA = 0.1     # ~ the last 10 observations dominate
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
         self._counts: dict[str, int] = {}
         self._gauges: dict[str, float] = {}
-        self._emas: dict[str, float] = {}
         self._hists: dict[str, list[float]] = {}
         self._hist_pos: dict[str, int] = {}
 
@@ -54,14 +54,6 @@ class MetricsRegistry:
     def gauge(self, key: str, value: float) -> None:
         with self._lock:
             self._gauges[key] = float(value)
-
-    def ema(self, key: str, value: float, alpha: float | None = None) -> None:
-        a = self.EMA_ALPHA if alpha is None else alpha
-        with self._lock:
-            prev = self._emas.get(key)
-            self._emas[key] = float(value) if prev is None else (
-                (1.0 - a) * prev + a * float(value)
-            )
 
     def observe(self, key: str, value: float) -> None:
         with self._lock:
@@ -90,7 +82,6 @@ class MetricsRegistry:
         with self._lock:
             out: dict[str, float] = {k: v for k, v in self._counts.items() if v}
             out.update(self._gauges)
-            out.update(self._emas)
             for k, h in self._hists.items():
                 if not h:
                     continue

@@ -16,23 +16,30 @@ primitive: a context-manager span that
   the HOST spans line up with the DEVICE timeline in xprof — the
   correlation that turns "the step got slower" into "the step got slower
   because the refill drain ran under it";
-- optionally feeds a :class:`~crosscoder_tpu.obs.registry.MetricsRegistry`
-  (EMA duration + call counter per span name under ``perf/``), so span
-  timings ride the ordinary metrics stream without separate plumbing.
+- adds its duration to a per-name total that the Trainer takes and resets
+  at every log step (:meth:`SpanTracer.take_interval`), so span time rides
+  the ordinary metrics stream as ADDITIVE per-interval totals
+  (``perf/span/<name>_s`` / ``_n``) — a span is credited to the interval
+  it ends in.
+
+Every ``ts`` is ``time.perf_counter_ns() / 1e3`` with no private epoch
+(Perfetto takes any origin), so the span file joins anything else stamped
+with ``time.perf_counter()`` in the same process — a harness's own log
+stamps, for one — without an offset.
 
 Library code records spans through the module-level :func:`span` /
 :func:`instant` hooks, which delegate to a process-global tracer that
 defaults to :class:`NullTracer` — a shared no-op context manager, so with
 observability off (the default) a span site costs one global load and one
 attribute call, touches no lock, allocates nothing, and transfers nothing.
-:class:`~crosscoder_tpu.obs.Observability` installs a real tracer for the
-run's duration and restores the null tracer on close.
+:func:`crosscoder_tpu.obs.acquire` installs a real tracer for the JOB —
+whichever of ``make_buffer`` and ``Trainer.__init__`` runs first creates
+the plane, so set-up (``calibrate``, ``first_fill``, ``init_state``) is
+traced too — and ``Trainer.close()`` restores the null tracer.
 
-Span taxonomy (docs/OBSERVABILITY.md): ``step`` (train-step dispatch),
-``refill_wait`` (train loop blocked on batch production), ``harvest`` (one
-chunk's fetch+scatter landing), ``refill`` (cycle completion at the serve
-trigger), ``save`` / ``save_write`` / ``restore`` (checkpoint), and
-``compile`` (step-variant compilation).
+The span taxonomy, with each span's thread and cause, is the table in
+docs/OBSERVABILITY.md (``lint-span-taxonomy`` holds every literal span
+name to it).
 """
 
 from __future__ import annotations
@@ -69,6 +76,9 @@ class NullTracer:
         return _NULL_SPAN
 
     def instant(self, name: str, /, **args: Any) -> None:
+        return None
+
+    def complete(self, name: str, t0_ns: int, t1_ns: int, /, **args: Any) -> None:
         return None
 
     def flush(self) -> None:
@@ -117,6 +127,10 @@ class SpanTracer:
     Thread-safe: spans may open/close concurrently on any thread; each
     event carries its recording thread's id so Perfetto renders one track
     per thread (main loop, batch-prefetch, ckpt-writer, watchdog).
+
+    Beside the event list the tracer keeps per-name totals (nanoseconds
+    and count) of the spans that ENDED since the last
+    :meth:`take_interval` — what the Trainer publishes at each log step.
     """
 
     enabled = True
@@ -127,13 +141,12 @@ class SpanTracer:
     # truncated trace can never read as a complete one
     MAX_EVENTS = 500_000
 
-    def __init__(self, path: str | Path, registry: Any | None = None,
+    def __init__(self, path: str | Path,
                  process_name: str = "crosscoder_tpu") -> None:
         self.path = Path(path)
-        self.registry = registry
         self.dropped = 0
         self._lock = threading.Lock()
-        self._epoch_ns = time.perf_counter_ns()
+        self._totals: dict[str, list[int]] = {}     # name -> [ns, count]
         self._pid = os.getpid()
         self._events: list[dict[str, Any]] = [{
             "name": "process_name", "ph": "M", "pid": self._pid, "tid": 0,
@@ -153,7 +166,7 @@ class SpanTracer:
     def instant(self, name: str, /, **args: Any) -> None:
         ev: dict[str, Any] = {
             "name": name, "ph": "i", "s": "t",
-            "ts": (time.perf_counter_ns() - self._epoch_ns) / 1e3,
+            "ts": time.perf_counter_ns() / 1e3,
             "pid": self._pid, "tid": threading.get_ident() & 0xFFFFFFFF,
         }
         if args:
@@ -164,11 +177,19 @@ class SpanTracer:
             else:
                 self.dropped += 1
 
+    def complete(self, name: str, t0_ns: int, t1_ns: int, /, **args: Any) -> None:
+        """Record a span from two ``perf_counter_ns`` readings the caller
+        took itself — for a span that straddles loop iterations
+        (``log_interval``) and so cannot be a ``with`` block. No
+        ``TraceAnnotation``: the profiler cannot be told of a span after
+        the fact."""
+        self._record(name, t0_ns, t1_ns - t0_ns, args)
+
     def _record(self, name: str, t0_ns: int, dur_ns: int,
                 args: dict[str, Any]) -> None:
         ev: dict[str, Any] = {
             "name": name, "ph": "X", "cat": "host",
-            "ts": (t0_ns - self._epoch_ns) / 1e3,
+            "ts": t0_ns / 1e3,
             "dur": dur_ns / 1e3,
             "pid": self._pid, "tid": threading.get_ident() & 0xFFFFFFFF,
         }
@@ -179,9 +200,20 @@ class SpanTracer:
                 self._events.append(ev)
             else:
                 self.dropped += 1
-        if self.registry is not None:
-            self.registry.ema(f"perf/{name}_ms", dur_ns / 1e6)
-            self.registry.count(f"perf/{name}_spans")
+            tot = self._totals.get(name)
+            if tot is None:
+                self._totals[name] = [dur_ns, 1]
+            else:
+                tot[0] += dur_ns
+                tot[1] += 1
+
+    def take_interval(self) -> dict[str, tuple[float, int]]:
+        """``{name: (seconds, count)}`` of the spans that ended since the
+        last call, and reset — totals, so they add up (over names against
+        the interval's wall, over intervals against the run's)."""
+        with self._lock:
+            totals, self._totals = self._totals, {}
+        return {name: (ns / 1e9, n) for name, (ns, n) in totals.items()}
 
     # -- inspection / output -------------------------------------------
     def events(self) -> list[dict[str, Any]]:

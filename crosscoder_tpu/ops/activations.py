@@ -248,8 +248,11 @@ def batchtopk_fixed(h: jax.Array, threshold: float,
     return hp * jax.lax.stop_gradient(mask.astype(hp.dtype))
 
 
+@jax.named_scope("cc/select")
 def apply(h: jax.Array, cfg: "CrossCoderConfig", params: dict | None = None) -> jax.Array:
-    """Dispatch on ``cfg.activation``."""
+    """Dispatch on ``cfg.activation``. Whichever activation it is, its ops
+    carry the scope ``cc/select`` in the compiled program (the TopK family
+    selects; ReLU and JumpReLU gate)."""
     if cfg.activation == "relu":
         return relu(h)
     if cfg.activation == "topk":

@@ -380,7 +380,9 @@ class InferenceEngine:
 
         cfg = self.cfg
         t0 = time.perf_counter()
-        with trace.span("prefill", bucket=b):
+        # (the scope names the prefill's ops in a device trace; it takes
+        # effect when the bucket's program is traced, i.e. on a build)
+        with trace.span("prefill", bucket=b), jax.named_scope("serve/prefill"):
             caps = lm.paged_capture_aot(
                 self._lm_params, chunk, self.lm_cfg, self._hooks,
                 page_size=cfg.page_size, pad_mode="zero",

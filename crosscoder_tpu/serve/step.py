@@ -30,6 +30,7 @@ from crosscoder_tpu.utils.dtypes import dtype_of
 @functools.partial(
     jax.jit, static_argnames=("enc_dtype", "k", "fused", "pair")
 )
+@jax.named_scope("serve/encode_topk_diff")
 def encode_topk_diff(
     params, captures, lengths, norm, *, enc_dtype: str, k: int,
     fused: bool, pair: tuple[int, int],

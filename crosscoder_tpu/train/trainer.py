@@ -41,6 +41,7 @@ import numpy as np
 import optax
 from jax.sharding import NamedSharding, PartitionSpec
 
+from crosscoder_tpu import obs
 from crosscoder_tpu.config import CrossCoderConfig
 from crosscoder_tpu.models import crosscoder as cc
 from crosscoder_tpu.parallel import mesh as mesh_lib
@@ -173,8 +174,9 @@ def make_step_body(
         """Shared tail: optimizer update, aux bookkeeping, metric dict.
         ``mets`` carries the loss surface pieces (already globally reduced
         on the quantized path)."""
-        updates, new_opt = tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("cc/adam"):
+            updates, new_opt = tx.update(grads, state.opt_state, state.params)
+            new_params = optax.apply_updates(state.params, updates)
         metrics = {
             "loss": loss,
             "l2_loss": mets["l2_loss"],
@@ -330,8 +332,14 @@ def make_step_body(
                 "l1_input (fleet stacked step) is incompatible with "
                 "quant_grads' shard_map path"
             )
-        return step_fn_l1
-    return quant_step_fn if use_qgrads else step_fn
+        fn = step_fn_l1
+    else:
+        fn = quant_step_fn if use_qgrads else step_fn
+    # the jitted program takes the function's name: say which variant ran
+    # (XLA module ``jit_step_fn_bare`` / ``jit_step_fn_full``), so a device
+    # trace tells the two apart — trace-time metadata only
+    fn.__name__ += "_full" if with_metrics else "_bare"
+    return fn
 
 
 def make_train_step(
@@ -455,11 +463,9 @@ class Trainer:
         # globally (buffer/checkpointer/watchdog spans light up), perf/*
         # and comm/* registry metrics merge into the log stream, and step
         # compiles are AOT'd + reported via utils.compile_cache.observed.
-        self._obs = None
-        if cfg.obs == "on":
-            from crosscoder_tpu.obs import Observability
-
-            self._obs = Observability(cfg, mesh=self.mesh)
+        # The plane belongs to the job: make_buffer has usually created it
+        # already (set-up is traced too) and this adopts it; close() ends it.
+        self._obs = obs.acquire(cfg, mesh=self.mesh)
         # persistent AOT disk tier (cfg.compile_cache_dir; docs/SCALING.md
         # "Persistent compile cache"): off (the default) configures
         # nothing and every compile path below stays byte-identical
@@ -471,14 +477,15 @@ class Trainer:
         self._batch_dtype = None
 
         self._tx = tx = make_optimizer(cfg, schedules.lr_schedule(cfg))
-        # n_data pins the quant_grads error-feedback residual shapes to
-        # THIS mesh (checkpoints of quant runs restore on a same-width mesh)
-        state = init_train_state(
-            jax.random.key(cfg.seed), cfg, tx,
-            n_data=int(self.mesh.shape.get("data", 1)),
-        )
-        self._state_shardings = mesh_lib.state_shardings(self.mesh, state, cfg.shard_sources)
-        self.state = multihost.put_global(state, self._state_shardings)
+        with trace.span("init_state"):
+            # n_data pins the quant_grads error-feedback residual shapes to
+            # THIS mesh (checkpoints of quant runs restore on a same-width mesh)
+            state = init_train_state(
+                jax.random.key(cfg.seed), cfg, tx,
+                n_data=int(self.mesh.shape.get("data", 1)),
+            )
+            self._state_shardings = mesh_lib.state_shardings(self.mesh, state, cfg.shard_sources)
+            self.state = multihost.put_global(state, self._state_shardings)
         # the sparse backward plane's dispatch is static per cfg/batch —
         # announce it once so runs record WHICH backward they measured
         # (cfg.sparse_bwd="auto" silently stays dense off-TPU / without
@@ -521,6 +528,10 @@ class Trainer:
         self._prefetch_pool = None
         self._pending = None
         self._buffer_snapshot = None
+        # id of the current refill_wait span (one per step): a production
+        # names the wait that consumes it, so the trace joins the producer
+        # thread's ``produce`` spans to the main thread's waits
+        self._wait_seq = 0
         # Narrows the window of interleaved jax enqueues between the main
         # thread (step) and the prefetch worker (batch device_put). JAX
         # dispatch is documented thread-safe — the buffer's own harvest
@@ -679,7 +690,8 @@ class Trainer:
             return contextlib.nullcontext()
         return self._sequencer.turn(ticket)
 
-    def _produce_batch(self, ticket: int | None = None) -> tuple[jax.Array, jax.Array]:
+    def _produce_batch(self, ticket: int | None = None,
+                       wait: int | None = None) -> tuple[jax.Array, jax.Array]:
         """Gather the next batch and start its host→device transfer.
 
         Runs on the prefetch worker when prefetching is on. Raw-bf16 serving
@@ -691,8 +703,12 @@ class Trainer:
         ticketed (multi-process) run the whole production executes under
         its reserved launch slot — the serve gather's collectives then
         land in the pod-wide enqueue order the ticket fixed.
+
+        The ``produce`` span brackets the whole production on whichever
+        thread runs it; ``wait`` is the id of the ``refill_wait`` span (on
+        the main thread) that will consume this batch — its cause.
         """
-        with self._launch_turn(ticket):
+        with trace.span("produce", wait=wait), self._launch_turn(ticket):
             serve = self._serve_count
             self._serve_count += 1
             if self._watchdog is not None:
@@ -708,7 +724,7 @@ class Trainer:
                 return (multihost.put_global(batch, self._batch_sharding),
                         self._device_scale())
 
-    def _submit_prefetch(self) -> None:
+    def _submit_prefetch(self, wait: int) -> None:
         # Stream-state snapshot BEFORE producing the next batch: a checkpoint
         # written while batch i+1 sits prefetched must record the stream at
         # position i+1's start, or resume would skip that batch (the buffer
@@ -717,7 +733,8 @@ class Trainer:
             self._buffer_snapshot = self.buffer.state_dict()
         ticket = self._reserve_ticket()
         try:
-            self._pending = self._prefetch_pool.submit(self._produce_batch, ticket)
+            self._pending = self._prefetch_pool.submit(
+                self._produce_batch, ticket, wait)
         except BaseException:
             if ticket is not None:
                 # a reservation that never runs would wedge every later
@@ -729,16 +746,17 @@ class Trainer:
         """The consumed batch plus the launch ticket for the step that will
         train on it (None on unticketed runs)."""
         if self._prefetch_pool is None:
-            return self._produce_batch(), self._reserve_ticket()
+            return (self._produce_batch(wait=self._wait_seq),
+                    self._reserve_ticket())
         if self._pending is None:
-            self._submit_prefetch()
+            self._submit_prefetch(self._wait_seq)
         out = self._pending.result()
         # reserve the step's launch slot BEFORE submitting the next
         # production: the step's enqueue then precedes the worker's in the
         # pod-wide launch order, so the production overlaps the step's
         # device execution instead of serializing in front of it
         ticket = self._reserve_ticket()
-        self._submit_prefetch()
+        self._submit_prefetch(self._wait_seq + 1)     # the next wait's batch
         return out, ticket
 
     def _drain_prefetch(self, discard: bool = False) -> None:
@@ -812,16 +830,12 @@ class Trainer:
                 cfg, self.mesh, self._tx, self._state_shardings,
                 with_metrics=key[0], aux_on=key[1], mask_refresh=key[2],
             ))
-        if self._obs is not None:
-            # refill_wait: the train loop blocked on batch production —
-            # the numerator of perf/refill_bubble_frac. With prefetch on
-            # this is only the non-overlapped residue of harvest/refill
-            # (the bubble); with it off, the full production time.
-            t_wait = time.perf_counter_ns()
-            with self._obs.tracer.span("refill_wait"):
-                (batch, scale), ticket = self._next_batch()
-            self._obs.add_blocked_ns(time.perf_counter_ns() - t_wait)
-        else:
+        # refill_wait: the train loop blocked on batch production — the
+        # numerator of perf/refill_bubble_frac. With prefetch on this is
+        # only the non-overlapped residue of the production (where a
+        # device-bound loop parks); with it off, the full production time.
+        self._wait_seq += 1
+        with trace.span("refill_wait", id=self._wait_seq):
             (batch, scale), ticket = self._next_batch()
         if self._batch_dtype is None:
             # the dtype the stream actually serves — the remesh prewarm
@@ -857,15 +871,11 @@ class Trainer:
             # its collectives must not execute concurrently with another
             # sharded program (a second trainer's step, a producer thread's
             # harvest) — see pipeline.sharded_program_guard
-            if self._obs is not None:
-                with self._dispatch_lock, pipeline.sharded_program_guard(), \
-                        self._obs.tracer.span("step", step=self._host_step):
-                    self.state, metrics = fn(self.state, batch, scale)
-                    pipeline.finish_on_cpu((self.state, metrics))
-            else:
-                with self._dispatch_lock, pipeline.sharded_program_guard():
-                    self.state, metrics = fn(self.state, batch, scale)
-                    pipeline.finish_on_cpu((self.state, metrics))
+            with self._dispatch_lock, pipeline.sharded_program_guard(), \
+                    trace.span("step", step=self._host_step,
+                               variant="full" if key[0] else "bare"):
+                self.state, metrics = fn(self.state, batch, scale)
+                pipeline.finish_on_cpu((self.state, metrics))
         if n_resampled is not None:
             metrics["resampled"] = n_resampled
         self._host_step += 1
@@ -889,7 +899,7 @@ class Trainer:
             # perf/* + comm/* telemetry (cfg.obs="on" only; an untouched
             # registry snapshots to {} exactly like the resilience channel)
             if self._obs is not None:
-                scalars.update(self._obs.registry.snapshot())
+                scalars.update(self._obs.snapshot())
             self.logger.log(scalars, step)
 
     # --- divergence guard + rollback (cfg.guard_loss; docs/resilience.md) --
@@ -1472,12 +1482,12 @@ class Trainer:
                 rolled_back = False
                 start = self.step_counter  # nonzero after restore()/rollback
                 progress = _progress_bar(start, num_steps)
-                last_log_t, last_log_i = time.perf_counter(), start
+                last_log_ns, last_log_i = time.perf_counter_ns(), start
                 if self._obs is not None:
-                    # drop refill waits accumulated before a rollback
-                    # restarted the stretch — the first post-rollback
-                    # bubble gauge must cover only its own log interval
-                    self._obs.take_blocked_s()
+                    # drop span time accumulated before the stretch (set-up;
+                    # the steps a rollback abandoned) — the first interval's
+                    # totals must cover only its own log interval
+                    self._obs.tracer.take_interval()
                 if profiler is not None:
                     profiler.begin_stretch(start)
                 try:
@@ -1523,7 +1533,8 @@ class Trainer:
                         if i % self.cfg.log_every == 0:
                             # the fetch is the loop's one device sync per log
                             # interval (and the value the guard and logger need)
-                            loss_val = float(jax.device_get(metrics["loss"]))
+                            with trace.span("log_sync"):
+                                loss_val = float(jax.device_get(metrics["loss"]))
                             if self._obs is not None:
                                 self._obs.registry.count("comm/d2h_transfers")
                             if guard and self._loss_diverged(loss_val):
@@ -1538,23 +1549,21 @@ class Trainer:
                                 self._rollback(i)
                                 rolled_back = True
                                 break
-                            now = time.perf_counter()
+                            now_ns = time.perf_counter_ns()
+                            n_steps = max(i - last_log_i, 1)
                             metrics = dict(metrics)
-                            metrics["step_time_ms"] = 1000 * (now - last_log_t) / max(i - last_log_i, 1)
+                            metrics["step_time_ms"] = (now_ns - last_log_ns) / 1e6 / n_steps
                             if self._obs is not None:
-                                # refill-bubble attribution: the fraction of
-                                # this log interval's wall-clock the loop spent
-                                # BLOCKED on batch production (VERDICT r5's
-                                # refill-bubble criterion, now measurable in
-                                # every run rather than only in bench phase B)
-                                wall_s = max(now - last_log_t, 1e-9)
-                                reg = self._obs.registry
-                                reg.gauge("perf/step_wall_ms", metrics["step_time_ms"])
-                                reg.gauge(
-                                    "perf/refill_bubble_frac",
-                                    min(1.0, self._obs.take_blocked_s() / wall_s),
-                                )
-                            last_log_t, last_log_i = now, i
+                                # the log interval closes here, after the loss
+                                # fetch: its span, and the span time since the
+                                # last log step as per-interval totals — among
+                                # them the fraction of the interval the loop
+                                # spent BLOCKED on batch production
+                                # (perf/refill_bubble_frac)
+                                self._obs.registry.gauge(
+                                    "perf/step_wall_ms", metrics["step_time_ms"])
+                                self._obs.publish_interval(last_log_ns, now_ns, n_steps)
+                            last_log_ns, last_log_i = now_ns, i
                             self.log(metrics, step=i)
                         if (i + 1) % self.cfg.save_every == 0:
                             # background: the file write overlaps subsequent steps;

@@ -17,7 +17,7 @@ This package closes the loop with a two-stage search:
   docs/SCALING.md refill/harvest cost models for the data-plane knobs.
 - **Stage 2 (measured)** — :mod:`crosscoder_tpu.tune.calibrate` runs the
   top-K candidates as short calibration windows through the real Trainer,
-  scoring with the PR-5 span EMAs (``perf/step_ms``) and the refill
+  scoring with the mean ``step`` span (PR 5's tracer) and the refill
   bubble fraction, with every candidate mechanically gated by the
   contracts engine — a tuned config that violates a contract is
   discarded (counted under ``tune/rejected_contract``), not shipped.

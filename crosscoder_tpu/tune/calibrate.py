@@ -4,7 +4,7 @@ Stage 1 ranks on a model; stage 2 believes only what it measures. Each
 surviving candidate runs a short window through the REAL Trainer (the
 production step, refill engine, prefetch worker — nothing mocked),
 scored with the PR-5 telemetry the run would log anyway: the
-``perf/step_ms`` span EMA and the refill bubble fraction. Before any
+mean ``step`` span and the refill bubble fraction. Before any
 candidate is measured it passes the contracts gate — its step lowering
 is checked against the full HLO rule set plus one tune-specific
 identity: the candidate must lower byte-identically to its projection
@@ -103,8 +103,8 @@ def measure_window(cfg: Any, *, steps: int = 6, warmup: int = 2,
     The window runs with ``obs="on"`` regardless of the candidate's own
     obs setting (the telemetry IS the measurement; obs overhead is flat
     across candidates so the ranking is unbiased) into throwaway
-    checkpoint/obs dirs, logging nothing. Scoring: the ``perf/step_ms``
-    span EMA over the post-warmup steps, inflated by the measured refill
+    checkpoint/obs dirs, logging nothing. Scoring: the mean ``step``
+    span over the post-warmup steps, inflated by the measured refill
     bubble — ``effective_ms = step_ms / (1 - bubble)`` — so a candidate
     whose data-plane knobs starve the step loop loses even when its
     device program is fast. Score is acts/s/chip on the effective rate.
@@ -124,18 +124,18 @@ def measure_window(cfg: Any, *, steps: int = 6, warmup: int = 2,
             for _ in range(max(1, warmup)):
                 m = tr.step(full_metrics=False)
             jax.block_until_ready(m["loss"])
-            tr._obs.take_blocked_s()            # reset the bubble clock
+            tr._obs.tracer.take_interval()      # reset the span totals
             t0 = time.perf_counter()
             for _ in range(max(1, steps)):
                 m = tr.step(full_metrics=False)
             jax.block_until_ready(m["loss"])
             wall_s = max(1e-9, time.perf_counter() - t0)
-            blocked_s = tr._obs.take_blocked_s()
-            snap = tr._obs.registry.snapshot()
+            spans = tr._obs.tracer.take_interval()
         finally:
             tr.close()
-    step_ms = float(snap.get("perf/step_ms",
-                             1e3 * wall_s / max(1, steps)))
+    blocked_s = spans.get("refill_wait", (0.0, 0))[0]
+    step_s, n = spans.get("step", (wall_s, max(1, steps)))
+    step_ms = 1e3 * step_s / max(1, n)
     bubble = min(0.95, max(0.0, blocked_s / wall_s))
     effective_ms = step_ms / (1.0 - bubble)
     score = cfg.batch_size * 1e3 / (effective_ms * max(1, n_devices))

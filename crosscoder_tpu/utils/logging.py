@@ -42,8 +42,8 @@ class ResilienceCounters:
     keys; an untouched instance snapshots to ``{}``, so runs with no
     faults log exactly the reference's scalar surface.
 
-    The observability plane generalizes this shape to counters/gauges/EMA
-    timers/histograms (:class:`crosscoder_tpu.obs.registry.MetricsRegistry`,
+    The observability plane generalizes this shape to counters/gauges/histograms
+    (:class:`crosscoder_tpu.obs.registry.MetricsRegistry`,
     the ``perf/*``/``comm/*`` channels — docs/OBSERVABILITY.md); the
     resilience counters stay a separate instance because they must exist
     (and stay zero-cost) even when ``cfg.obs`` is off.

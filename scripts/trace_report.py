@@ -2,7 +2,10 @@
 """Summarize a crosscoder_tpu Chrome trace-event file without Perfetto.
 
 ``python scripts/trace_report.py <trace.json>`` prints one table row per
-span name — count, total ms, p50/p99/max — plus the refill-bubble
+span name — count, total ms, SELF ms (a span less the spans nested in it
+on its own thread: for ``produce``, the producer's host work once its
+device calls ``serve_gather`` / ``harvest_dispatch`` / ``harvest`` are
+taken out), p50/p99/max — plus the refill-bubble
 fraction (total ``refill_wait`` time over total ``step`` time: the
 fraction of train-loop step wall-clock spent blocked on batch
 production), so a trace captured on an air-gapped pod answers "where did
@@ -71,6 +74,28 @@ def _pct(sorted_vals: list[float], q: float) -> float:
     return sorted_vals[min(len(sorted_vals) - 1, int(len(sorted_vals) * q))]
 
 
+def self_ms(events: list[dict]) -> dict[str, float]:
+    """Total self time (ms) per span name: each span's duration less what
+    its direct children cover, a child being a span that lies inside it on
+    the same thread (the tracer records nesting by time, not by id). Only
+    ratios and differences of ``ts`` are used, so any clock origin does."""
+    by_thread: dict[tuple, list[dict]] = {}
+    for ev in events:
+        if ev.get("ph") == "X":
+            by_thread.setdefault((ev.get("pid"), ev.get("tid")), []).append(ev)
+    out: dict[str, float] = {}
+    for spans in by_thread.values():
+        stack: list[dict] = []          # the open spans, outermost first
+        for ev in sorted(spans, key=lambda e: (e["ts"], -e["dur"])):
+            while stack and stack[-1]["ts"] + stack[-1]["dur"] <= ev["ts"]:
+                stack.pop()
+            if stack and ev["ts"] + ev["dur"] <= stack[-1]["ts"] + stack[-1]["dur"]:
+                out[stack[-1]["name"]] -= ev["dur"] / 1e3
+            out[ev["name"]] = out.get(ev["name"], 0.0) + ev["dur"] / 1e3
+            stack.append(ev)
+    return out
+
+
 def summarize(events: list[dict]) -> tuple[list[dict], float | None]:
     """Per-span-name stats (ms) + the bubble fraction (None when the trace
     has no ``step`` spans to attribute against)."""
@@ -78,6 +103,7 @@ def summarize(events: list[dict]) -> tuple[list[dict], float | None]:
     for ev in events:
         if ev.get("ph") == "X":
             by_name.setdefault(ev["name"], []).append(ev["dur"] / 1e3)  # µs→ms
+    own = self_ms(events)
     rows = []
     for name in sorted(by_name, key=lambda n: -sum(by_name[n])):
         durs = sorted(by_name[name])
@@ -85,6 +111,7 @@ def summarize(events: list[dict]) -> tuple[list[dict], float | None]:
             "span": name,
             "count": len(durs),
             "total_ms": sum(durs),
+            "self_ms": own[name],
             "p50_ms": _pct(durs, 0.50),
             "p99_ms": _pct(durs, 0.99),
             "max_ms": durs[-1],
@@ -114,12 +141,13 @@ def main(argv: list[str] | None = None) -> int:
         print("trace_report: no complete ('X') span events in trace",
               file=sys.stderr)
         return 1
-    hdr = f"{'span':<16} {'count':>7} {'total_ms':>12} {'p50_ms':>10} {'p99_ms':>10} {'max_ms':>10}"
+    hdr = (f"{'span':<16} {'count':>7} {'total_ms':>12} {'self_ms':>12} "
+           f"{'p50_ms':>10} {'p99_ms':>10} {'max_ms':>10}")
     print(hdr)
     print("-" * len(hdr))
     for r in rows:
         print(f"{r['span']:<16} {r['count']:>7} {r['total_ms']:>12.2f} "
-              f"{r['p50_ms']:>10.3f} {r['p99_ms']:>10.3f} {r['max_ms']:>10.3f}")
+              f"{r['self_ms']:>12.2f} {r['p50_ms']:>10.3f} {r['p99_ms']:>10.3f} {r['max_ms']:>10.3f}")
     if bubble is not None:
         print(f"\nrefill_bubble_frac: {bubble:.4f}  "
               f"(refill_wait / (step + refill_wait) totals)")

@@ -10,6 +10,8 @@
 - profiler windows: exact [start, stop) capture, SIGUSR1 arming, legacy
   profile_dir behavior
 - compile events + predicted-vs-measured comm keys in the log stream
+- one plane per job on perf_counter: set-up spans in the loop's file, span
+  time as per-interval totals, scope and variant names in the lowered programs
 - scripts/trace_report.py summary + malformed-trace exit code
 - scripts/check_metric_keys.py namespace lint
 - MetricsLogger satellites: stdout stays clean, stderr echo cadence,
@@ -144,14 +146,15 @@ def test_registry_shapes_snapshot():
     r.count("perf/things")
     r.count("perf/things", 2)
     r.gauge("perf/level", 0.5)
-    r.ema("perf/lat_ms", 10.0)
-    r.ema("perf/lat_ms", 20.0)
+    r.gauge("perf/lat_ms", 10.0)
+    r.gauge("perf/lat_ms", 20.0)
     for v in [1.0, 2.0, 3.0, 100.0]:
         r.observe("perf/hist", v)
     snap = r.snapshot()
     assert snap["perf/things"] == 3
     assert snap["perf/level"] == 0.5
-    assert 10.0 < snap["perf/lat_ms"] < 20.0        # EMA moved toward 20
+    assert snap["perf/lat_ms"] == 20.0              # a gauge keeps the last value
+    assert not hasattr(r, "ema")        # span time is per-interval totals now
     assert snap["perf/hist_n"] == 4
     assert snap["perf/hist_p50"] == 3.0
     assert snap["perf/hist_p99"] == 100.0
@@ -242,7 +245,9 @@ def test_obs_on_logs_compile_and_comm_keys(tmp_path):
     rec = lines[-1]
     assert rec["perf/compiles"] >= 1
     assert rec["perf/compile_s_p50"] > 0
-    assert "perf/step_ms" in rec and rec["perf/step_ms"] > 0
+    assert rec["perf/span/step_s"] > 0 and rec["perf/span/step_n"] == 2
+    assert not any(k.endswith(("_ms", "_spans")) and k != "perf/step_wall_ms"
+                   for k in rec if k.startswith("perf/")), sorted(rec)
     # predicted (comm model on the ACTUAL compiled step) next to measured
     assert "comm/predicted_wire_bytes" in rec
     assert rec["comm/h2d_transfers"] >= 5
@@ -569,3 +574,240 @@ def test_config_validates_obs_fields():
     with pytest.raises(ValueError):
         tiny_cfg(profile_steps="7:3")
     tiny_cfg(obs="on", profile_steps="3:9")     # valid combos construct
+
+
+# ---------------------------------------------------------------------------
+# one plane per job, one clock, per-interval totals, names in the programs
+
+
+def _tiny_lm_job(tmp_path, **kw):
+    """cfg, tiny LM pair and tokens for a harvest -> buffer -> train job."""
+    from crosscoder_tpu.models import lm
+
+    lm_cfg = lm.LMConfig.tiny()
+    params = [lm.init_params(jax.random.key(i), lm_cfg) for i in (0, 1)]
+    tokens = np.random.default_rng(7).integers(0, 257, size=(256, 17))
+    cfg = CrossCoderConfig(**{**dict(
+        batch_size=32, buffer_mult=8, seq_len=17, d_in=32, n_models=2,
+        dict_size=64, model_batch_size=4, norm_calib_batches=2,
+        hook_point="blocks.2.hook_resid_pre", seed=3, log_backend="null",
+        num_tokens=32 * 40, save_every=10**9, checkpoint_dir=str(tmp_path),
+    ), **kw})
+    return cfg, lm_cfg, params, tokens
+
+
+def test_one_plane_covers_setup_and_loop_in_one_file(tmp_path):
+    """make_buffer (first) creates the plane, the Trainer adopts it: the
+    set-up spans precede the first step in the same trace.json, and
+    Trainer.close() hands the null tracer back."""
+    from crosscoder_tpu import obs
+    from crosscoder_tpu.data.buffer import make_buffer
+
+    cfg, lm_cfg, params, tokens = _tiny_lm_job(tmp_path, obs="on", log_every=3)
+    buffer = make_buffer(cfg, lm_cfg, params, tokens)
+    plane = obs.acquire(cfg)
+    assert plane is not None and trace.get_tracer() is plane.tracer
+    tr = Trainer(cfg, buffer)
+    assert tr._obs is plane
+    tr.train(num_steps=7)
+    assert isinstance(trace.get_tracer(), NullTracer)
+    assert obs.acquire(cfg.replace(obs="off")) is None
+    files = sorted(p.name for p in (tmp_path / "obs").iterdir())
+    assert files == ["trace.json"]
+    spans = [e for e in json.loads((tmp_path / "obs" / "trace.json").read_text())
+             ["traceEvents"] if e["ph"] == "X"]
+    first_step = min(e["ts"] for e in spans if e["name"] == "step")
+    for name in ("calibrate", "first_fill", "init_state"):
+        ends = [e["ts"] + e["dur"] for e in spans if e["name"] == name]
+        assert len(ends) == 1 and ends[0] <= first_step, name
+    names = {e["name"] for e in spans}
+    assert {"produce", "serve_gather", "harvest_dispatch", "harvest", "refill",
+            "log_interval", "log_sync", "refill_wait"} <= names
+    # a production names the wait that consumed it, across threads
+    waits = {e["args"]["id"]: e for e in spans if e["name"] == "refill_wait"}
+    produced = [e for e in spans if e["name"] == "produce"]
+    assert {e["args"]["wait"] for e in produced} >= set(waits)
+    assert any(e["tid"] != waits[e["args"]["wait"]]["tid"] for e in produced
+               if e["args"]["wait"] in waits)
+    # step spans say which variant ran: log steps 0, 3, 6 are full
+    variants = [e["args"]["variant"] for e in sorted(
+        (e for e in spans if e["name"] == "step"), key=lambda e: e["ts"])]
+    assert variants == ["full", "bare", "bare"] * 2 + ["full"]
+    # serve_gather and harvest_dispatch nest inside a produce, by time
+    for child in (e for e in spans if e["name"] in ("serve_gather", "harvest_dispatch")
+                  and e["ts"] > first_step):
+        assert any(p["tid"] == child["tid"] and p["ts"] <= child["ts"]
+                   and child["ts"] + child["dur"] <= p["ts"] + p["dur"] + 1e-3
+                   for p in produced), child
+
+
+def test_span_timestamps_are_perf_counter(tmp_path):
+    tracer = SpanTracer(tmp_path / "t.json")
+    t0 = time.perf_counter()
+    with tracer.span("a"):
+        pass
+    tracer.instant("b")
+    a_ns = time.perf_counter_ns()
+    tracer.complete("c", a_ns, a_ns + 5000, steps=2)
+    t1 = time.perf_counter()
+    for e in tracer.events():
+        if e["ph"] in ("X", "i"):
+            assert t0 * 1e6 <= e["ts"] <= t1 * 1e6, e
+    c = next(e for e in tracer.events() if e["name"] == "c")
+    assert c["dur"] == 5.0 and c["args"] == {"steps": 2}
+    # totals since the last take, then reset
+    assert tracer.take_interval() == {"a": (pytest.approx(next(
+        e["dur"] for e in tracer.events() if e["name"] == "a") / 1e6), 1),
+        "c": (5e-6, 1)}
+    assert tracer.take_interval() == {}
+
+
+def test_interval_totals_add_up_in_every_logged_row(tmp_path):
+    cfg = tiny_cfg(log_every=4, save_every=10**9, checkpoint_dir=str(tmp_path),
+                   log_backend="jsonl", obs="on", num_tokens=32 * 40)
+    Trainer(cfg, logger=MetricsLogger(cfg)).train(num_steps=13)
+    rows = [json.loads(l) for l in (tmp_path / "metrics.jsonl").read_text().splitlines()]
+    assert [r["step"] for r in rows] == [0, 4, 8, 12]
+    for r in rows:
+        main = sum(r[f"perf/span/{n}_s"] for n in ("refill_wait", "step", "log_sync"))
+        assert 0 < main <= r["perf/interval_s"]
+        assert r["perf/interval_steps"] == (1 if r["step"] == 0 else 4)
+        assert r["perf/span/step_n"] == r["perf/interval_steps"]
+        assert r["perf/span/log_sync_n"] == 1
+        assert r["perf/refill_bubble_frac"] == pytest.approx(
+            r["perf/span/refill_wait_s"] / r["perf/interval_s"], rel=1e-4)
+        assert r["perf/interval_s"] == pytest.approx(
+            r["step_time_ms"] * r["perf/interval_steps"] / 1e3, rel=1e-4)
+    # a span that did not end in an interval is absent from its row
+    assert "perf/span/compile_s" in rows[0] and "perf/span/compile_s" not in rows[-1]
+
+
+def test_obs_off_logs_no_perf_key_and_builds_no_plane(tmp_path):
+    from crosscoder_tpu import obs
+    from crosscoder_tpu.data.buffer import make_buffer
+
+    cfg, lm_cfg, params, tokens = _tiny_lm_job(
+        tmp_path, log_every=2, log_backend="jsonl")
+    tr = Trainer(cfg, make_buffer(cfg, lm_cfg, params, tokens),
+                 logger=MetricsLogger(cfg))
+    assert tr._obs is None and not obs._PLANES
+    assert isinstance(trace.get_tracer(), NullTracer)
+    tr.train(num_steps=5)
+    rows = [json.loads(l) for l in (tmp_path / "metrics.jsonl").read_text().splitlines()]
+    assert rows and not any(k.startswith(("perf/", "comm/")) for r in rows for k in r)
+    assert not (tmp_path / "obs").exists()
+
+
+def _lowered_step(cfg, with_metrics):
+    from crosscoder_tpu.parallel import mesh as mesh_lib
+    from crosscoder_tpu.train import schedules
+    from crosscoder_tpu.train.state import init_train_state, make_optimizer
+    from crosscoder_tpu.train.trainer import make_train_step
+
+    mesh = mesh_lib.make_mesh(1, 1, devices=jax.devices()[:1])
+    tx = make_optimizer(cfg, schedules.lr_schedule(cfg))
+    state = init_train_state(jax.random.key(0), cfg, tx, n_data=1)
+    fn = make_train_step(cfg, mesh, tx, mesh_lib.state_shardings(mesh, state),
+                         with_metrics=with_metrics)
+    return fn.lower(
+        state, jax.ShapeDtypeStruct((cfg.batch_size, cfg.n_sources, cfg.d_in), np.float32),
+        jax.ShapeDtypeStruct((cfg.n_sources,), np.float32)).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("activation", ["relu", "topk"])
+def test_step_programs_carry_scope_and_variant_names(activation):
+    cfg = tiny_cfg(activation=activation, topk_k=4,
+                   l1_coeff=0.0 if activation == "topk" else 0.02)
+    scopes = ["cc/encode", "cc/decode", "cc/loss", "cc/adam",
+              "transpose(jvp(cc/encode))"]
+    if activation == "topk":
+        scopes.append("cc/select")
+    for with_metrics, suffix in ((True, "step_fn_full"), (False, "step_fn_bare")):
+        text = _lowered_step(cfg, with_metrics)
+        assert f"module @jit_{suffix} " in text
+        for scope in scopes:
+            assert scope in text, (suffix, scope)
+
+
+def test_harvest_store_and_serve_programs_carry_scope_names():
+    from crosscoder_tpu.data import buffer as buffer_mod
+    from crosscoder_tpu.models import lm
+    from crosscoder_tpu.serve import step as serve_step
+
+    lm_cfg = lm.LMConfig.tiny()
+    params = lm.init_params(jax.random.key(0), lm_cfg)
+    tok = jax.ShapeDtypeStruct((2, 9), np.int32)
+    start = lm._seg_start_impl.lower(params, tok, cfg=lm_cfg, n_cap=1)
+    assert "harvest/embed" in start.as_text(debug_info=True)
+    resid, buf = jax.eval_shape(
+        lambda p, t: lm._seg_start_impl(p, t, cfg=lm_cfg, n_cap=1), params, tok)
+    scan = lm._seg_scan_impl.lower(
+        params, resid, buf, np.int32(0), cfg=lm_cfg, capture=((2, 0),), k=2,
+    ).as_text(debug_info=True)
+    assert "harvest/block/attn" in scan and "harvest/block/mlp" in scan
+    store = jax.ShapeDtypeStruct((64, 2, 8), jax.numpy.bfloat16)
+    acts = jax.ShapeDtypeStruct((2, 5, 2, 8), jax.numpy.bfloat16)
+    idx = jax.ShapeDtypeStruct((8,), np.int32)
+    assert "store/scatter" in buffer_mod._dev_scatter.lower(
+        store, idx, acts).as_text(debug_info=True)
+    assert "store/gather" in buffer_mod._dev_gather.lower(
+        store, idx).as_text(debug_info=True)
+    from crosscoder_tpu.models import crosscoder
+
+    cfg = tiny_cfg(activation="topk", topk_k=4, l1_coeff=0.0)
+    cc_params = jax.eval_shape(lambda k: crosscoder.init_params(k, cfg), jax.random.key(0))
+    text = serve_step.encode_topk_diff.lower(
+        cc_params, jax.ShapeDtypeStruct((4, 8, cfg.n_sources, cfg.d_in), np.float32),
+        jax.ShapeDtypeStruct((4,), np.int32),
+        jax.ShapeDtypeStruct((cfg.n_sources,), np.float32),
+        enc_dtype=cfg.enc_dtype, k=cfg.topk_k, fused=False,
+        pair=serve_step.diff_pair(cfg.n_sources, cfg.n_models),
+    ).as_text(debug_info=True)
+    assert "serve/encode_topk_diff" in text and "cc/encode" in text
+
+
+def test_profiler_window_records_its_place_on_the_span_clock(tmp_path, monkeypatch):
+    fake = _FakeProfiler()
+    monkeypatch.setattr(jax.profiler, "start_trace", fake.start_trace)
+    monkeypatch.setattr(jax.profiler, "stop_trace", fake.stop_trace)
+    tracer = SpanTracer(tmp_path / "t.json")
+    prev = trace.set_tracer(tracer)
+    try:
+        w = ProfilerWindow(tiny_cfg(profile_steps="2:4", profile_dir=str(tmp_path)))
+        synced = []
+        for i in range(6):
+            w.before_step(i)
+            w.after_step(i, sync=lambda: synced.append(i))
+    finally:
+        trace.set_tracer(prev)
+    spans = {e["name"]: e for e in tracer.events() if e["ph"] == "X"}
+    win, stop = spans["profile_window"], spans["profile_stop"]
+    assert synced == [3] and [c[0] for c in fake.calls] == ["start", "stop"]
+    assert win["ts"] <= stop["ts"]
+    assert stop["ts"] + stop["dur"] <= win["ts"] + win["dur"] + 1e-3
+
+
+
+def test_trace_report_prints_self_time_of_produce(tmp_path, capsys):
+    """A span less the spans nested in it on its own thread; a span of the
+    same time on another thread is no child."""
+    tracer = SpanTracer(tmp_path / "t.json")
+    t0 = time.perf_counter_ns()
+    ms = 1_000_000
+    tracer.complete("produce", t0, t0 + 10 * ms)
+    tracer.complete("serve_gather", t0 + 1 * ms, t0 + 3 * ms)
+    tracer.complete("harvest_dispatch", t0 + 4 * ms, t0 + 9 * ms)
+    other = threading.Thread(
+        target=lambda: tracer.complete("step", t0 + 2 * ms, t0 + 6 * ms))
+    other.start()
+    other.join()
+    tracer.flush()
+    mod = _load_script("trace_report")
+    assert mod.main([str(tmp_path / "t.json")]) == 0
+    assert "self_ms" in capsys.readouterr().out
+    rows, _ = mod.summarize(mod.load_events(str(tmp_path / "t.json")))
+    by = {r["span"]: r for r in rows}
+    assert by["produce"]["total_ms"] == pytest.approx(10.0)
+    assert by["produce"]["self_ms"] == pytest.approx(3.0)
+    assert by["step"]["self_ms"] == pytest.approx(4.0)
+    assert by["harvest_dispatch"]["self_ms"] == pytest.approx(5.0)
