@@ -407,7 +407,9 @@ def train_leg(sizes: Sizes, label: str, leg: dict, lm_params: list,
 # forward: two different compiled programs in bf16, where every block rounds
 # its activations to 8 mantissa bits and the two lowerings need not round
 # alike, so values agree to a few bf16 ulps, not bitwise. 2^-5 of the
-# request's largest activation is 8 ulps (seen on a v5e: 6e-3 to 9e-3);
+# request's largest activation is 8 ulps (seen on a v5e: 6e-3 to 9e-3, and
+# 1.25e-2 since the padded reference's attention is the fused kernel while
+# the served path's is the XLA form);
 # a wrong token position, norm factor or model changes values by O(1) of
 # it. Latents whose activation sits within that band of the k-th may swap
 # in or out of the top k.

@@ -1,7 +1,7 @@
-"""Static safety analyzer for the seven ops/ Pallas kernels.
+"""Static safety analyzer for the eight ops/ Pallas kernels.
 
 Every kernel family (topk, sparsify, batchtopk, quant, sparse_grad,
-paged_attention, fused_encoder_topk) is probed once at a canonical
+paged_attention, flash_attention, fused_encoder_topk) is probed once at a canonical
 supported shape with a recording ``pallas_call`` shim: the probe runs the
 real entry point, the shim captures every ``pallas_call``'s grid,
 BlockSpecs, scratch shapes and compiler params *as the non-interpret TPU
@@ -46,7 +46,7 @@ from crosscoder_tpu.analysis.contracts.engine import Finding, Rule
 VMEM_HARD_LIMIT = 16 << 20          # per-core VMEM the budget model assumes
 MAX_GRID_POINTS = 8192              # OOB/race enumeration cap per call
 
-# the seven kernel families the acceptance criteria name, with the VMEM
+# the kernel families under ops/, with the VMEM
 # budget each module declares for itself
 KERNEL_BUDGETS = {
     "topk": 13 << 20,
@@ -55,6 +55,7 @@ KERNEL_BUDGETS = {
     "quant": 12 << 20,
     "sparse_grad": 13 << 20,
     "paged_attention": 13 << 20,
+    "flash_attention": 13 << 20,
     "fused_encoder_topk": 13 << 20,
 }
 
@@ -324,6 +325,13 @@ def run_kernel_probes() -> PallasContext:
         v = jnp.asarray(rng.normal(size=(D, S, KV, hd)).astype(np.float32))
         lengths = jnp.asarray([1, 16, 7, 9], jnp.int32)
         pa.paged_attention(q, k, v, lengths, page_size=page, scale=0.35)
+    with probe("flash_attention"):
+        from crosscoder_tpu.ops import flash_attention as fa
+        S, H, KV, hd = 384, 4, 2, 128           # three 128-tiles, GQA g=2
+        assert fa.supported(S, H, KV, hd, jnp.float32)
+        q = jnp.asarray(rng.normal(size=(2, S, H, hd)).astype(np.float32))
+        k = jnp.asarray(rng.normal(size=(2, S, KV, hd)).astype(np.float32))
+        fa.flash_attention(q, k, k, scale=0.09, softcap=50.0, window=200)
     with probe("fused_encoder_topk"):
         from crosscoder_tpu.ops import fused_encoder_topk as fek
         B, nd, H, k = 48, 256, 1024, 8
@@ -564,7 +572,7 @@ PALLAS_RULES: list[Rule] = [
 
 def vmem_summary(ctx: PallasContext) -> dict[str, str]:
     """Per-family VMEM estimate for ``Report.info`` — the acceptance
-    surface: an estimate plus clean OOB/race status for all seven."""
+    surface: an estimate plus clean OOB/race status for every family."""
     by_family: dict[str, int] = {}
     for call in ctx.calls:
         by_family[call.kernel] = max(by_family.get(call.kernel, 0),

@@ -240,21 +240,50 @@ def _attn_core(
     """Masked-softmax attention on projected heads: q [B, S, H, hd],
     k/v [B, S, KV, hd] → [B, S, H·hd] (pre output-projection).
 
-    Delegates to the ONE attention-math implementation
-    (:func:`crosscoder_tpu.ops.paged_attention.ragged_attention_reference`)
-    with cfg-derived scalars, so the padded forward and the paged
-    runtime's XLA path / kernel oracle can never drift apart. ``lengths``
-    (the paged runtime's per-document valid token counts) adds a key-side
-    validity mask — a no-op for valid queries (causal ⊆ in-length), which
-    is what makes the paged XLA path bit-identical to the padded forward
-    at valid positions (rows at t >= length are computed on whatever the
-    gather clamped to, and discarded)."""
+    One algorithm with cfg-derived scalars, two forms, chosen here — when
+    the program is traced — from what the code can observe:
+
+    - the padded path (``lengths is None``) on a one-device TPU backend at
+      a shape :func:`crosscoder_tpu.ops.flash_attention.supported` accepts
+      runs the fused online-softmax kernel, which never writes the [S, S]
+      scores to HBM. ``is_local`` is traced: where the window cannot bind
+      (0, or ≥ S) local and global layers are one kernel instance,
+      otherwise ``lax.cond`` picks between two static ones;
+    - everything else — the CPU backend, a mesh, an unsupported shape, the
+      paged runtime (``lengths`` given) — runs the XLA form
+      (:func:`crosscoder_tpu.ops.paged_attention.ragged_attention_reference`),
+      which is also the oracle the kernel is pinned against.
+
+    ``lengths`` (the paged runtime's per-document valid token counts) adds
+    a key-side validity mask — a no-op for valid queries (causal ⊆
+    in-length), which is what makes the paged XLA path bit-identical to
+    the padded XLA forward at valid positions (rows at t >= length are
+    computed on whatever the gather clamped to, and discarded).
+
+    The choice is counted in the job's telemetry plane, once per trace:
+    ``harvest/attn_fused_traces`` / ``harvest/attn_xla_traces``."""
+    from crosscoder_tpu import obs
+    from crosscoder_tpu.ops import flash_attention as fa
     from crosscoder_tpu.ops import paged_attention as pa
 
+    S, H, hd = q.shape[1:]
+    scale = cfg.query_pre_attn_scalar ** -0.5
+    if lengths is None and fa.enabled() and fa.supported(
+            S, H, k.shape[2], hd, q.dtype):
+        obs.count("harvest/attn_fused_traces")
+
+        def fused(window):
+            return lambda qkv: fa.flash_attention(
+                *qkv, scale=scale, softcap=cfg.attn_softcap, window=window)
+
+        if not 0 < cfg.sliding_window < S:
+            return fused(0)((q, k, v))
+        return jax.lax.cond(
+            is_local, fused(cfg.sliding_window), fused(0), (q, k, v))
+    obs.count("harvest/attn_xla_traces")
     return pa.ragged_attention_reference(
         q, k, v, lengths,
-        scale=cfg.query_pre_attn_scalar ** -0.5,
-        softcap=cfg.attn_softcap, window=cfg.sliding_window,
+        scale=scale, softcap=cfg.attn_softcap, window=cfg.sliding_window,
         is_local=is_local,
     )
 

@@ -73,6 +73,14 @@ def acquire(cfg: Any, mesh: Any | None = None) -> "Observability | None":
     return plane
 
 
+def count(key: str, n: int = 1) -> None:
+    """Bump a counter in every live plane's registry — for library code
+    that holds no plane (a choice made while a program is traced). With
+    ``obs`` off there is no plane and this is a no-op."""
+    for plane in tuple(_PLANES.values()):
+        plane.registry.count(key, n)
+
+
 class Observability:
     def __init__(self, cfg: Any, mesh: Any | None = None) -> None:
         self.cfg = cfg
@@ -189,6 +197,7 @@ class Observability:
 __all__ = [
     "Observability",
     "acquire",
+    "count",
     "MetricsRegistry",
     "NullTracer",
     "SpanTracer",

@@ -11,12 +11,13 @@ Two implementations, one dispatch (the ops/quant.py discipline):
 
 - **pure XLA** (:func:`ragged_attention_reference`): padded masked-softmax
   attention with the ragged length mask — jittable anywhere, the CPU
-  fallback and the oracle the kernel is pinned against. Deliberately the
-  SAME op sequence as the padded LM attention
-  (``models/lm._attn_core``), so the paged harvest's XLA path is
-  bit-identical to the padded forward at valid positions (the CPU parity
-  gate); its attention cost is the padded cost — the paged runtime's XLA
-  win comes from the packed-plane projections/MLP, ~93% of harvest FLOPs
+  fallback and the oracle the kernels are pinned against. It is the XLA
+  form of the padded LM attention too (``models/lm._attn_core`` calls it
+  wherever the fused dense kernel of :mod:`crosscoder_tpu.ops.flash_attention`
+  may not dispatch), so on one backend the paged harvest's XLA path is
+  bit-identical to the padded XLA forward at valid positions (the CPU
+  parity gate); its attention cost is the padded cost — the paged runtime's
+  XLA win comes from the packed-plane projections/MLP, ~93% of harvest FLOPs
   at Gemma-2-2B shapes.
 - **Pallas TPU kernel** (:func:`_rpa_kernel`): grid ``(docs, kv_heads)``;
   the document's query block sits in VMEM, KV pages are DMA'd from the
@@ -44,8 +45,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from crosscoder_tpu.ops.dispatch import hw_kernel_enabled
 
-# THE attention mask fill: models/lm._attn_core delegates here, so every
-# dense/paged/kernel attention path masks with this one constant
+# THE attention mask fill: every dense/paged/kernel attention path (this
+# module's, ops/flash_attention's) masks with this one constant
 NEG_INF = -2.3819763e38
 
 DISPATCH_ENV = "CROSSCODER_PAGED_ATTN_PALLAS"
@@ -91,10 +92,11 @@ def ragged_attention_reference(
     window: int = 0,
     is_local=False,
 ) -> jax.Array:
-    """Masked-softmax attention over (per-document) padded buffers — THE
-    single attention-math implementation: ``models/lm._attn_core``
-    delegates here, so the padded forward, the paged XLA path, and the
-    kernel's oracle/fallback can never drift apart numerically.
+    """Masked-softmax attention over (per-document) padded buffers — the
+    XLA form of the attention mathematics: the paged XLA path, the padded
+    forward wherever ``models/lm._attn_core`` does not pick the fused
+    kernel, and the oracle both kernels are pinned against, so those can
+    never drift apart numerically.
 
     ``q [B, S, H, hd]`` (unscaled), ``k``/``v [B, S, KV, hd]``.
     ``lengths [B]`` adds the ragged key-side validity mask (None = the

@@ -39,6 +39,7 @@ import dataclasses
 import hashlib
 import json
 import os
+import re
 import sys
 import threading
 import time
@@ -88,18 +89,26 @@ def enable() -> str | None:
     JAX has already read it and this function sets no directory (an empty
     value leaves the cache off). Unset, the cache goes to the fixed
     ``<checkout>/.jax_cache`` — the path is part of the cache key, so it
-    never carries a temp name, pid or time. Safe to call before or after
-    backend init, but before the first compile.
+    never carries a temp name, pid or time. Source file names in what is
+    lowered from here on are made relative to the checkout, so that the
+    same program lowered from a checkout at another path has the same key
+    (tests/test_compile_cache.py). Safe to call before or after backend
+    init, but before the first trace.
     """
     import jax
 
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if cache_dir is None:
-        cache_dir = os.path.join(
-            os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
-            ".jax_cache",
-        )
+        cache_dir = os.path.join(root, ".jax_cache")
         jax.config.update("jax_compilation_cache_dir", cache_dir)
+    # source locations relative to the checkout: a Pallas call's payload
+    # carries the MLIR locations of its kernel's equations (file names of
+    # the traceback's user frames), the payload is in the HLO the cache key
+    # hashes, and so a program that holds a kernel missed from every
+    # checkout at another path until the root was stripped
+    jax.config.update(
+        "jax_hlo_source_file_canonicalization_regex", re.escape(root + os.sep))
     # cache EVERYTHING: the analysis entry points' first call is dominated
     # by many sub-second compiles (decoder norms, cosines, logit lens)
     # that a 1.0 s threshold would silently re-pay in every process

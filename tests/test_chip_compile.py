@@ -221,6 +221,61 @@ def test_quant_family_compiles(chip, gates_open):
               _sds((BATCH, ND), jnp.bfloat16, chip))
 
 
+@pytest.fixture
+def one_device(monkeypatch):
+    """The fused attention dispatches from a process that sees ONE device
+    (a pallas_call is not partitioned over a mesh); this one sees eight
+    virtual CPU devices."""
+    monkeypatch.setattr(jax, "device_count", lambda *a: 1)
+
+
+@pytest.mark.parametrize("heads", ["ouro-16x128", "gemma2-2b-8/4x256"])
+def test_fused_attention_compiles_inside_the_harvest(chip, one_device, heads):
+    """The refill's segment program (``_seg_scan_impl``, 3 blocks of a
+    4-sequence chunk at seq 1024, bf16) with the fused causal attention in
+    its layer scan: at the benchmark cells' heads (16 Q and KV x 128, the
+    4096 window inert) and at Gemma-2-2B's (8 Q / 4 KV x 256)."""
+    from crosscoder_tpu.ops import flash_attention as fa
+
+    if heads.startswith("ouro"):
+        cfg = lm.LMConfig(
+            vocab_size=49_152, d_model=2048, n_layers=HOOK_LAYER, n_heads=16,
+            n_kv_heads=16, head_dim=128, d_ff=5632, query_pre_attn_scalar=128.0)
+    else:
+        cfg = lm.LMConfig.gemma2_2b().replace(n_layers=HOOK_LAYER)
+    B, S = 4, 1024
+    assert fa.supported(S, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, jnp.bfloat16)
+    params = _abstract(jax.eval_shape(
+        lambda k: lm.init_params(k, cfg), jax.random.key(0)), chip)
+    capture = lm._hook_layers(cfg, (f"blocks.{HOOK_LAYER}.hook_resid_pre",))
+    compiled = lm._seg_scan_impl.lower(
+        params, _sds((B, S, cfg.d_model), jnp.bfloat16, chip),
+        _sds((1, B, S, cfg.d_model), jnp.bfloat16, chip),
+        _sds((), jnp.int32, chip), cfg=cfg, capture=capture, k=3,
+    ).compile()
+    _fits(compiled)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "the XLA form took the kernel's place"
+    # the kernel reads and writes the projections' own [B, S, H*hd] layout:
+    # nothing head-major is ever built
+    assert f"[{B},{cfg.n_heads},{S},{cfg.head_dim}]" not in text
+
+
+def test_fused_attention_window_pair_compiles(chip, one_device):
+    """A window that binds (512 of 1024): ``lax.cond`` between the windowed
+    and the causal instance on the traced layer parity."""
+    cfg = lm.LMConfig(
+        vocab_size=4096, d_model=512, n_layers=2, n_heads=4, n_kv_heads=2,
+        head_dim=128, d_ff=1024, sliding_window=512)
+    params = _abstract(jax.eval_shape(
+        lambda k: lm.init_params(k, cfg), jax.random.key(0)), chip)
+    text = lm._forward_impl.lower(
+        params, _sds((2, 1024), jnp.int32, chip), cfg=cfg, capture=(),
+        edit_fns=(), edit_layers=(), edit_values=(), return_logits=True,
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2
+
+
 @pytest.mark.parametrize("window", [0, 4096])
 def test_paged_attention_family_compiles(chip, gates_open, window):
     """Gemma-2-2B heads (8 Q / 4 KV × 256), global and sliding-window."""

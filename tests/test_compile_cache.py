@@ -1,6 +1,8 @@
 """Tests for the persistent-compile-cache helper
 (crosscoder_tpu/utils/compile_cache.py)."""
 
+import os
+
 import jax
 import pytest
 
@@ -15,6 +17,7 @@ def test_compile_cache_enable(tmp_path, monkeypatch, env):
 
     prev_dir = jax.config.jax_compilation_cache_dir
     prev_min = jax.config.jax_persistent_cache_min_compile_time_secs
+    prev_re = jax.config.jax_hlo_source_file_canonicalization_regex
     updates = []
     real_update = jax.config.update
 
@@ -36,7 +39,66 @@ def test_compile_cache_enable(tmp_path, monkeypatch, env):
             assert compile_cache.enable() == (want or None)
             assert "jax_compilation_cache_dir" not in updates
             assert jax.config.jax_compilation_cache_dir == prev_dir
+        # file names in lowered programs lose the checkout's root, whatever
+        # the directory
+        import re
+
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        pattern = jax.config.jax_hlo_source_file_canonicalization_regex
+        assert re.sub(pattern, "", os.path.join(root, "crosscoder_tpu", "x.py")) \
+            == os.path.join("crosscoder_tpu", "x.py")
     finally:
         monkeypatch.undo()
         jax.config.update("jax_compilation_cache_dir", prev_dir)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", prev_min)
+        jax.config.update("jax_hlo_source_file_canonicalization_regex", prev_re)
+
+
+_LOWER_FROM_A_COPY = """
+import hashlib, os, sys
+import jax, jax.numpy as jnp
+import crosscoder_tpu
+from crosscoder_tpu.ops import flash_attention, topk_pallas
+from crosscoder_tpu.utils import compile_cache
+
+assert crosscoder_tpu.__file__.startswith(os.getcwd()), crosscoder_tpu.__file__
+compile_cache.enable()
+h = jax.ShapeDtypeStruct((256, 4096), jnp.bfloat16)
+q = jax.ShapeDtypeStruct((1, 256, 2, 128), jnp.bfloat16)
+for fn, args in (
+        (lambda x: topk_pallas.topk(x, 32), (h,)),
+        (lambda q, k, v: flash_attention.flash_attention(
+            q, k, v, scale=0.1, softcap=50.0), (q, q, q))):
+    text = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text
+    print(hashlib.sha256(text.encode()).hexdigest())
+"""
+
+
+def test_lowering_is_the_same_from_a_checkout_at_another_path(tmp_path):
+    """A program that holds a Pallas call carries, in the call's payload,
+    the MLIR locations of the kernel's equations — file names of the
+    traceback's user frames — and the payload is in the HLO that JAX's
+    persistent-cache key hashes. After ``compile_cache.enable()`` those
+    names are relative to the checkout: the TopK kernel (the step programs)
+    and the fused attention (the harvest programs) lower to the same text
+    from two copies of the package at different paths."""
+    import shutil
+    import subprocess
+    import sys
+
+    src = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                       "crosscoder_tpu")
+    digests = []
+    for name in ("a", "somewhere/else/b"):
+        root = tmp_path / name
+        shutil.copytree(src, root / "crosscoder_tpu",
+                        ignore=shutil.ignore_patterns("__pycache__"))
+        env = {**os.environ, "PYTHONPATH": str(root), "JAX_PLATFORMS": "cpu",
+               "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache")}
+        p = subprocess.run(
+            [sys.executable, "-c", _LOWER_FROM_A_COPY], cwd=root, env=env,
+            capture_output=True, text=True, timeout=300)
+        assert p.returncode == 0, p.stderr[-2000:]
+        digests.append(p.stdout.split())
+    assert len(digests[0]) == 2 and digests[0] == digests[1], digests

@@ -83,7 +83,8 @@ def test_pallas_pack_clean_and_covers_all_seven_kernels():
     assert rep.ok, "\n".join(str(f) for f in rep.findings)
     families = {c.kernel for c in ctx.calls}
     assert {"topk", "sparsify", "batchtopk", "quant", "sparse_grad",
-            "paged_attention", "fused_encoder_topk"} <= families
+            "paged_attention", "flash_attention",
+            "fused_encoder_topk"} <= families
     summary = vmem_summary(ctx)
     assert len(summary) >= 7
     assert all("MiB" in v for v in summary.values())
