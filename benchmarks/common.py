@@ -73,25 +73,6 @@ def sub_seeds(seed: int, n: int = 4) -> list[int]:
     return [int(s) & 0x7FFFFFFF for s in state]
 
 
-def lm_config(config: dict, overrides: dict | None = None) -> Any:
-    """``lm.LMConfig`` from the published keys in a configuration file (the
-    departures of the repo's block from the source are in its ``assumed``)."""
-    from crosscoder_tpu.models import lm
-
-    a = config["assumed"]
-    kw = dict(
-        vocab_size=config["vocab_size"], d_model=config["hidden_size"],
-        n_layers=config["num_hidden_layers"], n_heads=config["num_attention_heads"],
-        n_kv_heads=config["num_key_value_heads"], head_dim=config["head_dim"],
-        d_ff=config["intermediate_size"], rope_theta=float(config["rope_theta"]),
-        rms_eps=config["rms_norm_eps"], attn_softcap=a["attn_softcap"],
-        final_softcap=a["final_softcap"], sliding_window=a["sliding_window"],
-        query_pre_attn_scalar=a["query_pre_attn_scalar"], dtype=a["lm_dtype"],
-    )
-    kw.update(overrides or {})
-    return lm.LMConfig(**kw)
-
-
 def init_lm_pair(lm_cfg: Any, seeds: list[int], sharding: Any = None) -> list:
     """Two subject models' weights from ``lm.init_params`` on the device in
     one jitted call each, in the type they are served in (eagerly the float32

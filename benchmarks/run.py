@@ -8,7 +8,9 @@ TPU devices of a kind in ``peaks.json`` and as many as the cell asks for,
 hands the cell to the runner its traffic file names, and prints as the last
 line of standard output one JSON object with the keys ``correct``,
 ``attempted``, ``failed``, ``metrics`` and ``device`` (with ``--trace 1``
-also ``breakdown``). ``--trace 0`` reports the cell's end-to-end metrics,
+also ``breakdown``), and last ``compared``: each number the runner compared
+with a plain reference, beside its limit (they are also the last lines of
+standard error). ``--trace 0`` reports the cell's end-to-end metrics,
 ``--trace 1`` its per-layer metrics. Earlier lines (``[bench] ...``) carry the
 series' summaries, the set-up phases and the host's load; the raw readings
 go to ``benchmarks/out/<workload>/``.
@@ -134,6 +136,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: int, *,
     if reduced:
         line["breakdown"] = {"device_ops": reduced["device_ops"],
                              "idle_gaps": reduced["idle_gaps"]}
+    # last: each number the runner compared with a reference, beside its limit
+    line["compared"] = res.get("compared", {})
     return line, obs
 
 
@@ -151,6 +155,8 @@ def main(argv: list[str] | None = None) -> int:
         traceback.print_exc(file=sys.stderr)
         return 1
     print(json.dumps(line), flush=True)
+    for name, c in line["compared"].items():    # the last lines of standard error
+        print(f"compared {name}: {c['value']:.6g} (limit {c['limit']:g})", file=sys.stderr)
     return 0
 
 

@@ -29,6 +29,7 @@ from typing import Any
 
 import numpy as np
 
+from benchmarks import arch as arch_lib
 from benchmarks import common, cycles, shapes, trace_reduce
 from benchmarks.common import say
 
@@ -45,11 +46,8 @@ FIRST_STEP_RTOL = 1e-2
 # latent in a few rows (seen on a v5e: PERF.md); a wrong bias or a selection
 # on the wrong axis shares next to nothing.
 TOPK_SHARED = 0.9
-# The hooked activations against the float32 reference, as the relative
-# Frobenius error over a seeded sample: 14 blocks each round their
-# activations to bf16, which measures about 1e-2 on a v5e (PERF.md); a wrong
-# position, mask or scale gives O(1).
-HARVEST_RTOL = 3e-2
+# (The harvest's limit against its plain reference, ``HARVEST_RTOL``, is the
+# architecture's: ``benchmarks/arch/<arch>.py``.)
 
 
 class FirstBatchTap:
@@ -172,21 +170,22 @@ def _kernel_in_step(trainer: Any) -> bool | None:
     return "tpu_custom_call" in text
 
 
-def _against_references(cfg: Any, lm_cfg: Any, lm_params: list, tokens: Any,
-                        first: Any, norm: Any, row0: dict, n_seq: int,
-                        check: Any) -> dict:
+def _against_references(cfg: Any, lm_cfg: Any, arch: Any, lm_params: list,
+                        tokens: Any, first: Any, norm: Any, row0: dict,
+                        n_seq: int, check: Any) -> dict:
     """Outside the window, at the cell's widths: the first step's losses and
     the TopK selection against the plain crosscoder, the hooked activations
-    of a seeded sample against the plain LM."""
+    of a seeded sample against the plain LM of the configuration's ``arch``."""
     import jax
     import jax.numpy as jnp
 
-    from benchmarks.reference import crosscoder_ref, lm_ref
+    from benchmarks.reference import crosscoder_ref
     from crosscoder_tpu.models import crosscoder as cc
     from crosscoder_tpu.models import lm
     from crosscoder_tpu.utils.dtypes import dtype_of
 
     topk = cfg.activation == "topk"
+    compared: dict[str, dict] = {}      # each number compared, beside its limit
     p0 = cc.init_params(jax.random.key(cfg.seed), cfg, dtype=jnp.float32)
     x = first.astype(jnp.float32) * norm[None, :, None]
     ref = crosscoder_ref.losses(p0, x, cfg.topk_k if topk else None)
@@ -196,7 +195,8 @@ def _against_references(cfg: Any, lm_cfg: Any, lm_params: list, tokens: Any,
         say(f"first step {key}_loss: program {got:.6g}, float32 reference "
             f"{want:.6g} (relative deviation {dev:.2e}, limit {FIRST_STEP_RTOL})")
         check(dev <= FIRST_STEP_RTOL, f"first-step {key}_loss deviates by {dev:.2e}")
-    out = {}
+        compared[f"first_step_{key}_dev"] = {"value": dev, "limit": FIRST_STEP_RTOL}
+    out = {"compared": compared}
     if topk:
         # the program's selection (the default tier: the Pallas kernel on a
         # chip) against the reference's k largest, on the same batch
@@ -209,6 +209,7 @@ def _against_references(cfg: Any, lm_cfg: Any, lm_params: list, tokens: Any,
             f"the reference's (limit {100 * TOPK_SHARED:.0f}%)")
         check(shared >= TOPK_SHARED, f"TopK selection shares only {shared:.3f}")
         out["topk_shared"] = shared
+        compared["topk_shared_floor"] = {"value": shared, "limit": TOPK_SHARED}
         del chosen, wanted
     del p0, x, ref
     hook_layer = int(cfg.hook_point.split(".")[1])
@@ -216,14 +217,15 @@ def _against_references(cfg: Any, lm_cfg: Any, lm_params: list, tokens: Any,
     got = lm.run_with_cache_multi(lm_params, sample, lm_cfg, cfg.resolved_hook_points())
     worst = 0.0
     for m, p in enumerate(lm_params):
-        want = lm_ref.resid_pre(p, sample, lm_cfg, hook_layer)
+        want = arch.resid_pre(p, sample, lm_cfg, hook_layer)
         diff = got[:, :, m].astype(jnp.float32) - want
         worst = max(worst, float(jnp.linalg.norm(diff) / jnp.linalg.norm(want)))
     say(f"harvest against the float32 reference: relative error {worst:.3e} "
         f"over {n_seq} x {cfg.seq_len} tokens x {len(lm_params)} models "
-        f"(limit {HARVEST_RTOL})")
-    check(worst <= HARVEST_RTOL, f"hooked activations deviate by {worst:.3e}")
+        f"(limit {arch.HARVEST_RTOL})")
+    check(worst <= arch.HARVEST_RTOL, f"hooked activations deviate by {worst:.3e}")
     out["harvest_rel_err"] = worst
+    compared["harvest_rel_err"] = {"value": worst, "limit": arch.HARVEST_RTOL}
     return out
 
 
@@ -238,7 +240,8 @@ def run(cell: dict, args: Any, rec: common.RunRecord, compiles: common.CompileLo
     config, traffic = cell["config"], {**cell["traffic"], **sizes.get("traffic", {})}
     chips = cell["workload"]["chips"]
     seed_cc, seed_tok, seed_a, seed_b = common.sub_seeds(args.seed)
-    lm_cfg = common.lm_config(config, sizes.get("lm"))
+    arch = arch_lib.of(config)
+    lm_cfg = arch.lm_config(config, sizes.get("lm"))
     problems: list[str] = []
 
     def check(cond: bool, what: str) -> None:
@@ -336,7 +339,7 @@ def run(cell: dict, args: Any, rec: common.RunRecord, compiles: common.CompileLo
         obs: dict[str, Any] = {
             "cycle": quiet, "window": r, "window_rows": rows, "spc": spc, "chips": chips,
             "compile_setup": log.compile_setup,
-            "shapes": shapes.train_shapes(cfg, lm_cfg, spc, mesh_shape=(data, model)),
+            "shapes": shapes.train_shapes(cfg, lm_cfg, spc, (data, model), arch),
         }
 
         # ---- outside the window: the references ----------------------
@@ -344,10 +347,12 @@ def run(cell: dict, args: Any, rec: common.RunRecord, compiles: common.CompileLo
         first = tap.first
         del trainer, tap
         gc.collect()
+        reference: dict = {}
         if log.rows:
-            rec.note("reference", _against_references(
-                cfg, lm_cfg, lm_params, tokens, first, norm, log.rows[0],
-                int(traffic.get("reference_seqs", 1)), check))
+            reference = _against_references(
+                cfg, lm_cfg, arch, lm_params, tokens, first, norm, log.rows[0],
+                int(traffic.get("reference_seqs", 1)), check)
+            rec.note("reference", reference)
         del first
         if args.trace:
             obs["trace"] = trace_reduce.load_profile(
@@ -358,6 +363,7 @@ def run(cell: dict, args: Any, rec: common.RunRecord, compiles: common.CompileLo
             obs["traced_steps"] = int(traffic["trace_cycles"]) * spc
     obs["memory_peak_bytes"] = common.device_block(devices)["memory_peak_bytes"] or None
     return {"metrics": metrics, "problems": problems, "observations": obs,
+            "compared": reference.get("compared", {}),
             "attempted": r["cycles"],
             "failed": sum(not math.isfinite(row["loss"]) for row in rows),
             "devices": devices}

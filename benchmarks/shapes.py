@@ -11,25 +11,16 @@ from __future__ import annotations
 from typing import Any
 
 
-def lm_flops_per_token(lm_cfg: Any, n_layers: int, seq_len: int) -> float:
-    """Forward FLOPs of ``n_layers`` blocks for one token of a ``seq_len``
-    causal sequence: the seven projections, and attention over the causal
-    half of the score matrix (QK^T and PV)."""
-    D, F = lm_cfg.d_model, lm_cfg.d_ff
-    qd, kd = lm_cfg.n_heads * lm_cfg.head_dim, lm_cfg.n_kv_heads * lm_cfg.head_dim
-    proj = 2 * (D * qd + 2 * D * kd + qd * D + 3 * D * F)
-    attn = 2 * 2 * qd * (seq_len + 1) / 2
-    return float(n_layers * (proj + attn))
-
-
-def harvest_flops_per_step(cfg: Any, lm_cfg: Any, spc: int) -> float:
+def harvest_flops_per_step(cfg: Any, lm_cfg: Any, spc: int, arch: Any) -> float:
     """The steady cycle re-harvests ``refill_frac`` of the store's sequences
-    through every model to the hook, spread over ``spc`` steps."""
+    through every model to the hook, spread over ``spc`` steps; a token's
+    FLOPs are the count of the configuration's architecture
+    (``benchmarks/arch/``)."""
     rows_per_seq = cfg.seq_len - 1
     seqs = cfg.batch_size * cfg.buffer_mult // rows_per_seq
     refill = max(1, int(seqs * cfg.refill_frac))
     hook_layer = int(cfg.hook_point.split(".")[1])
-    per_seq = cfg.seq_len * lm_flops_per_token(lm_cfg, hook_layer, cfg.seq_len)
+    per_seq = cfg.seq_len * arch.flops_per_token(lm_cfg, hook_layer, cfg.seq_len)
     return refill * cfg.n_models * per_seq / spc
 
 
@@ -53,13 +44,14 @@ def topk_kernel_bytes(cfg: Any) -> float:
                  + cfg.batch_size * cfg.topk_k * (item + 4))
 
 
-def train_shapes(cfg: Any, lm_cfg: Any, spc: int, mesh_shape: tuple) -> dict:
+def train_shapes(cfg: Any, lm_cfg: Any, spc: int, mesh_shape: tuple,
+                 arch: Any) -> dict:
     data, model = mesh_shape
     out = {
         # needed work is split over every chip: a harvest repeated along
         # the model axis shows as a lower share, which is what it is
         "harvest_flops_per_step_per_chip":
-            harvest_flops_per_step(cfg, lm_cfg, spc) / (data * model),
+            harvest_flops_per_step(cfg, lm_cfg, spc, arch) / (data * model),
         "cc_flops_per_step_per_chip":
             crosscoder_flops_per_step(cfg) / (data * model),
     }

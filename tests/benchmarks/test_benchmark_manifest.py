@@ -2,9 +2,11 @@
 
 import copy
 import json
+import shutil
 import subprocess
 import sys
 
+import accepted
 import pytest
 
 from benchmarks import manifest
@@ -14,9 +16,9 @@ SHIPPED = manifest.load()
 
 def test_the_shipped_manifest_is_valid_and_its_files_agree():
     manifest.validate(SHIPPED)
+    accepted.traffic_files_name_runners_that_are_there(SHIPPED, manifest.ROOT)
     for w in SHIPPED["workloads"]:
         cell = manifest.cell(SHIPPED, w["name"])
-        assert cell["traffic"]["runner"] in ("train", "serve")
         assert any(m["name"] == "setup_s" for m in cell["end_to_end"])
         assert len(cell["end_to_end"]) >= 2 and cell["per_layer"]
 
@@ -86,19 +88,141 @@ def test_an_end_to_end_metric_is_host_clock_or_device_trace():
 
 def test_configuration_files_keep_every_number_of_the_catalog_entry():
     """Numbers of the source's config under the same key; what differs is in
-    ``reduced``; no width is reduced."""
-    published = {"head_dim": 128, "hidden_size": 2048, "intermediate_size": 5632,
-                 "max_position_embeddings": 65536, "max_window_layers": 48,
-                 "num_attention_heads": 16, "num_hidden_layers": 48,
-                 "num_key_value_heads": 16, "rms_norm_eps": 1e-06,
-                 "rope_theta": 1000000, "total_ut_steps": 4,
-                 "early_exit_threshold": 1, "vocab_size": 49152}
-    for c in SHIPPED["configs"]:
-        held = manifest.load_json(manifest.ROOT / c["file"])
-        differs = {k for k, v in published.items() if held.get(k) != v}
-        assert differs <= set(c["reduced"]), (c["name"], differs)
-        assert held["crosscoder"]["d_in"] == held["hidden_size"]
-        assert c["source"] == held["source"]
+    ``reduced``; no width is reduced. The published numbers are one file a
+    model under ``published/``, found by the configuration's ``source``."""
+    accepted.configurations_keep_their_published_numbers(SHIPPED, manifest.ROOT)
+
+
+# The numbers of Mellum2-12B-A2.5B-Instruct's published config (the catalog
+# beside the model-configs guide): what the follow-on ``model_config`` PR pins.
+MELLUM2 = {
+    "source": "https://huggingface.co/JetBrains/Mellum2-12B-A2.5B-Instruct/blob/main/config.json",
+    "config": {"head_dim": 128, "hidden_size": 2304, "intermediate_size": 7168,
+               "max_position_embeddings": 131072, "max_window_layers": 0,
+               "moe_intermediate_size": 896, "num_attention_heads": 32, "num_experts": 64,
+               "num_experts_per_tok": 8, "num_hidden_layers": 28, "num_key_value_heads": 4,
+               "rms_norm_eps": 1e-06, "sliding_window": 1024, "vocab_size": 98304},
+}
+
+
+def _a_later_prs_additions(root):
+    """In the copy under ``root``: a configuration of another model (with its
+    published numbers' file), a traffic mix naming a second runner module, a
+    cell, an appended per-layer metric and the cell appended to a PR 26
+    metric's list. No file that was there is edited; returns the manifest and
+    the directory of published numbers."""
+    b = root / "benchmarks"
+    published = root / "published"
+    shutil.copytree(accepted.PUBLISHED, published)
+    (published / "JetBrains.Mellum2-12B-A2.5B-Instruct.json").write_text(json.dumps(MELLUM2))
+    cfg = {**MELLUM2["config"], "source": MELLUM2["source"], "num_hidden_layers": 4,
+           "layer_types": ["sliding_attention"] * 3 + ["full_attention"],
+           "crosscoder": {"d_in": 2304, "hook_point": "blocks.4.hook_resid_pre"}}
+    (b / "configs" / "mellum2-pair-topk32k.json").write_text(json.dumps(cfg))
+    (b / "runners" / "train_long.py").write_text(
+        "from benchmarks.runners.train import run  # noqa: F401\n")
+    mix = manifest.load_json(b / "traffic" / "live-full.json")
+    (b / "traffic" / "live-long.json").write_text(json.dumps({**mix, "runner": "train_long"}))
+    spec = {"unit": "%", "better": "higher", "source": "device_trace",
+            "layer": "harvest (models/lm.py)", "moves": "train_rows_per_s",
+            "reducer": "peak_share", "chip_only": True,
+            "args": {"group": "expert_kernel", "per": ["traced_steps"],
+                     "work": "expert_kernel_flops_per_step_per_chip",
+                     "peak": "bf16_flops_per_s"}}
+    (b / "metrics" / "expert_kernel_roofline.json").write_text(json.dumps(spec))
+    man = copy.deepcopy(SHIPPED)
+    man["configs"].append({"name": "mellum2-pair-topk32k", "source": MELLUM2["source"],
+                           "file": "benchmarks/configs/mellum2-pair-topk32k.json",
+                           "reduced": ["num_hidden_layers", "layer_types"],
+                           "why": "one period of the layer pattern, every expert"})
+    man["workloads"].append({"name": "train-live-mellum2", "config": "mellum2-pair-topk32k",
+                             "traffic": "live-long", "chips": 1, "why": "4096-token rows"})
+    man["per_layer"].append({k: v for k, v in spec.items()
+                             if k not in ("reducer", "args", "chip_only")}
+                            | {"name": "expert_kernel_roofline",
+                               "workloads": ["train-live-mellum2"]})
+    for m in man["end_to_end"] + man["per_layer"]:
+        if m["name"] in ("train_rows_per_s", "setup_first_fill_s"):
+            m["workloads"].append("train-live-mellum2")
+    return man, published
+
+
+def _the_four_statements(man, root, published):
+    manifest.validate(man, root)
+    accepted.configurations_keep_their_published_numbers(man, root, published)
+    accepted.traffic_files_name_runners_that_are_there(man, root)
+    for name in accepted.PR26:
+        accepted.a_pr26_metric_lists_both_accepted_cells_first(man, name)
+    accepted.pr26_metrics_keep_their_places(man)
+
+
+def test_a_later_prs_additions_pass_the_validator_and_the_four_statements(code_root):
+    """Before ISSUE 28 the same additions failed all four (Ouro's numbers as a
+    literal for every configuration, two runner names, ``workloads == CELLS``,
+    the ten the LAST ten)."""
+    before = {f: f.read_bytes() for f in code_root.rglob("*") if f.is_file()}
+    man, published = _a_later_prs_additions(code_root)
+    _the_four_statements(man, code_root, published)
+    assert accepted.may_be_absent_on_the_cpu("expert_kernel_roofline", man, code_root)
+    assert not accepted.may_be_absent_on_the_cpu("harvest_peak_share", man, code_root)
+    assert all(f.read_bytes() == was for f, was in before.items())
+
+
+def _edit_config(root, **keys):
+    f = root / "benchmarks" / "configs" / "mellum2-pair-topk32k.json"
+    f.write_text(json.dumps({**json.loads(f.read_text()), **keys}))
+
+
+def _entry(man, name):
+    return next(m for m in man["per_layer"] if m["name"] == name)
+
+
+def _swap_two_of_the_ten(man, root):
+    i, j = (man["per_layer"].index(_entry(man, n)) for n in accepted.PR26[2:4])
+    man["per_layer"][i], man["per_layer"][j] = man["per_layer"][j], man["per_layer"][i]
+
+
+# the faults the four were written for: each breaks the additions above
+STILL_REFUSED = {
+    "a number that differs from the published one and is not in reduced":
+        lambda man, root: _edit_config(root, vocab_size=49152),
+    "a published number left out of the configuration":
+        lambda man, root: _edit_config(root, num_experts_per_tok=None),
+    "a width in reduced":
+        lambda man, root: (_edit_config(root, moe_intermediate_size=448),
+                           man["configs"][-1]["reduced"].append("moe_intermediate_size")),
+    "a crosscoder narrower than the model":
+        lambda man, root: _edit_config(root, crosscoder={"d_in": 2048}),
+    "a source with no file of published numbers":
+        lambda man, root: (_edit_config(root, source="https://example.org/other"),
+                           man["configs"][-1].update(source="https://example.org/other")),
+    "a source that disagrees with the configuration's file":
+        lambda man, root: _edit_config(
+            root, source=SHIPPED["configs"][0]["source"]),
+    "a runner that is not there":
+        lambda man, root: (root / "benchmarks" / "runners" / "train_long.py").rename(
+            root / "benchmarks" / "runners" / "elsewhere.py"),
+    "a runner module with no run":
+        lambda man, root: (root / "benchmarks" / "runners" / "train_long.py").write_text(
+            "from benchmarks.runners.train import CycleLog  # noqa: F401\n"),
+    "a PR 26 metric dropped from an accepted cell":
+        lambda man, root: _entry(man, "setup_first_fill_s")["workloads"].remove(
+            "train-live-topk32k"),
+    "a later cell put before the accepted two":
+        lambda man, root: _entry(man, "setup_first_fill_s")["workloads"].sort(
+            key=lambda w: w != "train-live-mellum2"),
+    "two of the ten in another order": _swap_two_of_the_ten,
+    "an entry put before those that were there":
+        lambda man, root: man["per_layer"].insert(0, man["per_layer"].pop()),
+}
+
+
+@pytest.mark.parametrize("what", sorted(STILL_REFUSED))
+def test_the_four_statements_still_refuse(what, code_root):
+    man, published = _a_later_prs_additions(code_root)
+    STILL_REFUSED[what](man, code_root)
+    with pytest.raises((AssertionError, manifest.ManifestError)):
+        _the_four_statements(man, code_root, published)
 
 
 def test_run_py_fails_without_a_chip_and_prints_no_result():

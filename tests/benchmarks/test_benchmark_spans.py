@@ -5,6 +5,7 @@ hand-made cases, and on what a program WITHOUT the spans gives (nothing)."""
 import json
 from pathlib import Path
 
+import accepted
 import pytest
 
 from benchmarks import manifest, run
@@ -13,11 +14,7 @@ from benchmarks.reducers import (interval_spans, module_device_ms,
 
 DATA = Path(__file__).parent / "data"
 SHIPPED = manifest.load()
-NEW = ["loop_produce_self_ms_per_step", "loop_main_self_ms_per_step",
-       "loop_step_dispatch_ms_per_step", "loop_log_sync_ms",
-       "loop_slow_cycle_host_excess_ms", "setup_calibrate_s", "setup_first_fill_s",
-       "setup_init_state_s", "cc_bare_device_ms_per_step", "cc_full_device_ms_per_step"]
-CELLS = ["train-live-relu16k", "train-live-topk32k"]
+NEW, CELLS = accepted.PR26, accepted.CELLS
 
 
 def _spec(name: str) -> dict:
@@ -34,8 +31,7 @@ def _row(interval_s=2.0, steps=10, **spans) -> dict:
 @pytest.mark.parametrize("name", NEW)
 def test_the_manifest_lists_the_metric_in_both_cells_and_its_file_agrees(name):
     manifest.validate(SHIPPED)
-    entry = next(m for m in SHIPPED["per_layer"] if m["name"] == name)
-    assert entry["workloads"] == CELLS
+    entry = accepted.a_pr26_metric_lists_both_accepted_cells_first(SHIPPED, name)
     for cell in CELLS:
         assert name in [m["name"] for m in manifest.cell(SHIPPED, cell)["per_layer"]]
     spec = _spec(name)
@@ -48,8 +44,7 @@ def test_the_manifest_lists_the_metric_in_both_cells_and_its_file_agrees(name):
 
 
 def test_new_entries_come_after_every_entry_that_was_there():
-    names = [m["name"] for m in SHIPPED["per_layer"]]
-    assert names[-len(NEW):] == NEW and names.index("device_peak_hbm_gib.train") == 13
+    accepted.pr26_metrics_keep_their_places(SHIPPED)
 
 
 @pytest.mark.parametrize("sample", ["spans_relu16k_sample.json", "spans_topk32k_sample.json"])
