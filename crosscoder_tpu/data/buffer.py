@@ -438,8 +438,25 @@ class PairedActivationBuffer:
             sums += np.asarray(jax.device_get(part), np.float64)
 
         self._pipelined(produced(), drain)
+        if cfg.obs == "on" and self.lm_cfg.sparse:
+            self._gauge_expert_load()
         mean_norm = sums / max(count, 1)
         return (np.sqrt(cfg.d_in) / mean_norm).astype(np.float32)
+
+    def _gauge_expert_load(self) -> None:
+        """``harvest/moe_load_max_over_mean``: how unevenly the first model's
+        router spreads one calibration chunk over its experts (rows at the
+        busiest expert over the mean, worst layer). Read ONCE, here, where
+        calibration fetches from the device anyway: the loop gains no sync,
+        and with ``obs`` off nothing runs."""
+        from crosscoder_tpu.ops import moe
+
+        padded, _ = self._pad_chunk(self.tokens[: self._chunk_seqs])
+        counts = lm.expert_load(
+            self.model_params[0], jnp.asarray(padded), self.lm_cfg,
+            max(lm.hooked_depth(self.lm_cfg, self.hook_points), 1))
+        obs.gauge("harvest/moe_load_max_over_mean",
+                  moe.load_max_over_mean(jax.device_get(counts)))
 
     def refresh(self) -> None:
         """Synchronous refill: first fill, resume, and tests.

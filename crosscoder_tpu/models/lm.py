@@ -1,4 +1,4 @@
-"""JAX Gemma-2 runtime with residual-stream capture and splicing.
+"""JAX subject-LM runtime with residual-stream capture and splicing.
 
 This module replaces the reference's entire "external model runtime" layer —
 TransformerLens ``HookedTransformer`` (reference ``train.py:45-55``,
@@ -29,6 +29,17 @@ TPU-first design decisions (why this is not a TransformerLens translation):
   (Gemma-2-2B bf16 ≈ 5.2 GB/model fits one chip's HBM) — shardings are
   expressed at the call site, not baked in here.
 
+One config type (:class:`LMConfig`) carries a layer table (attention kind
+and MLP kind per layer), the block's style, RoPE per attention kind and the
+expert sizes; every forward below takes a layer's kind from ONE lookup
+(:func:`_layer_kind`) and runs ONE block (:func:`_block`). Two families run
+through it: Gemma-2 (next paragraph) and the pre-norm sparse-expert block of
+Mellum2 (``LMConfig.mellum2_12b``: plain-weight RMSNorm before each sublayer
+only, no soft-caps, no embedding scale, three window layers to one full
+layer with YaRN on the full layers only, every MLP ``ops/moe.py``'s routed
+experts; checked against ``benchmarks/reference/mellum_ref.py`` by
+``tests/test_mellum.py``).
+
 Gemma-2 architecture facts implemented (validated against the HF
 ``transformers`` Gemma2 implementation by ``tests/test_lm.py``): RMSNorm with
 (1+w) scaling in fp32; embedding scaled by sqrt(d_model); GeGLU MLP with
@@ -44,7 +55,7 @@ import functools
 import math
 import os
 from dataclasses import dataclass
-from typing import Any, Callable, Mapping, Sequence
+from typing import Any, Callable, Mapping, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -64,9 +75,50 @@ def _put_global(tree, shardings):
 LMParams = dict[str, Any]
 
 
+# layer kinds, under the names public configs give them (``layer_types``,
+# ``mlp_layer_types``)
+SLIDING, FULL = "sliding_attention", "full_attention"
+DENSE, SPARSE = "dense", "sparse"
+
+
+def _alternate(n_layers: int) -> tuple[str, ...]:
+    """Gemma-2's pattern: even layers attend within the sliding window."""
+    return tuple(SLIDING if i % 2 == 0 else FULL for i in range(n_layers))
+
+
+@dataclass(frozen=True)
+class Rope:
+    """Rotary-embedding parameters of ONE attention kind. ``yarn_factor``
+    0 is plain RoPE; otherwise static YaRN as HF computes it: frequencies
+    blended between ``theta``'s own and those divided by the factor over a
+    ramp found from ``original_max_position`` and the two betas, cos and sin
+    multiplied by ``attention_factor``, at every length."""
+
+    theta: float = 10_000.0
+    yarn_factor: float = 0.0
+    original_max_position: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
 @dataclass(frozen=True)
 class LMConfig:
-    """Gemma-2 family architecture config."""
+    """Architecture config of a decoder-only subject LM: sizes, a layer
+    table (attention kind and MLP kind per layer) and the block's style.
+
+    ``block_style``: ``"sandwich"`` is Gemma-2's block — (1 + w) RMSNorms
+    before AND after each sublayer, the embedding scaled by sqrt(d_model), a
+    tanh-GELU gate; ``"prenorm"`` is the plain pre-norm block — RMSNorm with
+    weight ``w`` before each sublayer only, no embedding scale, a SiLU gate.
+    Soft-caps are their own fields (0 = none). ``layer_types`` left None is
+    Gemma-2's alternate pattern, ``mlp_types`` left None is all dense; both
+    are tuples so the config stays hashable (it is a static jit argument).
+    ``rope`` maps an attention kind to its :class:`Rope`; a kind it does not
+    list rotates by plain ``rope_theta``. A sparse layer routes each token to
+    ``experts_per_tok`` of ``n_experts`` gated MLPs of width ``d_expert``
+    (:mod:`crosscoder_tpu.ops.moe`); ``d_ff`` is the dense layers' width.
+    """
 
     vocab_size: int
     d_model: int
@@ -82,6 +134,51 @@ class LMConfig:
     sliding_window: int = 4096
     query_pre_attn_scalar: float = 256.0
     dtype: str = "bf16"
+    layer_types: tuple[str, ...] | None = None
+    mlp_types: tuple[str, ...] | None = None
+    block_style: str = "sandwich"
+    rope: tuple[tuple[str, Rope], ...] = ()
+    n_experts: int = 0
+    experts_per_tok: int = 0
+    d_expert: int = 0
+    norm_topk_prob: bool = True
+    tie_embeddings: bool = True
+
+    def __post_init__(self) -> None:
+        def fill(name, default, valid):
+            table = getattr(self, name)
+            table = default if table is None else tuple(table)
+            if len(table) != self.n_layers or set(table) - set(valid):
+                raise ValueError(
+                    f"{name} must give one of {valid} for each of "
+                    f"{self.n_layers} layers, got {table}")
+            object.__setattr__(self, name, table)
+
+        fill("layer_types", _alternate(self.n_layers), (SLIDING, FULL))
+        fill("mlp_types", (DENSE,) * self.n_layers, (DENSE, SPARSE))
+        if self.block_style not in ("sandwich", "prenorm"):
+            raise ValueError(f"block_style must be sandwich|prenorm, got {self.block_style!r}")
+        if len(set(self.mlp_types)) > 1:
+            # the layers are STACKED leaves under one lax.scan: dense and
+            # sparse layers would need two stacks and two scans
+            raise ValueError(
+                "dense and sparse MLP layers in one model are not supported "
+                "yet (leading dense layers need a second stack of leaves); "
+                f"got mlp_types={self.mlp_types}")
+        if self.sparse and not (0 < self.experts_per_tok <= self.n_experts
+                                and self.d_expert > 0):
+            raise ValueError(
+                f"sparse layers need 0 < experts_per_tok <= n_experts and "
+                f"d_expert > 0, got {self.experts_per_tok} of {self.n_experts} "
+                f"experts of width {self.d_expert}")
+
+    @property
+    def sparse(self) -> bool:
+        """Whether the (homogeneous) MLP layers are expert layers."""
+        return SPARSE in self.mlp_types
+
+    def rope_of(self, kind: str) -> Rope:
+        return dict(self.rope).get(kind, Rope(theta=self.rope_theta))
 
     @classmethod
     def gemma2_2b(cls) -> "LMConfig":
@@ -110,6 +207,27 @@ class LMConfig:
         )
 
     @classmethod
+    def mellum2_12b(cls) -> "LMConfig":
+        """Mellum2-12B-A2.5B (JetBrains): a pre-norm block, every MLP 64
+        experts of width 896 with top-8 routing, three 1024-window layers
+        to one full layer, YaRN x16 on the full layers only, untied head."""
+        n = 28
+        return cls(
+            vocab_size=98_304, d_model=2304, n_layers=n, n_heads=32,
+            n_kv_heads=4, head_dim=128, d_ff=7168, rope_theta=500_000.0,
+            attn_softcap=0.0, final_softcap=0.0, sliding_window=1024,
+            query_pre_attn_scalar=128.0,
+            layer_types=tuple(FULL if i % 4 == 3 else SLIDING for i in range(n)),
+            mlp_types=(SPARSE,) * n, block_style="prenorm",
+            rope=((FULL, Rope(theta=500_000.0, yarn_factor=16.0,
+                              original_max_position=8192, beta_fast=32.0,
+                              beta_slow=1.0,
+                              attention_factor=1.2772588722239782)),),
+            n_experts=64, experts_per_tok=8, d_expert=896,
+            norm_topk_prob=True, tie_embeddings=False,
+        )
+
+    @classmethod
     def tiny(cls, vocab_size: int = 257, n_layers: int = 4) -> "LMConfig":
         """Deterministic test-sized config (the 'fake LM' of SURVEY.md §4 —
         same hook semantics as the real model, no 2.6B-param download)."""
@@ -120,6 +238,14 @@ class LMConfig:
         )
 
     def replace(self, **kw: Any) -> "LMConfig":
+        """``dataclasses.replace``; a new ``n_layers`` refills a layer table
+        that was the default pattern (a table given by hand must be given
+        again at the new depth)."""
+        if kw.get("n_layers", self.n_layers) != self.n_layers:
+            if self.layer_types == _alternate(self.n_layers):
+                kw.setdefault("layer_types", None)
+            if self.mlp_types:
+                kw.setdefault("mlp_types", (self.mlp_types[0],) * kw["n_layers"])
         return dataclasses.replace(self, **kw)
 
 
@@ -130,6 +256,9 @@ _NAMED_CONFIGS = {
     "gemma-2-9b-it": LMConfig.gemma2_9b,
     "gemma-2-27b": LMConfig.gemma2_27b,
     "gemma-2-27b-it": LMConfig.gemma2_27b,
+    "mellum2-12b-a2.5b": LMConfig.mellum2_12b,
+    "mellum2-12b-a2.5b-base": LMConfig.mellum2_12b,
+    "mellum2-12b-a2.5b-instruct": LMConfig.mellum2_12b,
 }
 
 
@@ -149,6 +278,11 @@ def init_params(key: jax.Array, cfg: LMConfig) -> LMParams:
     """Random-init params (the fake-LM fixture; real runs use ``from_hf``).
 
     Layer leaves are stacked on a leading [n_layers] axis for ``lax.scan``.
+    The leaves follow the config: a ``"prenorm"`` block has no post-norms
+    (and its norm weights start at 1, a ``"sandwich"`` block's (1 + w) at
+    0), a sparse MLP has ``router`` [D, E], ``we_gate_up`` [E, D, 2·Fe]
+    (gate columns first) and ``we_down`` [E, Fe, D] in place of the dense
+    three, an untied head is ``unembed`` [V, D].
     """
     dt = dtype_of(cfg.dtype)
     D, F, L = cfg.d_model, cfg.d_ff, cfg.n_layers
@@ -158,48 +292,117 @@ def init_params(key: jax.Array, cfg: LMConfig) -> LMParams:
     def nrm(k, shape, scale):
         return (jax.random.normal(k, shape, dtype=jnp.float32) * scale).astype(dt)
 
-    return {
-        "embed": nrm(ks[0], (cfg.vocab_size, D), D ** -0.5),
-        "final_norm": jnp.zeros((D,), dt),
-        "layers": {
-            "attn_norm": jnp.zeros((L, D), dt),
-            "post_attn_norm": jnp.zeros((L, D), dt),
-            "pre_ffw_norm": jnp.zeros((L, D), dt),
-            "post_ffw_norm": jnp.zeros((L, D), dt),
-            "wq": nrm(ks[1], (L, D, qd), D ** -0.5),
-            "wk": nrm(ks[2], (L, D, kd), D ** -0.5),
-            "wv": nrm(ks[3], (L, D, kd), D ** -0.5),
-            "wo": nrm(ks[4], (L, qd, D), qd ** -0.5),
-            "w_gate": nrm(ks[5], (L, D, F), D ** -0.5),
-            "w_up": nrm(ks[6], (L, D, F), D ** -0.5),
-            "w_down": nrm(ks[7], (L, F, D), F ** -0.5),
-        },
+    sandwich = cfg.block_style == "sandwich"
+    unit = jnp.zeros if sandwich else jnp.ones
+    layers = {
+        "attn_norm": unit((L, D), dt),
+        "pre_ffw_norm": unit((L, D), dt),
+        "wq": nrm(ks[1], (L, D, qd), D ** -0.5),
+        "wk": nrm(ks[2], (L, D, kd), D ** -0.5),
+        "wv": nrm(ks[3], (L, D, kd), D ** -0.5),
+        "wo": nrm(ks[4], (L, qd, D), qd ** -0.5),
     }
+    if sandwich:
+        layers["post_attn_norm"] = unit((L, D), dt)
+        layers["post_ffw_norm"] = unit((L, D), dt)
+    if cfg.sparse:
+        E, Fe = cfg.n_experts, cfg.d_expert
+        layers["router"] = nrm(ks[5], (L, D, E), D ** -0.5)
+        layers["we_gate_up"] = nrm(ks[6], (L, E, D, 2 * Fe), D ** -0.5)
+        layers["we_down"] = nrm(ks[7], (L, E, Fe, D), Fe ** -0.5)
+    else:
+        layers["w_gate"] = nrm(ks[5], (L, D, F), D ** -0.5)
+        layers["w_up"] = nrm(ks[6], (L, D, F), D ** -0.5)
+        layers["w_down"] = nrm(ks[7], (L, F, D), F ** -0.5)
+    params = {
+        "embed": nrm(ks[0], (cfg.vocab_size, D), D ** -0.5),
+        "final_norm": unit((D,), dt),
+        "layers": layers,
+    }
+    if not cfg.tie_embeddings:
+        params["unembed"] = nrm(ks[8], (cfg.vocab_size, D), D ** -0.5)
+    return params
 
 
 def param_count(cfg: LMConfig) -> int:
     D, F, L = cfg.d_model, cfg.d_ff, cfg.n_layers
     qd, kd = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
-    per_layer = 4 * D + D * qd + 2 * D * kd + qd * D + 2 * D * F + F * D
-    return cfg.vocab_size * D + D + L * per_layer
+    norms = 4 * D if cfg.block_style == "sandwich" else 2 * D
+    mlp = (D * cfg.n_experts + cfg.n_experts * 3 * D * cfg.d_expert
+           if cfg.sparse else 3 * D * F)
+    per_layer = norms + D * qd + 2 * D * kd + qd * D + mlp
+    heads = 1 if cfg.tie_embeddings else 2
+    return heads * cfg.vocab_size * D + D + L * per_layer
 
 
 # ---------------------------------------------------------------------------
 # numerics
 
 
-def _rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
-    """Gemma RMSNorm: fp32 compute, (1 + w) scale."""
+def _rms_norm(x: jax.Array, w: jax.Array, eps: float, plain: bool = False) -> jax.Array:
+    """RMSNorm in fp32: Gemma's (1 + w) scale, or ``plain`` w."""
     xf = x.astype(jnp.float32)
     xf = xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + eps)
-    return (xf * (1.0 + w.astype(jnp.float32))).astype(x.dtype)
+    scale = w.astype(jnp.float32) if plain else 1.0 + w.astype(jnp.float32)
+    return (xf * scale).astype(x.dtype)
+
+
+def _norm(x: jax.Array, w: jax.Array, cfg: LMConfig) -> jax.Array:
+    return _rms_norm(x, w, cfg.rms_eps, plain=cfg.block_style == "prenorm")
 
 
 def _softcap(x: jax.Array, cap: float) -> jax.Array:
     return cap * jnp.tanh(x / cap)
 
 
-def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def rope_inv_freq(rope: Rope, head_dim: int) -> jax.Array:
+    """The ``head_dim // 2`` rotation frequencies of one attention kind."""
+    d = head_dim
+    if not rope.yarn_factor:
+        return 1.0 / (rope.theta ** (jnp.arange(0, d // 2, dtype=jnp.float32) * 2.0 / d))
+    # YaRN (HF ``_compute_yarn_parameters``), closed form, in float64
+    extra = rope.theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+    inter = extra / rope.yarn_factor
+
+    def correction_dim(rotations: float) -> float:
+        return (d * math.log(rope.original_max_position / (rotations * 2 * math.pi))
+                / (2 * math.log(rope.theta)))
+
+    low = max(math.floor(correction_dim(rope.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(rope.beta_slow)), d - 1)
+    ramp = np.clip((np.arange(d // 2) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return jnp.asarray(inter * ramp + extra * (1.0 - ramp), jnp.float32)
+
+
+class _LayerKind(NamedTuple):
+    """What a layer's place in the config's table selects."""
+
+    index: Any          # the (traced) layer id itself
+    is_local: Any       # bool scalar (traced): the sliding-window mask
+    inv_freq: Any       # [head_dim // 2] RoPE frequencies of the layer's kind
+    rope_factor: Any    # what cos and sin are multiplied by; python 1.0 = not
+
+
+def _layer_kind(cfg: LMConfig, i: jax.Array) -> _LayerKind:
+    """The ONE lookup of the traced layer id ``i`` in ``cfg.layer_types``,
+    shared by every forward. Where both attention kinds rotate alike (the
+    Gemma-2 family) the RoPE side is static and only the mask is looked up."""
+    is_local = jnp.asarray([k == SLIDING for k in cfg.layer_types])[i]
+    local, full = cfg.rope_of(SLIDING), cfg.rope_of(FULL)
+    if local == full:
+        return _LayerKind(i, is_local, rope_inv_freq(local, cfg.head_dim),
+                          local.attention_factor)
+    return _LayerKind(
+        i, is_local,
+        jnp.where(is_local, rope_inv_freq(local, cfg.head_dim),
+                  rope_inv_freq(full, cfg.head_dim)),
+        jnp.where(is_local, jnp.float32(local.attention_factor),
+                  jnp.float32(full.attention_factor)),
+    )
+
+
+def _rope(x: jax.Array, positions: jax.Array, inv_freq: jax.Array,
+          factor: Any = 1.0) -> jax.Array:
     """Rotate pairs (x[..., :d/2], x[..., d/2:]) — HF 'split-half' layout.
 
     x: [B, S, n_heads, head_dim]; positions: [S] (shared across the batch,
@@ -207,10 +410,11 @@ def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     plane carries each document's own within-document positions).
     """
     d = x.shape[-1]
-    freqs = 1.0 / (theta ** (jnp.arange(0, d // 2, dtype=jnp.float32) * 2.0 / d))
-    ang = positions.astype(jnp.float32)[..., None] * freqs   # [(B,) S, d/2]
+    ang = positions.astype(jnp.float32)[..., None] * inv_freq  # [(B,) S, d/2]
     cos = jnp.expand_dims(jnp.cos(ang), -2)                  # [(B,) S, 1, d/2]
     sin = jnp.expand_dims(jnp.sin(ang), -2)
+    if not (isinstance(factor, float) and factor == 1.0):
+        cos, sin = cos * factor, sin * factor
     x1, x2 = x[..., : d // 2], x[..., d // 2:]
     xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
     return jnp.concatenate(
@@ -219,7 +423,8 @@ def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 
 
 def _qkv(
-    x: jax.Array, lp: Mapping[str, jax.Array], cfg: LMConfig, pos: jax.Array
+    x: jax.Array, lp: Mapping[str, jax.Array], cfg: LMConfig, pos: jax.Array,
+    kind: _LayerKind,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Project + RoPE: q [B,S,H,hd], k/v [B,S,KV,hd]. ``pos`` carries GLOBAL
     positions so sequence-sharded callers rotate correctly."""
@@ -228,8 +433,8 @@ def _qkv(
     q = jnp.einsum("bsd,dq->bsq", x, lp["wq"], preferred_element_type=jnp.float32)
     k = jnp.einsum("bsd,dk->bsk", x, lp["wk"], preferred_element_type=jnp.float32)
     v = jnp.einsum("bsd,dk->bsk", x, lp["wv"], preferred_element_type=jnp.float32)
-    q = _rope(q.astype(x.dtype).reshape(B, S, H, hd), pos, cfg.rope_theta)
-    k = _rope(k.astype(x.dtype).reshape(B, S, KV, hd), pos, cfg.rope_theta)
+    q = _rope(q.astype(x.dtype).reshape(B, S, H, hd), pos, kind.inv_freq, kind.rope_factor)
+    k = _rope(k.astype(x.dtype).reshape(B, S, KV, hd), pos, kind.inv_freq, kind.rope_factor)
     return q, k, v.astype(x.dtype).reshape(B, S, KV, hd)
 
 
@@ -288,48 +493,101 @@ def _attn_core(
     )
 
 
+# The expert leaves are never sliced per layer: one layer's are 0.8 GB at
+# Mellum2's sizes, and a slice that feeds a kernel is a copy. They stay
+# STACKED beside the scanned leaves and the expert layer indexes them in
+# place by the layer id (``ops/moe.py``).
+_HELD_LEAVES = ("we_gate_up", "we_down")
+
+
+def _scan_leaves(layers: Mapping[str, jax.Array], take: Callable) -> tuple[dict, dict]:
+    """The stacked layer leaves as ``(xs, held)``: ``xs`` cut to the scanned
+    layers by ``take`` (the scan's per-layer operand), ``held`` whole."""
+    held = {k: layers[k] for k in _HELD_LEAVES if k in layers}
+    xs = {k: take(v) for k, v in layers.items() if k not in held}
+    return xs, held
+
+
+def _attn_out(a: jax.Array, lp: Mapping[str, jax.Array], cfg: LMConfig) -> jax.Array:
+    """Output projection of the attended heads [B, S, H·hd], then the
+    sandwich block's post-norm: the contribution as ADDED to the stream."""
+    a = jnp.einsum("bsq,qd->bsd", a, lp["wo"], preferred_element_type=jnp.float32).astype(a.dtype)
+    if cfg.block_style == "sandwich":
+        a = _norm(a, lp["post_attn_norm"], cfg)
+    return a
+
+
 @jax.named_scope("harvest/block/attn")
 def _attention(
-    x: jax.Array, lp: Mapping[str, jax.Array], cfg: LMConfig, is_local: jax.Array
+    x: jax.Array, lp: Mapping[str, jax.Array], cfg: LMConfig, kind: _LayerKind
 ) -> jax.Array:
-    """One attention sublayer on [B, S, D]. ``is_local`` selects the
-    sliding-window mask (traced scalar — both masks are static precomputes)."""
+    """One attention sublayer on the normed stream [B, S, D], as added to
+    the stream. ``kind.is_local`` selects the sliding-window mask (traced
+    scalar — both masks are static precomputes)."""
     B, S, D = x.shape
-    q, k, v = _qkv(x, lp, cfg, jnp.arange(S))
-    out = _attn_core(q, k, v, cfg, is_local)
-    return jnp.einsum("bsq,qd->bsd", out, lp["wo"], preferred_element_type=jnp.float32).astype(x.dtype)
+    q, k, v = _qkv(x, lp, cfg, jnp.arange(S), kind)
+    return _attn_out(_attn_core(q, k, v, cfg, kind.is_local), lp, cfg)
 
 
 @jax.named_scope("harvest/block/mlp")
-def _mlp(x: jax.Array, lp: Mapping[str, jax.Array]) -> jax.Array:
-    """GeGLU: gelu_tanh(x·W_gate) ⊙ (x·W_up) · W_down."""
+def _mlp(x: jax.Array, lp: Mapping[str, jax.Array], cfg: LMConfig, layer: Any) -> jax.Array:
+    """The gated MLP of the config's kind on the normed stream: dense
+    ``act(x·W_gate) ⊙ (x·W_up) · W_down`` (tanh-GELU in the sandwich block,
+    SiLU in the pre-norm one), or the routed experts (``ops/moe.py``; their
+    leaves in ``lp`` are the STACKED ones, indexed by ``layer``)."""
+    if cfg.sparse:
+        from crosscoder_tpu.ops import moe
+
+        return moe.moe_mlp(
+            x, lp["router"], lp["we_gate_up"], lp["we_down"], layer,
+            top_k=cfg.experts_per_tok, norm_topk_prob=cfg.norm_topk_prob)
     gate = jnp.einsum("bsd,df->bsf", x, lp["w_gate"], preferred_element_type=jnp.float32)
     up = jnp.einsum("bsd,df->bsf", x, lp["w_up"], preferred_element_type=jnp.float32)
-    h = (jax.nn.gelu(gate, approximate=True) * up).astype(x.dtype)
+    if cfg.block_style == "sandwich":
+        gate = jax.nn.gelu(gate, approximate=True)
+    else:
+        gate = jax.nn.silu(gate)
+    h = (gate * up).astype(x.dtype)
     return jnp.einsum("bsf,fd->bsd", h, lp["w_down"], preferred_element_type=jnp.float32).astype(x.dtype)
 
 
+def _mlp_out(resid: jax.Array, lp: Mapping[str, jax.Array], cfg: LMConfig, layer: Any) -> jax.Array:
+    """The MLP sublayer of layer ``layer`` on the stream, as added to it."""
+    m = _mlp(_norm(resid, lp["pre_ffw_norm"], cfg), lp, cfg, layer)
+    if cfg.block_style == "sandwich":
+        m = _norm(m, lp["post_ffw_norm"], cfg)
+    return m
+
+
+def _embed(params: LMParams, tokens: jax.Array, cfg: LMConfig) -> jax.Array:
+    with jax.named_scope("harvest/embed"):
+        dt = dtype_of(cfg.dtype)
+        resid = params["embed"][tokens].astype(dt)
+        if cfg.block_style == "sandwich":
+            resid = resid * jnp.asarray(math.sqrt(cfg.d_model), dt)
+        return resid
+
+
 def _block(
-    resid: jax.Array, lp: Mapping[str, jax.Array], cfg: LMConfig, is_local: jax.Array,
+    resid: jax.Array, lp: Mapping[str, jax.Array], cfg: LMConfig, kind: _LayerKind,
     edit_attn: Callable[[jax.Array], jax.Array] | None = None,
     edit_mlp: Callable[[jax.Array], jax.Array] | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """One Gemma-2 transformer block (sandwich norms around attn and MLP).
+    """One transformer block of the config's style and kinds.
 
     Returns ``(resid, attn_out, mlp_out)`` — the updated stream plus the two
-    sublayer contributions exactly as they are ADDED to it (post the Gemma-2
-    sandwich post-norms), which is what ``hook_attn_out``/``hook_mlp_out``
-    capture: the intermediates exist anyway, so exposing them is free.
-    ``edit_attn``/``edit_mlp`` intervene on a contribution BEFORE it joins
-    the stream (and before its capture) — the sublayer-site analogue of the
-    residual edits, used by CE-recovered evals of sublayer crosscoders."""
-    a = _attention(_rms_norm(resid, lp["attn_norm"], cfg.rms_eps), lp, cfg, is_local)
-    attn_out = _rms_norm(a, lp["post_attn_norm"], cfg.rms_eps)
+    sublayer contributions exactly as they are ADDED to it (in the sandwich
+    block: after its post-norms), which is what ``hook_attn_out``/
+    ``hook_mlp_out`` capture: the intermediates exist anyway, so exposing
+    them is free. ``edit_attn``/``edit_mlp`` intervene on a contribution
+    BEFORE it joins the stream (and before its capture) — the sublayer-site
+    analogue of the residual edits, used by CE-recovered evals of sublayer
+    crosscoders."""
+    attn_out = _attention(_norm(resid, lp["attn_norm"], cfg), lp, cfg, kind)
     if edit_attn is not None:
         attn_out = edit_attn(attn_out)
     resid = resid + attn_out
-    m = _mlp(_rms_norm(resid, lp["pre_ffw_norm"], cfg.rms_eps), lp)
-    mlp_out = _rms_norm(m, lp["post_ffw_norm"], cfg.rms_eps)
+    mlp_out = _mlp_out(resid, lp, cfg, kind.index)
     if edit_mlp is not None:
         mlp_out = edit_mlp(mlp_out)
     return resid + mlp_out, attn_out, mlp_out
@@ -391,9 +649,11 @@ def _capture_into(
 
 
 def _unembed(params: LMParams, resid: jax.Array, cfg: LMConfig) -> jax.Array:
-    """Final RMSNorm → tied unembedding → final-logit softcap."""
-    x = _rms_norm(resid, params["final_norm"], cfg.rms_eps)
-    logits = jnp.einsum("bsd,vd->bsv", x, params["embed"], preferred_element_type=jnp.float32)
+    """Final RMSNorm → unembedding (the embedding again where tied) →
+    final-logit softcap."""
+    x = _norm(resid, params["final_norm"], cfg)
+    head = params["embed" if cfg.tie_embeddings else "unembed"]
+    logits = jnp.einsum("bsd,vd->bsv", x, head, preferred_element_type=jnp.float32)
     if cfg.final_softcap:
         logits = _softcap(logits, cfg.final_softcap)
     return logits
@@ -442,6 +702,11 @@ def _scan_stop(pairs: tuple[tuple[int, int], ...]) -> int:
     )
 
 
+def hooked_depth(cfg: LMConfig, hook_points: Sequence[str]) -> int:
+    """Blocks a capture-only forward over ``hook_points`` runs."""
+    return min(cfg.n_layers, _scan_stop(_hook_layers(cfg, tuple(hook_points))))
+
+
 # ---------------------------------------------------------------------------
 # forward
 
@@ -469,8 +734,7 @@ def _forward_impl(
     if n_scan is None:
         n_scan = cfg.n_layers
 
-    with jax.named_scope("harvest/embed"):
-        resid = params["embed"][tokens].astype(dt) * jnp.asarray(math.sqrt(D), dt)
+    resid = _embed(params, tokens, cfg)
 
     n_cap = len(capture)
     cap_arr = jnp.asarray([l for l, _ in capture], dtype=jnp.int32) if n_cap else None
@@ -498,15 +762,15 @@ def _forward_impl(
     # highest needed layer (the reference harvests with FULL forwards even
     # for a mid-stack hook — reference buffer.py:81-89 — wasting every layer
     # above it; at blocks.14 of 26 that is ~46% of the forward FLOPs)
-    stacked = jax.tree_util.tree_map(lambda x: x[:n_scan], params["layers"])
+    stacked, held = _scan_leaves(params["layers"], lambda x: x[:n_scan])
     layer_ids = jnp.arange(n_scan, dtype=jnp.int32)
 
     def body(carry, xs):
         resid, buf = carry
         lp, i = xs
+        lp = {**lp, **held}
         resid = apply_hooks(resid, i)
         buf = _capture_into(buf, resid, i, cap_arr, _SITE_RESID, cap_sites)
-        is_local = (i % 2) == 0                             # even layers: sliding window
 
         def editor_for(site):
             # sublayer-site edits, applied to the contribution at its own
@@ -526,7 +790,7 @@ def _forward_impl(
             return ed
 
         resid, attn_out, mlp_out = _block(
-            resid, lp, cfg, is_local,
+            resid, lp, cfg, _layer_kind(cfg, i),
             edit_attn=editor_for(_SITE_ATTN), edit_mlp=editor_for(_SITE_MLP),
         )
         if want_attn:
@@ -644,6 +908,33 @@ def ce_loss(
     return loss_fn(logits, tokens)
 
 
+@functools.partial(jax.jit, static_argnames=("cfg", "n_scan"))
+def expert_load(params: LMParams, tokens: jax.Array, cfg: LMConfig, n_scan: int) -> jax.Array:
+    """Routed rows per expert in the first ``n_scan`` (sparse) layers of a
+    forward over ``tokens``: ``[n_scan, n_experts]`` int32. A diagnostic
+    forward of its own (the harvest programs carry no such output) — the
+    buffer runs it once, at calibration, for the
+    ``harvest/moe_load_max_over_mean`` gauge, and only with ``obs`` on."""
+    from crosscoder_tpu.ops import moe
+
+    def body(resid, xs):
+        lp, i = xs
+        lp = {**lp, **held}
+        attn = _attention(_norm(resid, lp["attn_norm"], cfg), lp, cfg, _layer_kind(cfg, i))
+        resid = resid + attn
+        x = _norm(resid, lp["pre_ffw_norm"], cfg)
+        idx, _ = moe.route(x.reshape(-1, cfg.d_model), lp["router"],
+                           cfg.experts_per_tok, cfg.norm_topk_prob)
+        counts = jnp.zeros((cfg.n_experts,), jnp.int32).at[idx.reshape(-1)].add(1)
+        return resid + _mlp_out(resid, lp, cfg, i), counts
+
+    stacked, held = _scan_leaves(params["layers"], lambda x: x[:n_scan])
+    _, counts = jax.lax.scan(
+        body, _embed(params, tokens, cfg),
+        (stacked, jnp.arange(n_scan, dtype=jnp.int32)))
+    return counts
+
+
 # ---------------------------------------------------------------------------
 # segmented harvest (sub-forward dispatch quanta for the refill pipeline)
 
@@ -651,10 +942,8 @@ def ce_loss(
 @functools.partial(jax.jit, static_argnames=("cfg", "n_cap"))
 def _seg_start_impl(params: LMParams, tokens: jax.Array, cfg: LMConfig, n_cap: int):
     B, S = tokens.shape
-    dt = dtype_of(cfg.dtype)
-    with jax.named_scope("harvest/embed"):
-        resid = params["embed"][tokens].astype(dt) * jnp.asarray(math.sqrt(cfg.d_model), dt)
-    buf = jnp.zeros((n_cap, B, S, cfg.d_model), dt)
+    resid = _embed(params, tokens, cfg)
+    buf = jnp.zeros((n_cap, B, S, cfg.d_model), resid.dtype)
     return resid, buf
 
 
@@ -677,17 +966,16 @@ def _seg_scan_impl(
     cap_sites = jnp.asarray([c for _, c in capture], jnp.int32) if n_cap else None
     want_attn = any(c == _SITE_ATTN for _, c in capture)
     want_mlp = any(c == _SITE_MLP for _, c in capture)
-    stacked = jax.tree_util.tree_map(
-        lambda x: jax.lax.dynamic_slice_in_dim(x, lo, k, axis=0), params["layers"]
-    )
+    stacked, held = _scan_leaves(
+        params["layers"], lambda x: jax.lax.dynamic_slice_in_dim(x, lo, k, axis=0))
     layer_ids = lo + jnp.arange(k, dtype=jnp.int32)
 
     def body(carry, xs):
         resid, buf = carry
         lp, i = xs
+        lp = {**lp, **held}
         buf = _capture_into(buf, resid, i, cap_arr, _SITE_RESID, cap_sites)
-        is_local = (i % 2) == 0
-        resid, attn_out, mlp_out = _block(resid, lp, cfg, is_local)
+        resid, attn_out, mlp_out = _block(resid, lp, cfg, _layer_kind(cfg, i))
         if want_attn:
             buf = _capture_into(buf, attn_out, i, cap_arr, _SITE_ATTN, cap_sites)
         if want_mlp:
@@ -764,15 +1052,15 @@ class SegmentedHarvest:
         self.tokens = tokens
         self.cfg = cfg
         self.capture = _hook_layers(cfg, tuple(hook_points))
-        self.n_scan = min(cfg.n_layers, _scan_stop(self.capture))
+        self.n_scan = hooked_depth(cfg, hook_points)
         self.out_dtype = out_dtype
         # snapshot the granularity for the job's whole life: n_steps (the
-        # pacing denominator) and the per-step slice width must agree even
-        # if the knob changes while this job is in flight
-        self._seg_layers = self.seg_layers()
-        self.n_steps = self.count(cfg, hook_points, len(self.params_seq))
+        # pacing denominator) and the quanta's bounds must agree even if
+        # the knob changes while this job is in flight
+        self._bounds = self.quanta(self.n_scan, self.seg_layers())
+        self.n_steps = len(self.params_seq) * max(1, len(self._bounds))
         self._model_idx = 0
-        self._lo = 0
+        self._lo = self._q = 0          # next layer; next quantum
         self._resid = self._buf = None
         self._done_resids: list = []
         self._done_bufs: list = []
@@ -781,8 +1069,30 @@ class SegmentedHarvest:
     @classmethod
     def count(cls, cfg: LMConfig, hook_points: Sequence[str], n_models: int) -> int:
         """``step()`` calls a job over these hooks will need (for pacing)."""
-        n_scan = min(cfg.n_layers, _scan_stop(_hook_layers(cfg, tuple(hook_points))))
+        n_scan = hooked_depth(cfg, hook_points)
         return n_models * max(1, -(-n_scan // cls.seg_layers()))
+
+    @staticmethod
+    def quanta(n_scan: int, seg_layers: int) -> list[int]:
+        """The layer each quantum ends before: ``⌈n_scan / seg_layers⌉``
+        quanta of NEAR-EQUAL depth, the deeper ones first (14 layers by 3:
+        3, 3, 3, 3, 2; 4 layers by 3: 2, 2 — not 3, 1). The refill paces by
+        quanta as if they cost the same (``data/buffer.py``
+        ``_segs_per_chunk``), which a 3 + 1 split of four expert layers
+        would miss by half."""
+        n_q = -(-n_scan // seg_layers)
+        ends, lo = [], 0
+        for q in range(n_q):
+            lo += n_scan // n_q + (q < n_scan % n_q)
+            ends.append(lo)
+        return ends
+
+    def _take(self, n_quanta: int) -> tuple[int, int]:
+        """Advance over up to ``n_quanta`` of the current model's quanta:
+        ``(quanta taken, layers they span)`` from ``self._lo``."""
+        n_q = min(n_quanta, len(self._bounds) - self._q)
+        self._q += n_q
+        return n_q, self._bounds[self._q - 1] - self._lo
 
     def inflight(self):
         """Arrays dispatched but possibly still executing — for callers
@@ -801,7 +1111,7 @@ class SegmentedHarvest:
                 len(self.capture),
             )
         if self._lo < self.n_scan:
-            k = min(self._seg_layers, self.n_scan - self._lo)
+            _, k = self._take(1)
             # lo as a HOST scalar: jit uploads it straight to every device
             # of a sharded harvest; a jnp scalar would sit on the default
             # device and be re-replicated device-to-device per dispatch
@@ -814,7 +1124,7 @@ class SegmentedHarvest:
             self._done_resids.append(self._resid)
             self._done_bufs.append(self._buf)
             self._resid = self._buf = None
-            self._lo = 0
+            self._lo = self._q = 0
             self._model_idx += 1
             if self._model_idx == len(self.params_seq):
                 self._out = _seg_finish_impl(
@@ -874,9 +1184,7 @@ class SegmentedHarvest:
                     len(self.capture),
                 )
             if self._lo < self.n_scan:
-                n_q = min(quanta - used,
-                          -(-(self.n_scan - self._lo) // self._seg_layers))
-                k = min(n_q * self._seg_layers, self.n_scan - self._lo)
+                n_q, k = self._take(quanta - used)
                 self._resid, self._buf = self._scan_batched(k)
                 self._lo += k
                 used += n_q
@@ -884,7 +1192,7 @@ class SegmentedHarvest:
                 self._done_resids.append(self._resid)
                 self._done_bufs.append(self._buf)
                 self._resid = self._buf = None
-                self._lo = 0
+                self._lo = self._q = 0
                 self._model_idx += 1
                 if self._model_idx == len(self.params_seq):
                     self._out = _seg_finish_impl(
@@ -944,9 +1252,7 @@ def _paged_capture_one(
     want_attn = any(c == _SITE_ATTN for _, c in capture)
     want_mlp = any(c == _SITE_MLP for _, c in capture)
 
-    resid = params["embed"][plane_tokens].astype(dt) * jnp.asarray(
-        math.sqrt(cfg.d_model), dt
-    )
+    resid = _embed(params, plane_tokens, cfg)
     buf = jnp.zeros((n_cap, R, Sp, cfg.d_model), dt)
 
     def gather_docs(x):          # [R, Sp, ...] -> [D, S, ...]
@@ -975,28 +1281,24 @@ def _paged_capture_one(
     def body(carry, xs):
         resid, buf = carry
         lp, i = xs
+        lp = {**lp, **held}
         buf = _capture_into(buf, resid, i, cap_arr, _SITE_RESID, cap_sites)
-        is_local = (i % 2) == 0
-        xn = _rms_norm(resid, lp["attn_norm"], cfg.rms_eps)
-        q, k, v = _qkv(xn, lp, cfg, pos2d)
+        kind = _layer_kind(cfg, i)
+        xn = _norm(resid, lp["attn_norm"], cfg)
+        q, k, v = _qkv(xn, lp, cfg, pos2d, kind)
         a_docs = attn_docs(gather_docs(q), gather_docs(k), gather_docs(v),
-                           is_local)
-        a = scatter_plane(a_docs)
-        a = jnp.einsum(
-            "bsq,qd->bsd", a, lp["wo"], preferred_element_type=jnp.float32
-        ).astype(dt)
-        attn_out = _rms_norm(a, lp["post_attn_norm"], cfg.rms_eps)
+                           kind.is_local)
+        attn_out = _attn_out(scatter_plane(a_docs), lp, cfg)
         if want_attn:
             buf = _capture_into(buf, attn_out, i, cap_arr, _SITE_ATTN, cap_sites)
         resid = resid + attn_out
-        mlp = _mlp(_rms_norm(resid, lp["pre_ffw_norm"], cfg.rms_eps), lp)
-        mlp_out = _rms_norm(mlp, lp["post_ffw_norm"], cfg.rms_eps)
+        mlp_out = _mlp_out(resid, lp, cfg, i)
         if want_mlp:
             buf = _capture_into(buf, mlp_out, i, cap_arr, _SITE_MLP, cap_sites)
         resid = resid + mlp_out
         return (resid, buf), None
 
-    stacked = jax.tree_util.tree_map(lambda x: x[:n_scan], params["layers"])
+    stacked, held = _scan_leaves(params["layers"], lambda x: x[:n_scan])
     layer_ids = jnp.arange(n_scan, dtype=jnp.int32)
     (resid, buf), _ = jax.lax.scan(body, (resid, buf), (stacked, layer_ids))
     return _capture_into(buf, resid, jnp.int32(n_scan), cap_arr, _SITE_RESID,
@@ -1166,7 +1468,7 @@ def paged_capture_aot(
 # tensor-parallel harvest (models too big for one chip's HBM)
 
 
-def tp_shardings(mesh, axis: str = "model") -> LMParams:
+def tp_shardings(mesh, axis: str = "model", cfg: LMConfig | None = None) -> LMParams:
     """``NamedSharding`` pytree for TENSOR-PARALLEL LM params over
     ``mesh[axis]`` — the Megatron layout expressed as annotations only;
     GSPMD inserts the collectives (psum after ``wo``/``w_down``).
@@ -1184,29 +1486,44 @@ def tp_shardings(mesh, axis: str = "model") -> LMParams:
     - ``w_gate``/``w_up``: hidden (output) axis sharded.
     - ``embed``: d_model axis sharded — the token lookup stays shard-local.
     - norms: replicated (tiny).
+
+    The leaves follow ``cfg`` (None: the Gemma-2 family's). Expert leaves
+    have no layout here yet: a sparse config on an axis larger than 1 is
+    refused rather than mis-sharded.
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     def ns(*spec):
         return NamedSharding(mesh, P(*spec))
 
-    return {
-        "embed": ns(None, axis),
-        "final_norm": ns(None),
-        "layers": {
-            "attn_norm": ns(None, None),
-            "post_attn_norm": ns(None, None),
-            "pre_ffw_norm": ns(None, None),
-            "post_ffw_norm": ns(None, None),
-            "wq": ns(None, None, axis),
-            "wk": ns(None, None, axis),
-            "wv": ns(None, None, axis),
-            "wo": ns(None, axis, None),
-            "w_gate": ns(None, None, axis),
-            "w_up": ns(None, None, axis),
-            "w_down": ns(None, axis, None),
-        },
+    layers = {
+        "attn_norm": ns(None, None),
+        "pre_ffw_norm": ns(None, None),
+        "wq": ns(None, None, axis),
+        "wk": ns(None, None, axis),
+        "wv": ns(None, None, axis),
+        "wo": ns(None, axis, None),
     }
+    if cfg is None or cfg.block_style == "sandwich":
+        layers["post_attn_norm"] = ns(None, None)
+        layers["post_ffw_norm"] = ns(None, None)
+    if cfg is not None and cfg.sparse:
+        if mesh.shape[axis] > 1:
+            raise NotImplementedError(
+                f"sparse-expert layers on a {axis!r} axis of {mesh.shape[axis]}: "
+                "expert parallelism (experts split over the axis, the token "
+                "exchange before and after them) is not implemented; "
+                "ops/moe.py computes every expert on one device")
+        layers.update(router=ns(None, None, None),
+                      we_gate_up=ns(None, None, None, None),
+                      we_down=ns(None, None, None, None))
+    else:
+        layers.update(w_gate=ns(None, None, axis), w_up=ns(None, None, axis),
+                      w_down=ns(None, axis, None))
+    out = {"embed": ns(None, axis), "final_norm": ns(None), "layers": layers}
+    if cfg is not None and not cfg.tie_embeddings:
+        out["unembed"] = ns(None, axis)
+    return out
 
 
 def shard_params_tp(params: LMParams, mesh, axis: str = "model") -> LMParams:
@@ -1289,38 +1606,33 @@ def _seq_local_body(
     want_mlp = any(c == _SITE_MLP for _, c in cap_layers)
     idx = jax.lax.axis_index(axis_name)
     pos = idx * Sl + jnp.arange(Sl)
-    resid = params["embed"][tok_local].astype(dt) * jnp.asarray(
-        math.sqrt(cfg.d_model), dt
-    )
+    resid = _embed(params, tok_local, cfg)
     buf = jnp.zeros((n_cap, B, Sl, cfg.d_model), dt) if n_cap else None
 
     def body(carry, xs):
         resid, buf = carry
         lp, i = xs
+        lp = {**lp, **held}
         buf = _capture_into(buf, resid, i, cap_arr, _SITE_RESID, cap_sites)
-        is_local = (i % 2) == 0
-        xn = _rms_norm(resid, lp["attn_norm"], cfg.rms_eps)
-        q, k, v = _qkv(xn, lp, cfg, pos)
+        kind = _layer_kind(cfg, i)
+        xn = _norm(resid, lp["attn_norm"], cfg)
+        q, k, v = _qkv(xn, lp, cfg, pos, kind)
         a = ring_attention(
             q, k, v, axis_name=axis_name, n_shards=n, scale=scale,
             softcap=cfg.attn_softcap, sliding_window=cfg.sliding_window,
-            is_local=is_local,
+            is_local=kind.is_local,
         ).reshape(B, Sl, cfg.n_heads * cfg.head_dim)
-        a = jnp.einsum(
-            "bsq,qd->bsd", a, lp["wo"], preferred_element_type=jnp.float32
-        ).astype(dt)
-        attn_out = _rms_norm(a, lp["post_attn_norm"], cfg.rms_eps)
+        attn_out = _attn_out(a, lp, cfg)
         if want_attn:
             buf = _capture_into(buf, attn_out, i, cap_arr, _SITE_ATTN, cap_sites)
         resid = resid + attn_out
-        mlp = _mlp(_rms_norm(resid, lp["pre_ffw_norm"], cfg.rms_eps), lp)
-        mlp_out = _rms_norm(mlp, lp["post_ffw_norm"], cfg.rms_eps)
+        mlp_out = _mlp_out(resid, lp, cfg, i)
         if want_mlp:
             buf = _capture_into(buf, mlp_out, i, cap_arr, _SITE_MLP, cap_sites)
         resid = resid + mlp_out
         return (resid, buf), None
 
-    stacked = jax.tree_util.tree_map(lambda x: x[:n_scan], params["layers"])
+    stacked, held = _scan_leaves(params["layers"], lambda x: x[:n_scan])
     layer_ids = jnp.arange(n_scan, dtype=jnp.int32)
     (resid, buf), _ = jax.lax.scan(body, (resid, buf), (stacked, layer_ids))
     buf = _capture_into(buf, resid, jnp.int32(n_scan), cap_arr, _SITE_RESID, cap_sites)
@@ -1417,7 +1729,8 @@ def from_torch_state_dict(
     sd: Mapping[str, Any], cfg: LMConfig, dtype: str | None = None,
     shardings: LMParams | None = None,
 ) -> LMParams:
-    """Convert an HF-transformers Gemma2 ``state_dict`` to our stacked layout.
+    """Convert an HF-transformers ``state_dict`` (Gemma2's names; for a
+    pre-norm or sparse ``cfg`` the names assumed below) to our stacked layout.
 
     Works on anything indexable with ``.numpy()``-able values (torch CPU
     tensors or numpy arrays). HF projections are [out, in]; ours are [in, out].
@@ -1451,23 +1764,53 @@ def from_torch_state_dict(
         return leaf(("layers", key), arr)
 
     p = "model.layers.{}."
-    return {
+    layers = {
+        "attn_norm": stack("attn_norm", p + "input_layernorm.weight", False),
+        "wq": stack("wq", p + "self_attn.q_proj.weight", True),
+        "wk": stack("wk", p + "self_attn.k_proj.weight", True),
+        "wv": stack("wv", p + "self_attn.v_proj.weight", True),
+        "wo": stack("wo", p + "self_attn.o_proj.weight", True),
+    }
+    if cfg.block_style == "sandwich":
+        layers.update(
+            post_attn_norm=stack("post_attn_norm", p + "post_attention_layernorm.weight", False),
+            pre_ffw_norm=stack("pre_ffw_norm", p + "pre_feedforward_layernorm.weight", False),
+            post_ffw_norm=stack("post_ffw_norm", p + "post_feedforward_layernorm.weight", False),
+        )
+    else:
+        # the pre-norm families' second norm goes by this name (Llama's
+        # convention; ASSUMED for Mellum2: no checkpoint was read here)
+        layers["pre_ffw_norm"] = stack(
+            "pre_ffw_norm", p + "post_attention_layernorm.weight", False)
+    if cfg.sparse:
+        # ASSUMED key names (the Mixtral/Qwen-MoE convention; no Mellum2
+        # checkpoint was read here): ``mlp.gate.weight`` [E, D] and, per
+        # expert e, ``mlp.experts.{e}.{gate,up,down}_proj.weight``
+        def experts(fmt: str) -> np.ndarray:      # -> [L, E, in, out]
+            return np.stack([
+                np.stack([get((p + fmt).format(i, e)).T for e in range(cfg.n_experts)])
+                for i in range(cfg.n_layers)])
+
+        layers["router"] = stack("router", p + "mlp.gate.weight", True)
+        layers["we_gate_up"] = leaf(("layers", "we_gate_up"), np.concatenate(
+            [experts("mlp.experts.{}.gate_proj.weight"),
+             experts("mlp.experts.{}.up_proj.weight")], axis=-1))
+        layers["we_down"] = leaf(
+            ("layers", "we_down"), experts("mlp.experts.{}.down_proj.weight"))
+    else:
+        layers.update(
+            w_gate=stack("w_gate", p + "mlp.gate_proj.weight", True),
+            w_up=stack("w_up", p + "mlp.up_proj.weight", True),
+            w_down=stack("w_down", p + "mlp.down_proj.weight", True),
+        )
+    params = {
         "embed": leaf(("embed",), get("model.embed_tokens.weight")),
         "final_norm": leaf(("final_norm",), get("model.norm.weight")),
-        "layers": {
-            "attn_norm": stack("attn_norm", p + "input_layernorm.weight", False),
-            "post_attn_norm": stack("post_attn_norm", p + "post_attention_layernorm.weight", False),
-            "pre_ffw_norm": stack("pre_ffw_norm", p + "pre_feedforward_layernorm.weight", False),
-            "post_ffw_norm": stack("post_ffw_norm", p + "post_feedforward_layernorm.weight", False),
-            "wq": stack("wq", p + "self_attn.q_proj.weight", True),
-            "wk": stack("wk", p + "self_attn.k_proj.weight", True),
-            "wv": stack("wv", p + "self_attn.v_proj.weight", True),
-            "wo": stack("wo", p + "self_attn.o_proj.weight", True),
-            "w_gate": stack("w_gate", p + "mlp.gate_proj.weight", True),
-            "w_up": stack("w_up", p + "mlp.up_proj.weight", True),
-            "w_down": stack("w_down", p + "mlp.down_proj.weight", True),
-        },
+        "layers": layers,
     }
+    if not cfg.tie_embeddings:
+        params["unembed"] = leaf(("unembed",), get("lm_head.weight"))
+    return params
 
 
 def from_hf(

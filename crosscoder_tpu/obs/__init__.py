@@ -81,6 +81,13 @@ def count(key: str, n: int = 1) -> None:
         plane.registry.count(key, n)
 
 
+def gauge(key: str, value: float) -> None:
+    """Set a last-value gauge in every live plane's registry (a no-op with
+    ``obs`` off, as :func:`count`)."""
+    for plane in tuple(_PLANES.values()):
+        plane.registry.gauge(key, value)
+
+
 class Observability:
     def __init__(self, cfg: Any, mesh: Any | None = None) -> None:
         self.cfg = cfg
@@ -198,6 +205,7 @@ __all__ = [
     "Observability",
     "acquire",
     "count",
+    "gauge",
     "MetricsRegistry",
     "NullTracer",
     "SpanTracer",

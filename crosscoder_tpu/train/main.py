@@ -58,7 +58,7 @@ def build_buffer(
             )
         # leaves go straight into their tensor-parallel shards during
         # conversion — the full model never lands on one device
-        lm_shardings = lm.tp_shardings(mesh)
+        lm_shardings = lm.tp_shardings(mesh, cfg=lm_cfg)
     params_list = [lm.from_hf(n, lm_cfg, shardings=lm_shardings)[0] for n in names]
     cfg = cfg.replace(d_in=lm_cfg.d_model)
     tokens = load_pile_lmsys_mixed_tokens(cfg)

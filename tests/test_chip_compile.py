@@ -276,6 +276,45 @@ def test_fused_attention_window_pair_compiles(chip, one_device):
     assert text.count("tpu_custom_call") >= 2
 
 
+def test_sparse_expert_harvest_segment_compiles(chip, one_device):
+    """The third cell's refill quantum at its published widths: two blocks of
+    Mellum2 (``benchmarks/configs/mellum2-pair-relu16k.json``) over one
+    4096-token sequence — the window (1024) and the full instance of the
+    fused attention at 32 Q / 4 KV heads, and the expert layer's two kernels
+    over 64 experts of 2304 x 896, whose stacked weights reach them whole."""
+    import sys
+    from pathlib import Path
+
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+    from benchmarks import manifest
+    from benchmarks.arch import mellum
+    from crosscoder_tpu.ops import flash_attention as fa
+    from crosscoder_tpu.ops import moe
+
+    cfg = mellum.lm_config(manifest.load_json(
+        manifest.BENCH_DIR / "configs" / "mellum2-pair-relu16k.json"))
+    S = 4096
+    assert fa.supported(S, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, jnp.bfloat16)
+    assert moe.enabled() and moe.supported(cfg.d_model, cfg.d_expert, jnp.bfloat16)
+    params = _abstract(jax.eval_shape(
+        lambda k: lm.init_params(k, cfg), jax.random.key(0)), chip)
+    compiled = lm._seg_scan_impl.lower(
+        params, _sds((1, S, cfg.d_model), jnp.bfloat16, chip),
+        _sds((1, 1, S, cfg.d_model), jnp.bfloat16, chip),
+        _sds((), jnp.int32, chip), cfg=cfg,
+        capture=lm._hook_layers(cfg, ("blocks.4.hook_resid_pre",)), k=2,
+    ).compile()
+    _fits(compiled)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 4      # attention x2, gate_up, down
+    assert "moe_gate_up" in text and "moe_down" in text
+    # no layer's experts are sliced out or copied on the way to the kernels
+    stacked = f"bf16[{cfg.n_layers},{cfg.n_experts},"
+    assert not [line for line in text.splitlines()
+                if stacked in line.split(" = ")[-1][:60]
+                and (" copy(" in line or "dynamic-slice(" in line)]
+
+
 @pytest.mark.parametrize("window", [0, 4096])
 def test_paged_attention_family_compiles(chip, gates_open, window):
     """Gemma-2-2B heads (8 Q / 4 KV × 256), global and sliding-window."""
