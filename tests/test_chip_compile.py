@@ -280,8 +280,10 @@ def test_sparse_expert_harvest_segment_compiles(chip, one_device):
     """The third cell's refill quantum at its published widths: two blocks of
     Mellum2 (``benchmarks/configs/mellum2-pair-relu16k.json``) over one
     4096-token sequence — the window (1024) and the full instance of the
-    fused attention at 32 Q / 4 KV heads, and the expert layer's two kernels
-    over 64 experts of 2304 x 896, whose stacked weights reach them whole."""
+    fused attention at 32 Q / 4 KV heads, and the expert layer's three
+    kernels: the grouped product's two over 64 experts of 2304 x 896, whose
+    stacked weights reach them whole, and the combine, which reads
+    ``moe_down``'s rows in place (no gathered ``[T*k, D]`` copy is left)."""
     import sys
     from pathlib import Path
 
@@ -296,6 +298,7 @@ def test_sparse_expert_harvest_segment_compiles(chip, one_device):
     S = 4096
     assert fa.supported(S, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, jnp.bfloat16)
     assert moe.enabled() and moe.supported(cfg.d_model, cfg.d_expert, jnp.bfloat16)
+    assert moe.combine_supported(S, cfg.experts_per_tok, cfg.d_model, jnp.bfloat16)
     params = _abstract(jax.eval_shape(
         lambda k: lm.init_params(k, cfg), jax.random.key(0)), chip)
     compiled = lm._seg_scan_impl.lower(
@@ -306,8 +309,18 @@ def test_sparse_expert_harvest_segment_compiles(chip, one_device):
     ).compile()
     _fits(compiled)
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= 4      # attention x2, gate_up, down
+    assert text.count("tpu_custom_call") >= 5      # attention x2, gate_up, down, combine
     assert "moe_gate_up" in text and "moe_down" in text
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    combine = [line for line in calls if "expert_combine" in line.split(" = ")[0]]
+    assert combine, "XLA's gather and sum took the combine kernel's place"
+    # ... and it reads moe_down's result itself: nothing sits between them
+    down = [line.split(" = ")[0].split()[-1] for line in calls
+            if "moe_down" in line.split(" = ")[0]]
+    assert down and all(any(d + ")" in c or d + "," in c for d in down) for c in combine)
+    # the gathered rows are gone, not renamed: no array of T*k rows of D
+    gathered = f"[{S * cfg.experts_per_tok},{cfg.d_model}]"
+    assert not [line for line in text.splitlines() if gathered in line]
     # no layer's experts are sliced out or copied on the way to the kernels
     stacked = f"bf16[{cfg.n_layers},{cfg.n_experts},"
     assert not [line for line in text.splitlines()

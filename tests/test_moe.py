@@ -1,7 +1,8 @@
 """The sparse-expert layer (crosscoder_tpu/ops/moe.py) alone: both forms of
 the grouped product against the plain reference's loop over experts
 (benchmarks/reference/mellum_ref.py, which shares no code with it), under
-even and heavily skewed routing; the router's choice; the load gauge."""
+even and heavily skewed routing; the combine kernel against the gathered
+sum it replaces; the router's choice; the load gauge."""
 
 import sys
 import types
@@ -88,6 +89,118 @@ def test_expert_layer_matches_the_plain_loop(form, skew, tokens, request):
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0, atol=ATOL)
 
 
+# ---------------------------------------------------------------------------
+# the combine kernel (bf16 rows, D a multiple of 256)
+
+DC = 256
+
+
+def _bf16_ulp(v):
+    """The spacing of bfloat16 (8 significant bits) at ``|v|`` — and, for a
+    sum that cancels to nearly nothing, the float32 roundings of its k terms
+    of magnitude up to 4 (2^-24 each), which are then the larger."""
+    return 2.0 ** (np.floor(np.log2(np.maximum(np.abs(v), 1e-30))) - 7) + 1e-6
+
+
+def _combine_case(seed, tokens, skew):
+    """A routing's own row table (``_tile_layout``) over random bf16 rows."""
+    w_router, _, _ = _layer(seed, skew)
+    idx, gates = moe.route(_x(seed, tokens, skew)[0], w_router, K, True)
+    _, _, token, rows = moe._tile_layout(idx, E)
+    y = jax.random.normal(jax.random.key(200 + seed), (token.shape[0], DC), jnp.bfloat16)
+    return idx, gates, rows, y
+
+
+def _kernel_combine(rows, gates, y):
+    return moe._combine_rows(rows, gates, moe._pack_rows(y).reshape(-1, 1, 128), DC)
+
+
+@pytest.mark.parametrize("tokens,skew", [(256, False), (256, True), (300, False), (72, True)],
+                         ids=["even", "skewed", "300tok-not-a-whole-tile", "72tok-skewed"])
+def test_combine_kernel_matches_the_gathered_sum(tokens, skew, interpret):
+    idx, gates, rows, y = _combine_case(11, tokens, skew)
+    sizes = np.bincount(np.asarray(idx).reshape(-1), minlength=E)
+    if skew:
+        assert sizes[5] >= 0.9 * tokens and sizes.max() > 2 * sizes.mean()
+    assert moe.combine_supported(tokens, K, DC, jnp.bfloat16)
+    got = _kernel_combine(rows, gates, y)
+    want = moe._combine(y[rows].reshape(tokens, K, DC), gates)
+    assert got.shape == want.shape and got.dtype == want.dtype == jnp.bfloat16
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    # Both forms sum the same k float32 products ``gate * row`` of one token
+    # and round the sum to bf16 once. They differ only in the ORDER of the
+    # float32 additions (XLA's reduce over the slot axis against the
+    # kernel's slot-by-slot accumulation): a few float32 roundings, which
+    # can carry the one bf16 rounding across a tie — one bf16 ulp at most,
+    # on a few elements in 10^5. A wrong row, gate or half moves O(1).
+    assert (np.abs(got - want) <= _bf16_ulp(want)).all()
+    assert (got != want).mean() < 1e-3
+
+
+def test_combine_kernel_fails_on_a_swapped_table(interpret):
+    """The planted fault: two tokens trade one row of the table."""
+    tokens = 256
+    _, gates, rows, y = _combine_case(12, tokens, False)
+    want = np.asarray(moe._combine(y[rows].reshape(tokens, K, DC), gates), np.float32)
+    a, b = 17 * K + 1, 201 * K + 2
+    swapped = rows.at[a].set(rows[b]).at[b].set(rows[a])
+    got = np.asarray(_kernel_combine(swapped, gates, y), np.float32)
+    wrong = (np.abs(got - want) > _bf16_ulp(want)).any(axis=1)
+    assert set(np.flatnonzero(wrong)) == {17, 201}
+
+
+def test_packed_rows_hold_both_halves():
+    y = jax.random.normal(jax.random.key(9), (16, DC), jnp.bfloat16)
+    words = np.asarray(moe._pack_rows(y))
+    assert words.dtype == np.uint32 and words.shape == (16, DC // 2)
+    bits = np.asarray(jax.lax.bitcast_convert_type(y, jnp.uint16)).astype(np.uint32)
+    np.testing.assert_array_equal(words & 0xFFFF, bits[:, :DC // 2])
+    np.testing.assert_array_equal(words >> 16, bits[:, DC // 2:])
+
+
+@pytest.mark.parametrize("n_tokens,top_k,d_model,dtype,want", [
+    (4096, 8, 2304, jnp.bfloat16, True),      # the mellum2 cell
+    (4096, 8, 2304, jnp.float32, False),      # rows are packed two bf16 a word
+    (4096, 8, 2176, jnp.bfloat16, False),     # half a row is not whole lanes
+    (2 ** 16, 8, 2304, jnp.bfloat16, False),  # the row table passes its share of SMEM
+    (4096, 8, 2 ** 15, jnp.bfloat16, False),  # a tile's double buffer passes VMEM
+], ids=["cell", "float32", "half-lanes", "smem", "vmem"])
+def test_combine_supported(n_tokens, top_k, d_model, dtype, want):
+    assert moe.combine_supported(n_tokens, top_k, d_model, dtype) is want
+
+
+@pytest.mark.parametrize("skew", [False, True], ids=["even", "skewed"])
+def test_bf16_tile_form_with_the_combine_kernel_matches_ragged(skew, interpret, tmp_path):
+    """The whole tile form at a shape the combine kernel takes (bf16, D 256)
+    against ``_experts_ragged`` on the same routing."""
+    tokens = 300
+    ks = jax.random.split(jax.random.key(21), 3)
+    w_router = _layer(21, skew)[0]
+    w_gate_up = (jax.random.normal(ks[0], (L, E, DC, 2 * F)) * DC ** -0.5).astype(jnp.bfloat16)
+    w_down = (jax.random.normal(ks[1], (L, E, F, DC)) * F ** -0.5).astype(jnp.bfloat16)
+    x = jax.random.normal(ks[2], (tokens, DC), jnp.bfloat16)
+    idx, gates = moe.route(_x(21, tokens, skew)[0], w_router, K, True)
+    cfg = CrossCoderConfig(obs="on", obs_dir=str(tmp_path / "obs"), log_backend="null")
+    plane = obs.acquire(cfg)
+    try:
+        got = moe._experts_tiles(x, idx, gates, w_gate_up, w_down, jnp.int32(1))
+        assert plane.registry.get_count("harvest/moe_combine_kernel_traces") == 1
+        assert plane.registry.get_count("harvest/moe_combine_xla_traces") == 0
+    finally:
+        plane.close()
+    want = moe._experts_ragged(x, idx, gates, w_gate_up, w_down, jnp.int32(1))
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    # Same roundings in both (gated hidden and each expert's row to bf16, the
+    # weighted sum in float32, one rounding of it), but the products'
+    # float32 sums run in another order (ragged_dot against the kernels'
+    # dots), so a hidden value or a row can land one bf16 ulp apart, and k
+    # such rows sum into an output: a few ulp of the LARGEST row, not of the
+    # output. Outputs are O(1); rows O(1): 4 ulp at 2 = 6.3e-2. A wrong
+    # expert or gate moves O(1) on every element of a token.
+    assert np.abs(got - want).max() <= 6.3e-2
+    assert np.median(np.abs(got - want)) <= 4e-3
+
+
 def test_the_routers_choice_is_the_references_exactly():
     w_router, _, _ = _layer(3, False)
     x = _x(3, 512, False)[0]
@@ -124,6 +237,9 @@ def test_which_form_ran_is_counted_once_per_trace(tmp_path, interpret):
                     top_k=K, norm_topk_prob=True)
         assert plane.registry.get_count("harvest/moe_tiles_traces") == 1
         assert plane.registry.get_count("harvest/moe_ragged_traces") == 1
+        # float32 rows: the tile form keeps XLA's gather and sum, and says so
+        assert plane.registry.get_count("harvest/moe_combine_xla_traces") == 1
+        assert plane.registry.get_count("harvest/moe_combine_kernel_traces") == 0
     finally:
         plane.close()
 
