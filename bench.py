@@ -29,9 +29,8 @@ to stderr for the whole run:
   air-gapped, which changes no matmul shapes). Reports steady-state
   acts/sec and the refresh-bubble profile (max vs median step).
 - **refill_overlap**: zero-bubble refill engine A/B (docs/SCALING.md
-  "Zero-bubble refill") — the e2e leg with ``refill_overlap`` off vs on
-  at fine/coarse harvest segmentation; gates on bubble_frac ≤ 0.10 with
-  no throughput loss.
+  "Zero-bubble refill") — the e2e leg with ``refill_overlap`` off vs on;
+  gates on bubble_frac ≤ 0.10 with no throughput loss.
 - **harvest**: the LM-harvest side (the dominant per-step cost outside
   the crosscoder) on a mixed-length synthetic corpus: padded-vs-paged
   runtime A/B — tokens/s over REAL tokens, padding-efficiency %, and the
@@ -733,13 +732,12 @@ def section_e2e() -> dict:
 def section_refill_overlap() -> dict:
     """Zero-bubble refill engine A/B (docs/SCALING.md "Zero-bubble
     refill"): the ``e2e`` harvest→buffer→train leg run with
-    ``refill_overlap`` off vs on, at fine (SEG_LAYERS=3) and coarse
-    (SEG_LAYERS=14) harvest segmentation. Per leg: the measured refill
+    ``refill_overlap`` off vs on, at the library's harvest quantum
+    (``SegmentedHarvest.SEG_LAYERS``). Per leg: the measured refill
     bubble fraction (obs ``refill_wait`` span total / wall — exactly what
     ``perf/refill_bubble_frac`` logs), the max/median step ratio (the
     refresh spike), and acts/s/chip. Gate (ISSUE 14 acceptance): with
-    overlap ON, bubble_frac <= 0.10 AND acts/s no worse than overlap-off
-    at both segmentations."""
+    overlap ON, bubble_frac <= 0.10 AND acts/s no worse than overlap-off."""
     import tempfile
 
     import numpy as np
@@ -779,67 +777,52 @@ def section_refill_overlap() -> dict:
                           size=(2048, base["seq_len"]), dtype=np.int32)
 
     n_steps = int(os.environ.get("BENCH_OVERLAP_STEPS", 48 if tiny else 32))
-    seg_saved = os.environ.get("CROSSCODER_SEG_LAYERS")
     out: dict = {}
-    try:
-        # resolved at use time by SegmentedHarvest.seg_layers(): fine
-        # segmentation = many dispatch quanta/serve (the host-cost regime
-        # the overlap engine exists for), coarse = the device-bound regime
-        for seg in (3, 14):
-            os.environ["CROSSCODER_SEG_LAYERS"] = str(seg)
-            for ov in ("off", "on"):
-                cfg = _make_cfg(
-                    **base, num_tokens=10**12, save_every=10**9,
-                    prefetch=True, obs="on", refill_overlap=ov,
-                    checkpoint_dir=tempfile.mkdtemp(),
-                )
-                buffer = make_buffer(
-                    cfg, lm_cfg, params, tokens,
-                    batch_sharding=NamedSharding(mesh, P("data", None)),
-                )
-                trainer = Trainer(cfg, buffer, mesh=mesh)
-                m = trainer.step()            # compile both variants
-                _sync(m["loss"])
-                m = trainer.step(full_metrics=False)
-                _sync(m["loss"])
-                trainer._obs.tracer.take_interval()     # reset the span totals
-                # per-step sync on every step of both arms: its cost
-                # cancels in the A/B, and per-step times expose the
-                # refresh spike as max - median
-                times = []
-                t0 = time.perf_counter()
-                for _ in range(n_steps):
-                    t1 = time.perf_counter()
-                    m = trainer.step(full_metrics=False)
-                    _sync(m["loss"])
-                    times.append(1000 * (time.perf_counter() - t1))
-                wall = time.perf_counter() - t0
-                blocked = trainer._obs.tracer.take_interval().get(
-                    "refill_wait", (0.0, 0))[0]
-                trainer.close()
-                median_ms = sorted(times)[len(times) // 2]
-                leg = {
-                    "bubble_frac": round(min(1.0, blocked / wall), 4),
-                    "acts_per_sec_chip": round(
-                        cfg.batch_size * n_steps / wall / n_dev, 1),
-                    "step_ms_median": round(median_ms, 2),
-                    "step_ms_max": round(max(times), 2),
-                    "max_over_median": round(max(times) / median_ms, 2),
-                }
-                log(f"[refill_overlap] seg{seg} overlap={ov}: {leg}")
-                out[f"seg{seg}_{ov}"] = leg
-            on, off = out[f"seg{seg}_on"], out[f"seg{seg}_off"]
-            out[f"seg{seg}_gate_ok"] = bool(
-                on["bubble_frac"] <= 0.10
-                and on["acts_per_sec_chip"] >= off["acts_per_sec_chip"])
-    finally:
-        if seg_saved is None:
-            os.environ.pop("CROSSCODER_SEG_LAYERS", None)
-        else:
-            os.environ["CROSSCODER_SEG_LAYERS"] = seg_saved
+    for ov in ("off", "on"):
+        cfg = _make_cfg(
+            **base, num_tokens=10**12, save_every=10**9,
+            prefetch=True, obs="on", refill_overlap=ov,
+            checkpoint_dir=tempfile.mkdtemp(),
+        )
+        buffer = make_buffer(
+            cfg, lm_cfg, params, tokens,
+            batch_sharding=NamedSharding(mesh, P("data", None)),
+        )
+        trainer = Trainer(cfg, buffer, mesh=mesh)
+        m = trainer.step()            # compile both variants
+        _sync(m["loss"])
+        m = trainer.step(full_metrics=False)
+        _sync(m["loss"])
+        trainer._obs.tracer.take_interval()     # reset the span totals
+        # per-step sync on every step of both arms: its cost
+        # cancels in the A/B, and per-step times expose the
+        # refresh spike as max - median
+        times = []
+        t0 = time.perf_counter()
+        for _ in range(n_steps):
+            t1 = time.perf_counter()
+            m = trainer.step(full_metrics=False)
+            _sync(m["loss"])
+            times.append(1000 * (time.perf_counter() - t1))
+        wall = time.perf_counter() - t0
+        blocked = trainer._obs.tracer.take_interval().get(
+            "refill_wait", (0.0, 0))[0]
+        trainer.close()
+        median_ms = sorted(times)[len(times) // 2]
+        leg = {
+            "bubble_frac": round(min(1.0, blocked / wall), 4),
+            "acts_per_sec_chip": round(
+                cfg.batch_size * n_steps / wall / n_dev, 1),
+            "step_ms_median": round(median_ms, 2),
+            "step_ms_max": round(max(times), 2),
+            "max_over_median": round(max(times) / median_ms, 2),
+        }
+        log(f"[refill_overlap] overlap={ov}: {leg}")
+        out[ov] = leg
     out["n_steps_measured"] = n_steps
-    out["gate_ok"] = bool(out.get("seg3_gate_ok")
-                          and out.get("seg14_gate_ok"))
+    out["gate_ok"] = bool(
+        out["on"]["bubble_frac"] <= 0.10
+        and out["on"]["acts_per_sec_chip"] >= out["off"]["acts_per_sec_chip"])
     log(f"[refill_overlap] gate_ok={out['gate_ok']}")
     return out
 
@@ -1493,7 +1476,7 @@ _SUMMARY_KEYS = {
     "step": ("acts_per_sec_chip", "vs_a100_step"),
     "e2e": ("acts_per_sec_chip", "vs_a100_e2e", "step_ms_median",
             "refresh_bubble_ms", "loss_finite"),
-    "refill_overlap": ("gate_ok", "seg3_gate_ok", "seg14_gate_ok"),
+    "refill_overlap": ("gate_ok",),
     "harvest": ("padding_efficiency", "paged_step_ms", "paged_speedup"),
     "quant": ("roundtrip_rel_mse", "quality_gate_ok"),
     "obs": ("obs_overhead_frac", "overhead_gate_ok"),

@@ -31,9 +31,10 @@ TPU-first design decisions (why this is not a TransformerLens translation):
 
 One config type (:class:`LMConfig`) carries a layer table (attention kind
 and MLP kind per layer), the block's style, RoPE per attention kind and the
-expert sizes; every forward below takes a layer's kind from ONE lookup
-(:func:`_layer_kind`) and runs ONE block (:func:`_block`). Two families run
-through it: Gemma-2 (next paragraph) and the pre-norm sparse-expert block of
+expert sizes; every forward below is ONE layer loop (:func:`_scan_blocks`)
+that takes a layer's kind from ONE lookup (:func:`_layer_kind`) and runs ONE
+block (:func:`_block`), and hands in only its positions and how it reaches
+attention. Two families run through it: Gemma-2 (next paragraph) and the pre-norm sparse-expert block of
 Mellum2 (``LMConfig.mellum2_12b``: plain-weight RMSNorm before each sublayer
 only, no soft-caps, no embedding scale, three window layers to one full
 layer with YaRN on the full layers only, every MLP ``ops/moe.py``'s routed
@@ -53,7 +54,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-import os
 from dataclasses import dataclass
 from typing import Any, Callable, Mapping, NamedTuple, Sequence
 
@@ -519,14 +519,17 @@ def _attn_out(a: jax.Array, lp: Mapping[str, jax.Array], cfg: LMConfig) -> jax.A
 
 @jax.named_scope("harvest/block/attn")
 def _attention(
-    x: jax.Array, lp: Mapping[str, jax.Array], cfg: LMConfig, kind: _LayerKind
+    x: jax.Array, lp: Mapping[str, jax.Array], cfg: LMConfig, kind: _LayerKind,
+    pos: jax.Array | None = None, attend: Callable | None = None,
 ) -> jax.Array:
     """One attention sublayer on the normed stream [B, S, D], as added to
     the stream. ``kind.is_local`` selects the sliding-window mask (traced
-    scalar — both masks are static precomputes)."""
-    B, S, D = x.shape
-    q, k, v = _qkv(x, lp, cfg, jnp.arange(S), kind)
-    return _attn_out(_attn_core(q, k, v, cfg, kind.is_local), lp, cfg)
+    scalar — both masks are static precomputes). ``pos`` and ``attend`` are
+    the ONE thing the forwards differ in (see :func:`_block`)."""
+    q, k, v = _qkv(x, lp, cfg, jnp.arange(x.shape[1]) if pos is None else pos, kind)
+    a = (_attn_core(q, k, v, cfg, kind.is_local) if attend is None
+         else attend(q, k, v, kind))
+    return _attn_out(a, lp, cfg)
 
 
 @jax.named_scope("harvest/block/mlp")
@@ -572,8 +575,11 @@ def _block(
     resid: jax.Array, lp: Mapping[str, jax.Array], cfg: LMConfig, kind: _LayerKind,
     edit_attn: Callable[[jax.Array], jax.Array] | None = None,
     edit_mlp: Callable[[jax.Array], jax.Array] | None = None,
+    pos: jax.Array | None = None, attend: Callable | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """One transformer block of the config's style and kinds.
+    """One transformer block of the config's style and kinds — the ONLY
+    place norm → QKV → attention → out-projection → add → MLP → add is
+    spelled; every forward runs it through :func:`_scan_blocks`.
 
     Returns ``(resid, attn_out, mlp_out)`` — the updated stream plus the two
     sublayer contributions exactly as they are ADDED to it (in the sandwich
@@ -582,8 +588,17 @@ def _block(
     them is free. ``edit_attn``/``edit_mlp`` intervene on a contribution
     BEFORE it joins the stream (and before its capture) — the sublayer-site
     analogue of the residual edits, used by CE-recovered evals of sublayer
-    crosscoders."""
-    attn_out = _attention(_norm(resid, lp["attn_norm"], cfg), lp, cfg, kind)
+    crosscoders.
+
+    ``pos`` (the positions RoPE rotates by: ``[S]`` or per-token ``[B, S]``)
+    and ``attend(q, k, v, kind) -> [B, S, H·hd]`` (pre output-projection)
+    are how a forward that is not the padded one reaches attention: the
+    paged runtime passes each document's own positions and a gather →
+    per-document attention → scatter, the sequence-sharded one its shard's
+    global positions and the ring. Left None they are ``arange(S)`` and
+    :func:`_attn_core`."""
+    attn_out = _attention(_norm(resid, lp["attn_norm"], cfg), lp, cfg, kind,
+                          pos, attend)
     if edit_attn is not None:
         attn_out = edit_attn(attn_out)
     resid = resid + attn_out
@@ -632,20 +647,25 @@ class Edit:
 _SITE_RESID, _SITE_ATTN, _SITE_MLP = 0, 1, 2
 
 
+def _slots(capture: tuple[tuple[int, int], ...]):
+    """The capture slots of a forward from its static ``capture`` tuple, as
+    two [n_cap] int32 arrays: each slot's layer, and its site code."""
+    if not capture:
+        return None
+    return (jnp.asarray([l for l, _ in capture], jnp.int32),
+            jnp.asarray([c for _, c in capture], jnp.int32))
+
+
 def _capture_into(
-    buf: jax.Array | None, resid: jax.Array, i, cap_arr, site: int = _SITE_RESID,
-    site_arr=None,
+    buf: jax.Array | None, x: jax.Array, i, slots, site: int = _SITE_RESID,
 ) -> jax.Array | None:
-    """Accumulate ``resid`` into the capture slot whose (layer, site) equals
-    ``(i, site)`` (one-hot over slots; shared by the dense and
-    sequence-parallel paths)."""
+    """Accumulate ``x`` into the capture slot whose (layer, site) equals
+    ``(i, site)`` (one-hot over slots)."""
     if buf is None:
         return None
-    match = (cap_arr == i)
-    if site_arr is not None:
-        match = match & (site_arr == site)
-    match = match.astype(resid.dtype)
-    return buf + match[:, None, None, None] * resid[None]
+    layers, sites = slots
+    match = ((layers == i) & (sites == site)).astype(x.dtype)
+    return buf + match[:, None, None, None] * x[None]
 
 
 def _unembed(params: LMParams, resid: jax.Array, cfg: LMConfig) -> jax.Array:
@@ -711,6 +731,102 @@ def hooked_depth(cfg: LMConfig, hook_points: Sequence[str]) -> int:
 # forward
 
 
+def _fresh_carry(params: LMParams, tokens: jax.Array, cfg: LMConfig, n_cap: int):
+    """What a forward's layer loop starts from: the embedded stream and a
+    zero capture buffer ``[n_cap, B, S, D]`` (None where nothing is captured)."""
+    B, S = tokens.shape
+    resid = _embed(params, tokens, cfg)
+    buf = jnp.zeros((n_cap, B, S, cfg.d_model), resid.dtype) if n_cap else None
+    return resid, buf
+
+
+def _scan_blocks(
+    params: LMParams, cfg: LMConfig, capture: tuple[tuple[int, int], ...],
+    carry: tuple[jax.Array, jax.Array | None], k: int, lo: jax.Array | None = None,
+    *, pos: jax.Array | None = None, attend: Callable | None = None,
+    edits: tuple[tuple, tuple, tuple] = ((), (), ()),
+    emit: Callable | None = None,
+):
+    """THE layer loop, behind every forward in this module: blocks
+    ``[lo, lo + k)`` of the stacked layers under one ``lax.scan`` carrying
+    ``carry = (resid, buf)``. Per layer: residual-site edits, the residual-site
+    capture, :func:`_block` (with the sublayer-site edits inside it), the
+    sublayer-site captures. Returns ``((resid, buf), ys)`` as the scan does.
+
+    What a caller hands in is what truly differs between the forwards:
+
+    - the range. ``lo=None`` is the static ``[0, k)`` of a whole forward,
+      which ends with the VIRTUAL layer ``k`` — resid_pre of the first
+      unscanned block (== the final resid_post when ``k == n_layers``),
+      edited and captured like any other. A TRACED ``lo`` is one segment of
+      a longer job (``dynamic_slice`` on the stacked leaves, so one compiled
+      program serves every segment of a given width — no per-range
+      recompiles and no pre-split param copies); its virtual layer is the
+      job's business (:func:`_seg_finish_impl`);
+    - the carry: :func:`_fresh_carry`, or a segment's donated one;
+    - ``pos`` / ``attend``: how attention is reached (:func:`_block`);
+    - ``edits``: ``(fns, (layer, site) pairs, values)``, parallel tuples.
+      With none, the body traces exactly the capture-only op sequence;
+    - ``emit(lp, resid, attn_out)``: a per-layer output of the caller's own
+      (``ys``), from the stream entering the block and its attention
+      contribution.
+    """
+    slots = _slots(capture)
+    # static: skip the sublayer-capture FMAs entirely on resid-only runs
+    captured_sites = {c for _, c in capture}
+    edit_fns, edit_layers, edit_values = edits
+    edit_arr = (
+        jnp.asarray([l for l, _ in edit_layers], dtype=jnp.int32)
+        if edit_layers else None
+    )
+
+    def edited(x, i, site):
+        # the edits at this site whose layer is ``i``: the site selection is
+        # static, layer matching a one-hot where-chain like the capture's
+        for j, fn in enumerate(edit_fns):
+            if edit_layers[j][1] == site:
+                new = fn(x, edit_values[j])
+                x = jnp.where(edit_arr[j] == i, new, x)
+        return x
+
+    layers = params["layers"]
+    if lo is None:
+        # TransformerLens-style stop_at_layer: scan only the blocks below the
+        # highest needed layer (the reference harvests with FULL forwards even
+        # for a mid-stack hook — reference buffer.py:81-89 — wasting every layer
+        # above it; at blocks.14 of 26 that is ~46% of the forward FLOPs)
+        stacked, held = _scan_leaves(layers, lambda x: x[:k])
+        layer_ids = jnp.arange(k, dtype=jnp.int32)
+    else:
+        stacked, held = _scan_leaves(
+            layers, lambda x: jax.lax.dynamic_slice_in_dim(x, lo, k, axis=0))
+        layer_ids = lo + jnp.arange(k, dtype=jnp.int32)
+
+    def body(carry, xs):
+        resid, buf = carry
+        lp, i = xs
+        lp = {**lp, **held}
+        entering = resid = edited(resid, i, _SITE_RESID)
+        buf = _capture_into(buf, resid, i, slots)
+        resid, attn_out, mlp_out = _block(
+            resid, lp, cfg, _layer_kind(cfg, i),
+            edit_attn=functools.partial(edited, i=i, site=_SITE_ATTN),
+            edit_mlp=functools.partial(edited, i=i, site=_SITE_MLP),
+            pos=pos, attend=attend,
+        )
+        if _SITE_ATTN in captured_sites:
+            buf = _capture_into(buf, attn_out, i, slots, _SITE_ATTN)
+        if _SITE_MLP in captured_sites:
+            buf = _capture_into(buf, mlp_out, i, slots, _SITE_MLP)
+        return (resid, buf), (emit(lp, entering, attn_out) if emit else None)
+
+    (resid, buf), ys = jax.lax.scan(body, carry, (stacked, layer_ids))
+    if lo is None:
+        resid = edited(resid, jnp.int32(k), _SITE_RESID)
+        buf = _capture_into(buf, resid, jnp.int32(k), slots)
+    return (resid, buf), ys
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(
@@ -728,83 +844,11 @@ def _forward_impl(
     return_logits: bool,
     n_scan: int | None = None,
 ):
-    B, S = tokens.shape
-    D = cfg.d_model
-    dt = dtype_of(cfg.dtype)
     if n_scan is None:
         n_scan = cfg.n_layers
-
-    resid = _embed(params, tokens, cfg)
-
-    n_cap = len(capture)
-    cap_arr = jnp.asarray([l for l, _ in capture], dtype=jnp.int32) if n_cap else None
-    cap_sites = jnp.asarray([c for _, c in capture], dtype=jnp.int32) if n_cap else None
-    # static: skip the sublayer-capture FMAs entirely on resid-only runs
-    want_attn = any(c == _SITE_ATTN for _, c in capture)
-    want_mlp = any(c == _SITE_MLP for _, c in capture)
-    cap_buf = jnp.zeros((n_cap, B, S, D), dtype=dt) if n_cap else None
-    edit_site_codes = tuple(c for _, c in edit_layers)      # static
-    edit_arr = (
-        jnp.asarray([l for l, _ in edit_layers], dtype=jnp.int32)
-        if edit_layers else None
-    )
-
-    def apply_hooks(resid, i):
-        # residual-site edits only; sublayer-site edits run inside _block
-        for j, fn in enumerate(edit_fns):
-            if edit_site_codes[j] != _SITE_RESID:
-                continue
-            edited = fn(resid, edit_values[j])
-            resid = jnp.where(edit_arr[j] == i, edited, resid)
-        return resid
-
-    # TransformerLens-style stop_at_layer: scan only the blocks below the
-    # highest needed layer (the reference harvests with FULL forwards even
-    # for a mid-stack hook — reference buffer.py:81-89 — wasting every layer
-    # above it; at blocks.14 of 26 that is ~46% of the forward FLOPs)
-    stacked, held = _scan_leaves(params["layers"], lambda x: x[:n_scan])
-    layer_ids = jnp.arange(n_scan, dtype=jnp.int32)
-
-    def body(carry, xs):
-        resid, buf = carry
-        lp, i = xs
-        lp = {**lp, **held}
-        resid = apply_hooks(resid, i)
-        buf = _capture_into(buf, resid, i, cap_arr, _SITE_RESID, cap_sites)
-
-        def editor_for(site):
-            # sublayer-site edits, applied to the contribution at its own
-            # layer BEFORE it joins the stream (and before capture). The
-            # site selection is static; layer matching is the same
-            # one-hot where-chain as the residual edits.
-            js = [j for j, c in enumerate(edit_site_codes) if c == site]
-            if not js:
-                return None
-
-            def ed(out):
-                for j in js:
-                    edited = edit_fns[j](out, edit_values[j])
-                    out = jnp.where(edit_arr[j] == i, edited, out)
-                return out
-
-            return ed
-
-        resid, attn_out, mlp_out = _block(
-            resid, lp, cfg, _layer_kind(cfg, i),
-            edit_attn=editor_for(_SITE_ATTN), edit_mlp=editor_for(_SITE_MLP),
-        )
-        if want_attn:
-            buf = _capture_into(buf, attn_out, i, cap_arr, _SITE_ATTN, cap_sites)
-        if want_mlp:
-            buf = _capture_into(buf, mlp_out, i, cap_arr, _SITE_MLP, cap_sites)
-        return (resid, buf), None
-
-    (resid, cap_buf), _ = jax.lax.scan(body, (resid, cap_buf), (stacked, layer_ids))
-    # virtual layer n_scan: resid_pre of the first unscanned block (== final
-    # resid_post when n_scan == n_layers)
-    resid = apply_hooks(resid, jnp.int32(n_scan))
-    cap_buf = _capture_into(cap_buf, resid, jnp.int32(n_scan), cap_arr, _SITE_RESID, cap_sites)
-
+    (resid, cap_buf), _ = _scan_blocks(
+        params, cfg, capture, _fresh_carry(params, tokens, cfg, len(capture)),
+        n_scan, edits=(edit_fns, edit_layers, edit_values))
     logits = _unembed(params, resid, cfg) if return_logits else None
     return logits, cap_buf
 
@@ -917,21 +961,15 @@ def expert_load(params: LMParams, tokens: jax.Array, cfg: LMConfig, n_scan: int)
     ``harvest/moe_load_max_over_mean`` gauge, and only with ``obs`` on."""
     from crosscoder_tpu.ops import moe
 
-    def body(resid, xs):
-        lp, i = xs
-        lp = {**lp, **held}
-        attn = _attention(_norm(resid, lp["attn_norm"], cfg), lp, cfg, _layer_kind(cfg, i))
-        resid = resid + attn
-        x = _norm(resid, lp["pre_ffw_norm"], cfg)
+    def routed(lp, resid, attn_out):
+        # the router reads what the block's MLP sublayer reads
+        x = _norm(resid + attn_out, lp["pre_ffw_norm"], cfg)
         idx, _ = moe.route(x.reshape(-1, cfg.d_model), lp["router"],
                            cfg.experts_per_tok, cfg.norm_topk_prob)
-        counts = jnp.zeros((cfg.n_experts,), jnp.int32).at[idx.reshape(-1)].add(1)
-        return resid + _mlp_out(resid, lp, cfg, i), counts
+        return jnp.zeros((cfg.n_experts,), jnp.int32).at[idx.reshape(-1)].add(1)
 
-    stacked, held = _scan_leaves(params["layers"], lambda x: x[:n_scan])
-    _, counts = jax.lax.scan(
-        body, _embed(params, tokens, cfg),
-        (stacked, jnp.arange(n_scan, dtype=jnp.int32)))
+    _, counts = _scan_blocks(
+        params, cfg, (), _fresh_carry(params, tokens, cfg, 0), n_scan, emit=routed)
     return counts
 
 
@@ -941,10 +979,7 @@ def expert_load(params: LMParams, tokens: jax.Array, cfg: LMConfig, n_scan: int)
 
 @functools.partial(jax.jit, static_argnames=("cfg", "n_cap"))
 def _seg_start_impl(params: LMParams, tokens: jax.Array, cfg: LMConfig, n_cap: int):
-    B, S = tokens.shape
-    resid = _embed(params, tokens, cfg)
-    buf = jnp.zeros((n_cap, B, S, cfg.d_model), resid.dtype)
-    return resid, buf
+    return _fresh_carry(params, tokens, cfg, n_cap)
 
 
 @functools.partial(
@@ -954,36 +989,11 @@ def _seg_scan_impl(
     params: LMParams, resid: jax.Array, buf: jax.Array, lo: jax.Array,
     cfg: LMConfig, capture: tuple[tuple[int, int], ...], k: int,
 ):
-    """Blocks [lo, lo+k) of the capture forward, carrying (resid, buf).
-
-    ``lo`` is TRACED (``dynamic_slice`` on the stacked layer leaves), so one
-    compiled program serves every segment of a given width — no per-range
-    recompiles and no pre-split param copies. Per-layer math is identical to
-    ``_forward_impl``'s scan body (same ops in the same order); only the
-    scan is cut into sub-scans."""
-    n_cap = len(capture)
-    cap_arr = jnp.asarray([l for l, _ in capture], jnp.int32) if n_cap else None
-    cap_sites = jnp.asarray([c for _, c in capture], jnp.int32) if n_cap else None
-    want_attn = any(c == _SITE_ATTN for _, c in capture)
-    want_mlp = any(c == _SITE_MLP for _, c in capture)
-    stacked, held = _scan_leaves(
-        params["layers"], lambda x: jax.lax.dynamic_slice_in_dim(x, lo, k, axis=0))
-    layer_ids = lo + jnp.arange(k, dtype=jnp.int32)
-
-    def body(carry, xs):
-        resid, buf = carry
-        lp, i = xs
-        lp = {**lp, **held}
-        buf = _capture_into(buf, resid, i, cap_arr, _SITE_RESID, cap_sites)
-        resid, attn_out, mlp_out = _block(resid, lp, cfg, _layer_kind(cfg, i))
-        if want_attn:
-            buf = _capture_into(buf, attn_out, i, cap_arr, _SITE_ATTN, cap_sites)
-        if want_mlp:
-            buf = _capture_into(buf, mlp_out, i, cap_arr, _SITE_MLP, cap_sites)
-        return (resid, buf), None
-
-    (resid, buf), _ = jax.lax.scan(body, (resid, buf), (stacked, layer_ids))
-    return resid, buf
+    """Blocks [lo, lo+k) of the capture forward, carrying (resid, buf):
+    :func:`_scan_blocks` with a TRACED ``lo``. Per-layer math is that of
+    ``_forward_impl`` (same ops in the same order); only the scan is cut
+    into sub-scans."""
+    return _scan_blocks(params, cfg, capture, (resid, buf), k, lo)[0]
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "capture", "n_scan", "out_dtype"))
@@ -993,11 +1003,10 @@ def _seg_finish_impl(
 ):
     """Virtual-layer capture per model + the model-major source stack —
     output shape/order identical to :func:`run_with_cache_multi`."""
-    cap_arr = jnp.asarray([l for l, _ in capture], jnp.int32)
-    cap_sites = jnp.asarray([c for _, c in capture], jnp.int32)
+    slots = _slots(capture)
     outs = []
     for resid, buf in zip(resids, bufs):
-        buf = _capture_into(buf, resid, jnp.int32(n_scan), cap_arr, _SITE_RESID, cap_sites)
+        buf = _capture_into(buf, resid, jnp.int32(n_scan), slots)
         outs.extend(buf[i] for i in range(buf.shape[0]))
     out = jnp.stack(outs, axis=2)                  # [B, S, n_sources, D]
     return out.astype(out_dtype) if out_dtype is not None else out
@@ -1008,15 +1017,16 @@ class SegmentedHarvest:
     dispatches instead of one monolithic one.
 
     Why: the replay buffer's incremental refill interleaves harvest
-    forwards with train steps on ONE serial device queue. At Gemma-2-2B
-    shapes a whole-chunk forward is ~108 ms of device time — an indivisible
-    quantum that lands in whichever train step queues behind it, producing
-    the measured 111 ms refresh bubble (BENCH_r04 e2e max-vs-median step).
-    Splitting the forward into ``SEG_LAYERS``-block sub-scans (~10-15 ms
-    each) lets the buffer meter harvest work evenly across serves; the math
-    is the same per-layer op sequence, so results match the monolithic path
-    (asserted by tests/test_lm.py). No reference counterpart — the
-    reference harvests in one blocking stall (reference buffer.py:78-96).
+    forwards with train steps on ONE serial device queue, where a
+    whole-chunk forward is an indivisible quantum that lands in whichever
+    train step queues behind it (the harvest is 57–94 ms of device time a
+    train step in the benchmark's cells: ledger, PR 30,
+    ``harvest_device_ms_per_step``). Cut into ``⌈n_scan / SEG_LAYERS⌉``
+    near-equal sub-scans, the refill can be metered evenly across serves;
+    the math is the same per-layer op sequence, so results match the
+    monolithic path (asserted by tests/test_lm.py). No reference
+    counterpart — the reference harvests in one blocking stall (reference
+    buffer.py:78-96).
 
     Protocol: ``step()`` dispatches one quantum (async, never blocks on the
     device) and returns False once the final stacked result has been
@@ -1029,16 +1039,8 @@ class SegmentedHarvest:
     # segments bound the refresh bubble tighter (a quantum lands inside
     # whichever train step queues behind it) but each segment dispatch
     # costs host time; where the balance sits on a chip is not measured
-    # (ROADMAP S3). None = resolve $CROSSCODER_SEG_LAYERS at USE time
-    # (default 3), so the env knob works regardless of import order;
-    # setting the class attribute to an int overrides both.
-    SEG_LAYERS: int | None = None
-
-    @classmethod
-    def seg_layers(cls) -> int:
-        if cls.SEG_LAYERS is not None:
-            return cls.SEG_LAYERS
-        return int(os.environ.get("CROSSCODER_SEG_LAYERS", "3"))
+    # (ROADMAP S3).
+    SEG_LAYERS = 3
 
     def __init__(
         self,
@@ -1054,10 +1056,7 @@ class SegmentedHarvest:
         self.capture = _hook_layers(cfg, tuple(hook_points))
         self.n_scan = hooked_depth(cfg, hook_points)
         self.out_dtype = out_dtype
-        # snapshot the granularity for the job's whole life: n_steps (the
-        # pacing denominator) and the quanta's bounds must agree even if
-        # the knob changes while this job is in flight
-        self._bounds = self.quanta(self.n_scan, self.seg_layers())
+        self._bounds = self.quanta(self.n_scan, self.SEG_LAYERS)
         self.n_steps = len(self.params_seq) * max(1, len(self._bounds))
         self._model_idx = 0
         self._lo = self._q = 0          # next layer; next quantum
@@ -1070,7 +1069,7 @@ class SegmentedHarvest:
     def count(cls, cfg: LMConfig, hook_points: Sequence[str], n_models: int) -> int:
         """``step()`` calls a job over these hooks will need (for pacing)."""
         n_scan = hooked_depth(cfg, hook_points)
-        return n_models * max(1, -(-n_scan // cls.seg_layers()))
+        return n_models * max(1, -(-n_scan // cls.SEG_LAYERS))
 
     @staticmethod
     def quanta(n_scan: int, seg_layers: int) -> list[int]:
@@ -1087,13 +1086,6 @@ class SegmentedHarvest:
             ends.append(lo)
         return ends
 
-    def _take(self, n_quanta: int) -> tuple[int, int]:
-        """Advance over up to ``n_quanta`` of the current model's quanta:
-        ``(quanta taken, layers they span)`` from ``self._lo``."""
-        n_q = min(n_quanta, len(self._bounds) - self._q)
-        self._q += n_q
-        return n_q, self._bounds[self._q - 1] - self._lo
-
     def inflight(self):
         """Arrays dispatched but possibly still executing — for callers
         that must drive the pipeline to quiescence before releasing a
@@ -1101,39 +1093,54 @@ class SegmentedHarvest:
         return [x for x in (self._resid, self._buf, self._out)
                 if x is not None]
 
-    def step(self) -> bool:
-        """Dispatch the next quantum; False once fully dispatched."""
-        if self._out is not None:
-            return False
-        if self._resid is None:
-            self._resid, self._buf = _seg_start_impl(
-                self.params_seq[self._model_idx], self.tokens, self.cfg,
-                len(self.capture),
-            )
-        if self._lo < self.n_scan:
-            _, k = self._take(1)
-            # lo as a HOST scalar: jit uploads it straight to every device
-            # of a sharded harvest; a jnp scalar would sit on the default
-            # device and be re-replicated device-to-device per dispatch
-            self._resid, self._buf = _seg_scan_impl(
-                self.params_seq[self._model_idx], self._resid, self._buf,
-                np.int32(self._lo), self.cfg, self.capture, k,
-            )
-            self._lo += k
-        if self._lo >= self.n_scan:
-            self._done_resids.append(self._resid)
-            self._done_bufs.append(self._buf)
-            self._resid = self._buf = None
-            self._lo = self._q = 0
-            self._model_idx += 1
-            if self._model_idx == len(self.params_seq):
-                self._out = _seg_finish_impl(
-                    tuple(self._done_resids), tuple(self._done_bufs),
-                    self.cfg, self.capture, self.n_scan, self.out_dtype,
+    def _advance(self, quanta: int, scan: Callable[[int], tuple]) -> tuple[int, bool]:
+        """The job's ONE state machine, behind :meth:`step` and
+        :meth:`step_many`: start a model, take up to ``quanta`` of its
+        quanta as one ``scan(k)`` dispatch of their ``k`` layers, retire the
+        model at its last layer, stack the result after the last model.
+        Returns ``(quanta consumed, alive)``."""
+        used = 0
+        while used < quanta:
+            if self._out is not None:
+                return used, False
+            if self._resid is None:
+                self._resid, self._buf = _seg_start_impl(
+                    self.params_seq[self._model_idx], self.tokens, self.cfg,
+                    len(self.capture),
                 )
-                self._done_resids = self._done_bufs = []
-                return False
-        return True
+            if self._lo < self.n_scan:
+                # consecutive quanta of the same model fuse into one sub-scan
+                n_q = min(quanta - used, len(self._bounds) - self._q)
+                self._q += n_q
+                k = self._bounds[self._q - 1] - self._lo
+                self._resid, self._buf = scan(k)
+                self._lo += k
+                used += n_q
+            else:
+                used += 1       # a hook at the embedding: the model's one step
+            if self._lo >= self.n_scan:
+                self._done_resids.append(self._resid)
+                self._done_bufs.append(self._buf)
+                self._resid = self._buf = None
+                self._lo = self._q = 0
+                self._model_idx += 1
+                if self._model_idx == len(self.params_seq):
+                    self._out = _seg_finish_impl(
+                        tuple(self._done_resids), tuple(self._done_bufs),
+                        self.cfg, self.capture, self.n_scan, self.out_dtype,
+                    )
+                    self._done_resids = self._done_bufs = []
+                    return used, False
+        return used, True
+
+    def _scan_plain(self, k: int):
+        # lo as a HOST scalar: jit uploads it straight to every device
+        # of a sharded harvest; a jnp scalar would sit on the default
+        # device and be re-replicated device-to-device per dispatch
+        return _seg_scan_impl(
+            self.params_seq[self._model_idx], self._resid, self._buf,
+            np.int32(self._lo), self.cfg, self.capture, k,
+        )
 
     def _scan_batched(self, k: int):
         """One ``k``-wide sub-scan dispatch through a pre-built donated
@@ -1163,6 +1170,10 @@ class SegmentedHarvest:
             return _seg_scan_impl(*args, cfg=self.cfg, capture=self.capture, k=k)
         return compiled(*args)
 
+    def step(self) -> bool:
+        """Dispatch the next quantum; False once fully dispatched."""
+        return self._advance(1, self._scan_plain)[1]
+
     def step_many(self, quanta: int) -> tuple[int, bool]:
         """Advance by up to ``quanta`` dispatch quanta, FUSING consecutive
         same-model quanta into one wide sub-scan dispatch (``k`` up to
@@ -1174,34 +1185,7 @@ class SegmentedHarvest:
         sequential, so a k-wide sub-scan is bitwise identical to k/SEG
         narrow ones (asserted by tests/test_refill_overlap.py).
         """
-        used = 0
-        while used < quanta:
-            if self._out is not None:
-                return used, False
-            if self._resid is None:
-                self._resid, self._buf = _seg_start_impl(
-                    self.params_seq[self._model_idx], self.tokens, self.cfg,
-                    len(self.capture),
-                )
-            if self._lo < self.n_scan:
-                n_q, k = self._take(quanta - used)
-                self._resid, self._buf = self._scan_batched(k)
-                self._lo += k
-                used += n_q
-            if self._lo >= self.n_scan:
-                self._done_resids.append(self._resid)
-                self._done_bufs.append(self._buf)
-                self._resid = self._buf = None
-                self._lo = self._q = 0
-                self._model_idx += 1
-                if self._model_idx == len(self.params_seq):
-                    self._out = _seg_finish_impl(
-                        tuple(self._done_resids), tuple(self._done_bufs),
-                        self.cfg, self.capture, self.n_scan, self.out_dtype,
-                    )
-                    self._done_resids = self._done_bufs = []
-                    return used, False
-        return used, True
+        return self._advance(quanta, self._scan_batched)
 
     def result(self) -> jax.Array:
         while self._out is None:
@@ -1245,15 +1229,6 @@ def _paged_capture_one(
 
     R, Sp = plane_tokens.shape
     D, S = doc_idx.shape
-    dt = dtype_of(cfg.dtype)
-    n_cap = len(capture)
-    cap_arr = jnp.asarray([l for l, _ in capture], jnp.int32) if n_cap else None
-    cap_sites = jnp.asarray([c for _, c in capture], jnp.int32) if n_cap else None
-    want_attn = any(c == _SITE_ATTN for _, c in capture)
-    want_mlp = any(c == _SITE_MLP for _, c in capture)
-
-    resid = _embed(params, plane_tokens, cfg)
-    buf = jnp.zeros((n_cap, R, Sp, cfg.d_model), dt)
 
     def gather_docs(x):          # [R, Sp, ...] -> [D, S, ...]
         return x.reshape((R * Sp,) + x.shape[2:])[doc_idx]
@@ -1278,31 +1253,14 @@ def _paged_capture_one(
             is_local, run(cfg.sliding_window), run(0), (qd, kd, vd)
         )
 
-    def body(carry, xs):
-        resid, buf = carry
-        lp, i = xs
-        lp = {**lp, **held}
-        buf = _capture_into(buf, resid, i, cap_arr, _SITE_RESID, cap_sites)
-        kind = _layer_kind(cfg, i)
-        xn = _norm(resid, lp["attn_norm"], cfg)
-        q, k, v = _qkv(xn, lp, cfg, pos2d, kind)
-        a_docs = attn_docs(gather_docs(q), gather_docs(k), gather_docs(v),
-                           kind.is_local)
-        attn_out = _attn_out(scatter_plane(a_docs), lp, cfg)
-        if want_attn:
-            buf = _capture_into(buf, attn_out, i, cap_arr, _SITE_ATTN, cap_sites)
-        resid = resid + attn_out
-        mlp_out = _mlp_out(resid, lp, cfg, i)
-        if want_mlp:
-            buf = _capture_into(buf, mlp_out, i, cap_arr, _SITE_MLP, cap_sites)
-        resid = resid + mlp_out
-        return (resid, buf), None
+    def attend(q, k, v, kind):
+        return scatter_plane(attn_docs(
+            gather_docs(q), gather_docs(k), gather_docs(v), kind.is_local))
 
-    stacked, held = _scan_leaves(params["layers"], lambda x: x[:n_scan])
-    layer_ids = jnp.arange(n_scan, dtype=jnp.int32)
-    (resid, buf), _ = jax.lax.scan(body, (resid, buf), (stacked, layer_ids))
-    return _capture_into(buf, resid, jnp.int32(n_scan), cap_arr, _SITE_RESID,
-                         cap_sites)
+    (_, buf), _ = _scan_blocks(
+        params, cfg, capture, _fresh_carry(params, plane_tokens, cfg, len(capture)),
+        n_scan, pos=pos2d, attend=attend)
+    return buf
 
 
 @functools.partial(
@@ -1592,50 +1550,24 @@ def _seq_local_body(
     """
     from crosscoder_tpu.parallel.ring_attention import ring_attention
 
-    dt = dtype_of(cfg.dtype)
-    n_cap = len(cap_layers)
-    scale = cfg.query_pre_attn_scalar ** -0.5
     n_scan = cfg.n_layers if return_logits else min(
         cfg.n_layers, _scan_stop(cap_layers)
     )
-
     B, Sl = tok_local.shape
-    cap_arr = jnp.asarray([l for l, _ in cap_layers], jnp.int32) if n_cap else None
-    cap_sites = jnp.asarray([c for _, c in cap_layers], jnp.int32) if n_cap else None
-    want_attn = any(c == _SITE_ATTN for _, c in cap_layers)
-    want_mlp = any(c == _SITE_MLP for _, c in cap_layers)
-    idx = jax.lax.axis_index(axis_name)
-    pos = idx * Sl + jnp.arange(Sl)
-    resid = _embed(params, tok_local, cfg)
-    buf = jnp.zeros((n_cap, B, Sl, cfg.d_model), dt) if n_cap else None
 
-    def body(carry, xs):
-        resid, buf = carry
-        lp, i = xs
-        lp = {**lp, **held}
-        buf = _capture_into(buf, resid, i, cap_arr, _SITE_RESID, cap_sites)
-        kind = _layer_kind(cfg, i)
-        xn = _norm(resid, lp["attn_norm"], cfg)
-        q, k, v = _qkv(xn, lp, cfg, pos, kind)
-        a = ring_attention(
-            q, k, v, axis_name=axis_name, n_shards=n, scale=scale,
+    def ring(q, k, v, kind):
+        return ring_attention(
+            q, k, v, axis_name=axis_name, n_shards=n,
+            scale=cfg.query_pre_attn_scalar ** -0.5,
             softcap=cfg.attn_softcap, sliding_window=cfg.sliding_window,
             is_local=kind.is_local,
         ).reshape(B, Sl, cfg.n_heads * cfg.head_dim)
-        attn_out = _attn_out(a, lp, cfg)
-        if want_attn:
-            buf = _capture_into(buf, attn_out, i, cap_arr, _SITE_ATTN, cap_sites)
-        resid = resid + attn_out
-        mlp_out = _mlp_out(resid, lp, cfg, i)
-        if want_mlp:
-            buf = _capture_into(buf, mlp_out, i, cap_arr, _SITE_MLP, cap_sites)
-        resid = resid + mlp_out
-        return (resid, buf), None
 
-    stacked, held = _scan_leaves(params["layers"], lambda x: x[:n_scan])
-    layer_ids = jnp.arange(n_scan, dtype=jnp.int32)
-    (resid, buf), _ = jax.lax.scan(body, (resid, buf), (stacked, layer_ids))
-    buf = _capture_into(buf, resid, jnp.int32(n_scan), cap_arr, _SITE_RESID, cap_sites)
+    (resid, buf), _ = _scan_blocks(
+        params, cfg, cap_layers,
+        _fresh_carry(params, tok_local, cfg, len(cap_layers)), n_scan,
+        # GLOBAL positions: the shard's offset into the sequence
+        pos=jax.lax.axis_index(axis_name) * Sl + jnp.arange(Sl), attend=ring)
     logits = _unembed(params, resid, cfg) if return_logits else None
     return logits, buf
 

@@ -94,8 +94,7 @@ def test_bench_compact_summary_is_small_and_gated():
         "e2e": {"acts_per_sec_chip": 25000.0, "vs_a100_e2e": 1.1,
                 "step_ms_median": 40.0, "refresh_bubble_ms": 12.0,
                 "loss_finite": True, "workload": "w" * 200},
-        "refill_overlap": {"gate_ok": True, "seg3_gate_ok": True,
-                           "seg14_gate_ok": True, "n_steps_measured": 30},
+        "refill_overlap": {"gate_ok": True, "n_steps_measured": 30},
         "harvest": {"padding_efficiency": 0.62, "paged_step_ms": 50.0,
                     "paged_speedup": 1.4, "workload": "w" * 120},
         "quant": {"roundtrip_rel_mse": 1.2e-4, "quality_gate_ok": True,

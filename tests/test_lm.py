@@ -383,12 +383,60 @@ def test_gemma2_family_named_configs():
     assert lm.config_for("gemma-2-9b").query_pre_attn_scalar == 256.0
 
 
+def _seam_forward(cfg, params, tokens):
+    lm.run_with_cache_multi([params], tokens, cfg, ["blocks.2.hook_resid_pre"])
+
+
+def _seam_segmented(cfg, params, tokens):
+    lm.SegmentedHarvest([params], tokens, cfg, ["blocks.2.hook_resid_pre"]).result()
+
+
+def _seam_paged(cfg, params, tokens):
+    lm.run_with_cache_multi_paged(
+        [params], np.asarray(tokens), np.full(tokens.shape[0], tokens.shape[1]),
+        cfg, ["blocks.2.hook_resid_pre"], page_size=8)
+
+
+def _seam_seq_parallel(cfg, params, tokens):
+    mesh = jax.sharding.Mesh(np.array(jax.devices()), ("data",))
+    lm.run_with_cache_multi_seq_parallel(
+        [params], tokens, cfg, ["blocks.2.hook_resid_pre"], mesh)
+
+
+def _seam_expert_load(cfg, params, tokens):
+    lm.expert_load(params, tokens, cfg, 2)
+
+
+_SEAMS = [_seam_forward, _seam_segmented, _seam_paged, _seam_seq_parallel,
+          _seam_expert_load]
+
+
+@pytest.mark.parametrize("entry", _SEAMS, ids=lambda f: f.__name__[len("_seam_"):])
+def test_every_forward_traces_through_the_one_block(entry, monkeypatch):
+    """The layer loop is written once: each of the five entry points reaches
+    norm → QKV → attention → out-projection → add → MLP → add through
+    ``lm._block`` and nowhere else. A config of its own per case, so that no
+    case is served from another's jit cache."""
+    sparse = entry is _seam_expert_load
+    cfg = lm.LMConfig.tiny(vocab_size=300 + _SEAMS.index(entry)).replace(
+        **(dict(mlp_types=(lm.SPARSE,) * 4, n_experts=4, experts_per_tok=2,
+                d_expert=16) if sparse else {}))
+    params = lm.init_params(jax.random.key(0), cfg)
+    tokens = jnp.asarray(
+        np.random.default_rng(0).integers(0, cfg.vocab_size, size=(2, 16)))
+    real, traced = lm._block, []
+    monkeypatch.setattr(
+        lm, "_block", lambda *a, **kw: traced.append(1) or real(*a, **kw))
+    entry(cfg, params, tokens)
+    assert traced, f"{entry.__name__} spelled the block by hand"
+
+
 def test_segmented_harvest_matches_monolithic():
     """SegmentedHarvest (the refill pipeline's sub-forward dispatch quanta)
     computes the same stacked capture as run_with_cache_multi — same per-layer
     op sequence, only the scan is cut into sub-scans. Covers mixed sublayer
-    sites, a ragged final segment (n_scan % SEG_LAYERS != 0), and the
-    pacing count contract."""
+    sites, near-equal quanta (n_scan % SEG_LAYERS != 0), and the pacing count
+    contract."""
     cfg = lm.LMConfig.tiny()
     pa = lm.init_params(jax.random.key(11), cfg)
     pb = lm.init_params(jax.random.key(12), cfg)
@@ -397,7 +445,8 @@ def test_segmented_harvest_matches_monolithic():
     )
     for hooks in (
         ("blocks.2.hook_resid_pre",),
-        # mixed sites + multi-layer: n_scan = 4 → ranges (3, 1) at SEG_LAYERS=3
+        # mixed sites + multi-layer: n_scan = 4 → quanta of (2, 2) layers at
+        # SEG_LAYERS = 3 (two near-equal sub-scans, not 3 + 1)
         ("blocks.1.hook_resid_pre", "blocks.3.hook_attn_out",
          "blocks.2.hook_mlp_out"),
     ):
