@@ -374,7 +374,7 @@ def section_matrix() -> list[dict]:
     on_tpu = jax.default_backend() == "tpu"
     # (label, cfg overrides, topk impl, env for the leg). A non-empty env
     # is a kernel opt-in gate (ships conservative-default, see
-    # ops/sparse_grad.py / topk_pallas.batchtopk_kernel_enabled) — those
+    # ops/dispatch.py / topk_pallas.batchtopk_kernel_enabled) — those
     # legs are TPU-only: timing the interpret path or a silent dense
     # fallback under a kernel label would be a lie.
     variants = [
@@ -386,14 +386,14 @@ def section_matrix() -> list[dict]:
         ("topk_sparse_decode",
          dict(activation="topk", topk_k=32, l1_coeff=0.0, sparse_decode=True),
          "auto", {}),
-        # the sparse backward plane (tentpole of the scatter-accumulate PR):
-        # identical forward to topk_pallas + factored tier, backward through
-        # ops/sparse_grad.py — step_ms vs topk_pallas is the headline A/B,
-        # bwd_ms vs topk_pallas's carries the attribution
+        # the sparse backward plane: identical forward to topk_pallas +
+        # factored tier, backward through ops/row_gather.py where its
+        # kernels are live (one TPU device) — step_ms vs topk_pallas is the
+        # headline A/B, bwd_ms vs topk_pallas's carries the attribution
         ("topk_sparse_bwd",
          dict(activation="topk", topk_k=32, l1_coeff=0.0, sparse_bwd="on",
               factored_decode="on"),
-         "pallas", {"CROSSCODER_SPARSE_GRAD_PALLAS": "1"}),
+         "pallas", {}),
         # the fused encoder→TopK megakernel (PR "melt the dense floor"):
         # identical math to topk_sparse_bwd with the encode+TopK+sparsify
         # chain fused so [B, dict] pre-acts never hit HBM — step_ms vs
@@ -506,12 +506,12 @@ def section_matrix() -> list[dict]:
                 # sparse_bwd="on" with an unsupported scatter shape falls
                 # back to the XLA scatter — sparse math but the measured-
                 # slow path; don't time it under the sparse_bwd label
-                from crosscoder_tpu.ops import sparse_grad, topk_pallas
+                from crosscoder_tpu.models import crosscoder as cc
+                from crosscoder_tpu.ops import topk_pallas
 
                 if not (topk_pallas.sparsify_supported(dict_size, cfg.topk_k)
-                        and sparse_grad.decode_grad_supported(
-                            dict_size, cfg.topk_k, cfg.n_sources, cfg.d_in,
-                            cfg.batch_size)):
+                        and cc.use_sparse_bwd(cfg.replace(sparse_bwd="auto"),
+                                              cfg.batch_size)):
                     out.append({"variant": label, "dict_size": dict_size,
                                 "skipped": "scatter kernel unsupported at "
                                            "this shape"})

@@ -231,7 +231,7 @@ def resolved_tiers(cfg: Any) -> dict:
         probe, cfg.topk_k)
     return {
         "topk": "pallas" if kernel else "xla",
-        "factored_decode": cc.use_factored_decode(cfg),
+        "factored_decode": cc.use_factored_decode(cfg, cfg.batch_size),
         "sparse_bwd": cc.use_sparse_bwd(cfg, cfg.batch_size),
         "fused_encoder": cc.use_fused_encoder(cfg, cfg.batch_size),
     }

@@ -174,10 +174,10 @@ def step_jaxpr_consts(cfg) -> list[tuple[int, str]]:
 def _interpret_kernels(flag: bool):
     """Flip every step-path kernel module's interpret latch, restoring on
     exit — the CPU stand-in that makes 'kernel live' variants lowerable."""
-    from crosscoder_tpu.ops import (fused_encoder_topk, sparse_grad,
+    from crosscoder_tpu.ops import (fused_encoder_topk, row_gather,
                                     topk_pallas)
 
-    mods = (fused_encoder_topk, sparse_grad, topk_pallas)
+    mods = (fused_encoder_topk, row_gather, topk_pallas)
     prev = [m._INTERPRET for m in mods]
     for m in mods:
         m.set_interpret(flag)

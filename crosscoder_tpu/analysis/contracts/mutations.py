@@ -180,7 +180,7 @@ def _mut_probe_coverage() -> PallasContext:
 
 def _pallas_ctx(call: CapturedCall) -> PallasContext:
     calls = [_call(kernel=f) for f in
-             ("topk", "sparsify", "batchtopk", "quant", "sparse_grad",
+             ("topk", "sparsify", "batchtopk", "quant",
               "paged_attention", "fused_encoder_topk")]
     calls.append(call)
     return PallasContext(calls=calls)

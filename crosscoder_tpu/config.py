@@ -101,28 +101,34 @@ class CrossCoderConfig:
                                     # dense [B,H]x[H,n,d] matmul
     factored_decode: str = "auto"   # topk + Pallas tier: decode FORWARD
                                     # through the k active rows (sparsify
-                                    # kernel + gather), backward through
-                                    # the same dense matmuls as the dense
-                                    # path. "auto" = on for dict >= 2^17
-                                    # (measured v5e crossover vs the dense
-                                    # matmul: -8 ms at 2^17, +6 ms at
-                                    # 2^16); "on"/"off" force. Requires
-                                    # l1_coeff == 0 (see
+                                    # kernel + the rows). "auto" is
+                                    # resolved when a step is traced
+                                    # (models.crosscoder.rows_live): on
+                                    # where the rows are fetched by DMA
+                                    # (ops/row_gather.py: a TPU backend
+                                    # with ONE device, bf16 rows, no AuxK
+                                    # consumer of the pre-acts on that
+                                    # step, a supported shape), and
+                                    # elsewhere from dict >= 2^17 (XLA's
+                                    # take against the dense matmul: -8 ms
+                                    # at 2^17, +6 ms at 2^16); "on"/"off"
+                                    # force. Requires l1_coeff == 0 (see
                                     # models.crosscoder._factored_topk_forward)
     sparse_bwd: str = "auto"        # topk factored tier: replace the dense
                                     # backward matmuls (dW_dec, df, dW_enc)
-                                    # with O(B·k) Pallas scatter-accumulate
-                                    # gradients (ops/sparse_grad.py;
-                                    # docs/SCALING.md "Sparse backward
-                                    # plane"). "auto" = on when the
-                                    # factored tier is active AND the
-                                    # scatter kernel is live (TPU +
-                                    # CROSSCODER_SPARSE_GRAD_PALLAS=1, or
-                                    # interpret mode) AND shapes are
-                                    # kernel-supported; "on" forces (also
-                                    # forces the factored tier); "off"
-                                    # never. Requires l1_coeff == 0 (the
-                                    # factored tier's soundness gate).
+                                    # with O(B·k) sums over rows fetched by
+                                    # DMA (ops/row_gather.py; docs/SCALING.md
+                                    # "Sparse backward plane"). "auto" = on
+                                    # where the row kernels are live (the
+                                    # factored tier then runs on them too:
+                                    # all four k-sparse products, or none);
+                                    # "on" forces
+                                    # (also forces the factored tier; where
+                                    # the kernels are not live it is the
+                                    # XLA scatter: sound, slow, for CPU
+                                    # parity and A/Bs); "off" never.
+                                    # Requires l1_coeff == 0 (the factored
+                                    # tier's soundness gate).
     fused_encoder: str = "auto"     # fused encoder→TopK megakernel
                                     # (ops/fused_encoder_topk.py;
                                     # docs/SCALING.md "Fused encoder→

@@ -283,22 +283,55 @@ _sparse_decode_product.defvjp(_sparse_decode_fwd, _sparse_decode_bwd)
 # reference crosscoder.py:82-89).
 
 
+@functools.cache
+def _row_ops():
+    """``ops/row_gather.py``'s entry points under ``jax.jit``. A job traces
+    its step once a variant (with and without metrics), and a jitted
+    callee's jaxpr is kept from one trace to the next: the kernels' bodies,
+    two thirds of a variant's tracing on the chip's host (PERF.md §6,
+    PR 32), are traced once a process."""
+    import types
+
+    from crosscoder_tpu.ops import row_gather
+
+    return types.SimpleNamespace(
+        weighted_sum=jax.jit(row_gather.weighted_sum, static_argnames=(
+            "d", "name", "interpret", "out_dtype")),
+        dots=jax.jit(row_gather.dots, static_argnames=("k", "name", "interpret")),
+        grouped_sums=jax.jit(row_gather.grouped_sums, static_argnames=(
+            "n_out", "name", "interpret")),
+    )
+
+
 def _select_decode(
-    h: jax.Array, W_dec: jax.Array, k: int
-) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    h: jax.Array, W_dec: jax.Array, k: int, rows: bool = False,
+) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
     """The factored tiers' shared forward tail: kernel mask → sparsify →
     k-row decode. Returns ``(f [B,H], vals [B,k], idx [B,k], recon [B,n,d]
-    f32 without b_dec)``; the scopes name the two halves in a device trace."""
-    from crosscoder_tpu.ops import topk_pallas
+    f32 without b_dec, W_dec as the backward wants it)``; the scopes name
+    the two halves in a device trace. ``rows``: the k rows are fetched by
+    DMA from ``W_dec`` in packed rows (``ops/row_gather.py``; the pack takes
+    the place of the step's bf16 cast of ``W_dec`` and is the residual);
+    else XLA's ``take``."""
+    from crosscoder_tpu.ops import row_gather, topk_pallas
 
     with jax.named_scope("cc/select"):
         f = topk_pallas.topk(h, k)
         vals, idx = topk_pallas.sparsify(f, k)
     with jax.named_scope("cc/decode"):
-        w = jnp.take(W_dec, idx, axis=0)                   # [B, k, n, d]
-        recon = jnp.einsum("bk,bknd->bnd", vals, w,
-                           preferred_element_type=jnp.float32)
-    return f, vals, idx, recon
+        if rows:
+            _, n, d = W_dec.shape
+            W_dec = row_gather.packed_sources(
+                W_dec, interpret=row_gather._INTERPRET)
+            recon = _row_ops().weighted_sum(
+                idx.reshape(-1), vals.astype(jnp.float32), W_dec, d=n * d,
+                name="topk_rows_decode", interpret=row_gather._INTERPRET,
+                out_dtype=jnp.float32).reshape(-1, n, d)
+        else:
+            w = jnp.take(W_dec, idx, axis=0)               # [B, k, n, d]
+            recon = jnp.einsum("bk,bknd->bnd", vals, w,
+                               preferred_element_type=jnp.float32)
+    return f, vals, idx, recon, W_dec
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
@@ -314,12 +347,12 @@ def _factored_topk_forward(
     when nothing differentiable consumes them (the dispatch in get_losses
     guarantees l1_coeff == 0 on this path; metric-only uses are fine).
     """
-    _, vals, idx, recon = _select_decode(h, W_dec, k)
+    _, vals, idx, recon, _ = _select_decode(h, W_dec, k)
     return recon, vals, idx
 
 
 def _factored_topk_fwd(h, W_dec, k):
-    f, vals, idx, recon = _select_decode(h, W_dec, k)
+    f, vals, idx, recon, _ = _select_decode(h, W_dec, k)
     # f is the residual: both backward matmuls consume the masked [B,H]
     # activations (dW_dec contraction + the straight-through mask on df)
     return (recon, vals, idx), (f, W_dec)
@@ -380,74 +413,101 @@ _factored_topk_forward.defvjp(_factored_topk_fwd, _factored_topk_bwd)
 # through (vals, idx) cotangents).
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
 def _sparse_topk_step(
     x: jax.Array, W_enc: jax.Array, b_enc: jax.Array, W_dec: jax.Array,
-    k: int,
+    k: int, rows: bool = False,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """``(recon [B,n,d] f32 (no b_dec), vals [B,k], idx [B,k])`` from the
     batch ``x [B,n,d]`` — encode + TopK + factored decode in one
-    custom-vjp scope so the backward never leaves factored form."""
+    custom-vjp scope so the backward never leaves factored form. ``rows``:
+    every k-sparse product goes through rows fetched by DMA
+    (``ops/row_gather.py``) — the decode and ``d_vals`` token-major, both
+    weight gradients and ``db_enc`` in one latent-major pass."""
+    return _encode_select_decode(x, W_enc, b_enc, W_dec, k, rows)[:3]
+
+
+def _encode_select_decode(x, W_enc, b_enc, W_dec, k, rows):
     with jax.named_scope("cc/encode"):
         hf = jnp.einsum("bnd,ndh->bh", x, W_enc,
                         preferred_element_type=jnp.float32)
         h = (hf + b_enc.astype(jnp.float32)).astype(x.dtype)
-    _, vals, idx, recon = _select_decode(h, W_dec, k)
-    return recon, vals, idx
+    _, vals, idx, recon, w = _select_decode(h, W_dec, k, rows)
+    return recon, vals, idx, w
 
 
-def _sparse_topk_step_fwd(x, W_enc, b_enc, W_dec, k):
-    out = _sparse_topk_step(x, W_enc, b_enc, W_dec, k)
-    _, vals, idx = out
+def _sparse_topk_step_fwd(x, W_enc, b_enc, W_dec, k, rows):
+    recon, vals, idx, w = _encode_select_decode(x, W_enc, b_enc, W_dec, k, rows)
     # residuals are FACTORED: (vals, idx) [B,k] replace the [B,H] masked
-    # activations the dense backward keeps — ~H/k less residual memory.
+    # activations the dense backward keeps — ~H/k less residual memory;
+    # W_dec as the decode read it (packed rows in the ``rows`` form).
     # (b_tok: zero-size dtype token — residual leaves must be arrays.)
-    return out, (x, vals, idx, W_enc, W_dec, jnp.zeros((0,), b_enc.dtype))
+    return (recon, vals, idx), (x, vals, idx, W_enc, w,
+                                jnp.zeros((0,), b_enc.dtype))
 
 
-def _sparse_topk_step_bwd(k, res, g):
-    from crosscoder_tpu.ops import sparse_grad
+def _sparse_topk_step_bwd(k, rows, res, g):
+    from crosscoder_tpu.ops import row_gather, sparse_grad
 
     x, vals, idx, W_enc, W_dec, b_tok = res
     b_dtype = b_tok.dtype
     g_recon = g[0].astype(jnp.float32)                     # [B, n, d]
     # cotangents g[1], g[2] (vals, idx) are ignored — soundness gated on
     # l1_coeff == 0, exactly like _factored_topk_forward
-    B = vals.shape[0]
-    H, n, d = W_dec.shape
+    B, n, d = x.shape
+    H = W_enc.shape[-1]
     nd = n * d
     g_flat = g_recon.reshape(B, nd)
 
-    # d_vals through the k active decoder rows, straight-through masked on
-    # the survivors (vals > 0; padded slots carry val 0 and drop out —
-    # the same rule as the dense path's f > 0 mask)
-    w = jnp.take(W_dec, idx, axis=0).astype(jnp.float32)   # [B, k, n, d]
-    d_vals = jnp.einsum("bnd,bknd->bk", g_recon, w)
-    d_vals = jnp.where(vals > 0, d_vals, 0.0)              # [B, k] f32
+    if rows:
+        with jax.named_scope("cc/decode"):
+            # d_vals through the same table and the same packed rows as the
+            # decode, straight-through masked on the survivors and rounded
+            # to the compute dtype, as the dense df is
+            d_vals = _row_ops().dots(
+                idx.reshape(-1), g_flat, W_dec, k=k, name="topk_rows_dvals",
+                interpret=row_gather._INTERPRET)
+            d_vals = jnp.where(vals > 0, d_vals, 0.0).astype(vals.dtype)
+            # the cotangent of a bf16 reconstruction is bf16-valued: exact
+            dW_dec, dW_enc, db_enc = _row_ops().grouped_sums(
+                idx, vals, d_vals, g_flat.astype(x.dtype), x.reshape(B, nd),
+                n_out=H, name="topk_rows_grads", interpret=row_gather._INTERPRET)
+        dW_dec = dW_dec.reshape(H, n, d)
+        # the kernel wrote it transposed: bf16 values in float32 words, the
+        # form the optimizer reads (a cast here would be a pass of its own)
+        dW_enc = dW_enc.reshape(n, d, H)
+        db_enc = db_enc.astype(b_dtype)
+    else:
+        # d_vals through the k active decoder rows, straight-through masked
+        # on the survivors (vals > 0; padded slots carry val 0 and drop out
+        # — the same rule as the dense path's f > 0 mask)
+        w = jnp.take(W_dec, idx, axis=0).astype(jnp.float32)   # [B, k, n, d]
+        d_vals = jnp.einsum("bnd,bknd->bk", g_recon, w)
+        d_vals = jnp.where(vals > 0, d_vals, 0.0)              # [B, k] f32
 
-    # decoder gradient: B·k scatter-accumulate instead of [B,H]x[B,nd]
-    dW_dec = sparse_grad.scatter_add_rows(
-        vals.astype(jnp.float32), idx, g_flat, H
-    ).reshape(H, n, d).astype(W_dec.dtype)
+        # decoder gradient: B·k scatter-accumulate instead of [B,H]x[B,nd]
+        dW_dec = sparse_grad.scatter_add_rows(
+            vals.astype(jnp.float32), idx, g_flat, H
+        ).reshape(H, n, d).astype(W_dec.dtype)
 
-    # encoder gradients from the k-sparse dh: one scatter over the batch
-    # rows, with a ones column appended (lane-padded to 128) so db_enc
-    # rides the same accumulation instead of needing its own scatter
-    x_flat = x.reshape(B, nd).astype(jnp.float32)
-    ones_col = (jax.lax.broadcasted_iota(jnp.int32, (B, 128), 1) == 0
-                ).astype(jnp.float32)
-    x_aug = jnp.concatenate([x_flat, ones_col], axis=1)    # [B, nd + 128]
-    enc_grads = sparse_grad.scatter_add_rows(d_vals, idx, x_aug, H)
-    dW_enc = jnp.transpose(
-        enc_grads[:, :nd].reshape(H, n, d), (1, 2, 0)
-    ).astype(W_enc.dtype)
-    db_enc = enc_grads[:, nd].astype(b_dtype)
+        # encoder gradients from the k-sparse dh: one scatter over the batch
+        # rows, with a ones column appended (lane-padded to 128) so db_enc
+        # rides the same accumulation instead of needing its own scatter
+        x_flat = x.reshape(B, nd).astype(jnp.float32)
+        ones_col = (jax.lax.broadcasted_iota(jnp.int32, (B, 128), 1) == 0
+                    ).astype(jnp.float32)
+        x_aug = jnp.concatenate([x_flat, ones_col], axis=1)    # [B, nd + 128]
+        enc_grads = sparse_grad.scatter_add_rows(d_vals, idx, x_aug, H)
+        dW_enc = jnp.transpose(
+            enc_grads[:, :nd].reshape(H, n, d), (1, 2, 0)
+        ).astype(W_enc.dtype)
+        db_enc = enc_grads[:, nd].astype(b_dtype)
 
     # dx exactly (k-row gather of W_enc); XLA DCEs this whole branch when
     # only params are differentiated — i.e. on every training step
     we = jnp.take(W_enc, idx.reshape(-1), axis=2).reshape(n, d, B, k)
-    dx = jnp.einsum("bk,ndbk->bnd", d_vals, we.astype(jnp.float32)
-                    ).astype(x.dtype)
+    dx = jnp.einsum("bk,ndbk->bnd", d_vals.astype(jnp.float32),
+                    we.astype(jnp.float32)).astype(x.dtype)
     return dx, dW_enc, db_enc, dW_dec
 
 
@@ -499,7 +559,7 @@ def _fused_topk_step_fwd(x, W_enc, b_enc, W_dec, k, quant_block):
 def _fused_topk_step_bwd(k, quant_block, res, g):
     # gradients are the sparse plane's verbatim: the kernel only changed
     # how (vals, idx) were PRODUCED, not what they mean
-    return _sparse_topk_step_bwd(k, res, g)
+    return _sparse_topk_step_bwd(k, False, res, g)
 
 
 _fused_topk_step.defvjp(_fused_topk_step_fwd, _fused_topk_step_bwd)
@@ -596,8 +656,8 @@ def use_fused_encoder(cfg: CrossCoderConfig, batch: int | None = None) -> bool:
                 _warn_fused_demoted(
                     "activation='topk' needs the factored tier and the "
                     "sparse backward plane live (use_factored_decode/"
-                    "use_sparse_bwd resolved off — check dict_size, "
-                    "batch divisibility, and the sparse_grad kernel gate)"
+                    "use_sparse_bwd resolved off — check dict_size and "
+                    "whether the row kernels are live: rows_live)"
                 )
             return False
     elif cfg.activation == "batchtopk":
@@ -635,7 +695,7 @@ def _sparse_topk_from_h(
     replaced by the scatter/gather pair. ``dh`` is materialized [B, H]
     (one scatter) because ``h`` has other consumers on this path (the
     AuxK ranking) — the full-step variant above avoids even that."""
-    _, vals, idx, recon = _select_decode(h, W_dec, k)
+    _, vals, idx, recon, _ = _select_decode(h, W_dec, k)
     return recon, vals, idx
 
 
@@ -713,73 +773,77 @@ def _sparse_aux_product_bwd(res, g):
 _sparse_aux_product.defvjp(_sparse_aux_product_fwd, _sparse_aux_product_bwd)
 
 
+def rows_live(cfg: CrossCoderConfig, batch: int | None) -> bool:
+    """Whether the TopK step's k-sparse products go through rows fetched by
+    DMA (``ops/row_gather.py``), from what is visible when the step is
+    traced: TopK with no L1 objective (nothing differentiable may consume
+    ``(vals, idx)``), the sparse backward plane not switched off, a known
+    batch, a TPU backend with ONE device (a ``pallas_call`` is not
+    partitioned over a mesh; or the interpreter), bf16 rows, and shapes
+    BOTH kernel forms take — all four products or none: the decode and
+    ``df`` alone pay ``sparsify``, the pack and a ``[B, H]`` scatter of
+    ``dh`` for two 5.8 ms products and gain nothing (PERF.md §6, PR 32).
+    ``get_losses`` adds what only it can see: no AuxK consumer of ``h`` on
+    this step, and parameters already in the compute dtype."""
+    if cfg.activation != "topk" or cfg.sparse_decode or cfg.l1_coeff != 0:
+        return False
+    if batch is None or cfg.sparse_bwd == "off":
+        return False
+    from crosscoder_tpu.ops import row_gather
+
+    shape = (batch, cfg.topk_k, cfg.n_sources * cfg.d_in, dtype_of(cfg.enc_dtype))
+    return (row_gather.enabled() and row_gather.supported(*shape)
+            and row_gather.grouped_supported(cfg.dict_size, *shape))
+
+
 def use_sparse_bwd(cfg: CrossCoderConfig, batch: int | None = None) -> bool:
     """Dispatch for the sparse backward plane (``cfg.sparse_bwd``).
 
     Applies on top of the factored tier (callers AND the factored gate
     must agree — ``get_losses`` computes ``factored and use_sparse_bwd``).
     "off" never; "on" whenever sound (forced — CPU parity tests and
-    forced A/Bs; unsupported shapes fall back to the XLA scatter inside
-    scatter_add_rows, still sparse math); "auto" additionally requires
-    the Pallas scatter kernel to be live (interpret mode, or TPU with
-    ``CROSSCODER_SPARSE_GRAD_PALLAS=1`` — the ops/quant.py hardware gate)
-    and, when the batch size is known, kernel-supported shapes for both
-    scatter calls — without the kernel, a sparse backward IS the measured
-    42-76 ms XLA scatter the dense matmuls beat.
+    forced A/Bs; where the row kernels are not live the gradients fall
+    back to the XLA scatter inside scatter_add_rows, still sparse math);
+    "auto" where the row kernels are live (:func:`rows_live`) — without
+    them, a sparse backward IS the measured 42-76 ms XLA scatter the dense
+    matmuls beat.
     Soundness: the factored tier's l1_coeff == 0 gate.
     """
     if cfg.activation != "topk" or cfg.sparse_decode:
         return False
     if cfg.sparse_bwd == "off" or cfg.l1_coeff != 0:
         return False
-    if cfg.sparse_bwd == "on":
-        return True
-    from crosscoder_tpu.ops import sparse_grad
-
-    if not sparse_grad.kernel_enabled():
-        return False
-    if batch is not None and not sparse_grad.decode_grad_supported(
-        cfg.dict_size, cfg.topk_k, cfg.n_sources, cfg.d_in, batch
-    ):
-        return False
-    return True
+    return cfg.sparse_bwd == "on" or rows_live(cfg, batch)
 
 
 def use_sparse_aux(cfg: CrossCoderConfig, batch: int) -> bool:
     """Sparse backward for the AuxK aux term. Requires the sparse plane
-    active ("on"/live-"auto") AND kernel-supported aux shapes — the
-    B·aux_k pair list must be VMEM-resident (sparse_grad._MAX_PAIRS;
-    aux_k ≈ 8k at batch 4096 is ~32× over the cap, and the XLA fallback
-    would materialize a [B·aux_k, n·d] f32 update matrix, so the support
-    gate is hard even under forced "on" — unsupported aux falls back to
-    the dense aux VJP, which is the measured-best dense path anyway).
-    "auto" additionally applies the traffic heuristic
-    ``aux_k · 512 <= dict_size``: the sparse backward's pair-gather bytes
-    beat the dense VJP matmuls only once the dictionary is ~500× the aux
-    width (v5e flop:byte ratio ≈ 250, ×2 for the two matmuls replaced) —
-    provisional until a hardware A/B lands."""
+    forced ("on": "auto" answers for a step, and an AuxK step keeps the
+    dense form) AND aux shapes under the pair cap (sparse_grad._MAX_PAIRS;
+    aux_k ≈ 8k at batch 4096 is ~32× over it, and the XLA scatter
+    materializes a [B·aux_k, n·d] f32 update matrix, so the support gate
+    is hard even under forced "on" — unsupported aux falls back to the
+    dense aux VJP, which is the measured-best dense path anyway)."""
     if cfg.aux_k <= 0 or not use_sparse_bwd(cfg):
         return False
     from crosscoder_tpu.ops import sparse_grad
 
     k_aux = min(cfg.aux_k, cfg.dict_size)
-    aux_ok = sparse_grad.supported(
-        cfg.dict_size, cfg.n_sources * cfg.d_in, batch, batch * k_aux
-    )
-    if cfg.sparse_bwd == "on":
-        return aux_ok
-    return aux_ok and cfg.aux_k * 512 <= cfg.dict_size
+    return sparse_grad.supported(
+        cfg.dict_size, cfg.n_sources * cfg.d_in, batch * k_aux)
 
 
-def use_factored_decode(cfg: CrossCoderConfig) -> bool:
+def use_factored_decode(cfg: CrossCoderConfig, batch: int | None = None) -> bool:
     """Dispatch for the factored TopK decode tier.
 
     ``cfg.factored_decode``: "off" never; "on" whenever sound+supported;
-    "auto" additionally requires dict_size >= 2^17 — the XLA row gather
-    costs ~17-20 ms flat (131k x 9 KB rows is instruction-rate-bound on
-    v5e, ~74 GB/s effective), so it only beats the dense decode matmul
-    once that matmul crosses ~30 ms (dict 2^17 at bench shapes; measured
-    A/B: -8 ms at 2^17, +6 ms at 2^16).
+    "auto" where the k rows can be fetched by DMA (:func:`rows_live`, which
+    wants the step's ``batch``: 15 ns a row against the dense product's
+    5.6–8 ms at dict 2^15, PERF.md §6, PR 32), and elsewhere from dict_size
+    >= 2^17 — XLA's row gather costs ~17-20 ms flat (131k x 9 KB rows is
+    instruction-rate-bound on v5e, ~74 GB/s effective), so it only beats
+    the dense decode matmul once that matmul crosses ~30 ms (measured A/B:
+    -8 ms at 2^17, +6 ms at 2^16).
     Soundness gate: l1_coeff must be 0 (see _factored_topk_forward).
     """
     if cfg.activation != "topk" or cfg.sparse_decode:
@@ -800,8 +864,8 @@ def use_factored_decode(cfg: CrossCoderConfig) -> bool:
     # sparse_bwd="on" forces the factored tier too (the sparse backward
     # plane extends it — the factored (vals, idx) ARE its inputs), so a
     # forced sparse backward at sub-2^17 dicts doesn't silently noop
-    return (mode == "on" or cfg.dict_size >= 131072
-            or cfg.sparse_bwd == "on")
+    return (mode == "on" or cfg.sparse_bwd == "on"
+            or cfg.dict_size >= 131072 or rows_live(cfg, batch))
 
 
 def topk_vals_idx(params: Params, x: jax.Array, cfg: CrossCoderConfig) -> tuple[jax.Array, jax.Array]:
@@ -857,26 +921,43 @@ def get_losses(
     - ``l0``: mean count of strictly-positive latents
     """
     x = x.astype(dtype_of(cfg.enc_dtype))
-    factored = use_factored_decode(cfg)
+    aux_active = dead_mask is not None and cfg.aux_k > 0
+    # the step's form follows what this trace can see. An AuxK step keeps
+    # the form it takes without the row kernels (``h`` has another
+    # consumer there), and so do parameters that were not cast to the
+    # compute dtype: packing them would round what the dense path does not
+    rows = (not aux_active and rows_live(cfg, x.shape[0])
+            and params["W_dec"].dtype == x.dtype == jnp.bfloat16)
+    batch = x.shape[0] if rows else None
+    factored = use_factored_decode(cfg, batch)
     sparse = (cfg.sparse_decode and cfg.activation == "topk") or factored
     l0_penalty: jax.Array | float = 0.0
     h = None            # pre-acts, kept when a later consumer (the
                         # JumpReLU L0 penalty, the AuxK ranking) needs
                         # them — shared explicitly rather than trusting
                         # CSE to dedupe a second encode matmul
-    aux_active = dead_mask is not None and cfg.aux_k > 0
-    sparse_bwd = factored and use_sparse_bwd(cfg, x.shape[0])
+    sparse_bwd = factored and use_sparse_bwd(cfg, batch)
     fused = use_fused_encoder(cfg, x.shape[0])
+    rows = rows and sparse_bwd and not fused
+    if cfg.activation == "topk":
+        from crosscoder_tpu import obs
+
+        # which form this trace's k-sparse products took (forward: the
+        # decode; backward: df and both weight gradients)
+        obs.count("perf/cc_decode_rows_traces" if rows
+                  else "perf/cc_decode_dense_traces")
+        obs.count("perf/cc_bwd_rows_traces" if rows
+                  else "perf/cc_bwd_dense_traces")
     if factored and sparse_bwd and not aux_active:
         # sparse backward plane, full-step scope: encode + TopK + factored
-        # decode under ONE custom vjp (ops/sparse_grad.py) — none of the
-        # three dense backward matmuls survives. Forward numerics are the
-        # factored tier's exactly (same einsum/kernel/gather chain). The
-        # fused tier (cfg.fused_encoder) swaps that forward for the
-        # encoder→TopK megakernel — same (vals, idx) contract, same
-        # backward, no [B, H] pre-act matrix in HBM; aux-active steps
-        # fall through to the (h, W_dec) scope below (the h-residual
-        # escape hatch — the aux ranking consumes the pre-acts).
+        # decode under ONE custom vjp — none of the three dense backward
+        # matmuls survives. Forward numerics are the factored tier's
+        # exactly (same einsum/kernel/gather chain). The fused tier
+        # (cfg.fused_encoder) swaps that forward for the encoder→TopK
+        # megakernel — same (vals, idx) contract, same backward, no [B, H]
+        # pre-act matrix in HBM; aux-active steps fall through to the
+        # (h, W_dec) scope below (the h-residual escape hatch — the aux
+        # ranking consumes the pre-acts).
         if fused:
             qb = cfg.quant_block if cfg.quant_encoder else 0
             recon_f32, vals, idx = _fused_topk_step(
@@ -886,7 +967,7 @@ def get_losses(
         else:
             recon_f32, vals, idx = _sparse_topk_step(
                 x, params["W_enc"], params["b_enc"], params["W_dec"],
-                cfg.topk_k,
+                cfg.topk_k, rows,
             )
         recon = (recon_f32 + params["b_dec"].astype(jnp.float32)).astype(x.dtype)
         f = None
@@ -971,7 +1052,7 @@ def get_losses(
     # Objective-relevant, so computed in the with_metrics=False step too.
     aux_loss: jax.Array | float = 0.0
     fired = None
-    if track_fired or (dead_mask is not None and cfg.aux_k > 0):
+    if track_fired or aux_active:
         # which latents fired this batch (the trainer's steps_since_fired
         # update). Tracked on EVERY step even when the aux loss itself is
         # amortized to every cfg.aux_every-th step — deadness must stay

@@ -5,11 +5,15 @@ timed on a chip, so real-TPU dispatch is an explicit opt-in env var per
 family — one rule, stated once: the interpreter (CPU tests) always may
 run, hardware only with the opt-in. The chip's compiler has been asked:
 tests/test_chip_compile.py compiles one case per family for a described
-v5e at production shapes (all accepted except ops/sparse_grad, which it
-refuses — a strict xfail there). Flip a kernel's conservative default
-here-adjacent (its call site) once a real-TPU A/B lands; the GATE shape
-itself is shared so a policy change (new backend, global kill-switch)
-lands in one place.
+v5e at production shapes (all accepted). A family leaves this table when a
+real-TPU A/B lands: it becomes a default chosen at trace time from what the
+code can see, or a deletion — never a flag. So went the TopK kernel, the
+fused attention, the expert kernels, and (PR 32) the sparse backward plane:
+its sorted-pair scatter was refused by the chip's compiler, and its place
+is taken by ``ops/row_gather.py``, which dispatches like those
+(``models/crosscoder.rows_live``: TopK, ``l1_coeff == 0``, one TPU device,
+a supported shape). The GATE shape itself is shared so a policy change (new
+backend, global kill-switch) lands in one place.
 
 Gate resolution (first ``hw_kernel_enabled`` call logs the full table to
 stderr, once per process, so a run's kernel posture is always in its
@@ -44,7 +48,6 @@ KNOWN_GATES = (
     "CROSSCODER_FUSED_TOPK_PALLAS",
     "CROSSCODER_PAGED_ATTN_PALLAS",
     "CROSSCODER_QUANT_PALLAS",
-    "CROSSCODER_SPARSE_GRAD_PALLAS",
 )
 
 _LOGGED = False

@@ -24,13 +24,12 @@ shape), as ``models/lm._attn_core`` chooses its attention:
   ``pallas:moe_down``. Consecutive tiles of one expert keep its weights in
   VMEM; tiles past the last real one are skipped. The third,
   ``pallas:expert_combine``, forms each token's gate-weighted sum of its k
-  expert rows reading ``moe_down``'s result in place: the rows are fetched
-  by DMA, one copy a row, by a prefetched (token, slot) → row table, the
-  next token tile's copies in flight while this one is summed. For that
-  ``moe_down`` writes each row as 32-bit words of two bf16 columns
-  (:func:`_pack_rows`): rows of a 2-D bf16 array lie interleaved in tiles
-  and no copy can address one. At a shape the combine kernel refuses
-  (:func:`combine_supported`) ``moe_down`` writes plain rows and XLA gathers
+  expert rows reading ``moe_down``'s result in place: the row-gather
+  kernel of ``ops/row_gather.py`` (rows fetched by DMA, one copy a row, by
+  a prefetched (token, slot) → row table), which the TopK crosscoder step
+  shares. For that ``moe_down`` writes each row in that module's packed
+  form (32-bit words of two bf16 columns). At a shape the kernel refuses
+  (``row_gather.supported``) ``moe_down`` writes plain rows and XLA gathers
   and sums them.
 - ``ragged`` (everything else — the CPU backend, a mesh, an unsupported
   shape): rows sorted by expert, ``jax.lax.ragged_dot``. Also the oracle the
@@ -46,7 +45,6 @@ tile form, ``harvest/moe_combine_kernel_traces`` /
 
 from __future__ import annotations
 
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -54,18 +52,15 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_LANES = 128
+from crosscoder_tpu.ops import row_gather
+
+_LANES = row_gather.LANES
 # Rows of one expert-aligned tile. A constant of the kernel, not a knob:
 # PERF.md §6 (PR 29) has the table it was chosen from.
 TILE_ROWS = 128
 # both kernels hold one expert's weight block double-buffered (gate and up:
 # 2 x 2 x D x F x itemsize), which passes the default scoped limit
-_VMEM_LIMIT_BYTES = 64 << 20
-# Tokens of one tile of the combine kernel (its double buffer holds 2 x k
-# rows a token), and the most its prefetched row table may take of SMEM.
-COMBINE_TOKENS = 128
-_COMBINE_GROUP = 16         # tokens summed together: one whole bf16 tile stored
-_SMEM_TABLE_BYTES = 256 << 10
+_VMEM_LIMIT_BYTES = row_gather.VMEM_LIMIT_BYTES
 
 # test-only: route the kernels through the Pallas interpreter (and let them
 # dispatch on the CPU backend) — same pattern as ops/flash_attention.
@@ -168,7 +163,7 @@ def _down_kernel(te_ref, nv_ref, ly_ref, h_ref, w_ref, o_ref):
             half = y.shape[1] // 2
             W = half // _LANES              # lane tiles of words a row
             if _INTERPRET:      # the interpreter has no rule for the pack op
-                words = _pack_rows(y.astype(h_ref.dtype))
+                words = row_gather.pack_rows(y.astype(h_ref.dtype))
             else:               # one instruction a word: rounds as astype does
                 words = pltpu.pack_elementwise(
                     [y[:, :half], y[:, half:]], packed_dtype=jnp.bfloat16)
@@ -184,7 +179,7 @@ def _tile_call(kernel, name, prefetch, rows, weights, w_specs, n_out,
                packed=False):
     """One kernel over the row tiles: ``rows [M, K]`` × the weight block of
     each tile's expert → ``[M, n_out]``, or with ``packed`` the same rows as
-    :func:`_pack_rows` lays them out, ``[M · n_out / 256, 1, 128]`` uint32.
+    :func:`row_gather.pack_rows` lays them out, ``[M · n_out / 256, 1, 128]`` uint32.
     ``prefetch`` is (tile → expert, number of real tiles, layer); a tile past
     the last real one maps to that one's blocks (nothing is fetched or
     written for it)."""
@@ -217,139 +212,6 @@ def _tile_call(kernel, name, prefetch, rows, weights, w_specs, n_out,
         name=name,
         interpret=_INTERPRET,
     )(*prefetch, rows, *weights)
-
-
-# the combine kernel: each token's k expert rows, fetched by DMA and summed
-
-
-def _pack_rows(y):
-    """bf16 ``[R, D]`` → uint32 ``[R, D/2]``: column ``j`` in the low half
-    of word ``j`` and column ``j + D/2`` in its high half, so that both
-    halves of a row unpack to whole lane tiles. A row is then ``D/256``
-    lane tiles of 32-bit words, a unit a DMA can address: rows of a
-    ``[M, D]`` array lie interleaved in (8, 128) tiles (bf16: two rows a
-    word), and Mosaic refuses a one-row slice of such a tile."""
-    half = y.shape[-1] // 2
-    bits = jax.lax.bitcast_convert_type(y.astype(jnp.float32), jnp.uint32)
-    return (bits[..., :half] >> 16) | bits[..., half:]
-
-
-def combine_supported(n_tokens: int, top_k: int, d_model: int, dtype) -> bool:
-    """Shapes the combine kernel handles: bf16 rows whose halves are whole
-    lanes, at least one group of tokens, the ``[T·k]`` row table within
-    SMEM, and a token tile whose double buffer and output blocks fit the
-    raised VMEM limit."""
-    if jnp.dtype(dtype) != jnp.bfloat16 or d_model % (2 * _LANES):
-        return False
-    if n_tokens < _COMBINE_GROUP or n_tokens * top_k * 4 > _SMEM_TABLE_BYTES:
-        return False
-    return 2 * (top_k + 1) * COMBINE_TOKENS * d_model * 2 <= _VMEM_LIMIT_BYTES // 2
-
-
-def _combine_kernel(rows_ref, g_ref, y_ref, o_ref, buf, gate_buf, sem, *,
-                    n_tokens):
-    """Every loop is a ``fori_loop`` unrolled when the kernel is LOWERED: the
-    same instructions as Python loops give, but each body is traced once —
-    the k·16 copies and 2·k·D/256 sums of a group traced one by one cost
-    2.9 s a trace on the chip's host, 17 s of every run's set-up (PERF.md
-    §6, PR 30)."""
-    tt, k = g_ref.shape
-    half = o_ref.shape[1] // 2
-    W = half // _LANES                      # lane tiles of words a row
-    G = _COMBINE_GROUP
-    i, n = pl.program_id(0), pl.num_programs(0)
-    slot = i % 2
-    # the buffer's bytes seen twice: ``buf [2, k·tt·W, 1, 128]`` keeps a row's
-    # W lane tiles on an untiled axis, where a copy may address them; the
-    # loads go through a view in whole (8, 128) tiles (a load of 8 rows from
-    # ``buf`` itself is 8 one-row loads: 0.41 against 0.20 ms, PERF.md §6)
-    tiles = buf.reshape(2 * k * tt * W, _LANES)
-
-    def fetch(tile, slot, first, count):
-        """Start the copies of ``count`` tokens' k rows (slot-major in the
-        buffer), from token ``first`` of tile ``tile``."""
-        def token(j, carry):
-            t = first + j
-            # a token past the end (a last tile that is not whole) takes the
-            # last token's rows again: every tile moves the same bytes
-            tok = jnp.minimum(tile * tt + t, n_tokens - 1)
-
-            def row(s, carry):
-                pltpu.make_async_copy(
-                    y_ref.at[pl.ds(rows_ref[tok * k + s] * W, W)],
-                    buf.at[slot, pl.ds((s * tt + t) * W, W)], sem.at[slot]).start()
-                return carry
-            return jax.lax.fori_loop(0, k, row, carry, unroll=True)
-        # a group's copies are unrolled among its sums; the first tile's
-        # whole fetch, with nothing to overlap, stays a loop
-        jax.lax.fori_loop(0, count, token, 0, unroll=count == G)
-
-    @pl.when(i == 0)
-    def _():
-        fetch(0, 0, 0, tt)
-
-    # one wait for the tile's k·tt copies: a DMA semaphore counts bytes, and
-    # this descriptor (never started) is of the size they sum to
-    pltpu.make_async_copy(buf.at[slot], buf.at[slot], sem.at[slot]).wait()
-
-    def group(g, carry):
-        t0 = pl.multiple_of(g * G, G)
-
-        @pl.when(i + 1 < n)     # the next tile's copies go out between the sums
-        def _():
-            fetch(i + 1, 1 - slot, t0, G)
-
-        gates = g_ref[pl.ds(t0, G), :]
-        for s in range(k):      # each slot's gate along the lanes, once a group
-            gate_buf[s] = jnp.broadcast_to(gates[:, s:s + 1], (G, _LANES))
-
-        def lane_tile(c, carry):
-            def add_slot(s, acc):
-                # lane tile c of G tokens' slot-s rows: one strided load
-                w = tiles[pl.ds(((slot * k + s) * tt + t0) * W + c, G, stride=W), :]
-                gate = gate_buf[s]
-                lo = jax.lax.bitcast_convert_type(w << 16, jnp.float32)
-                hi = jax.lax.bitcast_convert_type(w & jnp.uint32(0xFFFF0000), jnp.float32)
-                return acc[0] + lo * gate, acc[1] + hi * gate
-            zero = jnp.zeros((G, _LANES), jnp.float32)
-            lo, hi = jax.lax.fori_loop(0, k, add_slot, (zero, zero), unroll=True)
-            col = pl.multiple_of(c * _LANES, _LANES)
-            o_ref[pl.ds(t0, G), pl.ds(col, _LANES)] = lo.astype(o_ref.dtype)
-            o_ref[pl.ds(t0, G), pl.ds(half + col, _LANES)] = hi.astype(o_ref.dtype)
-            return carry
-        return jax.lax.fori_loop(0, W, lane_tile, carry, unroll=True)
-
-    jax.lax.fori_loop(0, tt // G, group, 0)
-
-
-def _combine_rows(rows, gates, y_packed, d_model):
-    """``Σ_s gates[t, s] · y[rows[t·k + s]]`` → ``[T, D]`` bf16, in float32
-    from the bf16 rows, as :func:`_combine` of the gathered rows: ``rows
-    [T·k]`` (scalar-prefetched) names each slot's row of ``y_packed``
-    (:func:`_pack_rows`' layout, left in HBM). Per tile of tokens the row
-    copies land in one half of a double buffer while the other is summed."""
-    T, k = gates.shape
-    W = d_model // 2 // _LANES
-    tt = min(COMBINE_TOKENS, T // _COMBINE_GROUP * _COMBINE_GROUP)
-    return pl.pallas_call(
-        functools.partial(_combine_kernel, n_tokens=T),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(pl.cdiv(T, tt),),
-            in_specs=[pl.BlockSpec((tt, k), lambda i, rows: (i, 0)),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((tt, d_model), lambda i, rows: (i, 0)),
-            scratch_shapes=[pltpu.VMEM((2, k * tt * W, 1, _LANES), jnp.uint32),
-                            pltpu.VMEM((k, _COMBINE_GROUP, _LANES), jnp.float32),
-                            pltpu.SemaphoreType.DMA((2,))],
-        ),
-        out_shape=jax.ShapeDtypeStruct((T, d_model), jnp.bfloat16),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
-        name="expert_combine",
-        interpret=_INTERPRET,
-    )(rows, gates, y_packed)
 
 
 def _tile_layout(idx, n_experts):
@@ -386,16 +248,17 @@ def _tile_layout(idx, n_experts):
 def _experts_tiles(x, idx, gates, w_gate_up, w_down, layer):
     """The kernel form: expert-aligned row tiles; the weights stay stacked
     ``[L, E, ...]`` and a block is fetched by (layer, the tile's expert).
-    Where the combine kernel takes the shape (:func:`combine_supported`)
-    ``moe_down`` writes its rows packed for it; elsewhere the rows are
-    gathered and summed by XLA."""
+    Where the row-gather kernel takes the shape (``row_gather.supported``)
+    ``moe_down`` writes its rows packed for it and the combine is that
+    kernel (``Σ_s gates[t, s] · y[rows[t·k + s]]``, as :func:`_combine` of
+    the gathered rows); elsewhere the rows are gathered and summed by XLA."""
     from crosscoder_tpu import obs
 
     T, k = idx.shape
     _, E, F, D = w_down.shape
     tile_expert, n_valid, token, rows = _tile_layout(idx, E)
     xs = x[token]                                               # [M, D]
-    kernel = combine_supported(T, k, D, x.dtype)
+    kernel = row_gather.supported(T, k, D, x.dtype)
     obs.count("harvest/moe_combine_kernel_traces" if kernel
               else "harvest/moe_combine_xla_traces")
 
@@ -412,7 +275,8 @@ def _experts_tiles(x, idx, gates, w_gate_up, w_down, layer):
         _down_kernel, "moe_down", prefetch, h, (w_down,),
         [pl.BlockSpec((None, None, F, D), w_block(0))], D, packed=kernel)
     if kernel:
-        return _combine_rows(rows, gates, y, D)
+        return row_gather.weighted_sum(
+            rows, gates, y, D, name="expert_combine", interpret=_INTERPRET)
     return _combine(y[rows].reshape(T, k, D), gates)
 
 

@@ -488,17 +488,14 @@ class Trainer:
             self.state = multihost.put_global(state, self._state_shardings)
         # the sparse backward plane's dispatch is static per cfg/batch —
         # announce it once so runs record WHICH backward they measured
-        # (cfg.sparse_bwd="auto" silently stays dense off-TPU / without
-        # the kernel opt-in env), and flag the forced-"on" XLA-scatter
-        # fallback: sound, but it is the measured-slow path the kernel
-        # exists to beat
+        # (cfg.sparse_bwd="auto" stays dense off-TPU, on a mesh and at
+        # shapes the row kernels refuse), and flag the forced-"on"
+        # XLA-scatter fallback: sound, but it is the measured-slow path
+        # the kernels exist to beat
         if cc.use_sparse_bwd(cfg, cfg.batch_size):
-            from crosscoder_tpu.ops import sparse_grad
-
-            kind = ("pallas scatter-accumulate" if sparse_grad.kernel_enabled()
-                    and sparse_grad.decode_grad_supported(
-                        cfg.dict_size, cfg.topk_k, cfg.n_sources, cfg.d_in,
-                        cfg.batch_size)
+            kind = ("rows fetched by DMA (ops/row_gather.py)"
+                    if cc.use_sparse_bwd(cfg.replace(sparse_bwd="auto"),
+                                         cfg.batch_size)
                     else "XLA scatter fallback (forced; expect the dense "
                          "backward to be faster)")
             print(f"[crosscoder_tpu] sparse backward plane active: {kind}",

@@ -1,7 +1,7 @@
 #!/usr/bin/env bash
 # Standalone interpret-mode kernel parity suite: every Pallas kernel's
-# CPU oracle tests (topk / sparsify / quant / sparse_grad / batchtopk /
-# paged_attention / fused encoder→topk),
+# CPU oracle tests (topk / sparsify / quant / sparse_grad + row_gather /
+# batchtopk / paged_attention / fused encoder→topk),
 # without the full tier-1 run — so a kernel regression is catchable in
 # ~a minute while iterating on ops/. Same pytest flags as tier1.sh so
 # the two gates can never diverge on collection behavior.
@@ -15,6 +15,7 @@ exec env JAX_PLATFORMS=cpu python -m pytest -q -m 'not slow' \
   tests/test_factored_decode.py \
   tests/test_quant.py \
   tests/test_sparse_grad.py \
+  tests/test_row_gather.py \
   tests/test_batchtopk_pallas.py \
   tests/test_paged_attention.py \
   tests/test_fused_encoder_topk.py \

@@ -134,7 +134,8 @@ def _compiled_train_step(cfg, mesh, with_metrics: bool):
 def test_train_step_compiles(chip, topo, leg):
     """The whole train step (value_and_grad of training_loss, clip, Adam) at
     the production shape of each smoke leg. The default TopK tier must be
-    the kernel, not its XLA stand-in."""
+    the kernel, not its XLA stand-in. (This process sees eight devices, so
+    the TopK step is the dense one a mesh keeps.)"""
     from crosscoder_tpu.parallel import mesh as mesh_lib
 
     over = (dict(dict_size=2**14) if leg.startswith("relu") else
@@ -142,7 +143,33 @@ def test_train_step_compiles(chip, topo, leg):
     cfg = CrossCoderConfig(log_backend="null", **over)
     compiled = _compiled_train_step(
         cfg, mesh_lib.make_mesh(devices=topo.devices[:1]), with_metrics=False)
-    assert ("tpu_custom_call" in compiled.as_text()) == (cfg.activation == "topk")
+    text = compiled.as_text()
+    assert ("tpu_custom_call" in text) == (cfg.activation == "topk")
+    assert "topk_rows" not in text
+
+
+def test_topk_step_compiles_in_its_row_form(chip, topo, one_device):
+    """The cell train-live-topk32k's step (2 x 2048 wide, dict 2^15, k 32,
+    batch 4096) from a process that sees ONE device: every k-sparse product
+    goes through rows fetched by DMA — the pack of W_dec, the decode, d_vals
+    and the latent-major pass are in the compiled step, and of the dense
+    [4096 x 4096 x 32768] products only the encode is left. (The variant
+    with metrics holds the same kernels and is three minutes more of
+    compiling: the chip's runs compile it.)"""
+    from crosscoder_tpu.parallel import mesh as mesh_lib
+
+    cfg = CrossCoderConfig(log_backend="null", d_in=2048, dict_size=2**15,
+                           activation="topk", topk_k=K, l1_coeff=0.0)
+    assert cc.rows_live(cfg, BATCH) and cc.use_factored_decode(cfg, BATCH)
+    assert cc.use_sparse_bwd(cfg, BATCH) and not cc.use_fused_encoder(cfg, BATCH)
+    text = _compiled_train_step(
+        cfg, mesh_lib.make_mesh(devices=topo.devices[:1]), with_metrics=False).as_text()
+    calls = [line.split(" = ")[0] for line in text.splitlines() if "tpu_custom_call" in line]
+    for name in ("rows_pack", "topk_rows_decode", "topk_rows_dvals", "topk_rows_grads"):
+        assert [c for c in calls if name in c], f"{name} is not in the compiled step"
+    big = [line for line in text.splitlines()
+           if " convolution(" in line and "4096,32768" in line.split(" = ")[0]]
+    assert len(big) <= 1, big
 
 
 def test_harvest_forward_compiles(chip):
@@ -291,14 +318,14 @@ def test_sparse_expert_harvest_segment_compiles(chip, one_device):
     from benchmarks import manifest
     from benchmarks.arch import mellum
     from crosscoder_tpu.ops import flash_attention as fa
-    from crosscoder_tpu.ops import moe
+    from crosscoder_tpu.ops import moe, row_gather
 
     cfg = mellum.lm_config(manifest.load_json(
         manifest.BENCH_DIR / "configs" / "mellum2-pair-relu16k.json"))
     S = 4096
     assert fa.supported(S, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, jnp.bfloat16)
     assert moe.enabled() and moe.supported(cfg.d_model, cfg.d_expert, jnp.bfloat16)
-    assert moe.combine_supported(S, cfg.experts_per_tok, cfg.d_model, jnp.bfloat16)
+    assert row_gather.supported(S, cfg.experts_per_tok, cfg.d_model, jnp.bfloat16)
     params = _abstract(jax.eval_shape(
         lambda k: lm.init_params(k, cfg), jax.random.key(0)), chip)
     compiled = lm._seg_scan_impl.lower(
@@ -343,29 +370,48 @@ def test_paged_attention_family_compiles(chip, gates_open, window):
         q, kv, kv, _sds((D,), jnp.int32, chip))
 
 
-@pytest.mark.xfail(
-    strict=True,
-    reason="Mosaic refuses the sorted-pair scatter kernel as written: "
-           "'cannot statically prove that index in dimension 1 is a multiple "
-           "of 128' on the dynamic scalar reads of its pair list from VMEM "
-           "(ops/sparse_grad.py _scatter_rows_kernel). Scalar-prefetching the "
-           "four index vectors is not a local fix: at P = 4096x32 pairs the "
-           "compiler then reports 'Ran out of memory in memory space smem. "
-           "Used 1.50M of 1.00M smem', and the dynamic single-row load of the "
-           "bf16 cotangent block is refused next ('index in dimension 0 is a "
-           "multiple of 8'). Off by default and off chip_smoke's path.")
-def test_sparse_grad_family_compiles(chip, gates_open):
-    from crosscoder_tpu.ops import sparse_grad
+@pytest.mark.parametrize("use", ["pack", "decode", "dvals"])
+def test_row_gather_family_compiles(chip, use):
+    """The row kernels alone at the cell's shape (B 4096, k 32, H 2^15, n·d
+    4096, bf16 rows): the pack, and token-major with the weighted-sum and
+    the dot epilogue, the 512 KiB row table prefetched whole."""
+    _row_kernel_compiles(chip, use)
 
-    # scatter_add_rows falls to the interpreter whenever default_backend()
-    # is not "tpu" (ops/sparse_grad.py) — the chip fixture steers around it.
-    # 256 rows, not 4096: the refusal does not depend on the pair count,
-    # and the 131k-pair sort in front of the kernel alone compiles for ~27 s
-    rows = 256
-    _compiles(
-        lambda c, i, r: sparse_grad.scatter_add_rows(c, i, r, 2**15),
-        _sds((rows, K), jnp.float32, chip), _sds((rows, K), jnp.int32, chip),
-        _sds((rows, ND), jnp.bfloat16, chip))
+
+def test_sparse_grad_family_compiles(chip):
+    """The sparse backward plane's weight gradients at the cell's shape: the
+    latent-major pass of ops/row_gather.py with its sort in front — the
+    kernel that took the place of ops/sparse_grad's sorted-pair scatter,
+    which Mosaic refused (dynamic scalar reads of the pair list from VMEM;
+    scalar-prefetched, 1.50 M of 1.00 M SMEM; then the one-row load of a
+    tiled bf16 block)."""
+    _row_kernel_compiles(chip, "grads")
+
+
+def _row_kernel_compiles(chip, use):
+    from crosscoder_tpu.ops import row_gather as rg
+
+    H, D = 2**15, 4096
+    W = D // 256
+    assert rg.supported(BATCH, K, D, jnp.bfloat16) and len(rg._slices(BATCH, K)) == 1
+    assert rg.grouped_supported(H, BATCH, K, D, jnp.bfloat16)
+    table = _sds((BATCH * K,), jnp.int32, chip)
+    rows = _sds((H * W, 1, 128), jnp.uint32, chip)
+    if use == "pack":
+        _compiles(lambda w: rg.packed(w), _sds((H, D), jnp.bfloat16, chip))
+    elif use == "decode":
+        _compiles(lambda t, v, y: rg.weighted_sum(
+            t, v, y, D, name="topk_rows_decode", out_dtype=jnp.float32),
+            table, _sds((BATCH, K), jnp.float32, chip), rows)
+    elif use == "dvals":
+        _compiles(lambda t, g, y: rg.dots(t, g, y, K, name="topk_rows_dvals"),
+                  table, _sds((BATCH, D), jnp.float32, chip), rows)
+    else:
+        pairs = _sds((BATCH, K), jnp.bfloat16, chip)
+        wide = _sds((BATCH, D), jnp.bfloat16, chip)
+        _compiles(lambda i, v, dv, g, x: rg.grouped_sums(
+            i, v, dv, g, x, H, name="topk_rows_grads"),
+            _sds((BATCH, K), jnp.int32, chip), pairs, pairs, wide, wide)
 
 
 # ---------------------------------------------------------------------------

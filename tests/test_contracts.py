@@ -82,7 +82,7 @@ def test_pallas_pack_clean_and_covers_all_seven_kernels():
     rep = run_rules(PALLAS_RULES, ctx)
     assert rep.ok, "\n".join(str(f) for f in rep.findings)
     families = {c.kernel for c in ctx.calls}
-    assert {"topk", "sparsify", "batchtopk", "quant", "sparse_grad",
+    assert {"topk", "sparsify", "batchtopk", "quant",
             "paged_attention", "flash_attention",
             "fused_encoder_topk"} <= families
     summary = vmem_summary(ctx)

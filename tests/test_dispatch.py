@@ -35,7 +35,7 @@ def test_per_kernel_env_overrides_umbrella(monkeypatch):
     monkeypatch.setenv(dispatch.UMBRELLA_ENV, "all")
     monkeypatch.setenv("CROSSCODER_QUANT_PALLAS", "0")
     assert not dispatch.resolve_gate("CROSSCODER_QUANT_PALLAS")
-    assert dispatch.resolve_gate("CROSSCODER_SPARSE_GRAD_PALLAS")
+    assert dispatch.resolve_gate("CROSSCODER_PAGED_ATTN_PALLAS")
     monkeypatch.setenv(dispatch.UMBRELLA_ENV, "off")
     monkeypatch.setenv("CROSSCODER_FUSED_TOPK_PALLAS", "1")
     assert dispatch.resolve_gate("CROSSCODER_FUSED_TOPK_PALLAS")
@@ -49,13 +49,16 @@ def test_malformed_umbrella_raises_with_suggestion(monkeypatch):
 
 
 def test_unknown_gate_names_get_difflib_suggestions(monkeypatch):
-    monkeypatch.setenv("CROSSCODER_SPARSE_GRAD_PALLAS", "1")     # known: quiet
-    monkeypatch.setenv("CROSSCODER_SPASE_GRAD_PALLAS", "1")      # typo
+    monkeypatch.setenv("CROSSCODER_PAGED_ATTN_PALLAS", "1")      # known: quiet
+    monkeypatch.setenv("CROSSCODER_PAGD_ATTN_PALLAS", "1")       # typo
+    # gone with the kernel it gated (PR 32): now a name no kernel reads
+    monkeypatch.setenv("CROSSCODER_SPARSE_GRAD_PALLAS", "1")
     warnings = dispatch.validate_env()
-    assert len(warnings) == 1
-    assert "CROSSCODER_SPASE_GRAD_PALLAS" in warnings[0]
-    assert "did you mean CROSSCODER_SPARSE_GRAD_PALLAS?" in warnings[0]
+    assert len(warnings) == 2
+    assert "CROSSCODER_PAGD_ATTN_PALLAS" in warnings[0]
+    assert "did you mean CROSSCODER_PAGED_ATTN_PALLAS?" in warnings[0]
     assert "no-op" in warnings[0]
+    assert "CROSSCODER_SPARSE_GRAD_PALLAS" in warnings[1] and "no-op" in warnings[1]
 
 
 def test_typo_warning_prints_at_first_dispatch(monkeypatch, capsys):
@@ -102,7 +105,8 @@ def test_startup_log_emits_once_with_resolved_states(monkeypatch, capsys):
     err = capsys.readouterr().err
     assert "pallas gates (CROSSCODER_PALLAS=all)" in err
     assert "quant=off" in err                  # per-kernel override visible
-    assert "sparse_grad=on" in err             # umbrella default visible
+    assert "paged_attn=on" in err              # umbrella default visible
+    assert "sparse_grad" not in err            # left the table with its kernel (PR 32)
     # second dispatch decision: no second log line
     dispatch.hw_kernel_enabled("CROSSCODER_QUANT_PALLAS", True)
     assert "pallas gates" not in capsys.readouterr().err

@@ -39,19 +39,73 @@ def _data(cfg, seed=0):
     return cc.init_params(jax.random.key(1), cfg), jnp.asarray(x)
 
 
-def test_dispatch_gates():
-    dense, fact = _cfgs()
-    assert not cc.use_factored_decode(dense)
-    assert cc.use_factored_decode(fact)            # "on" + interpret forced
-    # auto requires dict >= 2^17 (gather-vs-matmul crossover)
-    assert not cc.use_factored_decode(fact.replace(factored_decode="auto"))
+# The dispatch table of the TopK step's forms (models/crosscoder.py:
+# use_factored_decode / use_sparse_bwd / rows_live), one case a row:
+# (config overrides, the step's batch or None, what the process looks like)
+# -> (factored tier, sparse backward plane, the row kernels). "chip": a TPU
+# backend with one device; "mesh": a TPU backend with eight; "cpu": neither
+# (the TopK kernel still answers through the interpreter, as in the file).
+_BF16 = dict(enc_dtype="bf16", d_in=128, dict_size=512)
+DISPATCH = {
+    "off": (dict(factored_decode="off"), 64, "cpu", (False, False, False)),
+    "on": (dict(factored_decode="on"), 64, "cpu", (True, False, False)),
+    # where the rows cannot be fetched by DMA, auto still asks for dict >= 2^17
+    # (XLA's gather against the dense matmul: the crossover measured at PR 3)
+    "auto-needs-2^17-off-the-chip": (dict(), 64, "cpu", (False, False, False)),
+    "auto-2^17-off-the-chip": (dict(dict_size=2 ** 17), 64, "cpu", (True, False, False)),
+    "auto-on-the-chip": (_BF16, 64, "chip", (True, True, True)),
+    "auto-on-the-chip-2^17": (dict(_BF16, dict_size=2 ** 17), 64, "chip", (True, True, True)),
+    "auto-on-a-mesh": (_BF16, 64, "mesh", (False, False, False)),
+    "auto-on-a-mesh-2^17": (dict(_BF16, dict_size=2 ** 17), 64, "mesh", (True, False, False)),
+    # an AuxK step, or a caller that knows no batch: today's behaviour stands
+    "auto-no-batch": (_BF16, None, "chip", (False, False, False)),
+    "auto-float32-rows": (dict(_BF16, enc_dtype="fp32"), 64, "chip", (False, False, False)),
+    "auto-half-a-row-not-whole-lanes": (dict(_BF16, d_in=100), 64, "chip", (False, False, False)),
+    "auto-under-one-group-of-tokens": (_BF16, 8, "chip", (False, False, False)),
+    # the token-major kernels cut their table into slices of the batch; the
+    # latent-major one prefetches the pairs' tokens whole (1 MiB here): all
+    # four products go through the rows, or none
+    "auto-pairs-past-smem": (_BF16, 32768, "chip", (False, False, False)),
+    # nonzero L1 objective is unsound on this path (no grad through vals):
+    # auto silently falls back rather than erroring
+    "auto-with-l1": (dict(_BF16, l1_coeff=0.5), 64, "chip", (False, False, False)),
+    "relu": (dict(_BF16, activation="relu", l1_coeff=1.0), 64, "chip", (False, False, False)),
+    "sparse-bwd-off": (dict(_BF16, sparse_bwd="off"), 64, "chip", (False, False, False)),
+    "sparse-bwd-on-on-the-chip": (dict(_BF16, sparse_bwd="on"), 64, "chip", (True, True, True)),
+    "sparse-bwd-on-forces-the-tier": (dict(sparse_bwd="on"), 64, "cpu", (True, True, False)),
+    # the fused encoder->TopK tier is another mechanism: it stays off
+    "fused-encoder-stays-off": (_BF16, 64, "chip", (True, True, True)),
+}
+
+
+@pytest.mark.parametrize("case", list(DISPATCH))
+def test_dispatch_gates(case, monkeypatch):
+    from crosscoder_tpu.ops import activations as act_ops
+    from crosscoder_tpu.ops import row_gather
+
+    over, batch, where, want = DISPATCH[case]
+    if where != "cpu":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(jax, "device_count", lambda *a: 1 if where == "chip" else 8)
+        act_ops._backend_is_tpu.cache_clear()
+    try:
+        base = dict(d_in=24, dict_size=256, batch_size=64, enc_dtype="fp32",
+                    activation="topk", topk_k=8, l1_coeff=0.0, log_backend="null",
+                    factored_decode="auto")
+        cfg = CrossCoderConfig(**dict(base, **over))
+        assert row_gather.enabled() == (where == "chip")
+        factored = cc.use_factored_decode(cfg, batch)
+        got = (factored, factored and cc.use_sparse_bwd(cfg, batch), cc.rows_live(cfg, batch))
+        assert got == want
+        assert not cc.use_fused_encoder(cfg, batch or 64)
+    finally:
+        act_ops._backend_is_tpu.cache_clear()
+
+
+def test_factored_on_with_l1_is_refused():
     # nonzero L1 objective is unsound on this path (no grad through vals)
     with pytest.raises(ValueError, match="factored_decode"):
-        fact.replace(l1_coeff=0.5)
-    # and auto silently falls back rather than erroring
-    assert not cc.use_factored_decode(
-        dense.replace(l1_coeff=0.5, factored_decode="auto")
-    )
+        _cfgs()[1].replace(l1_coeff=0.5)
 
 
 def test_losses_match_dense():

@@ -26,7 +26,7 @@ from crosscoder_tpu.config import CrossCoderConfig
 from crosscoder_tpu.models import crosscoder as cc
 from crosscoder_tpu.ops import activations as act_ops
 from crosscoder_tpu.ops import fused_encoder_topk as fek
-from crosscoder_tpu.ops import sparse_grad, topk_pallas
+from crosscoder_tpu.ops import topk_pallas
 
 
 @pytest.fixture(autouse=True)
@@ -35,11 +35,9 @@ def _interpret_kernels():
     for the TPU kernels, same as test_topk_pallas / test_sparse_grad)."""
     fek.set_interpret(True)
     topk_pallas.set_interpret(True)
-    sparse_grad.set_interpret(True)
     yield
     fek.set_interpret(False)
     topk_pallas.set_interpret(False)
-    sparse_grad.set_interpret(False)
 
 
 def _int_operands(rng, B, nd, H, dtype, b_scale=2):
@@ -406,7 +404,6 @@ def test_step_hlo_identical_with_fused_off(activation):
     the new knobs)."""
     fek.set_interpret(False)
     topk_pallas.set_interpret(False)
-    sparse_grad.set_interpret(False)
     texts = []
     for mode in ("off", "auto"):
         cfg = CrossCoderConfig(

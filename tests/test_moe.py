@@ -18,7 +18,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 from benchmarks.reference import mellum_ref   # noqa: E402
 from crosscoder_tpu import obs                 # noqa: E402
 from crosscoder_tpu.config import CrossCoderConfig   # noqa: E402
-from crosscoder_tpu.ops import moe             # noqa: E402
+from crosscoder_tpu.ops import moe, row_gather   # noqa: E402
 
 L, E, D, F, K = 2, 8, 128, 128, 3
 
@@ -112,7 +112,8 @@ def _combine_case(seed, tokens, skew):
 
 
 def _kernel_combine(rows, gates, y):
-    return moe._combine_rows(rows, gates, moe._pack_rows(y).reshape(-1, 1, 128), DC)
+    return row_gather.weighted_sum(rows, gates, row_gather.packed(y, interpret=True), DC,
+                                   name="expert_combine", interpret=True)
 
 
 @pytest.mark.parametrize("tokens,skew", [(256, False), (256, True), (300, False), (72, True)],
@@ -122,7 +123,7 @@ def test_combine_kernel_matches_the_gathered_sum(tokens, skew, interpret):
     sizes = np.bincount(np.asarray(idx).reshape(-1), minlength=E)
     if skew:
         assert sizes[5] >= 0.9 * tokens and sizes.max() > 2 * sizes.mean()
-    assert moe.combine_supported(tokens, K, DC, jnp.bfloat16)
+    assert row_gather.supported(tokens, K, DC, jnp.bfloat16)
     got = _kernel_combine(rows, gates, y)
     want = moe._combine(y[rows].reshape(tokens, K, DC), gates)
     assert got.shape == want.shape and got.dtype == want.dtype == jnp.bfloat16
@@ -151,7 +152,7 @@ def test_combine_kernel_fails_on_a_swapped_table(interpret):
 
 def test_packed_rows_hold_both_halves():
     y = jax.random.normal(jax.random.key(9), (16, DC), jnp.bfloat16)
-    words = np.asarray(moe._pack_rows(y))
+    words = np.asarray(row_gather.pack_rows(y))
     assert words.dtype == np.uint32 and words.shape == (16, DC // 2)
     bits = np.asarray(jax.lax.bitcast_convert_type(y, jnp.uint16)).astype(np.uint32)
     np.testing.assert_array_equal(words & 0xFFFF, bits[:, :DC // 2])
@@ -162,11 +163,13 @@ def test_packed_rows_hold_both_halves():
     (4096, 8, 2304, jnp.bfloat16, True),      # the mellum2 cell
     (4096, 8, 2304, jnp.float32, False),      # rows are packed two bf16 a word
     (4096, 8, 2176, jnp.bfloat16, False),     # half a row is not whole lanes
-    (2 ** 16, 8, 2304, jnp.bfloat16, False),  # the row table passes its share of SMEM
-    (4096, 8, 2 ** 15, jnp.bfloat16, False),  # a tile's double buffer passes VMEM
-], ids=["cell", "float32", "half-lanes", "smem", "vmem"])
+    (2 ** 16, 8, 2304, jnp.bfloat16, True),   # the row table (2 MiB) passes SMEM: slices of the batch
+    (4096, 8, 2 ** 15, jnp.bfloat16, True),   # a 128-token tile passes VMEM: a smaller tile
+    (4096, 32, 2 ** 15, jnp.bfloat16, False),  # not even one group's double buffer fits
+    (8, 8, 2304, jnp.bfloat16, False),        # less than one group of tokens
+], ids=["cell", "float32", "half-lanes", "smem-sliced", "vmem-smaller-tile", "vmem", "tokens"])
 def test_combine_supported(n_tokens, top_k, d_model, dtype, want):
-    assert moe.combine_supported(n_tokens, top_k, d_model, dtype) is want
+    assert row_gather.supported(n_tokens, top_k, d_model, dtype) is want
 
 
 @pytest.mark.parametrize("skew", [False, True], ids=["even", "skewed"])

@@ -1,11 +1,11 @@
 """Sparse backward compute plane (cfg.sparse_bwd; ops/sparse_grad.py,
-docs/SCALING.md "Sparse backward plane"): scatter-accumulate kernel vs
-XLA-scatter oracle (interpret mode on CPU), end-to-end gradient parity of
-the sparse custom VJPs against the dense factored backward — including
-the duplicate-index accumulation case and a non-chunk-divisible tail
-width — plus the dispatch gates, config validation, and the zero-cost
-guarantees (step-HLO identity with sparse_bwd="off", no XLA scatter on
-the supported "on" path). All CPU, tier-1."""
+ops/row_gather.py, docs/SCALING.md "Sparse backward plane"): the XLA
+scatter and the latent-major row kernel (interpret mode on CPU) against a
+numpy oracle, end-to-end gradient parity of the sparse custom VJPs against
+the dense factored backward — including the duplicate-index accumulation
+case and a non-chunk-divisible tail width — plus the dispatch gates,
+config validation, and the zero-cost guarantees (step-HLO identity with
+sparse_bwd="off", no XLA scatter in the row form). All CPU, tier-1."""
 
 import numpy as np
 import pytest
@@ -15,7 +15,7 @@ import jax.numpy as jnp
 
 from crosscoder_tpu.config import CrossCoderConfig
 from crosscoder_tpu.models import crosscoder as cc
-from crosscoder_tpu.ops import sparse_grad, topk_pallas
+from crosscoder_tpu.ops import row_gather, sparse_grad, topk_pallas
 from crosscoder_tpu.parallel import mesh as mesh_lib
 
 
@@ -25,10 +25,10 @@ def _interpret_kernels():
     interpreter (the CPU stand-in for the TPU kernel, same as
     test_topk_pallas / test_quant)."""
     topk_pallas.set_interpret(True)
-    sparse_grad.set_interpret(True)
+    row_gather.set_interpret(True)
     yield
     topk_pallas.set_interpret(False)
-    sparse_grad.set_interpret(False)
+    row_gather.set_interpret(False)
 
 
 def _np_scatter_oracle(coeff, idx, rows, n_out):
@@ -43,14 +43,29 @@ def _np_scatter_oracle(coeff, idx, rows, n_out):
 
 
 # ---------------------------------------------------------------------------
-# scatter_add_rows: kernel vs oracle
+# scatter_add_rows (XLA) and row_gather.grouped_sums (the kernel) vs oracle
+
+
+def _kernel_sums(coeff, idx, rows, n_out):
+    """The latent-major kernel's first sum, on bf16 coefficients and rows
+    (its second sum and its count ride along on the same pairs)."""
+    c, r = jnp.asarray(coeff, jnp.bfloat16), jnp.asarray(rows, jnp.bfloat16)
+    out_d, out_e, out_b = row_gather.grouped_sums(
+        jnp.asarray(idx), c, c, r, r, n_out, name="t", interpret=True)
+    np.testing.assert_array_equal(np.asarray(out_d), np.asarray(out_e).T)
+    return (np.asarray(out_d, np.float32), np.asarray(out_b),
+            np.asarray(c, np.float32), np.asarray(r, np.float32))
+
+
+def _bf16_ulp(v):
+    return 2.0 ** (np.floor(np.log2(np.maximum(np.abs(v), 1e-30))) - 7) + 1e-6
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("n_out,m,B,k", [
     (512, 128, 16, 4),
     (256, 256, 32, 8),
-    (1920, 128, 8, 4),      # 1920 % 256 != 0: shrunk row block (240)
+    (1920, 128, 8, 4),      # 1920 = 15 tiles of 128 latents
 ])
 def test_scatter_kernel_matches_xla_and_numpy(n_out, m, B, k, dtype):
     rng = np.random.default_rng(0)
@@ -58,22 +73,24 @@ def test_scatter_kernel_matches_xla_and_numpy(n_out, m, B, k, dtype):
     idx = rng.integers(0, n_out, size=(B, k)).astype(np.int32)
     rows = rng.standard_normal((B, m)).astype(np.float32)
     rows_j = jnp.asarray(rows, dtype)
-    assert sparse_grad.supported(n_out, m, B, B * k)
-    got_k = sparse_grad.scatter_add_rows(
-        jnp.asarray(coeff), jnp.asarray(idx), rows_j, n_out, use_pallas=True)
+    assert sparse_grad.supported(n_out, m, B * k)
     got_x = sparse_grad.scatter_add_rows(
-        jnp.asarray(coeff), jnp.asarray(idx), rows_j, n_out, use_pallas=False)
+        jnp.asarray(coeff), jnp.asarray(idx), rows_j, n_out)
     oracle = _np_scatter_oracle(coeff, idx, np.asarray(rows_j, np.float32),
                                 n_out)
-    np.testing.assert_allclose(np.asarray(got_k), oracle, atol=1e-5, rtol=1e-5)
     np.testing.assert_allclose(np.asarray(got_x), oracle, atol=1e-5, rtol=1e-5)
+    if dtype == jnp.bfloat16:       # the kernel's rows are bf16
+        assert row_gather.grouped_supported(n_out, B, k, m, dtype)
+        got_k, _, c, r = _kernel_sums(coeff, idx, rows, n_out)
+        oracle = _np_scatter_oracle(c, idx, r, n_out)
+        assert (np.abs(got_k - oracle) <= _bf16_ulp(oracle)).all()
 
 
 def test_scatter_duplicate_destinations_accumulate():
     """The scatter-add race case: many pairs landing on the SAME output
-    row must sum them all (the kernel serializes duplicates via the
-    dst-sorted pair walk; determinism is its construction, correctness
-    is this assert)."""
+    row must sum them all (the kernel puts duplicates side by side in one
+    chunk's selection matrix by its stable sort; determinism is its
+    construction, correctness is this assert)."""
     B, k, n_out, m = 24, 8, 256, 128
     rng = np.random.default_rng(1)
     coeff = rng.standard_normal((B, k)).astype(np.float32)
@@ -81,17 +98,21 @@ def test_scatter_duplicate_destinations_accumulate():
     idx[:, 1] = 200                              # and a second shared row
     rows = rng.standard_normal((B, m)).astype(np.float32)
     got = sparse_grad.scatter_add_rows(
-        jnp.asarray(coeff), jnp.asarray(idx), jnp.asarray(rows), n_out,
-        use_pallas=True)
+        jnp.asarray(coeff), jnp.asarray(idx), jnp.asarray(rows), n_out)
     oracle = _np_scatter_oracle(coeff, idx, rows, n_out)
     np.testing.assert_allclose(np.asarray(got), oracle, atol=1e-4, rtol=1e-5)
     assert float(np.abs(oracle[7]).max()) > 0    # the row really is contested
+    got_k, count, c, r = _kernel_sums(coeff, idx, rows, n_out)
+    oracle = _np_scatter_oracle(c, idx, r, n_out)
+    assert (np.abs(got_k - oracle) <= _bf16_ulp(oracle) + 1e-4).all()
+    np.testing.assert_allclose(count[[7, 200]], [c[:, [0] + list(range(2, k))].sum(),
+                                                 c[:, 1].sum()], rtol=1e-5)
 
 
 def test_scatter_out_of_range_dropped_not_wrapped():
     """Negative / >= n_out destinations are dropped (scatter mode="drop"
-    semantics) on BOTH implementations — numpy-style wrapping of a -1
-    would corrupt the last dictionary row's gradient."""
+    semantics) — numpy-style wrapping of a -1 would corrupt the last
+    dictionary row's gradient."""
     B, k, n_out, m = 8, 4, 256, 128
     rng = np.random.default_rng(2)
     coeff = rng.standard_normal((B, k)).astype(np.float32)
@@ -100,26 +121,24 @@ def test_scatter_out_of_range_dropped_not_wrapped():
     idx[1, 0] = n_out
     rows = rng.standard_normal((B, m)).astype(np.float32)
     oracle = _np_scatter_oracle(coeff, idx, rows, n_out)
-    for use_pallas in (True, False):
-        got = sparse_grad.scatter_add_rows(
-            jnp.asarray(coeff), jnp.asarray(idx), jnp.asarray(rows), n_out,
-            use_pallas=use_pallas)
-        np.testing.assert_allclose(np.asarray(got), oracle, atol=1e-5,
-                                   rtol=1e-5)
+    got = sparse_grad.scatter_add_rows(
+        jnp.asarray(coeff), jnp.asarray(idx), jnp.asarray(rows), n_out)
+    np.testing.assert_allclose(np.asarray(got), oracle, atol=1e-5, rtol=1e-5)
 
 
 def test_supported_gates():
-    ok = dict(n_out=512, m=256, n_rows=32, n_pairs=256)
-    assert sparse_grad.supported(**ok)
-    assert not sparse_grad.supported(512, 100, 32, 256)    # m not lane-aligned
-    assert not sparse_grad.supported(512, 64, 32, 256)     # m < 128
-    assert not sparse_grad.supported(28, 256, 32, 256)     # no row block divides
-    assert not sparse_grad.supported(512, 256, 32, 0)      # empty pair list
-    assert not sparse_grad.supported(                      # pair-list VMEM cap
-        512, 256, 32, sparse_grad._MAX_PAIRS + 1)
-    # decode gate = both scatter calls (nd and the bias-augmented nd+128)
-    assert sparse_grad.decode_grad_supported(1024, 8, 2, 128, 32)
-    assert not sparse_grad.decode_grad_supported(1024, 8, 2, 100, 32)
+    assert sparse_grad.supported(n_out=512, m=256, n_pairs=256)
+    assert not sparse_grad.supported(512, 100, 256)        # m not lane-aligned
+    assert not sparse_grad.supported(512, 64, 256)         # m < 128
+    assert not sparse_grad.supported(4, 256, 256)          # under one row tile
+    assert not sparse_grad.supported(512, 256, 0)          # empty pair list
+    assert not sparse_grad.supported(                      # the update matrix's cap
+        512, 256, sparse_grad._MAX_PAIRS + 1)
+    # the kernel: whole tiles of latents, bf16 rows of whole lanes
+    assert row_gather.grouped_supported(1024, 32, 8, 256, jnp.bfloat16)
+    assert not row_gather.grouped_supported(1024, 32, 8, 200, jnp.bfloat16)
+    assert not row_gather.grouped_supported(1000, 32, 8, 256, jnp.bfloat16)
+    assert not row_gather.grouped_supported(1024, 32, 8, 256, jnp.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -216,19 +235,24 @@ def test_sparse_step_forward_matches_factored_tier():
 def test_use_sparse_bwd_dispatch():
     assert cc.use_sparse_bwd(_cfg(sparse_bwd="on"))
     assert not cc.use_sparse_bwd(_cfg(sparse_bwd="off"))
-    # auto: live here because the fixture set interpret mode (the CPU
-    # stand-in for TPU + CROSSCODER_SPARSE_GRAD_PALLAS=1)
-    assert cc.use_sparse_bwd(_cfg(sparse_bwd="auto"), batch=32)
-    sparse_grad.set_interpret(False)
-    assert not cc.use_sparse_bwd(_cfg(sparse_bwd="auto"), batch=32)
-    sparse_grad.set_interpret(True)
-    # auto rejects kernel-unsupported shapes (d_in breaks lane alignment)
-    assert not cc.use_sparse_bwd(
-        _cfg(sparse_bwd="auto", d_in=100), batch=32)
+    # auto: the row kernels must be live (here: the fixture's interpret
+    # mode, the CPU stand-in for a TPU backend with one device), the rows
+    # bf16, and the step's batch known
+    assert not cc.use_sparse_bwd(_cfg(sparse_bwd="auto"), batch=32)     # fp32 rows
+    auto = _cfg(sparse_bwd="auto", enc_dtype="bf16", factored_decode="auto")
+    assert cc.use_sparse_bwd(auto, batch=32)
+    assert not cc.use_sparse_bwd(auto)
+    row_gather.set_interpret(False)
+    assert not cc.use_sparse_bwd(auto, batch=32)
+    row_gather.set_interpret(True)
+    # auto rejects kernel-unsupported shapes (d_in breaks lane alignment;
+    # a dictionary that is not whole tiles of latents)
+    assert not cc.use_sparse_bwd(auto.replace(d_in=100), batch=32)
+    assert not cc.use_sparse_bwd(auto.replace(dict_size=1000), batch=32)
     # non-topk / l1 never route sparse (validated for "on", gated for auto)
     assert not cc.use_sparse_bwd(
         _cfg(sparse_bwd="auto", activation="relu", l1_coeff=2.0,
-             factored_decode="auto"))
+             factored_decode="auto"), batch=32)
 
 
 def test_sparse_bwd_on_forces_factored_tier():
@@ -245,9 +269,9 @@ def test_use_sparse_aux_gates():
     assert cc.use_sparse_aux(_cfg(sparse_bwd="on", aux_k=16), batch=32)
     assert not cc.use_sparse_aux(_cfg(sparse_bwd="off", aux_k=16), batch=32)
     assert not cc.use_sparse_aux(_cfg(sparse_bwd="on", aux_k=0), batch=32)
-    # auto: aux_k·512 > dict_size fails the traffic heuristic at this width
+    # auto answers for a step, and an AuxK step keeps the dense form
     assert not cc.use_sparse_aux(
-        _cfg(sparse_bwd="auto", aux_k=16, dict_size=1024), batch=32)
+        _cfg(sparse_bwd="auto", aux_k=16, dict_size=1 << 17), batch=32)
     # the pair cap is HARD, forced "on" included: B·aux_k over
     # sparse_grad._MAX_PAIRS would route the aux VJP to the XLA fallback
     # that materializes a [B·aux_k, n·d] f32 update matrix — the bench
@@ -304,7 +328,7 @@ def test_step_hlo_identical_with_sparse_bwd_off():
     """sparse_bwd="off" (and a dead "auto" — no kernel, the seed's
     effective path) must trace the byte-identical step the pre-PR graph
     traced: the knob's presence costs nothing."""
-    sparse_grad.set_interpret(False)     # "auto" must be DEAD for this test
+    row_gather.set_interpret(False)      # "auto" must be DEAD for this test
     topk_pallas.set_interpret(False)
     texts = []
     for mode in ("off", "auto"):
@@ -317,14 +341,15 @@ def test_step_hlo_identical_with_sparse_bwd_off():
 
 
 def test_sparse_on_path_has_no_xla_scatter():
-    """The whole point: on supported shapes the "on" bare-step gradient
-    contains NO XLA scatter op — every gradient lands through the Pallas
-    scatter-accumulate (interpret-lowered here) or a matmul. The dense
-    baseline's same lowering is scatter-free too (it's all matmuls), so
-    also assert the sparse path didn't smuggle one in via sorting/searching
-    machinery. Mirrors test_quant's no-s8 assert."""
-    cfg = _cfg(sparse_bwd="on")
-    params = cc.init_params(jax.random.key(0), cfg)
+    """The whole point: in the row form the bare-step gradient contains NO
+    XLA scatter op — every gradient lands through the row kernels
+    (interpret-lowered here), a sort or a matmul. The dense baseline's same
+    lowering is scatter-free too (it's all matmuls), so also assert the
+    sparse path didn't smuggle one in via its sorting/searching machinery.
+    Mirrors test_quant's no-s8 assert."""
+    cfg = _cfg(sparse_bwd="auto", enc_dtype="bf16", factored_decode="auto")
+    assert cc.use_sparse_bwd(cfg, 32)
+    params = cc.init_params(jax.random.key(0), cfg, dtype=jnp.float32)
     x = jax.ShapeDtypeStruct((32, cfg.n_sources, cfg.d_in), jnp.float32)
 
     def loss(p, xb):
