@@ -446,17 +446,25 @@ class PairedActivationBuffer:
     def _gauge_expert_load(self) -> None:
         """``harvest/moe_load_max_over_mean``: how unevenly the first model's
         router spreads one calibration chunk over its experts (rows at the
-        busiest expert over the mean, worst layer). Read ONCE, here, where
-        calibration fetches from the device anyway: the loop gains no sync,
-        and with ``obs`` off nothing runs."""
+        busiest expert over the mean, worst layer), and
+        ``harvest/moe_local_row_share``: the share of its routed rows that
+        go to the experts this chip holds (mean over the sparse layers; 1
+        where it holds them all). Read ONCE, here, where calibration fetches
+        from the device anyway: the loop gains no sync, and with ``obs`` off
+        nothing runs."""
         from crosscoder_tpu.ops import moe
 
+        cfg = self.lm_cfg
+        depth = max(lm.hooked_depth(cfg, self.hook_points), 1)
+        sparse = [i for i in range(depth) if cfg.mlp_types[i] == lm.SPARSE]
+        if not sparse:
+            return
         padded, _ = self._pad_chunk(self.tokens[: self._chunk_seqs])
-        counts = lm.expert_load(
-            self.model_params[0], jnp.asarray(padded), self.lm_cfg,
-            max(lm.hooked_depth(self.lm_cfg, self.hook_points), 1))
-        obs.gauge("harvest/moe_load_max_over_mean",
-                  moe.load_max_over_mean(jax.device_get(counts)))
+        counts = jax.device_get(lm.expert_load(
+            self.model_params[0], jnp.asarray(padded), cfg, depth))[sparse]
+        obs.gauge("harvest/moe_load_max_over_mean", moe.load_max_over_mean(counts))
+        obs.gauge("harvest/moe_local_row_share",
+                  moe.local_row_share(counts, cfg.first_expert, cfg.n_held))
 
     def refresh(self) -> None:
         """Synchronous refill: first fill, resume, and tests.

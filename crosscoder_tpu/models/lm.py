@@ -34,12 +34,20 @@ and MLP kind per layer), the block's style, RoPE per attention kind and the
 expert sizes; every forward below is ONE layer loop (:func:`_scan_blocks`)
 that takes a layer's kind from ONE lookup (:func:`_layer_kind`) and runs ONE
 block (:func:`_block`), and hands in only its positions and how it reaches
-attention. Two families run through it: Gemma-2 (next paragraph) and the pre-norm sparse-expert block of
+attention. Three families run through it: Gemma-2 (next paragraph), the pre-norm sparse-expert block of
 Mellum2 (``LMConfig.mellum2_12b``: plain-weight RMSNorm before each sublayer
 only, no soft-caps, no embedding scale, three window layers to one full
 layer with YaRN on the full layers only, every MLP ``ops/moe.py``'s routed
 experts; checked against ``benchmarks/reference/mellum_ref.py`` by
-``tests/test_mellum.py``).
+``tests/test_mellum.py``), and Laguna-S-2.1's (``LMConfig.laguna_s_2_1``),
+whose layers differ in SHAPE: a dense layer 0, window layers of 72 query
+heads and full layers of 48 with a per-head output gate and a half-rotated
+head, a shared expert beside the routed ones, and — on one chip — a held
+share of each layer's experts. Layers of one shape are a class, one stack of
+leaves a class (:func:`layer_classes`); a table of one class — every other
+family — keeps the tree ``params["layers"][leaf]`` and the programs it had
+(checked against ``benchmarks/reference/laguna_ref.py`` by
+``tests/test_laguna.py``).
 
 Gemma-2 architecture facts implemented (validated against the HF
 ``transformers`` Gemma2 implementation by ``tests/test_lm.py``): RMSNorm with
@@ -92,7 +100,9 @@ class Rope:
     0 is plain RoPE; otherwise static YaRN as HF computes it: frequencies
     blended between ``theta``'s own and those divided by the factor over a
     ramp found from ``original_max_position`` and the two betas, cos and sin
-    multiplied by ``attention_factor``, at every length."""
+    multiplied by ``attention_factor``, at every length. ``rotary_factor``
+    is the LEADING share of each head that rotates (split-half pairs inside
+    it, frequencies computed at that width); the rest passes unrotated."""
 
     theta: float = 10_000.0
     yarn_factor: float = 0.0
@@ -100,6 +110,7 @@ class Rope:
     beta_fast: float = 32.0
     beta_slow: float = 1.0
     attention_factor: float = 1.0
+    rotary_factor: float = 1.0
 
 
 @dataclass(frozen=True)
@@ -118,6 +129,21 @@ class LMConfig:
     list rotates by plain ``rope_theta``. A sparse layer routes each token to
     ``experts_per_tok`` of ``n_experts`` gated MLPs of width ``d_expert``
     (:mod:`crosscoder_tpu.ops.moe`); ``d_ff`` is the dense layers' width.
+
+    Layers may differ in SHAPE: ``heads_by_layer`` gives each layer's query
+    heads (None: ``n_heads`` everywhere) and ``mlp_types`` may mix dense and
+    sparse layers. Layers of one shape form a class, one stack of leaves a
+    class (:func:`layer_classes`). ``attn_gate`` ``"per_head"`` multiplies
+    each attended head by ``sigmoid(x·Wg)`` before the output projection;
+    ``d_shared_expert`` > 0 adds one always-on gated MLP of that width to
+    every sparse layer's routed sum; ``routed_scale`` multiplies the routed
+    gates. A chip may hold a SHARE of each sparse layer's experts:
+    ``n_experts`` stays the model's count and the router's width,
+    ``experts_held`` (0: all) is how many this chip holds and ``expert_rank``
+    which share (experts ``[rank·held, (rank+1)·held)``); the layer's output
+    is then the held experts' part of the routed sum (plus the shared
+    expert), and nothing stands in for the absent chips. ``embed_std`` is the
+    seeded fixture's embedding scale (None: ``d_model ** -0.5``).
     """
 
     vocab_size: int
@@ -143,6 +169,13 @@ class LMConfig:
     d_expert: int = 0
     norm_topk_prob: bool = True
     tie_embeddings: bool = True
+    heads_by_layer: tuple[int, ...] | None = None
+    attn_gate: str = "none"
+    d_shared_expert: int = 0
+    routed_scale: float = 1.0
+    experts_held: int = 0
+    expert_rank: int = 0
+    embed_std: float | None = None
 
     def __post_init__(self) -> None:
         def fill(name, default, valid):
@@ -158,24 +191,45 @@ class LMConfig:
         fill("mlp_types", (DENSE,) * self.n_layers, (DENSE, SPARSE))
         if self.block_style not in ("sandwich", "prenorm"):
             raise ValueError(f"block_style must be sandwich|prenorm, got {self.block_style!r}")
-        if len(set(self.mlp_types)) > 1:
-            # the layers are STACKED leaves under one lax.scan: dense and
-            # sparse layers would need two stacks and two scans
-            raise ValueError(
-                "dense and sparse MLP layers in one model are not supported "
-                "yet (leading dense layers need a second stack of leaves); "
-                f"got mlp_types={self.mlp_types}")
+        if self.attn_gate not in ("none", "per_head"):
+            raise ValueError(f"attn_gate must be none|per_head, got {self.attn_gate!r}")
+        if self.heads_by_layer is not None:
+            heads = tuple(self.heads_by_layer)
+            if len(heads) != self.n_layers or any(
+                    h <= 0 or h % self.n_kv_heads for h in heads):
+                raise ValueError(
+                    f"heads_by_layer must give whole GQA groups of "
+                    f"{self.n_kv_heads} for each of {self.n_layers} layers, got {heads}")
+            object.__setattr__(self, "heads_by_layer", heads)
         if self.sparse and not (0 < self.experts_per_tok <= self.n_experts
                                 and self.d_expert > 0):
             raise ValueError(
                 f"sparse layers need 0 < experts_per_tok <= n_experts and "
                 f"d_expert > 0, got {self.experts_per_tok} of {self.n_experts} "
                 f"experts of width {self.d_expert}")
+        if self.sparse and (self.n_experts % self.n_held
+                            or not 0 <= self.expert_rank < self.n_experts // self.n_held):
+            raise ValueError(
+                f"a share of {self.experts_held} experts (rank {self.expert_rank}) "
+                f"does not divide the layer's {self.n_experts}")
 
     @property
     def sparse(self) -> bool:
-        """Whether the (homogeneous) MLP layers are expert layers."""
+        """Whether any MLP layer is an expert layer."""
         return SPARSE in self.mlp_types
+
+    @property
+    def n_held(self) -> int:
+        """Experts of each sparse layer this chip holds."""
+        return self.experts_held or self.n_experts
+
+    @property
+    def first_expert(self) -> int:
+        """The first expert of this chip's share."""
+        return self.expert_rank * self.n_held
+
+    def heads_of(self, layer: int) -> int:
+        return self.n_heads if self.heads_by_layer is None else self.heads_by_layer[layer]
 
     def rope_of(self, kind: str) -> Rope:
         return dict(self.rope).get(kind, Rope(theta=self.rope_theta))
@@ -228,6 +282,34 @@ class LMConfig:
         )
 
     @classmethod
+    def laguna_s_2_1(cls) -> "LMConfig":
+        """Laguna-S-2.1 (poolside): a pre-norm block; layer 0 dense (12,288),
+        the rest 256 experts of width 1024 with top-10 routing (gates times
+        2.5) beside one shared expert; one full layer (48 query heads, the
+        leading half of each head rotated by YaRN x128) to three 512-window
+        layers (72 heads, plain RoPE); a per-head sigmoid gate on the
+        attended heads; untied head. The whole model: every expert held."""
+        n = 48
+        full = tuple(i % 4 == 0 for i in range(n))
+        return cls(
+            vocab_size=100_352, d_model=3072, n_layers=n, n_heads=48,
+            n_kv_heads=8, head_dim=128, d_ff=12_288, rope_theta=10_000.0,
+            attn_softcap=0.0, final_softcap=0.0, sliding_window=512,
+            query_pre_attn_scalar=128.0,
+            layer_types=tuple(FULL if f else SLIDING for f in full),
+            mlp_types=(DENSE,) + (SPARSE,) * (n - 1), block_style="prenorm",
+            rope=((FULL, Rope(theta=500_000.0, yarn_factor=128.0,
+                              original_max_position=8192, beta_fast=32.0,
+                              beta_slow=1.0,
+                              attention_factor=1.4852030263919618,
+                              rotary_factor=0.5)),),
+            n_experts=256, experts_per_tok=10, d_expert=1024,
+            norm_topk_prob=True, tie_embeddings=False,
+            heads_by_layer=tuple(48 if f else 72 for f in full),
+            attn_gate="per_head", d_shared_expert=1024, routed_scale=2.5,
+        )
+
+    @classmethod
     def tiny(cls, vocab_size: int = 257, n_layers: int = 4) -> "LMConfig":
         """Deterministic test-sized config (the 'fake LM' of SURVEY.md §4 —
         same hook semantics as the real model, no 2.6B-param download)."""
@@ -259,6 +341,9 @@ _NAMED_CONFIGS = {
     "mellum2-12b-a2.5b": LMConfig.mellum2_12b,
     "mellum2-12b-a2.5b-base": LMConfig.mellum2_12b,
     "mellum2-12b-a2.5b-instruct": LMConfig.mellum2_12b,
+    "laguna-s-2.1": LMConfig.laguna_s_2_1,
+    "laguna-s-2.1-base": LMConfig.laguna_s_2_1,
+    "laguna-s-2.1-instruct": LMConfig.laguna_s_2_1,
 }
 
 
@@ -274,19 +359,67 @@ def config_for(model_name: str) -> LMConfig:
 # params
 
 
+class LayerClass(NamedTuple):
+    """Layers whose leaves have one shape: one stack of leaves."""
+
+    n_heads: int
+    mlp: str                    # DENSE | SPARSE
+    layers: tuple[int, ...]     # the model's layer ids, ascending
+    kind: str | None            # the attention kind, where all its layers share one
+
+
+@functools.lru_cache(maxsize=64)
+def layer_classes(cfg: LMConfig) -> tuple[LayerClass, ...]:
+    """The config's layers by the SHAPE of their leaves (query heads, MLP
+    kind), in order of first appearance. One class is the common case and
+    keeps the tree ``params["layers"][leaf]`` ``[L, ...]``; with more,
+    ``params["layers"]`` is a tuple of such dicts, one a class, each stacked
+    over its own layers."""
+    keys = [(cfg.heads_of(i), cfg.mlp_types[i]) for i in range(cfg.n_layers)]
+    out = []
+    for key in dict.fromkeys(keys):
+        layers = tuple(i for i, k in enumerate(keys) if k == key)
+        kinds = {cfg.layer_types[i] for i in layers}
+        out.append(LayerClass(*key, layers, kinds.pop() if len(kinds) == 1 else None))
+    return tuple(out)
+
+
+@functools.lru_cache(maxsize=64)
+def _class_slots(cfg: LMConfig) -> tuple[tuple[int, int], ...]:
+    """Per layer: (its class, its slot in that class's stack)."""
+    where = {}
+    for c, cls in enumerate(layer_classes(cfg)):
+        where.update({layer: (c, s) for s, layer in enumerate(cls.layers)})
+    return tuple(where[i] for i in range(cfg.n_layers))
+
+
+def class_stacks(params: LMParams, cfg: LMConfig) -> tuple[Mapping[str, jax.Array], ...]:
+    """The stacks of leaves, one a class, whichever tree ``params`` is."""
+    layers = params["layers"]
+    return (layers,) if len(layer_classes(cfg)) == 1 else tuple(layers)
+
+
+def _from_stacks(stacks: Sequence[Any]) -> Any:
+    """``params["layers"]`` from one stack a class."""
+    return stacks[0] if len(stacks) == 1 else tuple(stacks)
+
+
 def init_params(key: jax.Array, cfg: LMConfig) -> LMParams:
     """Random-init params (the fake-LM fixture; real runs use ``from_hf``).
 
-    Layer leaves are stacked on a leading [n_layers] axis for ``lax.scan``.
+    Layer leaves are stacked on a leading axis over the layers of their
+    class (:func:`layer_classes`; one class: ``[n_layers]``) for ``lax.scan``.
     The leaves follow the config: a ``"prenorm"`` block has no post-norms
     (and its norm weights start at 1, a ``"sandwich"`` block's (1 + w) at
-    0), a sparse MLP has ``router`` [D, E], ``we_gate_up`` [E, D, 2·Fe]
-    (gate columns first) and ``we_down`` [E, Fe, D] in place of the dense
-    three, an untied head is ``unembed`` [V, D].
+    0), a sparse MLP has ``router`` [D, E], ``we_gate_up`` [E_held, D, 2·Fe]
+    (gate columns first) and ``we_down`` [E_held, Fe, D] in place of the dense
+    three (and ``ws_gate``/``ws_up``/``ws_down``, the shared expert, where
+    the config has one), a gated attention ``w_attn_gate`` [D, H], an untied
+    head is ``unembed`` [V, D].
     """
     dt = dtype_of(cfg.dtype)
-    D, F, L = cfg.d_model, cfg.d_ff, cfg.n_layers
-    qd, kd = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
+    D, F = cfg.d_model, cfg.d_ff
+    kd = cfg.n_kv_heads * cfg.head_dim
     ks = jax.random.split(key, 9)
 
     def nrm(k, shape, scale):
@@ -294,30 +427,51 @@ def init_params(key: jax.Array, cfg: LMConfig) -> LMParams:
 
     sandwich = cfg.block_style == "sandwich"
     unit = jnp.zeros if sandwich else jnp.ones
-    layers = {
-        "attn_norm": unit((L, D), dt),
-        "pre_ffw_norm": unit((L, D), dt),
-        "wq": nrm(ks[1], (L, D, qd), D ** -0.5),
-        "wk": nrm(ks[2], (L, D, kd), D ** -0.5),
-        "wv": nrm(ks[3], (L, D, kd), D ** -0.5),
-        "wo": nrm(ks[4], (L, qd, D), qd ** -0.5),
-    }
-    if sandwich:
-        layers["post_attn_norm"] = unit((L, D), dt)
-        layers["post_ffw_norm"] = unit((L, D), dt)
-    if cfg.sparse:
-        E, Fe = cfg.n_experts, cfg.d_expert
-        layers["router"] = nrm(ks[5], (L, D, E), D ** -0.5)
-        layers["we_gate_up"] = nrm(ks[6], (L, E, D, 2 * Fe), D ** -0.5)
-        layers["we_down"] = nrm(ks[7], (L, E, Fe, D), Fe ** -0.5)
-    else:
-        layers["w_gate"] = nrm(ks[5], (L, D, F), D ** -0.5)
-        layers["w_up"] = nrm(ks[6], (L, D, F), D ** -0.5)
-        layers["w_down"] = nrm(ks[7], (L, F, D), F ** -0.5)
+
+    def stack(cls: LayerClass, ks, key) -> dict:
+        L, qd = len(cls.layers), cls.n_heads * cfg.head_dim
+        layers = {
+            "attn_norm": unit((L, D), dt),
+            "pre_ffw_norm": unit((L, D), dt),
+            "wq": nrm(ks[1], (L, D, qd), D ** -0.5),
+            "wk": nrm(ks[2], (L, D, kd), D ** -0.5),
+            "wv": nrm(ks[3], (L, D, kd), D ** -0.5),
+            "wo": nrm(ks[4], (L, qd, D), qd ** -0.5),
+        }
+        if sandwich:
+            layers["post_attn_norm"] = unit((L, D), dt)
+            layers["post_ffw_norm"] = unit((L, D), dt)
+        if cls.mlp == SPARSE:
+            E, Fe = cfg.n_held, cfg.d_expert
+            layers["router"] = nrm(ks[5], (L, D, cfg.n_experts), D ** -0.5)
+            layers["we_gate_up"] = nrm(ks[6], (L, E, D, 2 * Fe), D ** -0.5)
+            layers["we_down"] = nrm(ks[7], (L, E, Fe, D), Fe ** -0.5)
+            if cfg.d_shared_expert:
+                Fs = cfg.d_shared_expert
+                k_g, k_u, k_d = jax.random.split(jax.random.fold_in(key, 101), 3)
+                layers["ws_gate"] = nrm(k_g, (L, D, Fs), D ** -0.5)
+                layers["ws_up"] = nrm(k_u, (L, D, Fs), D ** -0.5)
+                layers["ws_down"] = nrm(k_d, (L, Fs, D), Fs ** -0.5)
+        else:
+            layers["w_gate"] = nrm(ks[5], (L, D, F), D ** -0.5)
+            layers["w_up"] = nrm(ks[6], (L, D, F), D ** -0.5)
+            layers["w_down"] = nrm(ks[7], (L, F, D), F ** -0.5)
+        if cfg.attn_gate == "per_head":
+            layers["w_attn_gate"] = nrm(
+                jax.random.fold_in(key, 102), (L, D, cls.n_heads), D ** -0.5)
+        return layers
+
+    classes = layer_classes(cfg)
+    if len(classes) == 1:
+        stacks = [stack(classes[0], ks, key)]
+    else:       # each class draws from a key of its own
+        stacks = [stack(cls, jax.random.split(k, 9), k) for cls, k in zip(
+            classes, (jax.random.fold_in(key, 1 + c) for c in range(len(classes))))]
     params = {
-        "embed": nrm(ks[0], (cfg.vocab_size, D), D ** -0.5),
+        "embed": nrm(ks[0], (cfg.vocab_size, D),
+                     D ** -0.5 if cfg.embed_std is None else cfg.embed_std),
         "final_norm": unit((D,), dt),
-        "layers": layers,
+        "layers": _from_stacks(stacks),
     }
     if not cfg.tie_embeddings:
         params["unembed"] = nrm(ks[8], (cfg.vocab_size, D), D ** -0.5)
@@ -325,14 +479,22 @@ def init_params(key: jax.Array, cfg: LMConfig) -> LMParams:
 
 
 def param_count(cfg: LMConfig) -> int:
-    D, F, L = cfg.d_model, cfg.d_ff, cfg.n_layers
-    qd, kd = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
+    """Parameters this chip holds (of a sparse layer: its share of the experts)."""
+    D, F = cfg.d_model, cfg.d_ff
+    kd = cfg.n_kv_heads * cfg.head_dim
     norms = 4 * D if cfg.block_style == "sandwich" else 2 * D
-    mlp = (D * cfg.n_experts + cfg.n_experts * 3 * D * cfg.d_expert
-           if cfg.sparse else 3 * D * F)
-    per_layer = norms + D * qd + 2 * D * kd + qd * D + mlp
+    layers = 0
+    for cls in layer_classes(cfg):
+        qd = cls.n_heads * cfg.head_dim
+        if cls.mlp == SPARSE:
+            mlp = (D * cfg.n_experts + cfg.n_held * 3 * D * cfg.d_expert
+                   + 3 * D * cfg.d_shared_expert)
+        else:
+            mlp = 3 * D * F
+        gate = D * cls.n_heads if cfg.attn_gate == "per_head" else 0
+        layers += len(cls.layers) * (norms + D * qd + 2 * D * kd + qd * D + gate + mlp)
     heads = 1 if cfg.tie_embeddings else 2
-    return heads * cfg.vocab_size * D + D + L * per_layer
+    return heads * cfg.vocab_size * D + D + layers
 
 
 # ---------------------------------------------------------------------------
@@ -356,8 +518,9 @@ def _softcap(x: jax.Array, cap: float) -> jax.Array:
 
 
 def rope_inv_freq(rope: Rope, head_dim: int) -> jax.Array:
-    """The ``head_dim // 2`` rotation frequencies of one attention kind."""
-    d = head_dim
+    """The rotation frequencies of one attention kind: one a pair of the
+    ``head_dim · rotary_factor`` leading dims that rotate."""
+    d = int(head_dim * rope.rotary_factor)
     if not rope.yarn_factor:
         return 1.0 / (rope.theta ** (jnp.arange(0, d // 2, dtype=jnp.float32) * 2.0 / d))
     # YaRN (HF ``_compute_yarn_parameters``), closed form, in float64
@@ -378,48 +541,64 @@ class _LayerKind(NamedTuple):
     """What a layer's place in the config's table selects."""
 
     index: Any          # the (traced) layer id itself
-    is_local: Any       # bool scalar (traced): the sliding-window mask
-    inv_freq: Any       # [head_dim // 2] RoPE frequencies of the layer's kind
+    is_local: Any       # the sliding-window mask: bool scalar, traced or (a
+                        # class of one attention kind) a static ``np.bool_``
+    inv_freq: Any       # RoPE frequencies of the layer's kind, one a rotated pair
     rope_factor: Any    # what cos and sin are multiplied by; python 1.0 = not
+    slot: Any = None    # the layer's place in its class's stack (None: ``index``)
 
 
-def _layer_kind(cfg: LMConfig, i: jax.Array) -> _LayerKind:
+def _layer_kind(cfg: LMConfig, i: jax.Array, cls: LayerClass | None = None,
+                slot: Any = None) -> _LayerKind:
     """The ONE lookup of the traced layer id ``i`` in ``cfg.layer_types``,
     shared by every forward. Where both attention kinds rotate alike (the
-    Gemma-2 family) the RoPE side is static and only the mask is looked up."""
+    Gemma-2 family) the RoPE side is static and only the mask is looked up;
+    where the layer's class ``cls`` is of one attention kind (a table whose
+    kinds differ in shape) everything is static and nothing is looked up."""
+    if cls is not None and cls.kind is not None:
+        rope = cfg.rope_of(cls.kind)
+        return _LayerKind(i, np.bool_(cls.kind == SLIDING),
+                          rope_inv_freq(rope, cfg.head_dim), rope.attention_factor, slot)
     is_local = jnp.asarray([k == SLIDING for k in cfg.layer_types])[i]
     local, full = cfg.rope_of(SLIDING), cfg.rope_of(FULL)
     if local == full:
         return _LayerKind(i, is_local, rope_inv_freq(local, cfg.head_dim),
-                          local.attention_factor)
+                          local.attention_factor, slot)
+    if local.rotary_factor != full.rotary_factor:
+        raise ValueError(
+            "attention kinds that rotate different shares of a head cannot "
+            "share a stack of leaves (the rotated width is static)")
     return _LayerKind(
         i, is_local,
         jnp.where(is_local, rope_inv_freq(local, cfg.head_dim),
                   rope_inv_freq(full, cfg.head_dim)),
         jnp.where(is_local, jnp.float32(local.attention_factor),
                   jnp.float32(full.attention_factor)),
+        slot,
     )
 
 
 def _rope(x: jax.Array, positions: jax.Array, inv_freq: jax.Array,
           factor: Any = 1.0) -> jax.Array:
-    """Rotate pairs (x[..., :d/2], x[..., d/2:]) — HF 'split-half' layout.
+    """Rotate pairs (x[..., :r/2], x[..., r/2:r]) — HF 'split-half' layout —
+    of the ``r = 2 · len(inv_freq)`` leading dims; the rest pass unrotated.
 
     x: [B, S, n_heads, head_dim]; positions: [S] (shared across the batch,
     the padded path) or [B, S] (per-token — the paged runtime's packed
     plane carries each document's own within-document positions).
     """
-    d = x.shape[-1]
+    d = 2 * inv_freq.shape[-1]
     ang = positions.astype(jnp.float32)[..., None] * inv_freq  # [(B,) S, d/2]
     cos = jnp.expand_dims(jnp.cos(ang), -2)                  # [(B,) S, 1, d/2]
     sin = jnp.expand_dims(jnp.sin(ang), -2)
     if not (isinstance(factor, float) and factor == 1.0):
         cos, sin = cos * factor, sin * factor
-    x1, x2 = x[..., : d // 2], x[..., d // 2:]
+    x1, x2 = x[..., : d // 2], x[..., d // 2: d]
     xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
-    return jnp.concatenate(
-        [xf1 * cos - xf2 * sin, xf2 * cos + xf1 * sin], axis=-1
-    ).astype(x.dtype)
+    parts = [xf1 * cos - xf2 * sin, xf2 * cos + xf1 * sin]
+    if d < x.shape[-1]:
+        parts.append(x[..., d:].astype(jnp.float32))
+    return jnp.concatenate(parts, axis=-1).astype(x.dtype)
 
 
 def _qkv(
@@ -429,7 +608,8 @@ def _qkv(
     """Project + RoPE: q [B,S,H,hd], k/v [B,S,KV,hd]. ``pos`` carries GLOBAL
     positions so sequence-sharded callers rotate correctly."""
     B, S, _ = x.shape
-    H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    KV, hd = cfg.n_kv_heads, cfg.head_dim
+    H = lp["wq"].shape[-1] // hd        # the layer's own (``heads_by_layer``)
     q = jnp.einsum("bsd,dq->bsq", x, lp["wq"], preferred_element_type=jnp.float32)
     k = jnp.einsum("bsd,dk->bsk", x, lp["wk"], preferred_element_type=jnp.float32)
     v = jnp.einsum("bsd,dk->bsk", x, lp["wv"], preferred_element_type=jnp.float32)
@@ -451,9 +631,10 @@ def _attn_core(
     - the padded path (``lengths is None``) on a one-device TPU backend at
       a shape :func:`crosscoder_tpu.ops.flash_attention.supported` accepts
       runs the fused online-softmax kernel, which never writes the [S, S]
-      scores to HBM. ``is_local`` is traced: where the window cannot bind
-      (0, or ≥ S) local and global layers are one kernel instance,
-      otherwise ``lax.cond`` picks between two static ones;
+      scores to HBM. Where the window cannot bind (0, or ≥ S) local and
+      global layers are one kernel instance; otherwise a static ``is_local``
+      names its instance and a traced one has ``lax.cond`` pick between
+      the two;
     - everything else — the CPU backend, a mesh, an unsupported shape, the
       paged runtime (``lengths`` given) — runs the XLA form
       (:func:`crosscoder_tpu.ops.paged_attention.ragged_attention_reference`),
@@ -483,6 +664,8 @@ def _attn_core(
 
         if not 0 < cfg.sliding_window < S:
             return fused(0)((q, k, v))
+        if isinstance(is_local, np.bool_):      # a class of one attention kind
+            return fused(cfg.sliding_window if is_local else 0)((q, k, v))
         return jax.lax.cond(
             is_local, fused(cfg.sliding_window), fused(0), (q, k, v))
     obs.count("harvest/attn_xla_traces")
@@ -496,7 +679,7 @@ def _attn_core(
 # The expert leaves are never sliced per layer: one layer's are 0.8 GB at
 # Mellum2's sizes, and a slice that feeds a kernel is a copy. They stay
 # STACKED beside the scanned leaves and the expert layer indexes them in
-# place by the layer id (``ops/moe.py``).
+# place by the layer's slot in its class's stack (``ops/moe.py``).
 _HELD_LEAVES = ("we_gate_up", "we_down")
 
 
@@ -529,34 +712,56 @@ def _attention(
     q, k, v = _qkv(x, lp, cfg, jnp.arange(x.shape[1]) if pos is None else pos, kind)
     a = (_attn_core(q, k, v, cfg, kind.is_local) if attend is None
          else attend(q, k, v, kind))
+    if "w_attn_gate" in lp:
+        with jax.named_scope("harvest/block/attn/gate"):
+            # one sigmoid scalar a head and position, from the block's normed
+            # input, on the attended heads before the output projection
+            g = jax.nn.sigmoid(jnp.einsum(
+                "bsd,dh->bsh", x, lp["w_attn_gate"], preferred_element_type=jnp.float32))
+            B, S, H = g.shape
+            a = (a.reshape(B, S, H, -1) * g[..., None]).astype(a.dtype).reshape(a.shape)
     return _attn_out(a, lp, cfg)
 
 
-@jax.named_scope("harvest/block/mlp")
-def _mlp(x: jax.Array, lp: Mapping[str, jax.Array], cfg: LMConfig, layer: Any) -> jax.Array:
-    """The gated MLP of the config's kind on the normed stream: dense
-    ``act(x·W_gate) ⊙ (x·W_up) · W_down`` (tanh-GELU in the sandwich block,
-    SiLU in the pre-norm one), or the routed experts (``ops/moe.py``; their
-    leaves in ``lp`` are the STACKED ones, indexed by ``layer``)."""
-    if cfg.sparse:
-        from crosscoder_tpu.ops import moe
-
-        return moe.moe_mlp(
-            x, lp["router"], lp["we_gate_up"], lp["we_down"], layer,
-            top_k=cfg.experts_per_tok, norm_topk_prob=cfg.norm_topk_prob)
-    gate = jnp.einsum("bsd,df->bsf", x, lp["w_gate"], preferred_element_type=jnp.float32)
-    up = jnp.einsum("bsd,df->bsf", x, lp["w_up"], preferred_element_type=jnp.float32)
+def _gated_mlp(x: jax.Array, w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
+               cfg: LMConfig) -> jax.Array:
+    """``act(x·W_gate) ⊙ (x·W_up) · W_down``: tanh-GELU in the sandwich
+    block, SiLU in the pre-norm one."""
+    gate = jnp.einsum("bsd,df->bsf", x, w_gate, preferred_element_type=jnp.float32)
+    up = jnp.einsum("bsd,df->bsf", x, w_up, preferred_element_type=jnp.float32)
     if cfg.block_style == "sandwich":
         gate = jax.nn.gelu(gate, approximate=True)
     else:
         gate = jax.nn.silu(gate)
     h = (gate * up).astype(x.dtype)
-    return jnp.einsum("bsf,fd->bsd", h, lp["w_down"], preferred_element_type=jnp.float32).astype(x.dtype)
+    return jnp.einsum("bsf,fd->bsd", h, w_down, preferred_element_type=jnp.float32).astype(x.dtype)
 
 
-def _mlp_out(resid: jax.Array, lp: Mapping[str, jax.Array], cfg: LMConfig, layer: Any) -> jax.Array:
-    """The MLP sublayer of layer ``layer`` on the stream, as added to it."""
-    m = _mlp(_norm(resid, lp["pre_ffw_norm"], cfg), lp, cfg, layer)
+@jax.named_scope("harvest/block/mlp")
+def _mlp(x: jax.Array, lp: Mapping[str, jax.Array], cfg: LMConfig, slot: Any) -> jax.Array:
+    """The gated MLP of the layer's kind (by its leaves) on the normed
+    stream: the dense one, or the routed experts (``ops/moe.py``; their
+    leaves in ``lp`` are the STACKED ones, indexed by ``slot``) — this
+    chip's share of them where the config holds one — plus, where the
+    config has one, the shared expert on the same input."""
+    if "router" not in lp:
+        return _gated_mlp(x, lp["w_gate"], lp["w_up"], lp["w_down"], cfg)
+    from crosscoder_tpu.ops import moe
+
+    m = moe.moe_mlp(
+        x, lp["router"], lp["we_gate_up"], lp["we_down"], slot,
+        top_k=cfg.experts_per_tok, norm_topk_prob=cfg.norm_topk_prob,
+        routed_scale=cfg.routed_scale, first_expert=cfg.first_expert)
+    if "ws_gate" in lp:
+        with jax.named_scope("harvest/block/moe/shared"):
+            m = m + _gated_mlp(x, lp["ws_gate"], lp["ws_up"], lp["ws_down"], cfg)
+    return m
+
+
+def _mlp_out(resid: jax.Array, lp: Mapping[str, jax.Array], cfg: LMConfig, slot: Any) -> jax.Array:
+    """The MLP sublayer of the layer at ``slot`` of its class's stack on the
+    stream, as added to it."""
+    m = _mlp(_norm(resid, lp["pre_ffw_norm"], cfg), lp, cfg, slot)
     if cfg.block_style == "sandwich":
         m = _norm(m, lp["post_ffw_norm"], cfg)
     return m
@@ -602,7 +807,7 @@ def _block(
     if edit_attn is not None:
         attn_out = edit_attn(attn_out)
     resid = resid + attn_out
-    mlp_out = _mlp_out(resid, lp, cfg, kind.index)
+    mlp_out = _mlp_out(resid, lp, cfg, kind.index if kind.slot is None else kind.slot)
     if edit_mlp is not None:
         mlp_out = edit_mlp(mlp_out)
     return resid + mlp_out, attn_out, mlp_out
@@ -740,18 +945,65 @@ def _fresh_carry(params: LMParams, tokens: jax.Array, cfg: LMConfig, n_cap: int)
     return resid, buf
 
 
+class _Run(NamedTuple):
+    """Consecutive layers of one class."""
+
+    cls: int        # index into :func:`layer_classes`
+    layer: int      # the first layer's id in the model
+    slot: int       # ... and its slot in the class's stack
+    n: int
+
+
+def _runs(cfg: LMConfig, lo: int, hi: int) -> list[_Run]:
+    """Layers ``[lo, hi)`` as maximal runs of one class."""
+    where, out = _class_slots(cfg), []
+    for i in range(lo, hi):
+        c, s = where[i]
+        if out and out[-1].cls == c:
+            out[-1] = out[-1]._replace(n=out[-1].n + 1)
+        else:
+            out.append(_Run(c, i, s, 1))
+    return out
+
+
+def _periodic(runs: list[_Run]) -> tuple[int, int, int]:
+    """``(lead, period, repeats)`` over a list of runs: after ``lead`` runs,
+    ``period`` runs repeat ``repeats`` ≥ 2 times in class and length, chosen
+    so that the fewest runs are written out (lead + period + what is left);
+    ``repeats`` 0 where nothing repeats."""
+    shape = [(r.cls, r.n) for r in runs]
+    best = (len(runs), 0, 0, 0)
+    for lead in range(len(runs)):
+        for period in range(1, (len(runs) - lead) // 2 + 1):
+            reps = 1
+            while shape[lead + reps * period: lead + (reps + 1) * period] == \
+                    shape[lead: lead + period]:
+                reps += 1
+            if reps >= 2:
+                best = min(best, (len(runs) - (reps - 1) * period, lead, period, reps))
+    return best[1:]
+
+
 def _scan_blocks(
     params: LMParams, cfg: LMConfig, capture: tuple[tuple[int, int], ...],
     carry: tuple[jax.Array, jax.Array | None], k: int, lo: jax.Array | None = None,
-    *, pos: jax.Array | None = None, attend: Callable | None = None,
+    *, cls: int | None = None, pos: jax.Array | None = None,
+    attend: Callable | None = None,
     edits: tuple[tuple, tuple, tuple] = ((), (), ()),
     emit: Callable | None = None,
 ):
     """THE layer loop, behind every forward in this module: blocks
-    ``[lo, lo + k)`` of the stacked layers under one ``lax.scan`` carrying
-    ``carry = (resid, buf)``. Per layer: residual-site edits, the residual-site
-    capture, :func:`_block` (with the sublayer-site edits inside it), the
-    sublayer-site captures. Returns ``((resid, buf), ys)`` as the scan does.
+    ``[lo, lo + k)`` carrying ``carry = (resid, buf)``. Per layer:
+    residual-site edits, the residual-site capture, :func:`_block` (with the
+    sublayer-site edits inside it), the sublayer-site captures. Returns
+    ``((resid, buf), ys)`` as a scan does.
+
+    Layers of one class (:func:`layer_classes`) are one stack of leaves and
+    run under one ``lax.scan`` (``run`` below); a table of ONE class — the
+    common case — is exactly that scan and nothing else. A range that spans
+    classes is walked run by run; where its runs repeat (a period of the
+    table) the repeats are ONE outer ``lax.scan`` whose body holds the
+    period's runs, so the program does not grow with depth.
 
     What a caller hands in is what truly differs between the forwards:
 
@@ -761,15 +1013,17 @@ def _scan_blocks(
       edited and captured like any other. A TRACED ``lo`` is one segment of
       a longer job (``dynamic_slice`` on the stacked leaves, so one compiled
       program serves every segment of a given width — no per-range
-      recompiles and no pre-split param copies); its virtual layer is the
-      job's business (:func:`_seg_finish_impl`);
+      recompiles and no pre-split param copies); on a table of several
+      classes the segment lies inside one run and names its class ``cls``
+      (static). Its virtual layer is the job's business
+      (:func:`_seg_finish_impl`);
     - the carry: :func:`_fresh_carry`, or a segment's donated one;
     - ``pos`` / ``attend``: how attention is reached (:func:`_block`);
     - ``edits``: ``(fns, (layer, site) pairs, values)``, parallel tuples.
       With none, the body traces exactly the capture-only op sequence;
     - ``emit(lp, resid, attn_out)``: a per-layer output of the caller's own
-      (``ys``), from the stream entering the block and its attention
-      contribution.
+      (``ys``, in layer order), from the stream entering the block and its
+      attention contribution.
     """
     slots = _slots(capture)
     # static: skip the sublayer-capture FMAs entirely on resid-only runs
@@ -789,42 +1043,89 @@ def _scan_blocks(
                 x = jnp.where(edit_arr[j] == i, new, x)
         return x
 
-    layers = params["layers"]
-    if lo is None:
-        # TransformerLens-style stop_at_layer: scan only the blocks below the
-        # highest needed layer (the reference harvests with FULL forwards even
-        # for a mid-stack hook — reference buffer.py:81-89 — wasting every layer
-        # above it; at blocks.14 of 26 that is ~46% of the forward FLOPs)
-        stacked, held = _scan_leaves(layers, lambda x: x[:k])
-        layer_ids = jnp.arange(k, dtype=jnp.int32)
+    classes, stacks = layer_classes(cfg), class_stacks(params, cfg)
+    one_class = len(classes) == 1
+
+    def run(carry, c, slot, n, shift=0):
+        """``n`` layers of class ``c`` from ``slot`` of its stack (static or
+        traced), under one scan; a layer's id is its slot plus ``shift``
+        (static 0 on a table of one class)."""
+        if isinstance(slot, int):
+            # TransformerLens-style stop_at_layer: scan only the blocks below the
+            # highest needed layer (the reference harvests with FULL forwards even
+            # for a mid-stack hook — reference buffer.py:81-89 — wasting every layer
+            # above it; at blocks.14 of 26 that is ~46% of the forward FLOPs)
+            stacked, held = _scan_leaves(stacks[c], lambda x: x[slot:slot + n])
+            at = jnp.arange(n, dtype=jnp.int32)
+            at = at + slot if slot else at
+        else:
+            stacked, held = _scan_leaves(
+                stacks[c], lambda x: jax.lax.dynamic_slice_in_dim(x, slot, n, axis=0))
+            at = slot + jnp.arange(n, dtype=jnp.int32)
+
+        def body(carry, xs):
+            resid, buf = carry
+            lp, s = xs
+            lp = {**lp, **held}
+            i = s if isinstance(shift, int) and shift == 0 else s + shift
+            entering = resid = edited(resid, i, _SITE_RESID)
+            buf = _capture_into(buf, resid, i, slots)
+            kind = _layer_kind(cfg, i) if one_class else _layer_kind(cfg, i, classes[c], s)
+            resid, attn_out, mlp_out = _block(
+                resid, lp, cfg, kind,
+                edit_attn=functools.partial(edited, i=i, site=_SITE_ATTN),
+                edit_mlp=functools.partial(edited, i=i, site=_SITE_MLP),
+                pos=pos, attend=attend,
+            )
+            if _SITE_ATTN in captured_sites:
+                buf = _capture_into(buf, attn_out, i, slots, _SITE_ATTN)
+            if _SITE_MLP in captured_sites:
+                buf = _capture_into(buf, mlp_out, i, slots, _SITE_MLP)
+            return (resid, buf), (emit(lp, entering, attn_out) if emit else None)
+
+        return jax.lax.scan(body, carry, (stacked, at))
+
+    if one_class:
+        carry, ys = run(carry, 0, 0 if lo is None else lo, k)
+    elif lo is not None:
+        # one segment: ``k`` layers of class ``cls`` from the traced layer ``lo``
+        slot = jnp.asarray([s for _, s in _class_slots(cfg)], jnp.int32)[lo]
+        carry, ys = run(carry, cls, slot, k, lo - slot)
     else:
-        stacked, held = _scan_leaves(
-            layers, lambda x: jax.lax.dynamic_slice_in_dim(x, lo, k, axis=0))
-        layer_ids = lo + jnp.arange(k, dtype=jnp.int32)
+        runs = _runs(cfg, 0, k)
+        lead, period, reps = _periodic(runs)
+        if not reps:
+            lead = len(runs)
+        span = runs[lead: lead + period]
+        step = sum(r.n for r in span)                       # layers a period
+        per_class = {r.cls: sum(q.n for q in span if q.cls == r.cls) for r in span}
 
-    def body(carry, xs):
-        resid, buf = carry
-        lp, i = xs
-        lp = {**lp, **held}
-        entering = resid = edited(resid, i, _SITE_RESID)
-        buf = _capture_into(buf, resid, i, slots)
-        resid, attn_out, mlp_out = _block(
-            resid, lp, cfg, _layer_kind(cfg, i),
-            edit_attn=functools.partial(edited, i=i, site=_SITE_ATTN),
-            edit_mlp=functools.partial(edited, i=i, site=_SITE_MLP),
-            pos=pos, attend=attend,
-        )
-        if _SITE_ATTN in captured_sites:
-            buf = _capture_into(buf, attn_out, i, slots, _SITE_ATTN)
-        if _SITE_MLP in captured_sites:
-            buf = _capture_into(buf, mlp_out, i, slots, _SITE_MLP)
-        return (resid, buf), (emit(lp, entering, attn_out) if emit else None)
+        def walk(carry, some, t=0):
+            out = []
+            for r in some:      # (t: the traced repeat inside the outer scan; else 0)
+                slot = r.slot + t * per_class.get(r.cls, 0)
+                carry, ys = run(carry, r.cls, slot, r.n, r.layer + t * step - slot)
+                out.append(ys)
+            return carry, out
 
-    (resid, buf), ys = jax.lax.scan(body, carry, (stacked, layer_ids))
+        carry, parts = walk(carry, runs[:lead])
+        if reps:
+            carry, ys = jax.lax.scan(
+                lambda c, t: walk(c, span, t), carry, jnp.arange(reps, dtype=jnp.int32))
+            if emit:            # [reps, n, ...] a run -> the layers in order
+                ys = jax.tree.map(lambda *a: jnp.concatenate(a, axis=1), *ys)
+                parts.append(jax.tree.map(
+                    lambda a: a.reshape((reps * step,) + a.shape[2:]), ys))
+            carry, rest = walk(carry, runs[lead + reps * period:])
+            parts += rest
+        ys = (jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *parts)
+              if emit and parts else None)
     if lo is None:
+        resid, buf = carry
         resid = edited(resid, jnp.int32(k), _SITE_RESID)
         buf = _capture_into(buf, resid, jnp.int32(k), slots)
-    return (resid, buf), ys
+        carry = (resid, buf)
+    return carry, ys
 
 
 @functools.partial(
@@ -954,19 +1255,24 @@ def ce_loss(
 
 @functools.partial(jax.jit, static_argnames=("cfg", "n_scan"))
 def expert_load(params: LMParams, tokens: jax.Array, cfg: LMConfig, n_scan: int) -> jax.Array:
-    """Routed rows per expert in the first ``n_scan`` (sparse) layers of a
-    forward over ``tokens``: ``[n_scan, n_experts]`` int32. A diagnostic
-    forward of its own (the harvest programs carry no such output) — the
-    buffer runs it once, at calibration, for the
-    ``harvest/moe_load_max_over_mean`` gauge, and only with ``obs`` on."""
+    """Routed rows per expert in the first ``n_scan`` layers of a forward
+    over ``tokens``: ``[n_scan, n_experts]`` int32, over the router's whole
+    width whatever share of the experts is held (a dense layer's row is 0).
+    A diagnostic forward of its own (the harvest programs carry no such
+    output) — the buffer runs it once, at calibration, for the
+    ``harvest/moe_load_max_over_mean`` and ``harvest/moe_local_row_share``
+    gauges, and only with ``obs`` on."""
     from crosscoder_tpu.ops import moe
 
     def routed(lp, resid, attn_out):
+        counts = jnp.zeros((cfg.n_experts,), jnp.int32)
+        if "router" not in lp:
+            return counts
         # the router reads what the block's MLP sublayer reads
         x = _norm(resid + attn_out, lp["pre_ffw_norm"], cfg)
         idx, _ = moe.route(x.reshape(-1, cfg.d_model), lp["router"],
                            cfg.experts_per_tok, cfg.norm_topk_prob)
-        return jnp.zeros((cfg.n_experts,), jnp.int32).at[idx.reshape(-1)].add(1)
+        return counts.at[idx.reshape(-1)].add(1)
 
     _, counts = _scan_blocks(
         params, cfg, (), _fresh_carry(params, tokens, cfg, 0), n_scan, emit=routed)
@@ -983,17 +1289,19 @@ def _seg_start_impl(params: LMParams, tokens: jax.Array, cfg: LMConfig, n_cap: i
 
 
 @functools.partial(
-    jax.jit, static_argnames=("cfg", "capture", "k"), donate_argnums=(1, 2)
+    jax.jit, static_argnames=("cfg", "capture", "k", "cls"), donate_argnums=(1, 2)
 )
 def _seg_scan_impl(
     params: LMParams, resid: jax.Array, buf: jax.Array, lo: jax.Array,
     cfg: LMConfig, capture: tuple[tuple[int, int], ...], k: int,
+    cls: int | None = None,
 ):
     """Blocks [lo, lo+k) of the capture forward, carrying (resid, buf):
-    :func:`_scan_blocks` with a TRACED ``lo``. Per-layer math is that of
-    ``_forward_impl`` (same ops in the same order); only the scan is cut
+    :func:`_scan_blocks` with a TRACED ``lo`` (and, on a table of several
+    classes, the one class ``cls`` the blocks are of). Per-layer math is that
+    of ``_forward_impl`` (same ops in the same order); only the scan is cut
     into sub-scans."""
-    return _scan_blocks(params, cfg, capture, (resid, buf), k, lo)[0]
+    return _scan_blocks(params, cfg, capture, (resid, buf), k, lo, cls=cls)[0]
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "capture", "n_scan", "out_dtype"))
@@ -1056,10 +1364,13 @@ class SegmentedHarvest:
         self.capture = _hook_layers(cfg, tuple(hook_points))
         self.n_scan = hooked_depth(cfg, hook_points)
         self.out_dtype = out_dtype
-        self._bounds = self.quanta(self.n_scan, self.SEG_LAYERS)
+        self._runs = _runs(cfg, 0, self.n_scan)
+        self._bounds = self.quanta(
+            self.n_scan, self.SEG_LAYERS, [r.n for r in self._runs])
         self.n_steps = len(self.params_seq) * max(1, len(self._bounds))
         self._model_idx = 0
         self._lo = self._q = 0          # next layer; next quantum
+        self._cls = None                # the next sub-scan's class (None: the one)
         self._resid = self._buf = None
         self._done_resids: list = []
         self._done_bufs: list = []
@@ -1069,21 +1380,26 @@ class SegmentedHarvest:
     def count(cls, cfg: LMConfig, hook_points: Sequence[str], n_models: int) -> int:
         """``step()`` calls a job over these hooks will need (for pacing)."""
         n_scan = hooked_depth(cfg, hook_points)
-        return n_models * max(1, -(-n_scan // cls.SEG_LAYERS))
+        runs = [r.n for r in _runs(cfg, 0, n_scan)]
+        return n_models * max(1, len(cls.quanta(n_scan, cls.SEG_LAYERS, runs)))
 
     @staticmethod
-    def quanta(n_scan: int, seg_layers: int) -> list[int]:
+    def quanta(n_scan: int, seg_layers: int, runs: Sequence[int] | None = None) -> list[int]:
         """The layer each quantum ends before: ``⌈n_scan / seg_layers⌉``
         quanta of NEAR-EQUAL depth, the deeper ones first (14 layers by 3:
         3, 3, 3, 3, 2; 4 layers by 3: 2, 2 — not 3, 1). The refill paces by
         quanta as if they cost the same (``data/buffer.py``
         ``_segs_per_chunk``), which a 3 + 1 split of four expert layers
-        would miss by half."""
-        n_q = -(-n_scan // seg_layers)
+        would miss by half. ``runs`` (the depths of the table's runs of one
+        class, summing to ``n_scan``; None: one run) are cut each on its
+        own: a quantum is one scan over one stack of leaves and never
+        straddles two classes (runs of 1, 3, 1 by 3: 1, 3, 1)."""
         ends, lo = [], 0
-        for q in range(n_q):
-            lo += n_scan // n_q + (q < n_scan % n_q)
-            ends.append(lo)
+        for n in [n_scan] if runs is None else runs:
+            n_q = -(-n // seg_layers)
+            for q in range(n_q):
+                lo += n // n_q + (q < n % n_q)
+                ends.append(lo)
         return ends
 
     def inflight(self):
@@ -1109,8 +1425,12 @@ class SegmentedHarvest:
                     len(self.capture),
                 )
             if self._lo < self.n_scan:
-                # consecutive quanta of the same model fuse into one sub-scan
-                n_q = min(quanta - used, len(self._bounds) - self._q)
+                # consecutive quanta of the same model — and of the same run
+                # of one class — fuse into one sub-scan
+                run = next(r for r in self._runs if self._lo < r.layer + r.n)
+                inside = sum(self._lo < b <= run.layer + run.n for b in self._bounds)
+                n_q = min(quanta - used, inside)
+                self._cls = run.cls if len(layer_classes(self.cfg)) > 1 else None
                 self._q += n_q
                 k = self._bounds[self._q - 1] - self._lo
                 self._resid, self._buf = scan(k)
@@ -1139,7 +1459,7 @@ class SegmentedHarvest:
         # device and be re-replicated device-to-device per dispatch
         return _seg_scan_impl(
             self.params_seq[self._model_idx], self._resid, self._buf,
-            np.int32(self._lo), self.cfg, self.capture, k,
+            np.int32(self._lo), self.cfg, self.capture, k, self._cls,
         )
 
     def _scan_batched(self, k: int):
@@ -1153,7 +1473,7 @@ class SegmentedHarvest:
 
         params = self.params_seq[self._model_idx]
         args = (params, self._resid, self._buf, np.int32(self._lo))
-        key = ("seg_scan", self.cfg, self.capture, k, self.tokens.shape,
+        key = ("seg_scan", self.cfg, self.capture, k, self._cls, self.tokens.shape,
                str(self._resid.dtype),
                getattr(self._resid, "sharding", None),
                getattr(params["embed"], "sharding", None))
@@ -1161,13 +1481,14 @@ class SegmentedHarvest:
             compiled = compile_cache.aot_get(
                 key,
                 lambda: _seg_scan_impl.lower(
-                    *args, cfg=self.cfg, capture=self.capture, k=k
+                    *args, cfg=self.cfg, capture=self.capture, k=k, cls=self._cls
                 ).compile(),
             )
         except Exception:   # noqa: BLE001 — AOT is an optimization only
             compiled = None
         if compiled is None:
-            return _seg_scan_impl(*args, cfg=self.cfg, capture=self.capture, k=k)
+            return _seg_scan_impl(
+                *args, cfg=self.cfg, capture=self.capture, k=k, cls=self._cls)
         return compiled(*args)
 
     def step(self) -> bool:
@@ -1349,8 +1670,8 @@ def run_with_cache_multi_paged(
     from crosscoder_tpu.ops import paged_attention as pa
 
     use_kernel = pa.kernel_enabled() and pa.supported(
-        chunk.n_docs, chunk.seq_len, cfg.n_heads, cfg.n_kv_heads,
-        cfg.head_dim, page_size,
+        chunk.n_docs, chunk.seq_len, max(c.n_heads for c in layer_classes(cfg)),
+        cfg.n_kv_heads, cfg.head_dim, page_size,
     )
     if batch_sharding is not None:
         plane = _put_global(chunk.tokens, batch_sharding)
@@ -1396,8 +1717,8 @@ def paged_capture_aot(
     cap_pairs = _hook_layers(cfg, tuple(hook_points))
     n_scan = min(cfg.n_layers, _scan_stop(cap_pairs))
     use_kernel = pa.kernel_enabled() and pa.supported(
-        chunk.n_docs, chunk.seq_len, cfg.n_heads, cfg.n_kv_heads,
-        cfg.head_dim, page_size,
+        chunk.n_docs, chunk.seq_len, max(c.n_heads for c in layer_classes(cfg)),
+        cfg.n_kv_heads, cfg.head_dim, page_size,
     )
     if pad_mode not in ("zero", "wrap"):
         raise ValueError(f"pad_mode must be zero|wrap, got {pad_mode!r}")
@@ -1445,40 +1766,50 @@ def tp_shardings(mesh, axis: str = "model", cfg: LMConfig | None = None) -> LMPa
     - ``embed``: d_model axis sharded — the token lookup stays shard-local.
     - norms: replicated (tiny).
 
-    The leaves follow ``cfg`` (None: the Gemma-2 family's). Expert leaves
-    have no layout here yet: a sparse config on an axis larger than 1 is
-    refused rather than mis-sharded.
+    The leaves follow ``cfg`` (None: the Gemma-2 family's), one stack a
+    class of layers. Expert leaves have no layout here yet: a sparse config
+    on an axis larger than 1 is refused rather than mis-sharded.
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     def ns(*spec):
         return NamedSharding(mesh, P(*spec))
 
-    layers = {
-        "attn_norm": ns(None, None),
-        "pre_ffw_norm": ns(None, None),
-        "wq": ns(None, None, axis),
-        "wk": ns(None, None, axis),
-        "wv": ns(None, None, axis),
-        "wo": ns(None, axis, None),
-    }
-    if cfg is None or cfg.block_style == "sandwich":
-        layers["post_attn_norm"] = ns(None, None)
-        layers["post_ffw_norm"] = ns(None, None)
-    if cfg is not None and cfg.sparse:
-        if mesh.shape[axis] > 1:
-            raise NotImplementedError(
-                f"sparse-expert layers on a {axis!r} axis of {mesh.shape[axis]}: "
-                "expert parallelism (experts split over the axis, the token "
-                "exchange before and after them) is not implemented; "
-                "ops/moe.py computes every expert on one device")
-        layers.update(router=ns(None, None, None),
-                      we_gate_up=ns(None, None, None, None),
-                      we_down=ns(None, None, None, None))
-    else:
-        layers.update(w_gate=ns(None, None, axis), w_up=ns(None, None, axis),
-                      w_down=ns(None, axis, None))
-    out = {"embed": ns(None, axis), "final_norm": ns(None), "layers": layers}
+    def stack(sparse: bool) -> dict:
+        layers = {
+            "attn_norm": ns(None, None),
+            "pre_ffw_norm": ns(None, None),
+            "wq": ns(None, None, axis),
+            "wk": ns(None, None, axis),
+            "wv": ns(None, None, axis),
+            "wo": ns(None, axis, None),
+        }
+        if cfg is None or cfg.block_style == "sandwich":
+            layers["post_attn_norm"] = ns(None, None)
+            layers["post_ffw_norm"] = ns(None, None)
+        if cfg is not None and cfg.attn_gate == "per_head":
+            layers["w_attn_gate"] = ns(None, None, axis)    # heads, as wq's
+        if sparse:
+            if mesh.shape[axis] > 1:
+                raise NotImplementedError(
+                    f"sparse-expert layers on a {axis!r} axis of {mesh.shape[axis]}: "
+                    "expert parallelism (experts split over the axis, the token "
+                    "exchange before and after them) is not implemented; "
+                    "ops/moe.py computes every expert it holds on one device")
+            layers.update(router=ns(None, None, None),
+                          we_gate_up=ns(None, None, None, None),
+                          we_down=ns(None, None, None, None))
+            if cfg.d_shared_expert:
+                layers.update(ws_gate=ns(None, None, None), ws_up=ns(None, None, None),
+                              ws_down=ns(None, None, None))
+        else:
+            layers.update(w_gate=ns(None, None, axis), w_up=ns(None, None, axis),
+                          w_down=ns(None, axis, None))
+        return layers
+
+    stacks = [stack(False)] if cfg is None else [
+        stack(c.mlp == SPARSE) for c in layer_classes(cfg)]
+    out = {"embed": ns(None, axis), "final_norm": ns(None), "layers": _from_stacks(stacks)}
     if cfg is not None and not cfg.tie_embeddings:
         out["unembed"] = ns(None, axis)
     return out
@@ -1561,7 +1892,7 @@ def _seq_local_body(
             scale=cfg.query_pre_attn_scalar ** -0.5,
             softcap=cfg.attn_softcap, sliding_window=cfg.sliding_window,
             is_local=kind.is_local,
-        ).reshape(B, Sl, cfg.n_heads * cfg.head_dim)
+        ).reshape(B, Sl, -1)
 
     (resid, buf), _ = _scan_blocks(
         params, cfg, cap_layers,
@@ -1690,51 +2021,72 @@ def from_torch_state_dict(
             sh = sh[k]
         return _put_global(arr, sh)
 
-    def stack(key: str, fmt: str, transpose: bool) -> jax.Array:
-        mats = [get(fmt.format(i)) for i in range(cfg.n_layers)]
-        arr = np.stack([m.T if transpose else m for m in mats])
-        return leaf(("layers", key), arr)
-
+    classes = layer_classes(cfg)
     p = "model.layers.{}."
-    layers = {
-        "attn_norm": stack("attn_norm", p + "input_layernorm.weight", False),
-        "wq": stack("wq", p + "self_attn.q_proj.weight", True),
-        "wk": stack("wk", p + "self_attn.k_proj.weight", True),
-        "wv": stack("wv", p + "self_attn.v_proj.weight", True),
-        "wo": stack("wo", p + "self_attn.o_proj.weight", True),
-    }
-    if cfg.block_style == "sandwich":
-        layers.update(
-            post_attn_norm=stack("post_attn_norm", p + "post_attention_layernorm.weight", False),
-            pre_ffw_norm=stack("pre_ffw_norm", p + "pre_feedforward_layernorm.weight", False),
-            post_ffw_norm=stack("post_ffw_norm", p + "post_feedforward_layernorm.weight", False),
-        )
-    else:
-        # the pre-norm families' second norm goes by this name (Llama's
-        # convention; ASSUMED for Mellum2: no checkpoint was read here)
-        layers["pre_ffw_norm"] = stack(
-            "pre_ffw_norm", p + "post_attention_layernorm.weight", False)
-    if cfg.sparse:
-        # ASSUMED key names (the Mixtral/Qwen-MoE convention; no Mellum2
-        # checkpoint was read here): ``mlp.gate.weight`` [E, D] and, per
-        # expert e, ``mlp.experts.{e}.{gate,up,down}_proj.weight``
-        def experts(fmt: str) -> np.ndarray:      # -> [L, E, in, out]
-            return np.stack([
-                np.stack([get((p + fmt).format(i, e)).T for e in range(cfg.n_experts)])
-                for i in range(cfg.n_layers)])
 
-        layers["router"] = stack("router", p + "mlp.gate.weight", True)
-        layers["we_gate_up"] = leaf(("layers", "we_gate_up"), np.concatenate(
-            [experts("mlp.experts.{}.gate_proj.weight"),
-             experts("mlp.experts.{}.up_proj.weight")], axis=-1))
-        layers["we_down"] = leaf(
-            ("layers", "we_down"), experts("mlp.experts.{}.down_proj.weight"))
-    else:
-        layers.update(
-            w_gate=stack("w_gate", p + "mlp.gate_proj.weight", True),
-            w_up=stack("w_up", p + "mlp.up_proj.weight", True),
-            w_down=stack("w_down", p + "mlp.down_proj.weight", True),
-        )
+    def stack_of(c: int, cls: LayerClass) -> dict:
+        where = ("layers",) if len(classes) == 1 else ("layers", c)
+
+        def stack(key: str, fmt: str, transpose: bool) -> jax.Array:
+            mats = [get(fmt.format(i)) for i in cls.layers]
+            arr = np.stack([m.T if transpose else m for m in mats])
+            return leaf((*where, key), arr)
+
+        layers = {
+            "attn_norm": stack("attn_norm", p + "input_layernorm.weight", False),
+            "wq": stack("wq", p + "self_attn.q_proj.weight", True),
+            "wk": stack("wk", p + "self_attn.k_proj.weight", True),
+            "wv": stack("wv", p + "self_attn.v_proj.weight", True),
+            "wo": stack("wo", p + "self_attn.o_proj.weight", True),
+        }
+        if cfg.attn_gate == "per_head":
+            # ASSUMED name (no Laguna checkpoint was read here)
+            layers["w_attn_gate"] = stack("w_attn_gate", p + "self_attn.g_proj.weight", True)
+        if cfg.block_style == "sandwich":
+            layers.update(
+                post_attn_norm=stack("post_attn_norm", p + "post_attention_layernorm.weight", False),
+                pre_ffw_norm=stack("pre_ffw_norm", p + "pre_feedforward_layernorm.weight", False),
+                post_ffw_norm=stack("post_ffw_norm", p + "post_feedforward_layernorm.weight", False),
+            )
+        else:
+            # the pre-norm families' second norm goes by this name (Llama's
+            # convention; ASSUMED for Mellum2: no checkpoint was read here)
+            layers["pre_ffw_norm"] = stack(
+                "pre_ffw_norm", p + "post_attention_layernorm.weight", False)
+        if cls.mlp == SPARSE:
+            # ASSUMED key names (the Mixtral/Qwen-MoE convention; no Mellum2
+            # or Laguna checkpoint was read here): ``mlp.gate.weight`` [E, D]
+            # and, per expert e, ``mlp.experts.{e}.{gate,up,down}_proj.weight``
+            # (the whole model's names; the chip's share of them is kept),
+            # the shared expert ``mlp.shared_expert.{gate,up,down}_proj.weight``
+            held = range(cfg.first_expert, cfg.first_expert + cfg.n_held)
+
+            def experts(fmt: str) -> np.ndarray:      # -> [L, E_held, in, out]
+                return np.stack([
+                    np.stack([get((p + fmt).format(i, e)).T for e in held])
+                    for i in cls.layers])
+
+            layers["router"] = stack("router", p + "mlp.gate.weight", True)
+            layers["we_gate_up"] = leaf((*where, "we_gate_up"), np.concatenate(
+                [experts("mlp.experts.{}.gate_proj.weight"),
+                 experts("mlp.experts.{}.up_proj.weight")], axis=-1))
+            layers["we_down"] = leaf(
+                (*where, "we_down"), experts("mlp.experts.{}.down_proj.weight"))
+            if cfg.d_shared_expert:
+                layers.update(
+                    ws_gate=stack("ws_gate", p + "mlp.shared_expert.gate_proj.weight", True),
+                    ws_up=stack("ws_up", p + "mlp.shared_expert.up_proj.weight", True),
+                    ws_down=stack("ws_down", p + "mlp.shared_expert.down_proj.weight", True),
+                )
+        else:
+            layers.update(
+                w_gate=stack("w_gate", p + "mlp.gate_proj.weight", True),
+                w_up=stack("w_up", p + "mlp.up_proj.weight", True),
+                w_down=stack("w_down", p + "mlp.down_proj.weight", True),
+            )
+        return layers
+
+    layers = _from_stacks([stack_of(c, cls) for c, cls in enumerate(classes)])
     params = {
         "embed": leaf(("embed",), get("model.embed_tokens.weight")),
         "final_norm": leaf(("final_norm",), get("model.norm.weight")),

@@ -6,6 +6,15 @@ by their sum where ``norm_topk_prob``. Every routed row is computed, none is
 dropped, there is no capacity factor: the (token, slot) rows are GROUPED by
 expert and each group meets only its own expert's weights.
 
+A chip may hold a SHARE of the layer's experts (``first_expert`` and the
+expert leaves' own count, fewer than the router's width): the router keeps
+its whole width and its k a token, rows whose expert is absent are neither
+gathered into tiles nor multiplied and count 0 in the combine, and every row
+of a held expert is computed. The result is this chip's part of the sum;
+nothing stands in for the absent chips. The row buffers keep their static
+bound (every token may choose k held experts). With every expert held the
+layer is the whole one, op for op.
+
 The router's product accumulates in float32 and its softmax, top-k and gates
 are float32 whatever the model's dtype: in bf16 near-ties between experts
 flip, and a flipped expert is an O(1) change of that token's output.
@@ -92,14 +101,27 @@ def supported(d_model: int, d_expert: int, dtype) -> bool:
 
 def route(
     x: jax.Array, w_router: jax.Array, top_k: int, norm_topk_prob: bool,
+    routed_scale: float = 1.0,
 ) -> tuple[jax.Array, jax.Array]:
     """``x [T, D]`` → the chosen experts ``[T, k]`` int32 (largest gate
-    first; ties to the lowest index) and their gates ``[T, k]`` float32."""
+    first; ties to the lowest index) and their gates ``[T, k]`` float32
+    (times ``routed_scale``, after the renormalisation)."""
     logits = jnp.einsum("td,de->te", x, w_router, preferred_element_type=jnp.float32)
     gates, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
     if norm_topk_prob:
         gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    if routed_scale != 1.0:
+        gates = gates * routed_scale
     return idx.astype(jnp.int32), gates
+
+
+def _held(idx: jax.Array, gates: jax.Array, first_expert: int, n_held: int):
+    """The routing as this chip's share sees it: expert ids relative to the
+    share, an absent expert's rows under the id ``n_held`` (they sort past
+    every held group, and no group counts them) with gate 0."""
+    local = idx - first_expert
+    held = (local >= 0) & (local < n_held)
+    return jnp.where(held, local, n_held), jnp.where(held, gates, 0.0)
 
 
 def _by_expert(idx: jax.Array, n_experts: int):
@@ -128,17 +150,21 @@ def _combine(y_slots: jax.Array, gates: jax.Array) -> jax.Array:
     return out.astype(y_slots.dtype)
 
 
-def _experts_ragged(x, idx, gates, w_gate_up, w_down, layer):
-    """The XLA form: sorted rows through ``ragged_dot``."""
+def _experts_ragged(x, idx, gates, w_gate_up, w_down, layer, share=False):
+    """The XLA form: sorted rows through ``ragged_dot``. Under a ``share``
+    the absent rows lie past the last group, where ``ragged_dot`` multiplies
+    nothing; what it leaves there is replaced by 0."""
     T, k = idx.shape
     w_gate_up, w_down = w_gate_up[layer], w_down[layer]
     F = w_down.shape[1]
-    _, order, sizes = _by_expert(idx, w_down.shape[0])
+    experts, order, sizes = _by_expert(idx, w_down.shape[0])
     inverse = _unsort(order, jnp.arange(T * k, dtype=jnp.int32))
     xs = x[order // k]                                          # [N, D]
     gu = jax.lax.ragged_dot(xs, w_gate_up, sizes, preferred_element_type=jnp.float32)
     h = (jax.nn.silu(gu[:, :F]) * gu[:, F:]).astype(x.dtype)
     y = jax.lax.ragged_dot(h, w_down, sizes, preferred_element_type=jnp.float32)
+    if share:
+        y = jnp.where((experts < w_down.shape[0])[:, None], y, 0.0)
     return _combine(y.astype(x.dtype)[inverse].reshape(T, k, -1), gates)
 
 
@@ -214,10 +240,15 @@ def _tile_call(kernel, name, prefetch, rows, weights, w_specs, n_out,
     )(*prefetch, rows, *weights)
 
 
-def _tile_layout(idx, n_experts):
+def _tile_layout(idx, n_experts, share=False):
     """The expert-aligned layout of the routed rows ``idx [T, k]``: each
     tile's expert ``[n_tiles]``, the number of real tiles ``[1]``, the token
-    each tiled row holds ``[M]`` and each flat slot's tiled row ``[T·k]``."""
+    each tiled row holds ``[M]`` and each flat slot's tiled row ``[T·k]``.
+
+    Under a ``share`` (ids of :func:`_held`) only the held groups get tiles.
+    The absent rows' slots point at tiled row 0 (their gate is 0, so the
+    combine reads a finite row and adds nothing), and the first tile counts
+    as real even where no row is held, so that row 0 is always written."""
     T, k = idx.shape
     E, N, tm = n_experts, T * k, TILE_ROWS
     experts, order, sizes = _by_expert(idx, E)
@@ -227,11 +258,15 @@ def _tile_layout(idx, n_experts):
     shift = p_end - padded - start                  # tiled row - sorted position
     n_tiles = (N + E * (tm - 1)) // tm              # static bound on Σ⌈size/tm⌉
     n_valid = p_end[-1:] // tm                      # [1]
+    if share:
+        n_valid = jnp.maximum(n_valid, 1)
     tile = jnp.arange(n_tiles, dtype=jnp.int32)
     # a tile past the end keeps the last real tile's expert: no new weights
     tile_expert = jnp.searchsorted(
         p_end, jnp.minimum(tile, n_valid[0] - 1) * tm, side="right"
     ).astype(jnp.int32)
+    if share:       # (no row held at all: the first tile is some held expert's)
+        tile_expert = jnp.minimum(tile_expert, E - 1)
     # tiled row -> the sorted row it holds: its tile's shift, taken once a
     # tile (a padding row holds some other row again; nothing reads its result)
     sorted_pos = jnp.clip(
@@ -242,10 +277,12 @@ def _tile_layout(idx, n_experts):
     mine = experts[:, None] == jnp.arange(E, dtype=jnp.int32)[None, :]
     dest = jnp.arange(N, dtype=jnp.int32) + jnp.sum(
         jnp.where(mine, shift[None, :], 0), axis=1)
+    if share:
+        dest = jnp.where(experts < E, dest, 0)
     return tile_expert, n_valid, (order // k)[sorted_pos], _unsort(order, dest)
 
 
-def _experts_tiles(x, idx, gates, w_gate_up, w_down, layer):
+def _experts_tiles(x, idx, gates, w_gate_up, w_down, layer, share=False):
     """The kernel form: expert-aligned row tiles; the weights stay stacked
     ``[L, E, ...]`` and a block is fetched by (layer, the tile's expert).
     Where the row-gather kernel takes the shape (``row_gather.supported``)
@@ -256,7 +293,7 @@ def _experts_tiles(x, idx, gates, w_gate_up, w_down, layer):
 
     T, k = idx.shape
     _, E, F, D = w_down.shape
-    tile_expert, n_valid, token, rows = _tile_layout(idx, E)
+    tile_expert, n_valid, token, rows = _tile_layout(idx, E, share)
     xs = x[token]                                               # [M, D]
     kernel = row_gather.supported(T, k, D, x.dtype)
     obs.count("harvest/moe_combine_kernel_traces" if kernel
@@ -282,27 +319,34 @@ def _experts_tiles(x, idx, gates, w_gate_up, w_down, layer):
 
 def moe_mlp(
     x: jax.Array, w_router: jax.Array, w_gate_up: jax.Array, w_down: jax.Array,
-    layer=0, *, top_k: int, norm_topk_prob: bool,
+    layer=0, *, top_k: int, norm_topk_prob: bool, routed_scale: float = 1.0,
+    first_expert: int = 0,
 ) -> jax.Array:
     """The expert layer ``layer`` on the normed stream ``x [B, S, D]``:
     ``w_router [D, E]`` (that layer's), and the STACKED expert weights
-    ``w_gate_up [L, E, D, 2·F]`` (gate columns first) and ``w_down
-    [L, E, F, D]``, indexed in place by ``layer`` (traced or not): one
+    ``w_gate_up [L, E_held, D, 2·F]`` (gate columns first) and ``w_down
+    [L, E_held, F, D]``, indexed in place by ``layer`` (traced or not): one
     layer's experts are too large to slice out for a kernel → ``[B, S, D]``
-    in ``x``'s dtype."""
+    in ``x``'s dtype. Where ``E_held < E`` the leaves are the experts
+    ``[first_expert, first_expert + E_held)`` and the result is their part
+    of the routed sum (the module's docstring)."""
     from crosscoder_tpu import obs
 
     B, S, D = x.shape
     x2 = x.reshape(B * S, D)
+    share = w_down.shape[1] < w_router.shape[1]
     with jax.named_scope("harvest/block/moe/route"):
-        idx, gates = route(x2, w_router, top_k, norm_topk_prob)
+        idx, gates = route(x2, w_router, top_k, norm_topk_prob, routed_scale)
+        if share:
+            obs.count("harvest/moe_held_traces")
+            idx, gates = _held(idx, gates, first_expert, w_down.shape[1])
     with jax.named_scope("harvest/block/moe/experts"):
         if enabled() and supported(D, w_down.shape[2], x.dtype):
             obs.count("harvest/moe_tiles_traces")
-            out = _experts_tiles(x2, idx, gates, w_gate_up, w_down, layer)
+            out = _experts_tiles(x2, idx, gates, w_gate_up, w_down, layer, share)
         else:
             obs.count("harvest/moe_ragged_traces")
-            out = _experts_ragged(x2, idx, gates, w_gate_up, w_down, layer)
+            out = _experts_ragged(x2, idx, gates, w_gate_up, w_down, layer, share)
     return out.reshape(B, S, D)
 
 
@@ -311,3 +355,12 @@ def load_max_over_mean(counts: jax.Array) -> float:
     counts: 1.0 is perfectly even routing, E one expert taking all."""
     counts = np.asarray(counts, np.float64)
     return float(np.max(counts / np.mean(counts, axis=-1, keepdims=True)))
+
+
+def local_row_share(counts: jax.Array, first_expert: int, n_held: int) -> float:
+    """Routed rows that go to the held experts over all routed rows, from
+    ``[..., E]`` counts, the mean over the leading axes: ``n_held / E`` where
+    routing is even."""
+    counts = np.asarray(counts, np.float64)
+    held = counts[..., first_expert:first_expert + n_held].sum(-1)
+    return float(np.mean(held / counts.sum(-1)))

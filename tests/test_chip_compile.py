@@ -355,6 +355,58 @@ def test_sparse_expert_harvest_segment_compiles(chip, one_device):
                 and (" copy(" in line or "dynamic-slice(" in line)]
 
 
+@pytest.mark.parametrize("cls,k", [(0, 1), (1, 3), (2, 1)],
+                         ids=["layer0-dense-48h", "window-sparse-72h", "full-sparse-48h"])
+def test_laguna_harvest_segments_compile(chip, one_device, cls, k):
+    """The fourth cell's three refill quanta at its published widths, two
+    4096-token sequences a forward (``benchmarks/configs/
+    laguna-s2.1-pair-relu16k.json``): one program a class of layers — layer
+    0 (48 heads, the full instance of the fused attention, the dense MLP of
+    12,288), the three window layers (72 heads, the 512-window instance, no
+    ``cond``: the class's kind is static) and the full sparse layer — the
+    sparse ones with the expert layer's three kernels over the HELD 32
+    experts of 3072 x 1024, whose stacked weights reach them whole."""
+    import sys
+    from pathlib import Path
+
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+    from benchmarks import manifest
+    from benchmarks.arch import laguna
+    from crosscoder_tpu.ops import flash_attention as fa
+    from crosscoder_tpu.ops import moe, row_gather
+
+    cfg = laguna.lm_config(manifest.load_json(
+        manifest.BENCH_DIR / "configs" / "laguna-s2.1-pair-relu16k.json"))
+    B, S = 2, 4096
+    heads = lm.layer_classes(cfg)[cls].n_heads
+    assert fa.supported(S, heads, cfg.n_kv_heads, cfg.head_dim, jnp.bfloat16)
+    assert moe.enabled() and moe.supported(cfg.d_model, cfg.d_expert, jnp.bfloat16)
+    assert row_gather.supported(B * S, cfg.experts_per_tok, cfg.d_model, jnp.bfloat16)
+    params = _abstract(jax.eval_shape(
+        lambda key: lm.init_params(key, cfg), jax.random.key(0)), chip)
+    compiled = lm._seg_scan_impl.lower(
+        params, _sds((B, S, cfg.d_model), jnp.bfloat16, chip),
+        _sds((1, B, S, cfg.d_model), jnp.bfloat16, chip),
+        _sds((), jnp.int32, chip), cfg=cfg,
+        capture=lm._hook_layers(cfg, ("blocks.5.hook_resid_pre",)), k=k, cls=cls,
+    ).compile()
+    _fits(compiled)
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert sum("fused_causal_attention" in c.split(" = ")[0] for c in calls) == 1   # no cond
+    assert " conditional(" not in text
+    if cls == 0:
+        assert "moe_" not in text
+        return
+    for name in ("moe_gate_up", "moe_down", "expert_combine"):
+        assert any(name in c.split(" = ")[0] for c in calls), name
+    # no layer's held experts are sliced out or copied on the way to the kernels
+    stacked = f"bf16[{len(lm.layer_classes(cfg)[cls].layers)},{cfg.n_held},"
+    assert not [line for line in text.splitlines()
+                if stacked in line.split(" = ")[-1][:60]
+                and (" copy(" in line or "dynamic-slice(" in line)]
+
+
 @pytest.mark.parametrize("window", [0, 4096])
 def test_paged_attention_family_compiles(chip, gates_open, window):
     """Gemma-2-2B heads (8 Q / 4 KV × 256), global and sliding-window."""

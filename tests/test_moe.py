@@ -252,3 +252,121 @@ def test_load_max_over_mean():
     one_hot = np.zeros((2, E)); one_hot[:, 3] = 10
     assert moe.load_max_over_mean(one_hot) == float(E)
     assert moe.load_max_over_mean([[1, 1, 2, 0], [1, 1, 1, 1]]) == 2.0
+
+
+# ---------------------------------------------------------------------------
+# a share of the experts held (PR 33): the router keeps its width, the rows
+# of absent experts are neither grouped nor multiplied and count 0
+
+
+def _share_reference(x, w_router, w_gate_up, w_down, layer, first, n_held, scale):
+    """The plain loop over the HELD experts only, gates from the whole
+    router (``mellum_ref``'s pieces; none of the program's)."""
+    with jax.default_matmul_precision("highest"):
+        x2 = x.reshape(-1, D)
+        chosen, gates = mellum_ref.routing(x2, w_router, K, True)
+        out = jnp.zeros_like(x2)
+        for e in range(first, first + n_held):
+            out = out + mellum_ref._expert_term(
+                x2, chosen, gates * scale, w_gate_up, w_down, np.int32(layer), np.int32(e))
+        return out.reshape(x.shape)
+
+
+@pytest.mark.parametrize("tokens", [300, 64], ids=["300tok", "64tok"])
+@pytest.mark.parametrize("skew", [False, True], ids=["even", "skewed"])
+@pytest.mark.parametrize("form", ["ragged", "tiles"])
+def test_a_held_share_matches_the_plain_loop_over_the_held_experts(form, skew, tokens, request):
+    """Each of four ranks' two experts, in both forms: every row of a held
+    expert is computed, none of an absent one; under the skewed router rank
+    0 (experts 0, 1) holds NO routed row at all and rank 2 (experts 4, 5)
+    nearly every one. The ranks' parts add up to the whole layer."""
+    if form == "tiles":
+        request.getfixturevalue("interpret")
+    w_router, w_gate_up, w_down = _layer(11, skew)
+    x = _x(11, tokens, skew)
+    whole = moe.moe_mlp(x, w_router, w_gate_up, w_down, jnp.int32(1), top_k=K,
+                        norm_topk_prob=True, routed_scale=2.5)
+    np.testing.assert_allclose(
+        np.asarray(whole), np.asarray(_share_reference(x, w_router, w_gate_up, w_down, 1, 0, E, 2.5)),
+        rtol=0, atol=2.5 * ATOL)
+    total = jnp.zeros_like(whole)
+    for rank in range(4):
+        held = slice(2 * rank, 2 * rank + 2)
+        got = moe.moe_mlp(x, w_router, w_gate_up[:, held], w_down[:, held], jnp.int32(1),
+                          top_k=K, norm_topk_prob=True, routed_scale=2.5, first_expert=2 * rank)
+        want = _share_reference(x, w_router, w_gate_up, w_down, 1, 2 * rank, 2, 2.5)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0, atol=2.5 * ATOL)
+        assert bool(jnp.isfinite(got).all())
+        if skew and rank == 0:
+            assert not np.asarray(got).any()        # no row held: exactly nothing
+        total = total + got
+    np.testing.assert_allclose(np.asarray(total), np.asarray(whole), rtol=0, atol=5 * ATOL)
+
+
+@pytest.mark.parametrize("skew", [False, True], ids=["even", "skewed"])
+def test_both_forms_agree_under_a_share_with_the_combine_kernel(skew, interpret):
+    """bf16 rows at a shape the combine kernel takes, a share of 2 of 8
+    experts: the tile form (absent slots point at tiled row 0 with gate 0)
+    against ``_experts_ragged`` on the same held routing; the tolerance is
+    ``test_bf16_tile_form_with_the_combine_kernel_matches_ragged``'s."""
+    tokens = 300
+    ks = jax.random.split(jax.random.key(23), 3)
+    w_router = _layer(23, skew)[0]
+    x = jax.random.normal(ks[2], (tokens, DC), jnp.bfloat16)
+    idx, gates = moe.route(_x(23, tokens, skew)[0], w_router, K, True, 2.5)
+    for first in (0, 4):
+        w_gate_up = (jax.random.normal(ks[0], (L, 2, DC, 2 * F)) * DC ** -0.5).astype(jnp.bfloat16)
+        w_down = (jax.random.normal(ks[1], (L, 2, F, DC)) * F ** -0.5).astype(jnp.bfloat16)
+        local, held_gates = moe._held(idx, gates, first, 2)
+        assert int(local.max()) == 2 and float(held_gates[local == 2].sum()) == 0.0
+        assert row_gather.supported(tokens, K, DC, x.dtype)
+        got = moe._experts_tiles(x, local, held_gates, w_gate_up, w_down, jnp.int32(1), True)
+        want = moe._experts_ragged(x, local, held_gates, w_gate_up, w_down, jnp.int32(1), True)
+        got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+        assert np.isfinite(got).all()
+        assert np.abs(got - want).max() <= 2.5 * 6.3e-2
+        assert np.median(np.abs(got - want)) <= 2.5 * 4e-3
+        # the layout: only held groups get tiles; absent slots read row 0
+        tile_expert, n_valid, token, rows = moe._tile_layout(local, 2, True)
+        absent = np.asarray(local).reshape(-1) == 2
+        assert (np.asarray(rows)[absent] == 0).all() and int(tile_expert.max()) <= 1
+        sizes = np.bincount(np.asarray(local).reshape(-1), minlength=3)[:2]
+        assert int(n_valid[0]) == max(int(np.ceil(sizes / moe.TILE_ROWS).sum()), 1)
+        assert len(set(np.asarray(rows)[~absent])) == (~absent).sum()   # no held row dropped
+
+
+def test_every_expert_held_is_the_whole_layer_op_for_op(tmp_path):
+    """With as many expert leaves as the router is wide, ``moe_mlp`` traces
+    what it traced before a share could be held (no ``_held``, no clamp, no
+    mask, no scale) and the held form is not counted."""
+    w_router, w_gate_up, w_down = _layer(5, False)
+    x = _x(5, 64, False)
+
+    def whole(x):
+        return moe.moe_mlp(x, w_router, w_gate_up, w_down, 0, top_k=K, norm_topk_prob=True)
+
+    def by_hand(x):     # the layer as PR 29 wrote it
+        x2 = x.reshape(-1, D)
+        idx, gates = moe.route(x2, w_router, K, True)
+        return moe._experts_ragged(x2, idx, gates, w_gate_up, w_down, 0).reshape(x.shape)
+
+    strip = lambda j: [str(e.primitive) for e in j.jaxpr.eqns]     # noqa: E731
+    flat = lambda f: strip(jax.make_jaxpr(f)(x))                    # noqa: E731
+    assert flat(whole) == flat(by_hand)
+    cfg = CrossCoderConfig(obs="on", obs_dir=str(tmp_path / "obs"), log_backend="null")
+    plane = obs.acquire(cfg)
+    try:
+        whole(x)
+        assert plane.registry.get_count("harvest/moe_held_traces") == 0
+        moe.moe_mlp(x, w_router, w_gate_up[:, :4], w_down[:, :4], 0, top_k=K,
+                    norm_topk_prob=True, first_expert=4)
+        assert plane.registry.get_count("harvest/moe_held_traces") == 1
+    finally:
+        plane.close()
+
+
+def test_local_row_share():
+    counts = np.array([[4, 4, 4, 4, 0, 0, 0, 0], [1, 1, 1, 1, 1, 1, 1, 1]])
+    assert moe.local_row_share(counts, 0, 4) == pytest.approx(0.75)
+    assert moe.local_row_share(counts, 4, 4) == pytest.approx(0.25)
+    assert moe.local_row_share(counts, 0, 8) == 1.0
