@@ -694,3 +694,167 @@ def grouped_sums(idx, cv, cd, g, x, n_out, *, name, interpret=False):
         interpret=interpret,
     )(chunk, tile, n_valid, tok, dst, cv, cd, rows)
     return out_d, out_e, out_b[:, 0]
+
+
+# ---------------------------------------------------------------------------
+# token-major over a COMPACTED table: only the pairs that are really there
+#
+# (Described here and not in the module's docstring: a Pallas payload carries
+# line numbers, and a line added above the kernels of the TopK step would
+# compile them again on every tree's first run.) :func:`weighted_sum` where
+# most of the table's slots name no row — a chip that holds a share of the
+# experts: the pairs that are there lie compacted at the front of the table
+# in token order, their number known to the device alone; a grid over tiles
+# of tokens, each walking the chunks of pairs that name its tokens by a loop
+# of dynamic length, the per-token sums formed on the MXU as the latent-major
+# pass forms its sums. Nothing is fetched or summed for a pair that is not
+# there.
+
+
+def unpack_words(words):
+    """Packed words → the float32 values of their low and high halves (a
+    row's column ``j`` and ``j + D/2``)."""
+    return (jax.lax.bitcast_convert_type(words << 16, jnp.float32),
+            jax.lax.bitcast_convert_type(words & jnp.uint32(0xFFFF0000), jnp.float32))
+
+
+def held_supported(n_tokens: int, top_k: int, d: int, dtype) -> bool:
+    """Shapes :func:`held_sums` handles: :func:`supported`'s, with the
+    ``[T·k]`` pair table WHOLE within SMEM — it cannot be cut into slices of
+    the batch, since where a slice's pairs lie is known only on the device."""
+    pairs = -(-n_tokens * top_k // PAIRS) * PAIRS
+    return supported(n_tokens, top_k, d, dtype) and 4 * pairs <= _SMEM_TABLE_BYTES
+
+
+def _held_kernel(starts_ref, live_ref, rows_ref, tok_ref, w_ref, y_ref, o_ref,
+                 buf, acc, sem):
+    """One tile of tokens against the chunks of :data:`PAIRS` sorted pairs
+    that name its tokens — a dynamic count, walked by a loop: pairs past the
+    live ones are neither fetched nor summed. A chunk's rows are due at its
+    first visit (a chunk that straddles two tiles is fetched once), when the
+    next live chunk's copies go out. The per-token sums are MXU products
+    with the selection matrix ``S[l, p] = w[p] · (tok[p] == tile·dt + l)``,
+    as :func:`_grouped_kernel` forms them — but the weights stay float32: each
+    is split into three bf16 parts that add up to it exactly, so every
+    product is exact and the sums are float32 sums of ``w · row``. The lane
+    tiles are written out, up to :data:`_UNROLLED_BODIES` products a visit
+    (0.48 against 0.69 ms at the laguna cell's shape; the next chunk's
+    copies started among them: 0.57 — PERF.md §6, PR 34)."""
+    C = PAIRS
+    dt, d = o_ref.shape
+    half = d // 2
+    W = half // LANES                           # lane tiles of words a row
+    i = pl.program_id(0)
+    s0, s1 = starts_ref[i], starts_ref[i + 1]   # the tile's sorted pairs
+    n_live = live_ref[0]
+    shift = C.bit_length() - 1
+    c0 = jax.lax.shift_right_arithmetic(s0, shift)
+    seen = jax.lax.shift_right_arithmetic(s0 - 1, shift)    # the last chunk visited (-1: none)
+    n = jnp.where(s1 > s0, jax.lax.shift_right_arithmetic(s1 - 1, shift) - c0 + 1, 0)
+    tiles = buf.reshape(2 * C * W, LANES)
+
+    def fetch(c, slot):
+        def some(m, carry):
+            def one(q, carry):
+                p = m * 8 + q
+                pltpu.make_async_copy(
+                    y_ref.at[pl.ds(rows_ref[c * C + p] * W, W)],
+                    buf.at[slot, pl.ds(p * W, W)], sem.at[slot]).start()
+                return carry
+            # eight written out an iteration, when the kernel is LOWERED
+            return jax.lax.fori_loop(0, 8, one, carry, unroll=True)
+        jax.lax.fori_loop(0, C // 8, some, 0)
+
+    @pl.when((i == 0) & (n_live > 0))
+    def _():
+        fetch(0, 0)
+
+    acc[...] = jnp.zeros_like(acc)
+
+    def visit(j, carry):
+        c = c0 + j
+        slot = jax.lax.rem(c, 2)
+        first = c > seen
+
+        @pl.when(first)
+        def _():    # one wait for the chunk's C copies: the semaphore counts bytes
+            pltpu.make_async_copy(buf.at[slot], buf.at[slot], sem.at[slot]).wait()
+
+        @pl.when(first & ((c + 1) * C < n_live))
+        def _():
+            fetch(c + 1, 1 - slot)
+
+        w = w_ref[pl.ds(c, 1), :]                               # [1, C] float32
+        w1 = w.astype(jnp.bfloat16).astype(jnp.float32)
+        w2 = (w - w1).astype(jnp.bfloat16).astype(jnp.float32)
+        w3 = w - w1 - w2                        # what is left fits bf16: w = w1 + w2 + w3
+        hit = (tok_ref[pl.ds(c, 1), :] - i * dt) == jax.lax.broadcasted_iota(
+            jnp.int32, (dt, C), 0)
+        sel = jnp.concatenate(
+            [jnp.where(hit, part, 0.0).astype(jnp.bfloat16) for part in (w1, w2, w3)],
+            axis=0)                                             # [3·dt, C]
+
+        def lane_tile(q, carry):
+            lo, hi = unpack_words(tiles[pl.ds(slot * C * W + q, C, stride=W), :])  # [C, 128]
+            for k, part in ((q, lo), (W + q, hi)):
+                p = jnp.dot(sel, part.astype(jnp.bfloat16),
+                            preferred_element_type=jnp.float32)
+                acc[k] += p[:dt] + p[dt:2 * dt] + p[2 * dt:]
+            return carry
+        return jax.lax.fori_loop(0, W, lane_tile, carry, unroll=2 * W <= _UNROLLED_BODIES)
+
+    jax.lax.fori_loop(0, n, visit, 0)
+
+    def leave(q, carry):        # round once
+        col = pl.multiple_of(q * LANES, LANES)
+        o_ref[:, pl.ds(col, LANES)] = acc[q].astype(o_ref.dtype)
+        o_ref[:, pl.ds(half + col, LANES)] = acc[W + q].astype(o_ref.dtype)
+        return carry
+    jax.lax.fori_loop(0, W, leave, 0)
+
+
+def held_sums(rows, tokens, weights, n_live, y_packed, n_tokens, d, *, name,
+              interpret=False, out_dtype=jnp.bfloat16):
+    """``out[t] = Σ_{p < n_live, tokens[p] = t} weights[p] · y[rows[p]]`` →
+    ``[n_tokens, D]``: :func:`weighted_sum` over a table of PAIRS — ``rows
+    [P]`` (a row of ``y_packed``), ``tokens [P]`` (ascending over the live
+    pairs), ``weights [P]`` float32 — of which only the first ``n_live [1]``
+    are there, a count the device holds. Rows are fetched and summed for the
+    live pairs alone (up to the last live chunk's end, whose dead pairs fetch
+    row 0 and add nothing); a token no live pair names gets exactly 0.
+    Float32 weights, float32 sums of the bf16 rows, one rounding."""
+    P = rows.shape[0]
+    C = PAIRS
+    W = d // 2 // LANES
+    dt = min(_MAX_TOKENS, n_tokens // GROUP * GROUP)
+    n_tiles = pl.cdiv(n_tokens, dt)
+    live = jnp.arange(P, dtype=jnp.int32) < n_live[0]
+    pad = -P % C
+    # a dead pair names no token of any tile, and a row that is always there
+    tokens = jnp.pad(jnp.where(live, tokens, 1 << 30), (0, pad), constant_values=1 << 30)
+    rows = jnp.pad(jnp.where(live, rows, 0), (0, pad))
+    weights = jnp.pad(weights.astype(jnp.float32), (0, pad))
+    starts = jnp.searchsorted(
+        tokens, jnp.arange(n_tiles + 1, dtype=jnp.int32) * dt, side="left"
+    ).astype(jnp.int32)
+    n_chunks = (P + pad) // C
+    whole = pl.BlockSpec((n_chunks, C), lambda i, *_: (0, 0))   # fetched once
+    return pl.pallas_call(
+        _held_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(n_tiles,),
+            in_specs=[whole, whole, pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((dt, d), lambda i, *_: (i, 0)),
+            scratch_shapes=[pltpu.VMEM((2, C * W, 1, LANES), jnp.uint32),
+                            pltpu.VMEM((2 * W, dt, LANES), jnp.float32),
+                            pltpu.SemaphoreType.DMA((2,))],
+        ),
+        out_shape=jax.ShapeDtypeStruct((n_tokens, d), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        name=name,
+        interpret=interpret,
+    )(starts, n_live.astype(jnp.int32), rows,
+      tokens.reshape(n_chunks, C), weights.reshape(n_chunks, C), y_packed)

@@ -305,10 +305,10 @@ def test_a_held_share_matches_the_plain_loop_over_the_held_experts(form, skew, t
 
 @pytest.mark.parametrize("skew", [False, True], ids=["even", "skewed"])
 def test_both_forms_agree_under_a_share_with_the_combine_kernel(skew, interpret):
-    """bf16 rows at a shape the combine kernel takes, a share of 2 of 8
-    experts: the tile form (absent slots point at tiled row 0 with gate 0)
-    against ``_experts_ragged`` on the same held routing; the tolerance is
-    ``test_bf16_tile_form_with_the_combine_kernel_matches_ragged``'s."""
+    """bf16 rows at a shape the row kernels take, a share of 2 of 8 experts:
+    the tile form (x's rows fetched for the real tiles, the combine over the
+    held pairs alone) against ``_experts_ragged`` on the same held routing;
+    the tolerance is ``test_bf16_tile_form_with_the_combine_kernel_matches_ragged``'s."""
     tokens = 300
     ks = jax.random.split(jax.random.key(23), 3)
     w_router = _layer(23, skew)[0]
@@ -319,20 +319,164 @@ def test_both_forms_agree_under_a_share_with_the_combine_kernel(skew, interpret)
         w_down = (jax.random.normal(ks[1], (L, 2, F, DC)) * F ** -0.5).astype(jnp.bfloat16)
         local, held_gates = moe._held(idx, gates, first, 2)
         assert int(local.max()) == 2 and float(held_gates[local == 2].sum()) == 0.0
-        assert row_gather.supported(tokens, K, DC, x.dtype)
+        assert row_gather.held_supported(tokens, K, DC, x.dtype)
         got = moe._experts_tiles(x, local, held_gates, w_gate_up, w_down, jnp.int32(1), True)
         want = moe._experts_ragged(x, local, held_gates, w_gate_up, w_down, jnp.int32(1), True)
         got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
         assert np.isfinite(got).all()
         assert np.abs(got - want).max() <= 2.5 * 6.3e-2
         assert np.median(np.abs(got - want)) <= 2.5 * 4e-3
-        # the layout: only held groups get tiles; absent slots read row 0
-        tile_expert, n_valid, token, rows = moe._tile_layout(local, 2, True)
-        absent = np.asarray(local).reshape(-1) == 2
-        assert (np.asarray(rows)[absent] == 0).all() and int(tile_expert.max()) <= 1
-        sizes = np.bincount(np.asarray(local).reshape(-1), minlength=3)[:2]
+        # the layout: only held groups get tiles; the pair table holds the held
+        # (token, slot) pairs alone, in token order, each with its own tiled row
+        tile_expert, n_valid, tile_first, token, rows, toks, weights, n_live = (
+            np.asarray(a) for a in jax.tree.leaves(moe._held_layout(local, held_gates, 2)))
+        flat = np.asarray(local).reshape(-1)
+        absent = flat == 2
+        sizes = np.bincount(flat, minlength=3)[:2]
+        assert int(tile_expert.max()) <= 1
         assert int(n_valid[0]) == max(int(np.ceil(sizes / moe.TILE_ROWS).sum()), 1)
-        assert len(set(np.asarray(rows)[~absent])) == (~absent).sum()   # no held row dropped
+        n = int(n_live[0])
+        assert n == (~absent).sum()                         # no absent slot is fetched ...
+        held_slots = np.flatnonzero(~absent)
+        np.testing.assert_array_equal(toks[:n], held_slots // K)     # ... in token order
+        np.testing.assert_array_equal(weights[:n], np.asarray(held_gates).reshape(-1)[held_slots])
+        assert len(set(rows[:n])) == n                      # ... and no held row dropped
+        # each pair's tiled row lies in a real tile of its own expert, and that
+        # tile fetches the pair's token into that row
+        assert (rows[:n] < int(n_valid[0]) * moe.TILE_ROWS).all()
+        np.testing.assert_array_equal(tile_expert[rows[:n] // moe.TILE_ROWS], flat[held_slots])
+        pos = tile_first[rows[:n] // moe.TILE_ROWS] + rows[:n] % moe.TILE_ROWS
+        np.testing.assert_array_equal(token[pos], toks[:n])
+
+
+def _plain_gate_up(x, tile_expert, n_valid, token, w_gate_up, layer):
+    """``moe_gate_up`` as the whole layer runs it, on rows gathered by XLA."""
+    from jax.experimental import pallas as pl
+
+    d, f = w_gate_up.shape[2], w_gate_up.shape[3] // 2
+    return moe._tile_call(
+        moe._gate_up_kernel, "moe_gate_up", (tile_expert, n_valid, layer), x[token],
+        (w_gate_up, w_gate_up),
+        [pl.BlockSpec((None, None, d, f), moe._w_block(0)),
+         pl.BlockSpec((None, None, d, f), moe._w_block(1))], f)
+
+
+def _rows_in_case(tokens, skew, first, n_held=2, seed=31):
+    ks = jax.random.split(jax.random.key(seed), 2)
+    x = jax.random.normal(ks[0], (tokens, DC), jnp.bfloat16)
+    w_gate_up = (jax.random.normal(ks[1], (L, n_held, DC, 2 * F)) * DC ** -0.5).astype(jnp.bfloat16)
+    idx, gates = moe.route(_x(seed, tokens, skew)[0], _layer(seed, skew)[0], K, True)
+    return x, w_gate_up, moe._held(idx, gates, first, n_held)
+
+
+@pytest.mark.parametrize("tokens,skew,first", [
+    (300, False, 0), (64, False, 2), (300, True, 0), (300, True, 4), (64, True, 4)],
+    ids=["300tok-even", "64tok-even", "300tok-no-row-held", "300tok-nearly-every-row",
+         "64tok-nearly-every-row"])
+def test_the_held_tiles_fetch_their_own_rows(tokens, skew, first, interpret):
+    """``moe_gate_up`` fetching x's rows by DMA for the real tiles alone
+    against the plain kernel on XLA's gather of every tile's rows: the real
+    tiles' results are the same bits (the same bf16 rows meet the same
+    products); under the skewed router rank 0 holds no routed row (one tile
+    counts as real all the same, so that tiled row 0 is written) and rank 2
+    nearly every one (more real tiles than one: the double buffer turns)."""
+    x, w_gate_up, (local, held_gates) = _rows_in_case(tokens, skew, first)
+    layer = jnp.ones((1,), jnp.int32)
+    tile_expert, n_valid, tile_first, token, _ = moe._held_layout(local, held_gates, 2)
+    held = int((np.asarray(local) < 2).sum())
+    if skew:
+        assert held == 0 if first == 0 else held >= 0.9 * tokens
+    got = moe._gate_up_rows(tile_expert, n_valid, layer, tile_first, token,
+                            row_gather.packed(x, interpret=True), w_gate_up, x.dtype)
+    te, nv, gathered, _ = moe._tile_layout(local, 2, True)
+    np.testing.assert_array_equal(np.asarray(te), np.asarray(tile_expert))
+    want = _plain_gate_up(x, te, nv, gathered, w_gate_up, layer)
+    real = int(n_valid[0]) * moe.TILE_ROWS
+    assert real >= moe.TILE_ROWS and (first != 4 or tokens < 128 or real > moe.TILE_ROWS)
+    np.testing.assert_array_equal(np.asarray(got[:real], np.float32),
+                                  np.asarray(want[:real], np.float32))
+
+
+def test_the_held_tiles_row_fetch_fails_on_a_swapped_table(interpret):
+    """The planted fault: two sorted positions trade their tokens."""
+    x, w_gate_up, (local, held_gates) = _rows_in_case(300, False, 0)
+    layer = jnp.ones((1,), jnp.int32)
+    tile_expert, n_valid, tile_first, token, (rows, _, _, n_live) = moe._held_layout(
+        local, held_gates, 2)
+    assert int(n_valid[0]) >= 2
+    a, b = int(tile_first[0]) + 5, int(tile_first[1]) + 9       # real rows of two tiles
+    assert int(token[a]) != int(token[b])
+    swapped = token.at[a].set(token[b]).at[b].set(token[a])
+    run = lambda t: np.asarray(moe._gate_up_rows(      # noqa: E731
+        tile_expert, n_valid, layer, tile_first, t,
+        row_gather.packed(x, interpret=True), w_gate_up, x.dtype), np.float32)
+    moved = (run(token) != run(swapped)).any(axis=1)
+    # (a tile's padding rows hold some other sorted row again: only the rows
+    # a held pair names are anyone's result)
+    named = np.sort(np.asarray(rows)[:int(n_live[0])])
+    assert set(named[moved[named]]) == {5, moe.TILE_ROWS + 9}
+
+
+def _traced_ops(jaxpr, out):
+    """Every equation's primitive, depth first through the sub-programs
+    (``jit`` bodies, kernel bodies, loops and branches inside them)."""
+    for eqn in jaxpr.eqns:
+        out.append(str(eqn.primitive))
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _traced_ops(sub, out)
+    return out
+
+
+def _whole_layer(dtype, d_model):
+    w_router, _, _ = _layer(5, False)
+    ks = jax.random.split(jax.random.key(5), 2)
+    w_gate_up = (jax.random.normal(ks[0], (L, E, d_model, 2 * F)) * d_model ** -0.5).astype(dtype)
+    w_down = (jax.random.normal(ks[1], (L, E, F, d_model)) * F ** -0.5).astype(dtype)
+    w_router = jnp.zeros((d_model, E)).at[:D].set(w_router)
+    x = jnp.zeros((1, 64, d_model), dtype).at[:, :, :D].set(_x(5, 64, False).astype(dtype))
+    return x, lambda x: moe.moe_mlp(x, w_router, w_gate_up, w_down, 0, top_k=K,
+                                    norm_topk_prob=True)
+
+
+# The whole layer's traced ops in the tile form AS THE PARENT TRACED THEM
+# (commit d8b8f0a, PR 33; the same script run on its checkout): the number of
+# top-level equations, of equations at any depth (kernel bodies included), and
+# the first 16 hex digits of the SHA-256 of their primitives joined by blanks.
+# Float32 rows keep XLA's gather and sum; bf16 rows at D 256 take the combine
+# kernel (``row_gather.weighted_sum`` over all k slots of every token).
+PARENT_WHOLE_LAYER = {
+    "float32-xla-combine": (jnp.float32, D, (86, 196, "a30b0ae31814a556")),
+    "bf16-combine-kernel": (jnp.bfloat16, DC, (78, 300, "9422a609e16ae0b4")),
+}
+
+
+@pytest.mark.parametrize("case", list(PARENT_WHOLE_LAYER))
+def test_every_expert_held_is_the_parents_tile_form_op_for_op(case, interpret, tmp_path):
+    """With every expert held the tile form traces what it traced before the
+    held rows moved by DMA: the layouts, the two product kernels and the
+    combine are the parent's, equation for equation, inside the kernels too;
+    none of the held form's counters moves."""
+    import hashlib
+
+    dtype, d_model, want = PARENT_WHOLE_LAYER[case]
+    x, whole = _whole_layer(dtype, d_model)
+    cfg = CrossCoderConfig(obs="on", obs_dir=str(tmp_path / "obs"), log_backend="null")
+    plane = obs.acquire(cfg)
+    try:
+        jaxpr = jax.make_jaxpr(whole)(x).jaxpr
+        get = plane.registry.get_count
+        assert get("harvest/moe_tiles_traces") == 1
+        assert [get(f"harvest/moe_{c}_traces") for c in (
+            "held", "held_combine", "rows_in_kernel", "rows_in_xla")] == [0, 0, 0, 0]
+    finally:
+        plane.close()
+    ops = _traced_ops(jaxpr, [])
+    got = (len(jaxpr.eqns), len(ops),
+           hashlib.sha256(" ".join(ops).encode()).hexdigest()[:16])
+    assert got == want
 
 
 def test_every_expert_held_is_the_whole_layer_op_for_op(tmp_path):
@@ -361,6 +505,36 @@ def test_every_expert_held_is_the_whole_layer_op_for_op(tmp_path):
         moe.moe_mlp(x, w_router, w_gate_up[:, :4], w_down[:, :4], 0, top_k=K,
                     norm_topk_prob=True, first_expert=4)
         assert plane.registry.get_count("harvest/moe_held_traces") == 1
+    finally:
+        plane.close()
+
+
+def test_which_way_the_held_rows_moved_is_counted_once_per_trace(tmp_path, interpret):
+    """Under a share, at a shape the row kernels take, the rows go in and out
+    by DMA (three counters, once a trace); float32 rows keep XLA's gathers
+    over the static bound, and say so."""
+    cfg = CrossCoderConfig(obs="on", obs_dir=str(tmp_path / "obs"), log_backend="null")
+    plane = obs.acquire(cfg)
+    names = ("held", "held_combine", "rows_in_kernel", "rows_in_xla",
+             "combine_kernel", "combine_xla")
+    counts = lambda: [plane.registry.get_count(f"harvest/moe_{c}_traces")   # noqa: E731
+                      for c in names]
+    try:
+        x, _ = _whole_layer(jnp.bfloat16, DC)
+        w_router, _, _ = _layer(5, False)
+        ks = jax.random.split(jax.random.key(6), 2)
+        w_gate_up = jax.random.normal(ks[0], (L, 2, DC, 2 * F), jnp.bfloat16)
+        w_down = jax.random.normal(ks[1], (L, 2, F, DC), jnp.bfloat16)
+        router = jnp.zeros((DC, E), jnp.bfloat16).at[:D].set(w_router.astype(jnp.bfloat16))
+        f = jax.jit(lambda x: moe.moe_mlp(x, router, w_gate_up, w_down, 0, top_k=K,
+                                          norm_topk_prob=True, first_expert=2))
+        for _ in range(3):
+            f(x)
+        assert counts() == [1, 1, 1, 0, 1, 0]
+        w_router, w_gate_up, w_down = _layer(5, False)
+        moe.moe_mlp(_x(5, 64, False), w_router, w_gate_up[:, :2], w_down[:, :2], 0,
+                    top_k=K, norm_topk_prob=True)
+        assert counts() == [2, 1, 1, 1, 1, 1]
     finally:
         plane.close()
 

@@ -5,7 +5,8 @@ batch), the latent-major grouped sums (duplicate destinations, empty
 destinations, a destination hit by more pairs than one chunk holds, a last
 chunk that is not whole), and the whole TopK step in its row form against
 the dense TopK step: losses and all four parameter gradients, the dispatch
-that chooses the form, and the counters that say which was traced."""
+that chooses the form, and the counters that say which was traced; the
+token-major sum over a compacted table of held pairs (``held_sums``)."""
 
 import jax
 import jax.numpy as jnp
@@ -121,6 +122,86 @@ def test_slices_cover_the_batch_in_whole_groups():
     assert rg._slices(4096, 8) == [(0, 4096)]                  # the mellum2 cell: one call
     assert rg._slices(4096, 32) == [(0, 4096)]                 # the topk32k cell: one, 512 KiB
     assert rg._slices(8192, 32) == [(0, 4096), (4096, 4096)]
+
+
+# ---------------------------------------------------------------------------
+# token-major over a compacted table: the held pairs alone
+
+
+def _held_case(T, k, D, R, share, seed=0):
+    """A ``[T, k]`` table of which a random ``share`` of the slots is held:
+    the held (token, slot) pairs in token order at the front of ``[T·k]``
+    tables, as ``ops/moe._held_layout`` hands them over, and the gathered
+    XLA form of the same sum."""
+    y, table, w, _ = _token_case(T, k, D, R, seed)
+    held = jax.random.uniform(jax.random.key(50 + seed), (T * k,)) < share
+    key = jnp.where(held, jnp.arange(T * k, dtype=jnp.int32), T * k)
+    key, rows, weights = jax.lax.sort((key, table, w.reshape(-1)), num_keys=1)
+    n_live = jnp.sum(held).astype(jnp.int32).reshape(1)
+    want = jnp.sum(jnp.where(held.reshape(T, k, 1),
+                             y[table].reshape(T, k, D).astype(F32) * w[..., None], 0.0), axis=1)
+    return y, (rows, key // k, weights, n_live), held.reshape(T, k), want
+
+
+@pytest.mark.parametrize("T,k,D,share", [
+    (300, 3, 256, 0.125), (64, 3, 256, 0.5), (300, 10, 768, 0.125), (300, 3, 256, 0.0),
+    (300, 3, 256, 0.97), (72, 4, 512, 1.0), (256, 8, 256, 1 / 64)],
+    ids=["300tok-an-eighth", "64tok-half", "k10-three-lane-tiles", "no-row-held",
+         "nearly-every-row", "every-row-a-tile-count-not-whole", "tiles-without-a-pair"])
+def test_held_sums_match_the_gathered_sum_over_the_held_slots(T, k, D, share):
+    assert rg.held_supported(T, k, D, jnp.bfloat16)
+    y, pairs, held, want = _held_case(T, k, D, R=64, share=share)
+    n = int(pairs[3][0])
+    assert n == int(held.sum()) and (share not in (0.0, 1.0) or n == int(share) * T * k)
+    got = jax.jit(lambda *a: rg.held_sums(*a, T, D, name="t", interpret=True))(
+        *pairs, rg.packed(y))
+    assert got.shape == (T, D) and got.dtype == jnp.bfloat16
+    got, want = np.asarray(got, np.float32), np.asarray(want)
+    # float32 weights (three exact bf16 parts on the MXU), float32 sums in
+    # another order, one rounding: the oracle to a bf16 ulp
+    assert (np.abs(got - want) <= _bf16_ulp(want)).all()
+    assert (got != np.asarray(jnp.asarray(want).astype(jnp.bfloat16), np.float32)).mean() < 1e-3
+    # a token with no held slot gets exactly nothing
+    assert not got[~np.asarray(held).any(axis=1)].any()
+    if share == 0.0:
+        assert not got.any()
+
+
+def test_held_sums_never_read_past_the_live_pairs():
+    """What lies behind the live pairs is neither fetched nor summed: rows
+    out of range and non-finite weights there change nothing."""
+    T, k, D = 300, 3, 256
+    y, (rows, tokens, weights, n_live), _, _ = _held_case(T, k, D, R=64, share=0.125)
+    dead = jnp.arange(T * k) >= n_live[0]
+    run = lambda r, t, w: np.asarray(rg.held_sums(      # noqa: E731
+        r, t, w, n_live, rg.packed(y), T, D, name="t", interpret=True), np.float32)
+    np.testing.assert_array_equal(
+        run(rows, tokens, weights),
+        run(jnp.where(dead, 2 ** 20, rows), jnp.where(dead, 7, tokens),
+            jnp.where(dead, jnp.nan, weights)))
+
+
+def test_held_sums_fail_on_a_swapped_table_entry():
+    """The planted fault: two live pairs of different tokens trade rows."""
+    T, k, D = 300, 3, 256
+    y, (rows, tokens, weights, n_live), _, _ = _held_case(T, k, D, R=64, share=0.25, seed=2)
+    a, b = 20, int(n_live[0]) - 20
+    assert int(rows[a]) != int(rows[b]) and int(tokens[a]) != int(tokens[b])
+    swapped = rows.at[a].set(rows[b]).at[b].set(rows[a])
+    run = lambda r: np.asarray(rg.held_sums(            # noqa: E731
+        r, tokens, weights, n_live, rg.packed(y), T, D, name="t", interpret=True), np.float32)
+    moved = (run(rows) != run(swapped)).any(axis=1)
+    assert set(np.flatnonzero(moved)) == {int(tokens[a]), int(tokens[b])}
+
+
+@pytest.mark.parametrize("args,want", [
+    ((8192, 10, 3072, jnp.bfloat16), True),       # the laguna cell: 320 KiB of pairs
+    ((8192, 10, 3072, F32), False),
+    ((16384, 10, 3072, jnp.bfloat16), False),     # the pair table passes SMEM, and is not cut
+    ((8, 10, 3072, jnp.bfloat16), False),
+], ids=["cell", "float32", "smem", "tokens"])
+def test_held_supported(args, want):
+    assert rg.held_supported(*args) is want
 
 
 # ---------------------------------------------------------------------------
