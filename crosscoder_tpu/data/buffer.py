@@ -440,6 +440,8 @@ class PairedActivationBuffer:
         self._pipelined(produced(), drain)
         if cfg.obs == "on" and self.lm_cfg.sparse:
             self._gauge_expert_load()
+        if cfg.obs == "on" and self.lm_cfg.n_streams > 1:
+            self._gauge_stream_maps()
         mean_norm = sums / max(count, 1)
         return (np.sqrt(cfg.d_in) / mean_norm).astype(np.float32)
 
@@ -465,6 +467,19 @@ class PairedActivationBuffer:
         obs.gauge("harvest/moe_load_max_over_mean", moe.load_max_over_mean(counts))
         obs.gauge("harvest/moe_local_row_share",
                   moe.local_row_share(counts, cfg.first_expert, cfg.n_held))
+
+    def _gauge_stream_maps(self) -> None:
+        """``harvest/mhc_col_err``: how far the first model's mixing matrices
+        are from doubly stochastic over one calibration chunk (``max
+        |colsum(M) − 1|`` over tokens, sublayers and the hooked layers) —
+        Sinkhorn's remainder, by which the streams' mean is the hooked
+        residual stream. Read ONCE, beside the load gauge, only with ``obs``
+        on: the loop gains no sync."""
+        depth = max(lm.hooked_depth(self.lm_cfg, self.hook_points), 1)
+        padded, _ = self._pad_chunk(self.tokens[: self._chunk_seqs])
+        errs = jax.device_get(lm.mhc_col_err(
+            self.model_params[0], jnp.asarray(padded), self.lm_cfg, depth))
+        obs.gauge("harvest/mhc_col_err", float(np.max(errs)))
 
     def refresh(self) -> None:
         """Synchronous refill: first fill, resume, and tests.

@@ -34,7 +34,7 @@ and MLP kind per layer), the block's style, RoPE per attention kind and the
 expert sizes; every forward below is ONE layer loop (:func:`_scan_blocks`)
 that takes a layer's kind from ONE lookup (:func:`_layer_kind`) and runs ONE
 block (:func:`_block`), and hands in only its positions and how it reaches
-attention. Three families run through it: Gemma-2 (next paragraph), the pre-norm sparse-expert block of
+attention. Four families run through it: Gemma-2 (next paragraph), the pre-norm sparse-expert block of
 Mellum2 (``LMConfig.mellum2_12b``: plain-weight RMSNorm before each sublayer
 only, no soft-caps, no embedding scale, three window layers to one full
 layer with YaRN on the full layers only, every MLP ``ops/moe.py``'s routed
@@ -47,7 +47,14 @@ share of each layer's experts. Layers of one shape are a class, one stack of
 leaves a class (:func:`layer_classes`); a table of one class — every other
 family — keeps the tree ``params["layers"][leaf]`` and the programs it had
 (checked against ``benchmarks/reference/laguna_ref.py`` by
-``tests/test_laguna.py``).
+``tests/test_laguna.py``). A fourth, Xing4.0-29B-A4B's
+(``LMConfig.xing4_0_29b``), changes what the loop CARRIES: four residual
+streams a token, side by side in one row ``[B, S, n·D]``, which each sublayer
+reads and writes through the maps of ``ops/mhc.py`` (:func:`_read` /
+:func:`_write`: with one stream the stream itself and an add), with latent
+attention (:func:`_latent_qkv`) and sigmoid-routed experts; its residual hooks
+see the streams' mean (checked against ``benchmarks/reference/xing_ref.py`` by
+``tests/test_xing.py``).
 
 Gemma-2 architecture facts implemented (validated against the HF
 ``transformers`` Gemma2 implementation by ``tests/test_lm.py``): RMSNorm with
@@ -144,6 +151,17 @@ class LMConfig:
     is then the held experts' part of the routed sum (plus the shared
     expert), and nothing stands in for the absent chips. ``embed_std`` is the
     seeded fixture's embedding scale (None: ``d_model ** -0.5``).
+
+    ``router`` ``"sigmoid_bias"`` scores each expert by a sigmoid, chooses by
+    score plus a per-expert bias and gates by the unbiased scores
+    (``moe.route``). ``n_streams`` > 1 gives a token that many residual
+    streams, read and written by each sublayer through the maps of
+    :mod:`crosscoder_tpu.ops.mhc` (``hc_sinkhorn_iters``, ``hc_eps``,
+    ``hc_clamp``); a residual hook is then their MEAN (:func:`_stream_mean`).
+    ``kv_lora_rank`` > 0 makes attention the LATENT form: ``q_lora_rank`` and
+    ``kv_lora_rank`` are the two low-rank paths' widths, ``head_dim`` the score
+    head — its own part, then ``qk_rope_dim`` rotary dims whose key all heads
+    share — and ``v_head_dim`` the value head.
     """
 
     vocab_size: int
@@ -176,6 +194,15 @@ class LMConfig:
     experts_held: int = 0
     expert_rank: int = 0
     embed_std: float | None = None
+    router: str = "softmax"
+    n_streams: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_clamp: tuple[float, float] = (-30.0, 30.0)
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
 
     def __post_init__(self) -> None:
         def fill(name, default, valid):
@@ -193,6 +220,21 @@ class LMConfig:
             raise ValueError(f"block_style must be sandwich|prenorm, got {self.block_style!r}")
         if self.attn_gate not in ("none", "per_head"):
             raise ValueError(f"attn_gate must be none|per_head, got {self.attn_gate!r}")
+        if self.router not in ("softmax", "sigmoid_bias"):
+            raise ValueError(f"router must be softmax|sigmoid_bias, got {self.router!r}")
+        if self.n_streams < 1 or (self.n_streams > 1 and self.block_style != "prenorm"):
+            raise ValueError(
+                f"n_streams {self.n_streams}: at least 1, and several only in a prenorm block")
+        object.__setattr__(self, "hc_clamp", tuple(float(c) for c in self.hc_clamp))
+        if self.latent and not (
+                self.q_lora_rank > 0 and 0 < self.qk_rope_dim < self.head_dim
+                and self.qk_rope_dim % 2 == 0 and self.v_head_dim > 0
+                and self.n_kv_heads == self.n_heads and self.heads_by_layer is None
+                and self.attn_gate == "none"):
+            raise ValueError(
+                "latent attention (kv_lora_rank > 0) needs q_lora_rank, an even "
+                "qk_rope_dim inside head_dim (the score head: nope | rope), "
+                "v_head_dim, and one key/value head a query head")
         if self.heads_by_layer is not None:
             heads = tuple(self.heads_by_layer)
             if len(heads) != self.n_layers or any(
@@ -217,6 +259,24 @@ class LMConfig:
     def sparse(self) -> bool:
         """Whether any MLP layer is an expert layer."""
         return SPARSE in self.mlp_types
+
+    @property
+    def latent(self) -> bool:
+        """Whether attention is the latent (low-rank) form."""
+        return self.kv_lora_rank > 0
+
+    @property
+    def rope_dim(self) -> int:
+        """The width RoPE's frequencies are computed at."""
+        return self.qk_rope_dim or self.head_dim
+
+    @property
+    def hc(self):
+        """The static side of the stream maps (``ops/mhc.HC``)."""
+        from crosscoder_tpu.ops import mhc
+
+        return mhc.HC(self.n_streams, self.hc_sinkhorn_iters, self.hc_eps,
+                      self.hc_clamp, self.rms_eps)
 
     @property
     def n_held(self) -> int:
@@ -310,6 +370,39 @@ class LMConfig:
         )
 
     @classmethod
+    def xing4_0_29b(cls) -> "LMConfig":
+        """Xing4.0-29B-A4B (XingChen-AGI): four residual streams a token,
+        read and written through input-dependent maps and mixed by a
+        Sinkhorn-normalised 4 x 4 matrix a sublayer (mHC); latent attention
+        (query rank 768, key/value rank 512, a score head of 128 + 64 rotary
+        dims whose rotary key is shared by all 32 heads, a value head of
+        128, static YaRN x64 at dim 64 with its m² on the softmax scale);
+        two dense layers (9,216), then 64 sigmoid-scored experts of width
+        1024, top-4 chosen by score plus a bias and gated by the unbiased
+        scores (renormalised, times 2) beside one shared expert; untied
+        head. The whole model: every expert held; the MTP module is not
+        modelled."""
+        n = 40
+        m = 0.1 * 1.0 * math.log(64.0) + 1.0        # yarn_get_mscale(factor, mscale_all_dim)
+        return cls(
+            vocab_size=131_072, d_model=3584, n_layers=n, n_heads=32,
+            n_kv_heads=32, head_dim=192, d_ff=9216, rope_theta=10_000.0,
+            attn_softcap=0.0, final_softcap=0.0, sliding_window=0,
+            # scores · 192^-0.5 · m²  ==  scores · (192 / m⁴)^-0.5
+            query_pre_attn_scalar=192.0 / m ** 4,
+            layer_types=(FULL,) * n,
+            mlp_types=(DENSE,) * 2 + (SPARSE,) * (n - 2), block_style="prenorm",
+            rope=((FULL, Rope(theta=10_000.0, yarn_factor=64.0,
+                              original_max_position=4096, beta_fast=32.0,
+                              beta_slow=1.0, attention_factor=1.0)),),
+            n_experts=64, experts_per_tok=4, d_expert=1024,
+            norm_topk_prob=True, tie_embeddings=False,
+            d_shared_expert=1024, routed_scale=2.0, router="sigmoid_bias",
+            n_streams=4, hc_sinkhorn_iters=20, hc_eps=1e-6, hc_clamp=(-30.0, 30.0),
+            q_lora_rank=768, kv_lora_rank=512, qk_rope_dim=64, v_head_dim=128,
+        )
+
+    @classmethod
     def tiny(cls, vocab_size: int = 257, n_layers: int = 4) -> "LMConfig":
         """Deterministic test-sized config (the 'fake LM' of SURVEY.md §4 —
         same hook semantics as the real model, no 2.6B-param download)."""
@@ -344,6 +437,8 @@ _NAMED_CONFIGS = {
     "laguna-s-2.1": LMConfig.laguna_s_2_1,
     "laguna-s-2.1-base": LMConfig.laguna_s_2_1,
     "laguna-s-2.1-instruct": LMConfig.laguna_s_2_1,
+    "xing4.0-29b-a4b": LMConfig.xing4_0_29b,
+    "xing4.0-29b-a4b-base": LMConfig.xing4_0_29b,
 }
 
 
@@ -430,14 +525,35 @@ def init_params(key: jax.Array, cfg: LMConfig) -> LMParams:
 
     def stack(cls: LayerClass, ks, key) -> dict:
         L, qd = len(cls.layers), cls.n_heads * cfg.head_dim
-        layers = {
-            "attn_norm": unit((L, D), dt),
-            "pre_ffw_norm": unit((L, D), dt),
-            "wq": nrm(ks[1], (L, D, qd), D ** -0.5),
-            "wk": nrm(ks[2], (L, D, kd), D ** -0.5),
-            "wv": nrm(ks[3], (L, D, kd), D ** -0.5),
-            "wo": nrm(ks[4], (L, qd, D), qd ** -0.5),
-        }
+        layers = {"attn_norm": unit((L, D), dt), "pre_ffw_norm": unit((L, D), dt)}
+        if cfg.latent:
+            H, rq, rkv = cls.n_heads, cfg.q_lora_rank, cfg.kv_lora_rank
+            dr, dv = cfg.qk_rope_dim, cfg.v_head_dim
+            kl = jax.random.split(jax.random.fold_in(key, 103), 4)
+            layers.update(
+                wq_a=nrm(ks[1], (L, D, rq), D ** -0.5), q_a_norm=unit((L, rq), dt),
+                wq_nope=nrm(ks[2], (L, rq, H * (cfg.head_dim - dr)), rq ** -0.5),
+                wq_rope=nrm(kl[0], (L, rq, H * dr), rq ** -0.5),
+                wkv_a=nrm(ks[3], (L, D, rkv + dr), D ** -0.5), kv_a_norm=unit((L, rkv), dt),
+                wk_nope=nrm(kl[1], (L, rkv, H * (cfg.head_dim - dr)), rkv ** -0.5),
+                wv=nrm(kl[2], (L, rkv, H * dv), rkv ** -0.5),
+                wo=nrm(ks[4], (L, H * dv, D), (H * dv) ** -0.5))
+        else:
+            layers.update(
+                wq=nrm(ks[1], (L, D, qd), D ** -0.5), wk=nrm(ks[2], (L, D, kd), D ** -0.5),
+                wv=nrm(ks[3], (L, D, kd), D ** -0.5), wo=nrm(ks[4], (L, qd, D), qd ** -0.5))
+        if cfg.n_streams > 1:
+            # the seeded fixture's maps (the configuration file's
+            # ``assumed.weights``): phi drawn so that z has unit variance,
+            # alpha 1, no bias but 2·I under the mixing logits
+            n, W = cfg.n_streams, cfg.hc.width
+            base = jnp.concatenate([jnp.zeros((2 * n,)), 2.0 * jnp.eye(n).reshape(-1)])
+            for j, site in enumerate(("attn", "ffn")):
+                layers[f"hc_{site}_phi"] = jax.random.normal(
+                    jax.random.fold_in(key, 104 + j), (L, n * D, W), jnp.float32
+                ) * (n * D) ** -0.5
+                layers[f"hc_{site}_alpha"] = jnp.ones((L, 3), jnp.float32)
+                layers[f"hc_{site}_bias"] = jnp.broadcast_to(base, (L, W)).astype(jnp.float32)
         if sandwich:
             layers["post_attn_norm"] = unit((L, D), dt)
             layers["post_ffw_norm"] = unit((L, D), dt)
@@ -446,6 +562,13 @@ def init_params(key: jax.Array, cfg: LMConfig) -> LMParams:
             layers["router"] = nrm(ks[5], (L, D, cfg.n_experts), D ** -0.5)
             layers["we_gate_up"] = nrm(ks[6], (L, E, D, 2 * Fe), D ** -0.5)
             layers["we_down"] = nrm(ks[7], (L, E, Fe, D), Fe ** -0.5)
+            if cfg.router == "sigmoid_bias":
+                # the fixture's choice bias: large enough to move choices (the
+                # 4th and 5th of 64 scores lie ~0.03 apart), small enough not
+                # to UNBALANCE the load a trained bias exists to balance (at
+                # 0.1 the busiest expert takes 5-8x the mean: PERF.md §6, PR 35)
+                layers["router_bias"] = 0.01 * jax.random.normal(
+                    jax.random.fold_in(key, 106), (L, cfg.n_experts), jnp.float32)
             if cfg.d_shared_expert:
                 Fs = cfg.d_shared_expert
                 k_g, k_u, k_d = jax.random.split(jax.random.fold_in(key, 101), 3)
@@ -475,6 +598,12 @@ def init_params(key: jax.Array, cfg: LMConfig) -> LMParams:
     }
     if not cfg.tie_embeddings:
         params["unembed"] = nrm(ks[8], (cfg.vocab_size, D), D ** -0.5)
+    if cfg.n_streams > 1:       # the learned read in front of the final norm
+        n = cfg.n_streams
+        params["hc_head_phi"] = jax.random.normal(
+            jax.random.fold_in(key, 107), (n * D, n), jnp.float32) * (n * D) ** -0.5
+        params["hc_head_alpha"] = jnp.ones((1,), jnp.float32)
+        params["hc_head_bias"] = jnp.zeros((n,), jnp.float32)
     return params
 
 
@@ -483,18 +612,28 @@ def param_count(cfg: LMConfig) -> int:
     D, F = cfg.d_model, cfg.d_ff
     kd = cfg.n_kv_heads * cfg.head_dim
     norms = 4 * D if cfg.block_style == "sandwich" else 2 * D
+    n, W = cfg.n_streams, cfg.hc.width
+    maps = 2 * (n * D * W + 3 + W) if n > 1 else 0
     layers = 0
     for cls in layer_classes(cfg):
         qd = cls.n_heads * cfg.head_dim
         if cls.mlp == SPARSE:
             mlp = (D * cfg.n_experts + cfg.n_held * 3 * D * cfg.d_expert
-                   + 3 * D * cfg.d_shared_expert)
+                   + 3 * D * cfg.d_shared_expert
+                   + (cfg.n_experts if cfg.router == "sigmoid_bias" else 0))
         else:
             mlp = 3 * D * F
+        if cfg.latent:
+            rq, rkv, vd = cfg.q_lora_rank, cfg.kv_lora_rank, cls.n_heads * cfg.v_head_dim
+            attn = (D * rq + rq + rq * qd + D * (rkv + cfg.qk_rope_dim) + rkv
+                    + rkv * (qd - cls.n_heads * cfg.qk_rope_dim) + rkv * vd + vd * D)
+        else:
+            attn = D * qd + 2 * D * kd + qd * D
         gate = D * cls.n_heads if cfg.attn_gate == "per_head" else 0
-        layers += len(cls.layers) * (norms + D * qd + 2 * D * kd + qd * D + gate + mlp)
+        layers += len(cls.layers) * (norms + attn + gate + mlp + maps)
     heads = 1 if cfg.tie_embeddings else 2
-    return heads * cfg.vocab_size * D + D + layers
+    head_read = n * D * n + 1 + n if n > 1 else 0
+    return heads * cfg.vocab_size * D + D + layers + head_read
 
 
 # ---------------------------------------------------------------------------
@@ -558,11 +697,11 @@ def _layer_kind(cfg: LMConfig, i: jax.Array, cls: LayerClass | None = None,
     if cls is not None and cls.kind is not None:
         rope = cfg.rope_of(cls.kind)
         return _LayerKind(i, np.bool_(cls.kind == SLIDING),
-                          rope_inv_freq(rope, cfg.head_dim), rope.attention_factor, slot)
+                          rope_inv_freq(rope, cfg.rope_dim), rope.attention_factor, slot)
     is_local = jnp.asarray([k == SLIDING for k in cfg.layer_types])[i]
     local, full = cfg.rope_of(SLIDING), cfg.rope_of(FULL)
     if local == full:
-        return _LayerKind(i, is_local, rope_inv_freq(local, cfg.head_dim),
+        return _LayerKind(i, is_local, rope_inv_freq(local, cfg.rope_dim),
                           local.attention_factor, slot)
     if local.rotary_factor != full.rotary_factor:
         raise ValueError(
@@ -570,8 +709,8 @@ def _layer_kind(cfg: LMConfig, i: jax.Array, cls: LayerClass | None = None,
             "share a stack of leaves (the rotated width is static)")
     return _LayerKind(
         i, is_local,
-        jnp.where(is_local, rope_inv_freq(local, cfg.head_dim),
-                  rope_inv_freq(full, cfg.head_dim)),
+        jnp.where(is_local, rope_inv_freq(local, cfg.rope_dim),
+                  rope_inv_freq(full, cfg.rope_dim)),
         jnp.where(is_local, jnp.float32(local.attention_factor),
                   jnp.float32(full.attention_factor)),
         slot,
@@ -616,6 +755,64 @@ def _qkv(
     q = _rope(q.astype(x.dtype).reshape(B, S, H, hd), pos, kind.inv_freq, kind.rope_factor)
     k = _rope(k.astype(x.dtype).reshape(B, S, KV, hd), pos, kind.inv_freq, kind.rope_factor)
     return q, k, v.astype(x.dtype).reshape(B, S, KV, hd)
+
+
+@jax.named_scope("harvest/block/attn/latent")
+def _latent_qkv(
+    x: jax.Array, lp: Mapping[str, jax.Array], cfg: LMConfig, pos: jax.Array,
+    kind: _LayerKind,
+) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
+    """The latent form's two low-rank paths, each with an RMSNorm in its
+    middle: ``q_nope [B,S,H,dn]``, ``q_rope [B,S,H,dr]`` (rotated),
+    ``k_nope [B,S,H,dn]``, the ONE rotary key ``k_rope [B,S,1,dr]`` all
+    heads share (rotated), ``v [B,S,H,dv]``."""
+    B, S, _ = x.shape
+    dr, rkv = cfg.qk_rope_dim, cfg.kv_lora_rank
+    H = lp["wq_rope"].shape[-1] // dr
+
+    def proj(a, w):
+        return jnp.einsum("bsd,dq->bsq", a, w, preferred_element_type=jnp.float32).astype(x.dtype)
+
+    cq = _norm(proj(x, lp["wq_a"]), lp["q_a_norm"], cfg)
+    ckv = proj(x, lp["wkv_a"])
+    c = _norm(ckv[..., :rkv], lp["kv_a_norm"], cfg)
+    q_rope = _rope(proj(cq, lp["wq_rope"]).reshape(B, S, H, dr), pos,
+                   kind.inv_freq, kind.rope_factor)
+    k_rope = _rope(ckv[..., rkv:].reshape(B, S, 1, dr), pos, kind.inv_freq, kind.rope_factor)
+    return (proj(cq, lp["wq_nope"]).reshape(B, S, H, -1), q_rope,
+            proj(c, lp["wk_nope"]).reshape(B, S, H, -1), k_rope,
+            proj(c, lp["wv"]).reshape(B, S, H, -1))
+
+
+def _latent_attend(
+    parts: tuple, cfg: LMConfig, kind: _LayerKind, attend: Callable | None,
+) -> jax.Array:
+    """Attention on the latent form's heads → ``[B, S, H·dv]``. Two forms,
+    chosen here as :func:`_attn_core` chooses: the padded path on a
+    one-device TPU backend at a supported shape runs the fused kernel's
+    latent instance (score and value head sizes of their own; the shared
+    rotary key one more band in VMEM, never copied a head); everything else
+    attends on the EXPANDED heads — q and k as ``[nope | rope]`` of
+    ``head_dim``, the rotary key repeated a head, v zero-padded to that width
+    and cut back — through whatever the forward reaches attention by."""
+    from crosscoder_tpu import obs
+    from crosscoder_tpu.ops import flash_attention as fa
+
+    q_nope, q_rope, k_nope, k_rope, v = parts
+    B, S, H, dv = v.shape
+    obs.count("harvest/attn_latent_traces")
+    if attend is None and fa.enabled() and fa.latent_supported(
+            S, q_nope.shape[-1], q_rope.shape[-1], dv, v.dtype):
+        obs.count("harvest/attn_fused_traces")
+        return fa.flash_attention_latent(
+            q_nope, q_rope, k_nope, k_rope, v, scale=cfg.query_pre_attn_scalar ** -0.5)
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, q_rope.shape)], axis=-1)
+    hd = q.shape[-1]
+    v = jnp.pad(v, ((0, 0),) * 3 + ((0, hd - dv),))
+    a = (_attn_core(q, k, v, cfg, kind.is_local) if attend is None
+         else attend(q, k, v, kind))
+    return a.reshape(B, S, H, hd)[..., :dv].reshape(B, S, H * dv)
 
 
 def _attn_core(
@@ -709,7 +906,11 @@ def _attention(
     the stream. ``kind.is_local`` selects the sliding-window mask (traced
     scalar — both masks are static precomputes). ``pos`` and ``attend`` are
     the ONE thing the forwards differ in (see :func:`_block`)."""
-    q, k, v = _qkv(x, lp, cfg, jnp.arange(x.shape[1]) if pos is None else pos, kind)
+    pos = jnp.arange(x.shape[1]) if pos is None else pos
+    if "wkv_a" in lp:
+        return _attn_out(
+            _latent_attend(_latent_qkv(x, lp, cfg, pos, kind), cfg, kind, attend), lp, cfg)
+    q, k, v = _qkv(x, lp, cfg, pos, kind)
     a = (_attn_core(q, k, v, cfg, kind.is_local) if attend is None
          else attend(q, k, v, kind))
     if "w_attn_gate" in lp:
@@ -751,7 +952,8 @@ def _mlp(x: jax.Array, lp: Mapping[str, jax.Array], cfg: LMConfig, slot: Any) ->
     m = moe.moe_mlp(
         x, lp["router"], lp["we_gate_up"], lp["we_down"], slot,
         top_k=cfg.experts_per_tok, norm_topk_prob=cfg.norm_topk_prob,
-        routed_scale=cfg.routed_scale, first_expert=cfg.first_expert)
+        routed_scale=cfg.routed_scale, first_expert=cfg.first_expert,
+        router=cfg.router, router_bias=lp.get("router_bias"))
     if "ws_gate" in lp:
         with jax.named_scope("harvest/block/moe/shared"):
             m = m + _gated_mlp(x, lp["ws_gate"], lp["ws_up"], lp["ws_down"], cfg)
@@ -759,8 +961,8 @@ def _mlp(x: jax.Array, lp: Mapping[str, jax.Array], cfg: LMConfig, slot: Any) ->
 
 
 def _mlp_out(resid: jax.Array, lp: Mapping[str, jax.Array], cfg: LMConfig, slot: Any) -> jax.Array:
-    """The MLP sublayer of the layer at ``slot`` of its class's stack on the
-    stream, as added to it."""
+    """The MLP sublayer of the layer at ``slot`` of its class's stack on
+    what it reads of the stream, as written back to it."""
     m = _mlp(_norm(resid, lp["pre_ffw_norm"], cfg), lp, cfg, slot)
     if cfg.block_style == "sandwich":
         m = _norm(m, lp["post_ffw_norm"], cfg)
@@ -776,24 +978,71 @@ def _embed(params: LMParams, tokens: jax.Array, cfg: LMConfig) -> jax.Array:
         return resid
 
 
+def _stream_mean(resid: jax.Array, cfg: LMConfig) -> jax.Array:
+    """What the residual hooks see, ``[B, S, D]``: the stream itself, or —
+    of a token's ``n_streams`` — their MEAN, the one quantity the doubly
+    stochastic mixing conserves (``m' = m + mean(h_post) · y``)."""
+    if cfg.n_streams == 1:
+        return resid
+    from crosscoder_tpu.ops import mhc
+
+    parts = [s.astype(jnp.float32) for s in mhc.streams_of(resid, cfg.n_streams)]
+    return (functools.reduce(jnp.add, parts) * (1.0 / cfg.n_streams)).astype(resid.dtype)
+
+
+def _read(resid: jax.Array, lp: Mapping[str, jax.Array], cfg: LMConfig, site: str):
+    """What a sublayer reads of the stream (before its own norm), and what
+    its write needs: the stream itself and nothing; or, of ``n_streams``,
+    the input-dependent weighted read and the maps (``ops/mhc.py``)."""
+    if cfg.n_streams == 1:
+        return resid, None
+    from crosscoder_tpu.ops import mhc
+
+    with jax.named_scope("harvest/block/mhc/read"):
+        return mhc.read(resid, lp[f"hc_{site}_phi"], lp[f"hc_{site}_alpha"],
+                        lp[f"hc_{site}_bias"], cfg.hc)
+
+
+def _write(resid: jax.Array, y: jax.Array, maps: Any, cfg: LMConfig):
+    """A sublayer's output joins the stream: ``(the stream after it, ``y``
+    as ADDED to what the residual hooks see)``."""
+    if maps is None:
+        return resid + y, y
+    from crosscoder_tpu.ops import mhc
+
+    with jax.named_scope("harvest/block/mhc/write"):
+        added = (y.astype(jnp.float32) * mhc.mean_gain(maps, y.shape[:-1])).astype(y.dtype)
+        return mhc.write(resid, y, maps, cfg.hc), added
+
+
+class _Seen(NamedTuple):
+    """What a block hands a forward's ``emit`` besides its leaves."""
+
+    mlp_in: jax.Array       # what the MLP sublayer read, before its norm
+    maps: tuple             # the two sublayers' stream maps (none: one stream)
+
+
 def _block(
     resid: jax.Array, lp: Mapping[str, jax.Array], cfg: LMConfig, kind: _LayerKind,
     edit_attn: Callable[[jax.Array], jax.Array] | None = None,
     edit_mlp: Callable[[jax.Array], jax.Array] | None = None,
     pos: jax.Array | None = None, attend: Callable | None = None,
-) -> tuple[jax.Array, jax.Array, jax.Array]:
+) -> tuple[jax.Array, jax.Array, jax.Array, _Seen]:
     """One transformer block of the config's style and kinds — the ONLY
-    place norm → QKV → attention → out-projection → add → MLP → add is
-    spelled; every forward runs it through :func:`_scan_blocks`.
+    place read → norm → QKV → attention → out-projection → write → read →
+    norm → MLP → write is spelled; every forward runs it through
+    :func:`_scan_blocks`. With one stream a read is the stream and a write
+    an add; with ``n_streams`` they are the maps of ``ops/mhc.py``.
 
-    Returns ``(resid, attn_out, mlp_out)`` — the updated stream plus the two
-    sublayer contributions exactly as they are ADDED to it (in the sandwich
-    block: after its post-norms), which is what ``hook_attn_out``/
-    ``hook_mlp_out`` capture: the intermediates exist anyway, so exposing
-    them is free. ``edit_attn``/``edit_mlp`` intervene on a contribution
-    BEFORE it joins the stream (and before its capture) — the sublayer-site
-    analogue of the residual edits, used by CE-recovered evals of sublayer
-    crosscoders.
+    Returns ``(resid, attn_out, mlp_out, seen)`` — the updated stream plus
+    the two sublayer contributions exactly as they are ADDED to what the
+    residual hooks see (in the sandwich block: after its post-norms; of
+    several streams: ``mean(h_post) · y``, what joins their mean), which is
+    what ``hook_attn_out``/``hook_mlp_out`` capture: the intermediates exist
+    anyway, so exposing them is free. ``edit_attn``/``edit_mlp`` intervene
+    on a sublayer's output BEFORE it joins the stream (and before its
+    capture) — the sublayer-site analogue of the residual edits, used by
+    CE-recovered evals of sublayer crosscoders.
 
     ``pos`` (the positions RoPE rotates by: ``[S]`` or per-token ``[B, S]``)
     and ``attend(q, k, v, kind) -> [B, S, H·hd]`` (pre output-projection)
@@ -802,15 +1051,17 @@ def _block(
     per-document attention → scatter, the sequence-sharded one its shard's
     global positions and the ring. Left None they are ``arange(S)`` and
     :func:`_attn_core`."""
-    attn_out = _attention(_norm(resid, lp["attn_norm"], cfg), lp, cfg, kind,
-                          pos, attend)
+    u, maps_a = _read(resid, lp, cfg, "attn")
+    attn_out = _attention(_norm(u, lp["attn_norm"], cfg), lp, cfg, kind, pos, attend)
     if edit_attn is not None:
         attn_out = edit_attn(attn_out)
-    resid = resid + attn_out
-    mlp_out = _mlp_out(resid, lp, cfg, kind.index if kind.slot is None else kind.slot)
+    resid, attn_out = _write(resid, attn_out, maps_a, cfg)
+    u, maps_m = _read(resid, lp, cfg, "ffn")
+    mlp_out = _mlp_out(u, lp, cfg, kind.index if kind.slot is None else kind.slot)
     if edit_mlp is not None:
         mlp_out = edit_mlp(mlp_out)
-    return resid + mlp_out, attn_out, mlp_out
+    resid, mlp_out = _write(resid, mlp_out, maps_m, cfg)
+    return resid, attn_out, mlp_out, _Seen(u, (maps_a, maps_m) if cfg.n_streams > 1 else ())
 
 
 # ---------------------------------------------------------------------------
@@ -874,8 +1125,13 @@ def _capture_into(
 
 
 def _unembed(params: LMParams, resid: jax.Array, cfg: LMConfig) -> jax.Array:
-    """Final RMSNorm → unembedding (the embedding again where tied) →
-    final-logit softcap."""
+    """(Of several streams: the learned read.) Final RMSNorm → unembedding
+    (the embedding again where tied) → final-logit softcap."""
+    if cfg.n_streams > 1:
+        from crosscoder_tpu.ops import mhc
+
+        resid = mhc.head_read(resid, params["hc_head_phi"], params["hc_head_alpha"],
+                              params["hc_head_bias"], cfg.rms_eps)
     x = _norm(resid, params["final_norm"], cfg)
     head = params["embed" if cfg.tie_embeddings else "unembed"]
     logits = jnp.einsum("bsd,vd->bsv", x, head, preferred_element_type=jnp.float32)
@@ -937,10 +1193,14 @@ def hooked_depth(cfg: LMConfig, hook_points: Sequence[str]) -> int:
 
 
 def _fresh_carry(params: LMParams, tokens: jax.Array, cfg: LMConfig, n_cap: int):
-    """What a forward's layer loop starts from: the embedded stream and a
-    zero capture buffer ``[n_cap, B, S, D]`` (None where nothing is captured)."""
+    """What a forward's layer loop starts from: the embedded stream (of
+    ``n_streams``: ``[B, S, n·D]``, a token's streams side by side, the
+    embedding in each) and a zero capture buffer ``[n_cap, B, S, D]`` (None
+    where nothing is captured)."""
     B, S = tokens.shape
     resid = _embed(params, tokens, cfg)
+    if cfg.n_streams > 1:
+        resid = jnp.tile(resid, (1, 1, cfg.n_streams))
     buf = jnp.zeros((n_cap, B, S, cfg.d_model), resid.dtype) if n_cap else None
     return resid, buf
 
@@ -993,10 +1253,14 @@ def _scan_blocks(
     emit: Callable | None = None,
 ):
     """THE layer loop, behind every forward in this module: blocks
-    ``[lo, lo + k)`` carrying ``carry = (resid, buf)``. Per layer:
-    residual-site edits, the residual-site capture, :func:`_block` (with the
-    sublayer-site edits inside it), the sublayer-site captures. Returns
-    ``((resid, buf), ys)`` as a scan does.
+    ``[lo, lo + k)`` carrying ``carry = (resid, buf)`` — ``resid`` ``[B, S,
+    D]``, or ``[B, S, n·D]`` where a token carries ``n_streams`` (side by
+    side: one row a token, so no relayout between the carry and the stream
+    maps' tiles); the
+    residual hooks then see the streams' mean (:func:`_stream_mean`). Per
+    layer: residual-site edits, the residual-site capture, :func:`_block`
+    (with the sublayer-site edits inside it), the sublayer-site captures.
+    Returns ``((resid, buf), ys)`` as a scan does.
 
     Layers of one class (:func:`layer_classes`) are one stack of leaves and
     run under one ``lax.scan`` (``run`` below); a table of ONE class — the
@@ -1021,9 +1285,8 @@ def _scan_blocks(
     - ``pos`` / ``attend``: how attention is reached (:func:`_block`);
     - ``edits``: ``(fns, (layer, site) pairs, values)``, parallel tuples.
       With none, the body traces exactly the capture-only op sequence;
-    - ``emit(lp, resid, attn_out)``: a per-layer output of the caller's own
-      (``ys``, in layer order), from the stream entering the block and its
-      attention contribution.
+    - ``emit(lp, seen)``: a per-layer output of the caller's own (``ys``, in
+      layer order), from the block's leaves and what it saw (:class:`_Seen`).
     """
     slots = _slots(capture)
     # static: skip the sublayer-capture FMAs entirely on resid-only runs
@@ -1042,6 +1305,22 @@ def _scan_blocks(
                 new = fn(x, edit_values[j])
                 x = jnp.where(edit_arr[j] == i, new, x)
         return x
+
+    def edited_resid(resid, i):
+        if cfg.n_streams == 1:
+            return edited(resid, i, _SITE_RESID)
+        if not any(site == _SITE_RESID for _, site in edit_layers):
+            return resid
+        # an edit of what the hooks see — the streams' mean — shifts every
+        # stream by the same amount: the mean becomes the edited value, the
+        # streams' deviations from it stay
+        m = _stream_mean(resid, cfg)
+        return resid + jnp.tile(edited(m, i, _SITE_RESID) - m, (1, 1, cfg.n_streams))
+
+    # a residual slot at ``n_layers`` is only ever the virtual layer's: with
+    # several streams the loop then skips forming their mean in every block
+    in_body = slots if cfg.n_streams == 1 or any(
+        layer < cfg.n_layers for layer, site in capture if site == _SITE_RESID) else None
 
     classes, stacks = layer_classes(cfg), class_stacks(params, cfg)
     one_class = len(classes) == 1
@@ -1068,10 +1347,11 @@ def _scan_blocks(
             lp, s = xs
             lp = {**lp, **held}
             i = s if isinstance(shift, int) and shift == 0 else s + shift
-            entering = resid = edited(resid, i, _SITE_RESID)
-            buf = _capture_into(buf, resid, i, slots)
+            resid = edited_resid(resid, i)
+            if in_body is not None:
+                buf = _capture_into(buf, _stream_mean(resid, cfg), i, slots)
             kind = _layer_kind(cfg, i) if one_class else _layer_kind(cfg, i, classes[c], s)
-            resid, attn_out, mlp_out = _block(
+            resid, attn_out, mlp_out, seen = _block(
                 resid, lp, cfg, kind,
                 edit_attn=functools.partial(edited, i=i, site=_SITE_ATTN),
                 edit_mlp=functools.partial(edited, i=i, site=_SITE_MLP),
@@ -1081,7 +1361,7 @@ def _scan_blocks(
                 buf = _capture_into(buf, attn_out, i, slots, _SITE_ATTN)
             if _SITE_MLP in captured_sites:
                 buf = _capture_into(buf, mlp_out, i, slots, _SITE_MLP)
-            return (resid, buf), (emit(lp, entering, attn_out) if emit else None)
+            return (resid, buf), (emit(lp, seen) if emit else None)
 
         return jax.lax.scan(body, carry, (stacked, at))
 
@@ -1122,8 +1402,8 @@ def _scan_blocks(
               if emit and parts else None)
     if lo is None:
         resid, buf = carry
-        resid = edited(resid, jnp.int32(k), _SITE_RESID)
-        buf = _capture_into(buf, resid, jnp.int32(k), slots)
+        resid = edited_resid(resid, jnp.int32(k))
+        buf = _capture_into(buf, _stream_mean(resid, cfg), jnp.int32(k), slots)
         carry = (resid, buf)
     return carry, ys
 
@@ -1264,19 +1544,38 @@ def expert_load(params: LMParams, tokens: jax.Array, cfg: LMConfig, n_scan: int)
     gauges, and only with ``obs`` on."""
     from crosscoder_tpu.ops import moe
 
-    def routed(lp, resid, attn_out):
+    def routed(lp, seen):
         counts = jnp.zeros((cfg.n_experts,), jnp.int32)
         if "router" not in lp:
             return counts
         # the router reads what the block's MLP sublayer reads
-        x = _norm(resid + attn_out, lp["pre_ffw_norm"], cfg)
+        x = _norm(seen.mlp_in, lp["pre_ffw_norm"], cfg)
         idx, _ = moe.route(x.reshape(-1, cfg.d_model), lp["router"],
-                           cfg.experts_per_tok, cfg.norm_topk_prob)
+                           cfg.experts_per_tok, cfg.norm_topk_prob,
+                           kind=cfg.router, bias=lp.get("router_bias"))
         return counts.at[idx.reshape(-1)].add(1)
 
     _, counts = _scan_blocks(
         params, cfg, (), _fresh_carry(params, tokens, cfg, 0), n_scan, emit=routed)
     return counts
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "n_scan"))
+def mhc_col_err(params: LMParams, tokens: jax.Array, cfg: LMConfig, n_scan: int) -> jax.Array:
+    """``max |colsum(M) − 1|`` over the tokens and the two sublayers of each
+    of the first ``n_scan`` layers, ``[n_scan]`` float32: how far Sinkhorn's
+    last iteration leaves the mixing matrices from doubly stochastic, which
+    is how exactly the streams' mean is the hooked residual stream. A
+    diagnostic forward like :func:`expert_load`, for the gauge
+    ``harvest/mhc_col_err``."""
+    from crosscoder_tpu.ops import mhc
+
+    def err(lp, seen):
+        return jnp.maximum(*(mhc.col_err(m, cfg.n_streams) for m in seen.maps))
+
+    _, errs = _scan_blocks(
+        params, cfg, (), _fresh_carry(params, tokens, cfg, 0), n_scan, emit=err)
+    return errs
 
 
 # ---------------------------------------------------------------------------
@@ -1314,7 +1613,7 @@ def _seg_finish_impl(
     slots = _slots(capture)
     outs = []
     for resid, buf in zip(resids, bufs):
-        buf = _capture_into(buf, resid, jnp.int32(n_scan), slots)
+        buf = _capture_into(buf, _stream_mean(resid, cfg), jnp.int32(n_scan), slots)
         outs.extend(buf[i] for i in range(buf.shape[0]))
     out = jnp.stack(outs, axis=2)                  # [B, S, n_sources, D]
     return out.astype(out_dtype) if out_dtype is not None else out
@@ -1784,6 +2083,19 @@ def tp_shardings(mesh, axis: str = "model", cfg: LMConfig | None = None) -> LMPa
             "wv": ns(None, None, axis),
             "wo": ns(None, axis, None),
         }
+        if cfg is not None and cfg.latent:
+            # the low-rank halves whole (small), the per-head halves by head
+            del layers["wq"], layers["wk"]
+            layers.update(
+                wq_a=ns(None, None, None), q_a_norm=ns(None, None),
+                wkv_a=ns(None, None, None), kv_a_norm=ns(None, None),
+                wq_nope=ns(None, None, axis), wq_rope=ns(None, None, axis),
+                wk_nope=ns(None, None, axis))
+        if cfg is not None and cfg.n_streams > 1:
+            for site in ("attn", "ffn"):        # the maps' parameters: whole
+                layers.update({f"hc_{site}_phi": ns(None, None, None),
+                               f"hc_{site}_alpha": ns(None, None),
+                               f"hc_{site}_bias": ns(None, None)})
         if cfg is None or cfg.block_style == "sandwich":
             layers["post_attn_norm"] = ns(None, None)
             layers["post_ffw_norm"] = ns(None, None)
@@ -1799,6 +2111,8 @@ def tp_shardings(mesh, axis: str = "model", cfg: LMConfig | None = None) -> LMPa
             layers.update(router=ns(None, None, None),
                           we_gate_up=ns(None, None, None, None),
                           we_down=ns(None, None, None, None))
+            if cfg.router == "sigmoid_bias":
+                layers["router_bias"] = ns(None, None)
             if cfg.d_shared_expert:
                 layers.update(ws_gate=ns(None, None, None), ws_up=ns(None, None, None),
                               ws_down=ns(None, None, None))
@@ -1812,6 +2126,8 @@ def tp_shardings(mesh, axis: str = "model", cfg: LMConfig | None = None) -> LMPa
     out = {"embed": ns(None, axis), "final_norm": ns(None), "layers": _from_stacks(stacks)}
     if cfg is not None and not cfg.tie_embeddings:
         out["unembed"] = ns(None, axis)
+    if cfg is not None and cfg.n_streams > 1:
+        out.update(hc_head_phi=ns(None, None), hc_head_alpha=ns(None), hc_head_bias=ns(None))
     return out
 
 
@@ -2012,8 +2328,9 @@ def from_torch_state_dict(
             v = v.detach().to("cpu").float().numpy()
         return np.asarray(v, dtype=np.float32)
 
-    def leaf(path: tuple[str, ...], arr: np.ndarray) -> jax.Array:
-        arr = arr.astype(np.dtype(dt), copy=False)   # host-side cast (ml_dtypes)
+    def leaf(path: tuple[str, ...], arr: np.ndarray, as_dtype=None) -> jax.Array:
+        # host-side cast (ml_dtypes)
+        arr = arr.astype(np.dtype(dt if as_dtype is None else as_dtype), copy=False)
         if shardings is None:
             return jnp.asarray(arr)
         sh = shardings
@@ -2023,6 +2340,34 @@ def from_torch_state_dict(
 
     classes = layer_classes(cfg)
     p = "model.layers.{}."
+
+    def latent_leaves(cls: LayerClass, where: tuple) -> dict:
+        """The latent attention's leaves from DeepSeek-V3's names (ASSUMED
+        for Xing4.0: no checkpoint was read here). ``q_b`` and ``kv_b`` are
+        laid out a head — ``[nope | rope]`` and ``[k_nope | v]`` — and cut
+        here into one leaf a part; the checkpoint's rotary columns are
+        INTERLEAVED pairs (2j, 2j + 1) and are permuted to this runtime's
+        split-half layout (j, j + dr/2)."""
+        H, dr, dv = cls.n_heads, cfg.qk_rope_dim, cfg.v_head_dim
+        dn, rkv = cfg.head_dim - dr, cfg.kv_lora_rank
+        halves = np.concatenate([np.arange(0, dr, 2), np.arange(1, dr, 2)])
+        out: dict[str, list] = {k: [] for k in (
+            "wq_a", "q_a_norm", "wq_nope", "wq_rope", "wkv_a", "kv_a_norm", "wk_nope", "wv")}
+        for i in cls.layers:
+            a = (p + "self_attn.").format(i)
+            q_b = get(a + "q_b_proj.weight").T.reshape(-1, H, dn + dr)
+            kv_a = get(a + "kv_a_proj_with_mqa.weight").T
+            kv_b = get(a + "kv_b_proj.weight").T.reshape(rkv, H, dn + dv)
+            out["wq_a"].append(get(a + "q_a_proj.weight").T)
+            out["q_a_norm"].append(get(a + "q_a_layernorm.weight"))
+            out["wq_nope"].append(q_b[..., :dn].reshape(-1, H * dn))
+            out["wq_rope"].append(q_b[..., dn:][..., halves].reshape(-1, H * dr))
+            out["wkv_a"].append(np.concatenate(
+                [kv_a[:, :rkv], kv_a[:, rkv:][:, halves]], axis=1))
+            out["kv_a_norm"].append(get(a + "kv_a_layernorm.weight"))
+            out["wk_nope"].append(kv_b[..., :dn].reshape(rkv, H * dn))
+            out["wv"].append(kv_b[..., dn:].reshape(rkv, H * dv))
+        return {k: leaf((*where, k), np.stack(v)) for k, v in out.items()}
 
     def stack_of(c: int, cls: LayerClass) -> dict:
         where = ("layers",) if len(classes) == 1 else ("layers", c)
@@ -2034,11 +2379,26 @@ def from_torch_state_dict(
 
         layers = {
             "attn_norm": stack("attn_norm", p + "input_layernorm.weight", False),
-            "wq": stack("wq", p + "self_attn.q_proj.weight", True),
-            "wk": stack("wk", p + "self_attn.k_proj.weight", True),
-            "wv": stack("wv", p + "self_attn.v_proj.weight", True),
             "wo": stack("wo", p + "self_attn.o_proj.weight", True),
         }
+        if cfg.latent:
+            layers.update(latent_leaves(cls, where))
+        else:
+            layers.update(
+                wq=stack("wq", p + "self_attn.q_proj.weight", True),
+                wk=stack("wk", p + "self_attn.k_proj.weight", True),
+                wv=stack("wv", p + "self_attn.v_proj.weight", True))
+        if cfg.n_streams > 1:
+            # ASSUMED names (no Xing4.0 checkpoint was read here): a sublayer's
+            # map as a Linear ``hc_{attn,ffn}_fn.weight [n² + 2n, n·D]`` with
+            # ``hc_*_scale [3]`` and ``hc_*_base [n² + 2n]``, kept in float32
+            for site in ("attn", "ffn"):
+                for key, name, t in (("phi", "fn.weight", True), ("alpha", "scale", False),
+                                     ("bias", "base", False)):
+                    mats = [get((p + f"hc_{site}_{name}").format(i)) for i in cls.layers]
+                    layers[f"hc_{site}_{key}"] = leaf(
+                        (*where, f"hc_{site}_{key}"),
+                        np.stack([m.T if t else m for m in mats]), np.float32)
         if cfg.attn_gate == "per_head":
             # ASSUMED name (no Laguna checkpoint was read here)
             layers["w_attn_gate"] = stack("w_attn_gate", p + "self_attn.g_proj.weight", True)
@@ -2067,16 +2427,22 @@ def from_torch_state_dict(
                     for i in cls.layers])
 
             layers["router"] = stack("router", p + "mlp.gate.weight", True)
+            if cfg.router == "sigmoid_bias":
+                layers["router_bias"] = leaf((*where, "router_bias"), np.stack([
+                    get((p + "mlp.gate.e_score_correction_bias").format(i))
+                    for i in cls.layers]), np.float32)
             layers["we_gate_up"] = leaf((*where, "we_gate_up"), np.concatenate(
                 [experts("mlp.experts.{}.gate_proj.weight"),
                  experts("mlp.experts.{}.up_proj.weight")], axis=-1))
             layers["we_down"] = leaf(
                 (*where, "we_down"), experts("mlp.experts.{}.down_proj.weight"))
             if cfg.d_shared_expert:
+                # (the sigmoid-routed family names it in the plural: ASSUMED)
+                se = "mlp.shared_experts." if cfg.router == "sigmoid_bias" else "mlp.shared_expert."
                 layers.update(
-                    ws_gate=stack("ws_gate", p + "mlp.shared_expert.gate_proj.weight", True),
-                    ws_up=stack("ws_up", p + "mlp.shared_expert.up_proj.weight", True),
-                    ws_down=stack("ws_down", p + "mlp.shared_expert.down_proj.weight", True),
+                    ws_gate=stack("ws_gate", p + se + "gate_proj.weight", True),
+                    ws_up=stack("ws_up", p + se + "up_proj.weight", True),
+                    ws_down=stack("ws_down", p + se + "down_proj.weight", True),
                 )
         else:
             layers.update(
@@ -2094,6 +2460,11 @@ def from_torch_state_dict(
     }
     if not cfg.tie_embeddings:
         params["unembed"] = leaf(("unembed",), get("lm_head.weight"))
+    if cfg.n_streams > 1:
+        params.update(
+            hc_head_phi=leaf(("hc_head_phi",), get("model.hc_head_fn.weight").T, np.float32),
+            hc_head_alpha=leaf(("hc_head_alpha",), get("model.hc_head_scale"), np.float32),
+            hc_head_bias=leaf(("hc_head_bias",), get("model.hc_head_base"), np.float32))
     return params
 
 

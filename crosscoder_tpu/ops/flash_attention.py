@@ -77,9 +77,10 @@ def block_for(seq_len: int) -> int:
     return 0
 
 
-def _vmem_bytes(seq_len: int, head_dim: int, block: int, itemsize: int) -> int:
-    kv = 2 * 2 * seq_len * head_dim * itemsize          # K, V: double-buffered
-    qo = 2 * 2 * block * head_dim * itemsize
+def _vmem_bytes(seq_len: int, head_dim: int, block: int, itemsize: int,
+                bands: int = 2) -> int:
+    kv = bands * 2 * seq_len * head_dim * itemsize      # K, V (a shared key band): double-buffered
+    qo = bands * 2 * block * head_dim * itemsize
     scratch = 2 * block * _LANES * 4 + block * head_dim * 4
     scores = 3 * block * block * 4                      # s, p and a mask's worth
     return kv + qo + scratch + scores
@@ -98,18 +99,39 @@ def supported(
     return _vmem_bytes(seq_len, head_dim, block, itemsize) <= _VMEM_BUDGET_BYTES
 
 
+def latent_supported(seq_len: int, d_nope: int, d_rope: int, d_v: int, dtype) -> bool:
+    """Shapes the LATENT instance handles (:func:`flash_attention_latent`):
+    a score head of ``d_nope`` dims of its own plus ``d_rope`` rotary dims
+    whose key is shared by all heads, a value head of ``d_v``. The head's own
+    part and the value head are one column band each (whole lanes, the same
+    width); the rotary part is zero-padded to ONE lane tile and its key is a
+    third whole-sequence band in VMEM."""
+    block = block_for(seq_len)
+    if not block or d_nope % _LANES or d_v != d_nope or not 0 < d_rope <= _LANES:
+        return False
+    itemsize = jnp.dtype(dtype).itemsize
+    return _vmem_bytes(seq_len, d_nope, block, itemsize, bands=3) <= _VMEM_BUDGET_BYTES
+
+
 _NT = (((1,), (1,)), ((), ()))     # [m, d] x [n, d] -> [m, n]
 _NN = (((1,), (0,)), ((), ()))     # [m, n] x [n, d] -> [m, d]
 
 
-def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-            block: int, softcap: float, window: int):
+def _kernel(q_ref, k_ref, v_ref, *rest, block: int, softcap: float, window: int,
+            shared: bool = False):
     """One (batch, head, q-block) grid point: fold the kv tiles this
-    q-block can see into (m, l, acc), then normalise.
+    q-block can see into (m, l, acc), then normalise. ``shared``: two more
+    operands, a second query band and ONE key band all heads share, whose
+    product joins the scores (the latent form's rotary part).
 
     ``m``/``l`` are kept lane-replicated ``[block, 128]`` (the layout the
     installed splash kernel uses), so a row statistic meets a score tile
     by a lane-aligned tile, not a cross-lane broadcast."""
+    if shared:
+        qs_ref, ks_ref, o_ref, m_ref, l_ref, acc_ref = rest
+        qs = qs_ref[...]
+    else:
+        o_ref, m_ref, l_ref, acc_ref = rest
     i = pl.program_id(2)
     q_lo = i * block
     hd = q_ref.shape[-1]
@@ -124,6 +146,10 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
             q, k_ref[pl.ds(k_lo, block), :], _NT,
             preferred_element_type=jnp.float32,
         )                                                   # [block, block]
+        if shared:
+            s = s + jax.lax.dot_general(
+                qs, ks_ref[pl.ds(k_lo, block), :], _NT,
+                preferred_element_type=jnp.float32)
         if softcap:
             s = softcap * jnp.tanh(s * (1.0 / softcap))
         if masked:
@@ -222,4 +248,51 @@ def flash_attention(
         q.reshape(B, S, H * hd) * scale,
         k.reshape(B, S, KV * hd),
         v.reshape(B, S, KV * hd),
+    )
+
+
+def flash_attention_latent(
+    q_nope: jax.Array, q_rope: jax.Array, k_nope: jax.Array, k_rope: jax.Array,
+    v: jax.Array, *, scale: float,
+) -> jax.Array:
+    """Causal attention of the latent form, fused: scores ``(q_nope·k_nope +
+    q_rope·k_rope) · scale`` with ONE rotary key a position for all heads,
+    no window, no soft-cap. ``q_nope``/``k_nope [B, S, H, dn]``, ``q_rope
+    [B, S, H, dr]``, ``k_rope [B, S, 1, dr]`` (both rotated), ``v [B, S, H,
+    dv]`` → ``[B, S, H·dv]``. The caller has checked
+    :func:`latent_supported`. The same kernel as :func:`flash_attention`
+    with two operands more: the rotary dims zero-padded to a lane tile, the
+    queries' a column band a head, the key's one band that every head's grid
+    points share (it is never copied a head)."""
+    B, S, H, dn = q_nope.shape
+    dr = q_rope.shape[-1]
+    block = block_for(S)
+    pad = ((0, 0),) * 3 + ((0, _LANES - dr),)
+    kernel = functools.partial(_kernel, block=block, softcap=0.0, window=0, shared=True)
+    q_spec = pl.BlockSpec((None, block, dn), lambda b, h, i: (b, i, h))
+    kv_spec = pl.BlockSpec((None, S, dn), lambda b, h, i: (b, 0, h))
+    qs_spec = pl.BlockSpec((None, block, _LANES), lambda b, h, i: (b, i, h))
+    ks_spec = pl.BlockSpec((None, S, _LANES), lambda b, h, i: (b, 0, 0))
+    return pl.pallas_call(
+        kernel,
+        grid=(B, H, S // block),
+        in_specs=[q_spec, kv_spec, kv_spec, qs_spec, ks_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((B, S, H * dn), q_nope.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((block, _LANES), jnp.float32),       # running max
+            pltpu.VMEM((block, _LANES), jnp.float32),       # running sum
+            pltpu.VMEM((block, dn), jnp.float32),           # accumulator
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel")),
+        name="fused_causal_attention_latent",
+        interpret=_INTERPRET,
+    )(
+        # scaled in q's own dtype, as the XLA form scales it
+        q_nope.reshape(B, S, H * dn) * scale,
+        k_nope.reshape(B, S, H * dn),
+        v.reshape(B, S, H * dn),
+        (jnp.pad(q_rope, pad) * scale).reshape(B, S, H * _LANES),
+        jnp.pad(k_rope, pad).reshape(B, S, _LANES),
     )

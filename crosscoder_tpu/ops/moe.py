@@ -116,12 +116,22 @@ def supported(d_model: int, d_expert: int, dtype) -> bool:
 
 def route(
     x: jax.Array, w_router: jax.Array, top_k: int, norm_topk_prob: bool,
-    routed_scale: float = 1.0,
+    routed_scale: float = 1.0, kind: str = "softmax", bias: jax.Array | None = None,
 ) -> tuple[jax.Array, jax.Array]:
-    """``x [T, D]`` → the chosen experts ``[T, k]`` int32 (largest gate
-    first; ties to the lowest index) and their gates ``[T, k]`` float32
-    (times ``routed_scale``, after the renormalisation)."""
+    """``x [T, D]`` → the chosen experts ``[T, k]`` int32 (largest first;
+    ties to the lowest index) and their gates ``[T, k]`` float32 (times
+    ``routed_scale``, after the renormalisation). ``kind`` ``"softmax"``
+    chooses and gates by the softmax over all experts; ``"sigmoid_bias"``
+    scores each expert by a sigmoid, CHOOSES by score plus the per-expert
+    ``bias [E]`` and GATES by the unbiased scores of the chosen."""
     logits = jnp.einsum("td,de->te", x, w_router, preferred_element_type=jnp.float32)
+    if kind == "sigmoid_bias":
+        scores = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+        gates = jnp.take_along_axis(scores, idx, axis=-1)
+        if norm_topk_prob:
+            gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
+        return idx.astype(jnp.int32), gates * routed_scale
     gates, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
     if norm_topk_prob:
         gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
@@ -498,7 +508,7 @@ def _held_rows(x, idx, gates, w_gate_up, w_down, layer):
 def moe_mlp(
     x: jax.Array, w_router: jax.Array, w_gate_up: jax.Array, w_down: jax.Array,
     layer=0, *, top_k: int, norm_topk_prob: bool, routed_scale: float = 1.0,
-    first_expert: int = 0,
+    first_expert: int = 0, router: str = "softmax", router_bias: jax.Array | None = None,
 ) -> jax.Array:
     """The expert layer ``layer`` on the normed stream ``x [B, S, D]``:
     ``w_router [D, E]`` (that layer's), and the STACKED expert weights
@@ -507,14 +517,18 @@ def moe_mlp(
     layer's experts are too large to slice out for a kernel → ``[B, S, D]``
     in ``x``'s dtype. Where ``E_held < E`` the leaves are the experts
     ``[first_expert, first_expert + E_held)`` and the result is their part
-    of the routed sum (the module's docstring)."""
+    of the routed sum (the module's docstring). ``router`` is :func:`route`'s
+    ``kind`` and ``router_bias [E]`` that layer's choice bias."""
     from crosscoder_tpu import obs
 
     B, S, D = x.shape
     x2 = x.reshape(B * S, D)
     share = w_down.shape[1] < w_router.shape[1]
     with jax.named_scope("harvest/block/moe/route"):
-        idx, gates = route(x2, w_router, top_k, norm_topk_prob, routed_scale)
+        if router != "softmax":
+            obs.count("harvest/moe_sigmoid_traces")
+        idx, gates = route(x2, w_router, top_k, norm_topk_prob, routed_scale,
+                           router, router_bias)
         if share:
             obs.count("harvest/moe_held_traces")
             idx, gates = _held(idx, gates, first_expert, w_down.shape[1])

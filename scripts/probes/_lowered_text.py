@@ -8,8 +8,9 @@ written out by one tree and compared with another tree's.
 ``write`` lowers, from the tree given (its ``crosscoder_tpu`` and its
 ``benchmarks``): the mellum2 cell's harvest programs (``_seg_start_impl``,
 ``_seg_scan_impl`` at widths 1, 2 and 4, ``_seg_finish_impl``,
-``_multi_cache_impl``) and both variants of the TopK 2^15 and the ReLU 2^14
-step at the cells' sizes. ``compare`` holds two such directories against each
+``_multi_cache_impl``), the Ouro cells' and the Laguna cell's refill quanta
+(``_seg_scan_impl``, one program a class of layers) and both variants of the
+TopK 2^15 and the ReLU 2^14 step at the cells' sizes. ``compare`` holds two such directories against each
 other: the text outside the kernels byte for byte, and each Pallas kernel —
 which travels as MLIR bytecode in its call's ``backend_config``, source
 locations and absolute file names included — parsed and printed WITHOUT
@@ -77,6 +78,26 @@ def write(root: str, out: str) -> None:
         (resid,) * 2, (buf,) * 2, cfg=cfg, capture=cap, n_scan=4, out_dtype=jnp.bfloat16)
     progs["mellum2_multi_cache"] = lm._multi_cache_impl.lower(
         (params, params), sds((1, S), jnp.int32), cfg=cfg, capture=(hook,))
+    # the other two harvests the benchmark has: the Gemma-style block of the two
+    # Ouro cells and Laguna's three classes of layers, at their cells' shapes
+    import importlib
+
+    for stem, arch_name, quanta in (
+            ("ouro2.6b-pair-relu16k", "gemma2_block", ((None, 3),)),
+            ("laguna-s2.1-pair-relu16k", "laguna", ((0, 1), (1, 3), (2, 1)))):
+        conf = manifest.load_json(manifest.BENCH_DIR / "configs" / f"{stem}.json")
+        acfg = importlib.import_module(f"benchmarks.arch.{arch_name}").lm_config(conf)
+        ccc = conf["crosscoder"]
+        B2, S2 = ccc["model_batch_size"], ccc["seq_len"]
+        aparams = jax.tree_util.tree_map(
+            lambda a: sds(a.shape, a.dtype),
+            jax.eval_shape(lambda k: lm.init_params(k, acfg), jax.random.key(0)))
+        acap = lm._hook_layers(acfg, (ccc["hook_point"],))
+        for c, k in quanta:
+            progs[f"{arch_name}_seg_scan_c{c}_k{k}"] = lm._seg_scan_impl.lower(
+                aparams, sds((B2, S2, acfg.d_model), jnp.bfloat16),
+                sds((1, B2, S2, acfg.d_model), jnp.bfloat16), sds((), jnp.int32),
+                cfg=acfg, capture=acap, k=k, **({} if c is None else {"cls": c}))
     mesh = mesh_lib.make_mesh(devices=topo.devices[:1])
     for name, over in (
             ("topk32k", dict(d_in=2048, dict_size=2**15, activation="topk", topk_k=32,

@@ -407,6 +407,53 @@ def test_laguna_harvest_segments_compile(chip, one_device, cls, k):
                 and (" copy(" in line or "dynamic-slice(" in line)]
 
 
+@pytest.mark.parametrize("cls,k", [(0, 2), (1, 2)], ids=["dense-2", "sparse-2"])
+def test_xing_harvest_segments_compile(chip, one_device, cls, k):
+    """The fifth cell's refill quanta at its published widths, two 4096-token
+    sequences a forward (``benchmarks/configs/xing4.0-pair-relu16k.json``):
+    one program a class of layers — the two dense layers and two of the four
+    sparse ones — each block with the stream maps' two kernels twice (read
+    and write, around attention and around the MLP) on the carry ``[B, S,
+    4 · 3584]`` with no relayout copy of it, the latent instance of the fused
+    attention (192 / 128 heads, the shared rotary key a third band), and in
+    the sparse class the expert layer's kernels over the HELD 16 experts."""
+    import sys
+    from pathlib import Path
+
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+    from benchmarks import manifest
+    from benchmarks.arch import xing
+    from crosscoder_tpu.ops import flash_attention as fa
+    from crosscoder_tpu.ops import mhc, moe
+
+    cfg = xing.lm_config(manifest.load_json(
+        manifest.BENCH_DIR / "configs" / "xing4.0-pair-relu16k.json"))
+    B, S = 2, 4096
+    assert fa.latent_supported(S, 128, 64, 128, jnp.bfloat16)
+    assert mhc.enabled() and mhc.supported(B * S, cfg.hc, cfg.d_model, jnp.bfloat16)
+    assert moe.enabled() and moe.supported(cfg.d_model, cfg.d_expert, jnp.bfloat16)
+    params = _abstract(jax.eval_shape(
+        lambda key: lm.init_params(key, cfg), jax.random.key(0)), chip)
+    compiled = lm._seg_scan_impl.lower(
+        params, _sds((B, S, cfg.n_streams * cfg.d_model), jnp.bfloat16, chip),
+        _sds((1, B, S, cfg.d_model), jnp.bfloat16, chip),
+        _sds((), jnp.int32, chip), cfg=cfg,
+        capture=lm._hook_layers(cfg, ("blocks.6.hook_resid_pre",)), k=k, cls=cls,
+    ).compile()
+    _fits(compiled)
+    text = compiled.as_text()
+    calls = [line.split(" = ")[0] for line in text.splitlines() if "tpu_custom_call" in line]
+    assert sum("mhc_read" in c for c in calls) == 2 and sum("mhc_write" in c for c in calls) == 2
+    assert sum("fused_causal_attention_latent" in c for c in calls) == 1
+    assert " conditional(" not in text
+    # the four streams ride the scan side by side: nothing re-tiles the carry
+    carry = f"bf16[{B},{S},{cfg.n_streams * cfg.d_model}]"
+    assert not [line for line in text.splitlines()
+                if " copy(" in line and carry in line.split(" = ")[1][:60]]
+    for name in ("moe_gate_up", "moe_down", "expert_combine"):
+        assert any(name in c for c in calls) == (cls == 1), name
+
+
 @pytest.mark.parametrize("window", [0, 4096])
 def test_paged_attention_family_compiles(chip, gates_open, window):
     """Gemma-2-2B heads (8 Q / 4 KV × 256), global and sliding-window."""

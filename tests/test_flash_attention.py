@@ -149,3 +149,90 @@ def test_counters_are_noops_without_a_plane(interpret):
     q, k, v = _qkv(256, 2, 2)
     assert not obs._PLANES
     lm._attn_core(q, k, v, _cfg(2, 2, HD, 0), jnp.asarray(False))
+
+
+# ---------------------------------------------------------------------------
+# the latent instance: a score head of 128 + 64 rotary dims whose key all
+# heads share, a value head of 128
+
+
+def _latent_parts(S, H, seed=0, B=1, dn=128, dr=64, dv=128):
+    rng = np.random.default_rng(seed)
+    mk = lambda n, d, s: jnp.asarray(   # noqa: E731
+        rng.normal(size=(B, S, n, d)).astype(np.float32) * s)
+    return mk(H, dn, 1.0), mk(H, dr, 1.0), mk(H, dn, 1.0), mk(1, dr, 1.0), mk(H, dv, 1.0)
+
+
+def _expanded(parts, scale):
+    """The XLA attention on the EXPANDED heads: q, k of 192 dims, the ONE
+    rotary key repeated a head, v zero-padded to 192 and cut back."""
+    q_nope, q_rope, k_nope, k_rope, v = parts
+    B, S, H, dv = v.shape
+    q = jnp.concatenate([q_nope, q_rope], -1)
+    k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, q_rope.shape)], -1)
+    hd = q.shape[-1]
+    out = pa.ragged_attention_reference(
+        q, k, jnp.pad(v, ((0, 0),) * 3 + ((0, hd - dv),)), None, scale=scale)
+    return out.reshape(B, S, H, hd)[..., :dv].reshape(B, S, H * dv)
+
+
+@pytest.mark.parametrize("S", [384, 1024], ids=["3x128", "2x512"])
+@pytest.mark.parametrize("H", [1, 3])
+def test_latent_instance_matches_xla_at_192_128_heads_with_the_shared_rotary_key(
+        interpret, S, H):
+    assert fa.latent_supported(S, 128, 64, 128, jnp.float32)
+    parts = _latent_parts(S, H, seed=S + H)
+    got = fa.flash_attention_latent(*parts, scale=0.1)
+    assert got.shape == (1, S, H * 128)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(_expanded(parts, 0.1)),
+                               rtol=0, atol=ATOL)
+    # the rotary key really is ONE for all heads: a key a head is another result
+    q_nope, q_rope, k_nope, k_rope, v = parts
+    if H > 1:
+        rolled = jnp.stack([jnp.roll(q_rope[:, :, h], -h, axis=-1) for h in range(H)], 2)
+        other = fa.flash_attention_latent(q_nope, rolled, k_nope, k_rope, v, scale=0.1)
+        assert float(jnp.max(jnp.abs(other - got))) > 100 * ATOL
+
+
+@pytest.mark.parametrize("shape", [
+    (200, 128, 64, 128, jnp.bfloat16),      # S not a multiple of a tile
+    (1024, 64, 64, 64, jnp.bfloat16),       # the head's own part narrower than the lanes
+    (1024, 128, 64, 256, jnp.bfloat16),     # a value head of another width than the nope part
+    (1024, 128, 192, 128, jnp.bfloat16),    # a rotary part past one lane tile
+    (16384, 128, 64, 128, jnp.float32),     # three whole-sequence bands past the VMEM budget
+])
+def test_latent_supported_refuses(shape):
+    assert not fa.latent_supported(*shape)
+
+
+def test_latent_supported_accepts_the_cell_and_leaves_the_plain_shapes_alone():
+    assert fa.latent_supported(4096, 128, 64, 128, jnp.bfloat16)
+    # a third whole-sequence band in VMEM: 6.3 MB of bands, 11.0 MB with the
+    # tiles and scratch, inside the 13 MiB budget at the cell's shape
+    assert 3 * 2 * 4096 * 128 * 2 == 6_291_456
+    assert fa._vmem_bytes(4096, 128, 512, 2, bands=3) == 11_010_048 < 13 << 20
+    # every shape the plain instance accepted before it accepts now, and refuses 192
+    assert fa._vmem_bytes(4096, 128, 512, 2) == (
+        2 * 2 * 4096 * 128 * 2 + 2 * 2 * 512 * 128 * 2 + 2 * 512 * 128 * 4
+        + 512 * 128 * 4 + 3 * 512 * 512 * 4)
+    assert not fa.supported(4096, 32, 32, 192, jnp.bfloat16)
+
+
+def test_latent_attention_in_the_block_takes_the_fused_path(interpret, plane):
+    """``lm._latent_attend`` picks the kernel on the padded path at a supported
+    shape, the expanded XLA form elsewhere; both are counted."""
+    cfg = lm.LMConfig.xing4_0_29b().replace(
+        n_layers=1, layer_types=(lm.FULL,), mlp_types=(lm.DENSE,), n_heads=2, n_kv_heads=2,
+        dtype="fp32")
+    parts = _latent_parts(256, 2, seed=5)
+    kind = types.SimpleNamespace(is_local=np.bool_(False))
+    got = lm._latent_attend(parts, cfg, kind, None)
+    want = _expanded(parts, cfg.query_pre_attn_scalar ** -0.5)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0, atol=ATOL)
+    assert plane.registry.get_count("harvest/attn_latent_traces") == 1
+    assert _counts(plane) == (1, 0)
+    fa.set_interpret(False)
+    np.testing.assert_array_equal(
+        np.asarray(lm._latent_attend(parts, cfg, kind, None)), np.asarray(want))
+    assert plane.registry.get_count("harvest/attn_latent_traces") == 2
+    assert _counts(plane) == (1, 1)

@@ -268,7 +268,8 @@ def test_fused_attention_at_gqa_8_to_1_under_a_binding_window():
 # they do on the chip; a bf16 router fails the float32 limit of these tests
 
 
-def _bf16_router(x, w_router, top_k, norm_topk_prob, routed_scale=1.0):
+def _bf16_router(x, w_router, top_k, norm_topk_prob, routed_scale=1.0, kind="softmax",
+                 bias=None):
     logits = jnp.einsum("td,de->te", x, w_router,
                         preferred_element_type=jnp.float32).astype(jnp.bfloat16)
     gates, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)

@@ -544,3 +544,103 @@ def test_local_row_share():
     assert moe.local_row_share(counts, 0, 4) == pytest.approx(0.75)
     assert moe.local_row_share(counts, 4, 4) == pytest.approx(0.25)
     assert moe.local_row_share(counts, 0, 8) == 1.0
+
+
+# ---------------------------------------------------------------------------
+# the sigmoid_bias routing kind: scores by sigmoid, choice by score + bias,
+# gates from the unbiased scores of the chosen
+
+
+def _bias(seed):
+    return 0.1 * jax.random.normal(jax.random.key(100 + seed), (E,), jnp.float32)
+
+
+def _by_hand_sigmoid(x, w_router, bias, scale):
+    """One token at a time in float64 numpy: the equation, not the code."""
+    logits = np.asarray(x, np.float64) @ np.asarray(w_router, np.float64)
+    s = 1.0 / (1.0 + np.exp(-logits))
+    idx = np.argsort(-(s + np.asarray(bias, np.float64)), axis=-1, kind="stable")[:, :K]
+    g = np.take_along_axis(s, idx, axis=-1)
+    return idx, g / (g.sum(-1, keepdims=True) + 1e-20) * scale
+
+
+@pytest.mark.parametrize("scale", [1.0, 2.0])
+def test_sigmoid_routing_is_the_equation(scale):
+    w_router, _, _ = _layer(6, False)
+    x, bias = _x(6, 256, False)[0], _bias(6)
+    idx, gates = moe.route(x, w_router, K, True, scale, "sigmoid_bias", bias)
+    want_idx, want = _by_hand_sigmoid(x, w_router, bias, scale)
+    assert idx.dtype == jnp.int32 and gates.dtype == jnp.float32
+    # (a float32 near-tie may order two experts otherwise than float64 does)
+    assert (np.sort(np.asarray(idx), -1) == np.sort(want_idx, -1)).mean() > 0.999
+    same = (np.asarray(idx) == want_idx).all(-1)
+    np.testing.assert_allclose(np.asarray(gates)[same], want[same], rtol=2e-5)
+    np.testing.assert_allclose(np.asarray(gates).sum(-1), scale, rtol=1e-5)
+    # without the renormalisation the gates are the raw scores of the chosen
+    _, raw = moe.route(x, w_router, K, False, 1.0, "sigmoid_bias", bias)
+    logits = np.asarray(x) @ np.asarray(w_router)
+    np.testing.assert_allclose(
+        np.asarray(raw), np.take_along_axis(1 / (1 + np.exp(-logits)), np.asarray(idx), -1),
+        rtol=2e-5)
+
+
+def test_the_bias_moves_choices_and_never_gates():
+    w_router, _, _ = _layer(7, False)
+    x = _x(7, 256, False)[0]
+    big = jnp.zeros((E,)).at[3].set(10.0)           # expert 3 is always chosen first...
+    idx, gates = moe.route(x, w_router, K, False, 1.0, "sigmoid_bias", big)
+    assert (np.asarray(idx)[:, 0] == 3).all()
+    logits = np.asarray(x) @ np.asarray(w_router)
+    np.testing.assert_allclose(np.asarray(gates)[:, 0], 1 / (1 + np.exp(-logits[:, 3])),
+                               rtol=2e-5)           # ... and gated by its own score, under 1
+    # ties go to the lowest index: equal scores and no bias pick 0..K-1
+    idx, _ = moe.route(x, jnp.zeros((D, E)), K, True, 1.0, "sigmoid_bias", jnp.zeros((E,)))
+    assert (np.asarray(idx) == np.arange(K)).all()
+
+
+def test_the_softmax_kind_traces_what_it_traced(tmp_path):
+    """``route`` with the default kind is the parent's op sequence (its
+    equation list is held by ``test_every_expert_held_is_the_whole_layer_op_
+    for_op`` through ``moe_mlp``), and only the sigmoid kind is counted."""
+    w_router, w_gate_up, w_down = _layer(5, False)
+    x = _x(5, 64, False)
+    prims = [str(e.primitive) for e in jax.make_jaxpr(
+        lambda x: moe.route(x, w_router, K, True))(x[0]).jaxpr.eqns]
+    assert "logistic" not in prims and prims.count("top_k") == 1
+    bias = _bias(5)
+    assert prims == [str(e.primitive) for e in jax.make_jaxpr(
+        lambda x: moe.route(x, w_router, K, True, 1.0, "softmax", bias))(x[0]).jaxpr.eqns]
+    cfg = CrossCoderConfig(obs="on", obs_dir=str(tmp_path / "obs"), log_backend="null")
+    plane = obs.acquire(cfg)
+    try:
+        moe.moe_mlp(x, w_router, w_gate_up, w_down, 0, top_k=K, norm_topk_prob=True)
+        assert plane.registry.get_count("harvest/moe_sigmoid_traces") == 0
+        f = jax.jit(lambda x: moe.moe_mlp(
+            x, w_router, w_gate_up, w_down, 0, top_k=K, norm_topk_prob=True,
+            routed_scale=2.0, router="sigmoid_bias", router_bias=_bias(5)))
+        for _ in range(3):
+            f(x)
+        assert plane.registry.get_count("harvest/moe_sigmoid_traces") == 1
+    finally:
+        plane.close()
+
+
+@pytest.mark.parametrize("form", ["ragged", "tiles"])
+def test_a_sigmoid_routed_held_share_matches_the_plain_loop(form, request):
+    """The held-share path, both forms of the grouped product, beneath the
+    sigmoid router: experts [4, 8) of the layer against a loop over them."""
+    if form == "tiles":
+        request.getfixturevalue("interpret")
+    w_router, w_gate_up, w_down = _layer(8, False)
+    x, bias = _x(8, 128, False), _bias(8)
+    got = moe.moe_mlp(x, w_router, w_gate_up[:, 4:8], w_down[:, 4:8], 0, top_k=K,
+                      norm_topk_prob=True, routed_scale=2.0, first_expert=4,
+                      router="sigmoid_bias", router_bias=bias)
+    idx, gates = moe.route(x[0], w_router, K, True, 2.0, "sigmoid_bias", bias)
+    want = jnp.zeros_like(x[0])
+    F = w_down.shape[2]
+    for e in range(4, 8):
+        g = jnp.sum(jnp.where(idx == e, gates, 0.0), -1)
+        h = jax.nn.silu(x[0] @ w_gate_up[0, e, :, :F]) * (x[0] @ w_gate_up[0, e, :, F:])
+        want = want + g[:, None] * (h @ w_down[0, e])
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want), rtol=2e-4, atol=2e-4)
