@@ -27,6 +27,7 @@ from typing import Any
 
 import jax
 import numpy as np
+from jax.experimental.layout import Layout
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 # leaf-name → PartitionSpec for the crosscoder param pytree.
@@ -144,6 +145,17 @@ def state_shardings(mesh: Mesh, state: Any, shard_sources: bool = False) -> Any:
         return replicated
 
     return jax.tree_util.tree_map_with_path(spec_of, state)
+
+
+def held_layout(x: Any, sharding: NamedSharding) -> Layout:
+    """The layout a device of ``sharding``'s mesh holds its shard of an array
+    like ``x`` (anything with a shape and a dtype) in — the layout a plain
+    ``device_put`` or a ``jit`` with no layout given leaves it in — asked of
+    the device itself, so it answers for a mesh this process has no array on
+    yet (a remesh target, a described topology) as for the live one."""
+    dev = sharding.mesh.devices.flat[0]
+    return Layout.from_pjrt_layout(dev.client.get_default_layout(
+        np.dtype(x.dtype), sharding.shard_shape(x.shape), dev))
 
 
 def shard_state(mesh: Mesh, state: Any, shard_sources: bool = False) -> Any:

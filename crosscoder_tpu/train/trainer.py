@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+from jax.experimental.layout import with_layout_constraint
 from jax.sharding import NamedSharding, PartitionSpec
 
 from crosscoder_tpu import obs
@@ -78,6 +79,7 @@ def variant_for_step(
 def make_step_body(
     cfg: CrossCoderConfig, mesh, tx, with_metrics: bool = True,
     aux_on: bool = True, mask_refresh: bool = True, l1_input: bool = False,
+    held_in: dict[str, NamedSharding] | None = None,
 ) -> Callable[..., tuple[TrainState, dict[str, jax.Array]]]:
     """The UNJITTED train-step body :func:`make_train_step` compiles.
 
@@ -118,6 +120,21 @@ def make_step_body(
     exchanged quantized with error feedback; optimizer, clipping, and
     schedules run outside on the (near-exact) mean gradient, so the step's
     update math is otherwise identical.
+
+    ``held_in`` (the parameters' shardings, given by
+    :func:`make_train_step` on a TPU mesh) hands each gradient to the
+    optimizer in the layout its parameter — and with it both Adam moments
+    — is held in and crosses the step's boundary in: the one the mesh's
+    devices keep such a shard in (``mesh_lib.held_layout``, asked of the
+    device when the step is traced). Without it the TPU compiler computes
+    the update of a leaf whose default tiling the products do not want
+    (``W_dec [H, n, d]`` with ``n = 2`` second-minor) in the gradient's
+    layout and then copies the new master and both new moments, whole,
+    back into the layout they are donated in — three relayouts a step of
+    the step's largest arrays; with it the one array relaid is the bf16
+    gradient (docs/TUNING.md "The optimizer update's layout"). None (the
+    CPU; the fleet) traces exactly the step it always traced, and so does
+    the quantized-gradient step whatever is given.
 
     ``cfg.sparse_bwd`` (the scatter-accumulate backward plane,
     docs/SCALING.md "Sparse backward plane") needs no key of its own in
@@ -232,6 +249,10 @@ def make_step_body(
             kwargs["dead_mask"] = dead
             kwargs["aux_coeff"] = cfg.aux_k_coeff * warm_fn(state.step)
         (loss, losses), grads = grad_fn(state.params, x, l1_coeff, **kwargs)
+        if held_in is not None:
+            grads = {name: with_layout_constraint(g, mesh_lib.held_layout(
+                state.params[name], held_in[name]))
+                     for name, g in grads.items()}
         mets = {
             "l2_loss": losses.l2_loss,
             "l1_loss": losses.l1_loss,
@@ -349,10 +370,15 @@ def make_train_step(
     """Build the compiled train step for a given mesh/optimizer: the
     :func:`make_step_body` body jitted with donated state and the mesh's
     batch/state shardings (see that function's docstring for the step's
-    semantics and the variant knobs)."""
+    semantics and the variant knobs). On a mesh of TPU devices
+    (:func:`update_in_held_layout`) the optimizer update is computed in
+    the layout the state is held in — every step built for that mesh, by
+    whomever (the Trainer's variants, its remesh prewarm, the bench), is
+    that one program."""
     fn = make_step_body(
         cfg, mesh, tx, with_metrics=with_metrics, aux_on=aux_on,
         mask_refresh=mask_refresh,
+        held_in=state_shardings.params if update_in_held_layout(mesh) else None,
     )
     batch_sh = mesh_lib.batch_sharding(mesh)
     replicated = NamedSharding(mesh, PartitionSpec())
@@ -362,6 +388,16 @@ def make_train_step(
         out_shardings=(state_shardings, None),
         donate_argnums=(0,),
     )
+
+
+def update_in_held_layout(mesh) -> bool:
+    """Whether a step built for ``mesh`` hands its gradients to the
+    optimizer in the layouts the state is held in (``make_step_body``'s
+    ``held_in``) — the one place that decides, from the mesh the step is
+    built FOR (a described topology's and a remesh target's too), never
+    from the process that builds it: on TPU devices; the CPU's compiler
+    has one layout an array and keeps the step it always had."""
+    return mesh.devices.flat[0].platform == "tpu"
 
 
 def expand_metrics(host_metrics: dict[str, Any], n_sources: int) -> dict[str, float]:
@@ -500,6 +536,23 @@ class Trainer:
                          "backward to be faster)")
             print(f"[crosscoder_tpu] sparse backward plane active: {kind}",
                   flush=True, file=sys.stderr)
+        # likewise the layout the optimizer update is computed in: on a TPU
+        # each gradient is handed to the optimizer in the layout its master
+        # is held in (make_train_step; a flag, not a count: which leaves'
+        # relayouts that spares is the compiler's business)
+        held = update_in_held_layout(self.mesh)
+        if self._obs is not None:
+            self._obs.registry.gauge("perf/step_update_in_held_layout",
+                                     float(held))
+        if held:
+            print("[crosscoder_tpu] optimizer update computed in the layout "
+                  "each master is held in: " + "; ".join(
+                      f"{name} major_to_minor={lay.major_to_minor} "
+                      f"tiling={lay.tiling}" for name, lay in (
+                          (name, mesh_lib.held_layout(
+                              x, self._state_shardings.params[name]))
+                          for name, x in self.state.params.items())),
+                  flush=True, file=sys.stderr)
         # compiled step variants, keyed (with_metrics, aux_on, mask_refresh);
         # built lazily except the default. aux_on alternates per
         # cfg.aux_every (AuxK amortization), mask_refresh per
@@ -614,12 +667,18 @@ class Trainer:
     def _compile_scope(self, mesh=None):
         """``(mesh topology, step-knob projection hash)`` — the scope
         half of this trainer's persistent compile-cache keys; ``None``
-        (no disk lookups) when the tier is off."""
+        (no disk lookups) when the tier is off. A step whose update is
+        computed in the held layouts says so in a third element: a cache
+        directory filled before that form existed holds, under the first
+        two, the step that copies its state back every step."""
         if not compile_cache.disk_enabled():
             return None
         mesh = self.mesh if mesh is None else mesh
-        return (tuple(sorted(mesh.shape.items())),
-                compile_cache.step_digest(self.cfg.to_dict()))
+        scope = (tuple(sorted(mesh.shape.items())),
+                 compile_cache.step_digest(self.cfg.to_dict()))
+        if update_in_held_layout(mesh):
+            scope += ("update-in-held-layout",)
+        return scope
 
     def _wrap_step(self, key: tuple[bool, bool, bool], fn: Callable) -> Callable:
         """Compile-event observation + persistent-cache scoping for one

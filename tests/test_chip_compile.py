@@ -529,3 +529,78 @@ def test_sharded_train_step_compiles(chip, topo, shape):
     compiled = _compiled_train_step(
         cfg, mesh_lib.make_mesh(*shape, devices=topo.devices), with_metrics=True)
     assert "all-reduce" in compiled.as_text()
+
+
+# ---------------------------------------------------------------------------
+# the optimizer update's layout: no whole-table relayout inside the step
+
+
+def _entry_copies(text: str, dtype_shape: str) -> list[str]:
+    """The plain ``copy`` ops of the ENTRY computation whose result is
+    ``dtype_shape`` (as ``f32[2048,2,256]``): a relayout of a whole table
+    that runs as an op of its own, every step."""
+    lines = text.splitlines()
+    start = next(i for i, line in enumerate(lines) if line.startswith("ENTRY "))
+    end = next(i for i in range(start, len(lines)) if lines[i].startswith("}"))
+    needle = f" = {dtype_shape}{{"
+    return [line.strip()[:160] for line in lines[start:end]
+            if needle in line and " copy(" in line]
+
+
+@pytest.mark.parametrize("over,shape,variant", [
+    ({}, (1, 1), "bare"), ({}, (1, 1), "full"),
+    (dict(activation="topk", topk_k=8, l1_coeff=0.0), (1, 1), "bare"),
+    (dict(data_axis_size=2, model_axis_size=2), (2, 2), "bare"),
+], ids=["relu-bare", "relu-full", "topk-dense-bare", "relu-2x2-bare"])
+def test_step_updates_its_state_in_the_layout_it_is_held_in(
+        topo, over, shape, variant, monkeypatch):
+    """The finding of PR 36, held without a chip at toy sizes (dict 2048,
+    d_in 256, batch 512, two sources): built as the CPU's step is built,
+    every variant of the step — the dense-tier TopK step and the step over
+    a mesh, a shard a device, too — ends in three ``copy`` ops that put the
+    new ``W_dec`` and its two Adam moments, whole, back into the layout
+    they were donated in. Built as ``make_train_step`` builds it for a
+    mesh of TPU devices — each gradient handed to the optimizer in the
+    layout the device says it holds such a shard in — none is left, and
+    the step takes and returns every leaf in the layout it had: the one
+    ``mesh_lib.held_layout`` names."""
+    from crosscoder_tpu.parallel import mesh as mesh_lib
+    from crosscoder_tpu.train import schedules, trainer
+    from crosscoder_tpu.train.state import init_train_state, make_optimizer
+
+    cfg = CrossCoderConfig(d_in=256, dict_size=2048, batch_size=512, n_models=2,
+                           enc_dtype="bf16", log_backend="null", **over)
+    mesh = mesh_lib.make_mesh(*shape, devices=topo.devices[:shape[0] * shape[1]])
+    tx = make_optimizer(cfg, schedules.lr_schedule(cfg))
+    state = jax.eval_shape(lambda k: init_train_state(k, cfg, tx, n_data=shape[0]),
+                           jax.random.key(0))
+    shardings = mesh_lib.state_shardings(mesh, state)
+    avals = jax.tree_util.tree_map(
+        lambda a, s: _sds(a.shape, a.dtype, s), state, shardings)
+    w_dec = shardings.params["W_dec"].shard_shape(state.params["W_dec"].shape)
+    master = f"f32[{','.join(map(str, w_dec))}]"
+
+    def compiled():
+        return trainer.make_train_step(
+            cfg, mesh, tx, shardings, with_metrics=variant == "full").lower(
+            avals,
+            _sds((cfg.batch_size, cfg.n_sources, cfg.d_in), jnp.bfloat16,
+                 mesh_lib.batch_sharding(mesh)),
+            _sds((cfg.n_sources,), jnp.float32, shardings.step),
+        ).compile()
+
+    def layouts(step):
+        (state_in, *_), _ = step.input_formats
+        return ([f.layout for f in jax.tree_util.tree_leaves(state_in)],
+                [f.layout for f in jax.tree_util.tree_leaves(step.output_formats[0])])
+
+    assert trainer.update_in_held_layout(mesh)
+    step = compiled()
+    assert _entry_copies(step.as_text(), master) == []
+    monkeypatch.setattr(trainer, "update_in_held_layout", lambda mesh: False)
+    plain = compiled()
+    assert len(_entry_copies(plain.as_text(), master)) == 3
+    assert layouts(step) == layouts(plain)
+    assert [f.layout for f in step.input_formats[0][0].params.values()] == [
+        mesh_lib.held_layout(avals.params[name], shardings.params[name])
+        for name in step.input_formats[0][0].params]
