@@ -648,6 +648,7 @@ def _rms_norm(x: jax.Array, w: jax.Array, eps: float, plain: bool = False) -> ja
     return (xf * scale).astype(x.dtype)
 
 
+@jax.named_scope("harvest/block/norm")
 def _norm(x: jax.Array, w: jax.Array, cfg: LMConfig) -> jax.Array:
     return _rms_norm(x, w, cfg.rms_eps, plain=cfg.block_style == "prenorm")
 
@@ -717,6 +718,7 @@ def _layer_kind(cfg: LMConfig, i: jax.Array, cls: LayerClass | None = None,
     )
 
 
+@jax.named_scope("harvest/block/attn/rope")
 def _rope(x: jax.Array, positions: jax.Array, inv_freq: jax.Array,
           factor: Any = 1.0) -> jax.Array:
     """Rotate pairs (x[..., :r/2], x[..., r/2:r]) — HF 'split-half' layout —
@@ -880,6 +882,7 @@ def _attn_core(
 _HELD_LEAVES = ("we_gate_up", "we_down")
 
 
+@jax.named_scope("harvest/leaves")
 def _scan_leaves(layers: Mapping[str, jax.Array], take: Callable) -> tuple[dict, dict]:
     """The stacked layer leaves as ``(xs, held)``: ``xs`` cut to the scanned
     layers by ``take`` (the scan's per-layer operand), ``held`` whole."""
@@ -978,6 +981,7 @@ def _embed(params: LMParams, tokens: jax.Array, cfg: LMConfig) -> jax.Array:
         return resid
 
 
+@jax.named_scope("harvest/capture")
 def _stream_mean(resid: jax.Array, cfg: LMConfig) -> jax.Array:
     """What the residual hooks see, ``[B, S, D]``: the stream itself, or —
     of a token's ``n_streams`` — their MEAN, the one quantity the doubly
@@ -1112,6 +1116,7 @@ def _slots(capture: tuple[tuple[int, int], ...]):
             jnp.asarray([c for _, c in capture], jnp.int32))
 
 
+@jax.named_scope("harvest/capture")
 def _capture_into(
     buf: jax.Array | None, x: jax.Array, i, slots, site: int = _SITE_RESID,
 ) -> jax.Array | None:

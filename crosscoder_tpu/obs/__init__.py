@@ -20,7 +20,8 @@ as the loop. ``Trainer.close()`` closes it.
   keys) exactly like the resilience counters — the resilience channel is
   now simply the oldest of the registry's siblings;
 - compile/comm observability: step-variant compilations are reported
-  (variant key, wall time, HLO cost-analysis FLOPs/bytes) via
+  (variant key, wall time; the HLO cost analysis is kept per key,
+  ``compile_cache.record_cost``) via
   :func:`crosscoder_tpu.utils.compile_cache.observed`, and each compiled
   step's collectives are accounted through
   :mod:`crosscoder_tpu.parallel.comm_model` into
@@ -151,12 +152,9 @@ class Observability:
         r = self.registry
         r.count("perf/compiles")
         r.observe("perf/compile_s", wall_s)
-        cost = compile_cache.record_cost(key, compiled)
-        flops, bytes_ = cost["flops"], cost["bytes_accessed"]
-        if flops:
-            r.gauge("perf/compile_flops", flops)
-        if bytes_:
-            r.gauge("perf/compile_bytes_accessed", bytes_)
+        # (the cost stays per KEY, for the tuner: a last-value gauge of
+        # whichever variant compiled last meant nothing with two variants)
+        flops = compile_cache.record_cost(key, compiled)["flops"]
         try:
             self._account_comm(compiled)
         except Exception:

@@ -30,12 +30,19 @@ Two spans put the window on the span clock (docs/OBSERVABILITY.md):
 ``profile_window`` from ``start_trace`` to the return of ``stop_trace``,
 and ``profile_stop`` around the sync and ``stop_trace`` themselves — the
 seconds the stop holds the loop are a named span, not a long step.
+
+A closed window is then READ, off the loop: a short-lived thread
+``profile-reader`` (span ``profile_read``) turns the window's xplane into
+device time by the program's own scopes (:mod:`.device_scopes`, imported
+there and only there), writes ``device_scopes.json`` beside it and sets the
+``perf/device/*`` gauges, which every later log line carries.
 """
 
 from __future__ import annotations
 
 import os
 import signal
+import sys
 import threading
 import time
 from typing import Any, Callable
@@ -82,6 +89,7 @@ class ProfilerWindow:
         self._active = False
         self._t_start_ns = 0            # perf_counter_ns at start_trace
         self.windows_captured = 0
+        self._reader: threading.Thread | None = None
         self._prev_handler: Any = None
 
     @property
@@ -153,6 +161,30 @@ class ProfilerWindow:
         if self.registry is not None:
             self.registry.count("perf/profile_windows")
             self.record_memory_gauges()
+        self.join_reader()      # (a window closed while the last is still read)
+        self._reader = threading.Thread(
+            target=self._read_window, name="profile-reader", daemon=True)
+        self._reader.start()
+
+    # -- device time by scope ------------------------------------------
+    def _read_window(self) -> None:
+        """The closed window's xplane → ``perf/device/*`` (never raises: a
+        file this reader cannot make sense of costs the job a warning)."""
+        try:
+            with trace.span("profile_read"):
+                from crosscoder_tpu.obs import device_scopes
+
+                device_scopes.publish(self.out_dir, self.registry)
+        except Exception as e:
+            print(f"[crosscoder_tpu] obs: profile window not read: {e!r}",
+                  file=sys.stderr, flush=True)
+
+    def join_reader(self, timeout: float | None = 120.0) -> None:
+        """Wait for the reader of the last window (the trainer's ``finally``:
+        its span and its file land before the plane closes)."""
+        if self._reader is not None:
+            self._reader.join(timeout)
+            self._reader = None
 
     # -- device memory gauges ------------------------------------------
     def record_memory_gauges(self) -> None:

@@ -1657,6 +1657,7 @@ class Trainer:
                     profiler.uninstall_sigusr1()
             if profiler is not None:
                 profiler.stop_if_active()
+                profiler.join_reader()
             if not multi_process:
                 # background + the close() below joining the writer: on
                 # SIGTERM the fetch and the write both still land before

@@ -26,9 +26,9 @@ Two distinct persistence layers live here:
 :func:`observed` is the telemetry side (``cfg.obs``;
 docs/OBSERVABILITY.md): a jitted step variant wrapped by it AOT-compiles
 on its first call under a ``compile`` span (``source=disk|build``), and
-the event — variant key, compile wall time, HLO cost-analysis
-FLOPs/bytes, and the compiled program's collective accounting — is
-reported through the observability registry. With observability off and
+the event — variant key, compile wall time and the compiled program's
+collective accounting — is reported through the observability registry
+(its HLO cost-analysis FLOPs/bytes are kept per key, :func:`record_cost`). With observability off and
 the disk tier off nothing here wraps anything: the jitted functions are
 called exactly as before, so the off path is untouched.
 """
@@ -94,6 +94,15 @@ def enable() -> str | None:
     same program lowered from a checkout at another path has the same key
     (tests/test_compile_cache.py). Safe to call before or after backend
     init, but before the first trace.
+
+    The key also takes the digest of the registered scope names
+    (``obs/scopes.py``), through JAX's own hook for an addition to it: JAX
+    strips op metadata from a program before hashing it, so a tree that
+    adds or renames a ``jax.named_scope`` would otherwise be served the
+    older tree's executable, and a profile window would show the older
+    tree's names. Only the names reach the key — no file name, no line —
+    so a change of the table, and nothing else of the metadata, compiles
+    each program once more.
     """
     import jax
 
@@ -113,7 +122,26 @@ def enable() -> str | None:
     # by many sub-second compiles (decoder norms, cosines, logit lens)
     # that a 1.0 s threshold would silently re-pay in every process
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    _key_scope_names()
     return cache_dir or None
+
+
+def _key_scope_names() -> None:
+    """``scopes:<digest>`` into every persistent-cache key from here on
+    (``jax._src.cache_key.custom_hook``: "any addition to the cache key",
+    read by ``get()`` at each call). The directory stays the one JAX was
+    given: a cache placed by ``JAX_COMPILATION_CACHE_DIR`` is not moved."""
+    from crosscoder_tpu.obs import scopes
+
+    addition = f"scopes:{scopes.digest()}"
+    try:
+        from jax._src import cache_key
+
+        cache_key.custom_hook = lambda: addition
+    except Exception as e:      # the hook moved: names may be an older tree's
+        print(f"[crosscoder_tpu] compile cache: scope names not in the key "
+              f"({e!r}); perf/device/scoped_share will say if they are stale",
+              file=sys.stderr, flush=True)
 
 
 # ---------------------------------------------------------------------------
@@ -292,7 +320,9 @@ def _canon(o: Any) -> str:
 
 def backend_fingerprint() -> str:
     """The compile-environment identity a persisted executable is only
-    valid under: jax/jaxlib versions, backend platform, and device kind.
+    valid under: jax/jaxlib versions, backend platform, device kind, and
+    the digest of the registered scope names (``obs/scopes.py``: an
+    executable carries the names it was compiled with).
     Part of every disk key AND stored in every entry — a version bump or
     hardware change makes old entries unreachable (key changes) and
     unloadable (stored fingerprint check), so stale binaries can never
@@ -303,13 +333,16 @@ def backend_fingerprint() -> str:
     the answer mid-process."""
     import jax
 
+    from crosscoder_tpu.obs import scopes
+
+    names = f"scopes={scopes.digest()}"     # an executable carries its names
     try:
         devs = jax.devices()
         kind = devs[0].device_kind if devs else "none"
         return (f"jax={jax.__version__},jaxlib={_jaxlib_version()},"
-                f"backend={jax.default_backend()},device={kind}")
+                f"backend={jax.default_backend()},device={kind},{names}")
     except Exception:
-        return f"jax={jax.__version__},backend=unknown"
+        return f"jax={jax.__version__},backend=unknown,{names}"
 
 
 def _jaxlib_version() -> str:

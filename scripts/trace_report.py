@@ -13,6 +13,12 @@ the time go" from the terminal. Exits nonzero on malformed input
 (unreadable file, non-trace JSON, events missing required fields), so CI
 and drivers can gate on trace validity.
 
+Where the job closed a profile window, its ``device_scopes.json`` (device
+self time by XLA module and by the program's own ``jax.named_scope``s, written
+beside the window's xplane by ``obs/device_scopes.py``) is printed as a second
+table: the newest under ``<the trace's directory>/profile``, or the file
+``--device-scopes`` names.
+
 Accepts both Chrome trace-event container forms: the JSON-object form
 ``{"traceEvents": [...]}`` (what :class:`crosscoder_tpu.obs.trace.SpanTracer`
 writes) and the bare JSON-array form.
@@ -23,6 +29,7 @@ from __future__ import annotations
 import argparse
 import json
 import sys
+from pathlib import Path
 
 
 def load_trace(path: str) -> tuple[list[dict], int]:
@@ -127,9 +134,47 @@ def summarize(events: list[dict]) -> tuple[list[dict], float | None]:
     return rows, bubble
 
 
+def device_scopes_file(trace: str, named: str | None) -> Path | None:
+    """The file ``--device-scopes`` names, else the newest one under the
+    profile directory beside the trace (``<obs_dir>/profile``, the default)."""
+    if named:
+        return Path(named)
+    found = sorted(Path(trace).resolve().parent.glob(
+        "profile/plugins/profile/*/device_scopes.json"),
+        key=lambda p: p.stat().st_mtime)
+    return found[-1] if found else None
+
+
+def print_device_scopes(path: Path) -> None:
+    reading = json.loads(path.read_text())
+    steps = reading["steps"]
+    rows = sorted(((seconds, module, scope)
+                   for module, row in reading["by_scope"].items()
+                   for scope, seconds in row.items()), reverse=True)
+    total = sum(r[0] for r in rows) or 1.0
+    print(f"\ndevice time by scope: window of {steps} steps on "
+          f"{reading['n_devices']} device(s), busy {reading['busy_s']:.4f} s, "
+          f"{100 * reading['scoped_share']:.1f}% of self time under a registered "
+          f"scope ({path})")
+    hdr = f"{'module':<32} {'scope':<30} {'ms/step':>10} {'share':>7}"
+    print(hdr)
+    print("-" * len(hdr))
+    for seconds, module, scope in rows:
+        print(f"{module[:32]:<32} {scope:<30} "
+              f"{1e3 * seconds / max(steps, 1):>10.3f} {100 * seconds / total:>6.2f}%")
+    if reading.get("longest"):
+        print("\nlongest ops (a fusion is credited whole to the scope it names):")
+        for module, op, scope, seconds in reading["longest"]:
+            print(f"{module[:32]:<32} {scope:<30} "
+                  f"{1e3 * seconds / max(steps, 1):>10.3f}  {op[:60]}")
+
+
 def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("trace", help="path to trace.json")
+    ap.add_argument("--device-scopes", default=None,
+                    help="a profile window's device_scopes.json (default: the "
+                         "newest under <the trace's directory>/profile)")
     args = ap.parse_args(argv)
     try:
         events, dropped = load_trace(args.trace)
@@ -151,6 +196,9 @@ def main(argv: list[str] | None = None) -> int:
     if bubble is not None:
         print(f"\nrefill_bubble_frac: {bubble:.4f}  "
               f"(refill_wait / (step + refill_wait) totals)")
+    scopes_file = device_scopes_file(args.trace, args.device_scopes)
+    if scopes_file is not None:
+        print_device_scopes(scopes_file)
     if dropped:
         print(f"WARNING: trace truncated — {dropped} events dropped at the "
               f"tracer's in-memory cap", file=sys.stderr)

@@ -297,14 +297,17 @@ from crosscoder_tpu.analysis.contracts.hlo_rules import \
     lower_step_text as _lower_step_text  # noqa: E402
 
 
-def test_step_hlo_independent_of_obs_config():
-    """cfg.obs / obs_dir / profile_steps / log_print_every are host-side
-    knobs: the compiled train step must be byte-identical across them."""
-    texts = []
-    for extra in ({}, dict(obs="on", obs_dir="/tmp/x",
-                           profile_steps="3:5", log_print_every=7)):
-        texts.append(_lower_step_text(tiny_cfg(**extra)))
-    assert texts[0] == texts[1]
+@pytest.mark.parametrize("extra", [
+    dict(obs="on", obs_dir="/tmp/x", profile_steps="3:5", log_print_every=7),
+    dict(obs="on"),                                 # the plane, no window
+    dict(profile_dir="/tmp/x/p"),                   # a window (read at its close), no plane
+], ids=["obs-and-window", "obs-no-window", "window-no-obs"])
+def test_step_hlo_independent_of_obs_config(extra):
+    """cfg.obs / obs_dir / profile_steps / profile_dir / log_print_every are
+    host-side knobs: the compiled train step must be byte-identical across
+    them — the plane, a profile window and the reader of its close
+    (obs/device_scopes.py) are nowhere in the program."""
+    assert _lower_step_text(tiny_cfg()) == _lower_step_text(tiny_cfg(**extra))
 
 
 def test_obs_adds_no_host_device_transfers(monkeypatch):
