@@ -875,20 +875,25 @@ def _attn_core(
     )
 
 
-# The expert leaves are never sliced per layer: one layer's are 0.8 GB at
-# Mellum2's sizes, and a slice that feeds a kernel is a copy. They stay
-# STACKED beside the scanned leaves and the expert layer indexes them in
-# place by the layer's slot in its class's stack (``ops/moe.py``).
+# Every leaf of a class's stack is read where it lies: the scan's body takes
+# layer ``s``'s leaves out of the stack by index (:func:`_layer_leaves`), and
+# the product that consumes one reads it from there. What sets the expert
+# leaves apart is that a KERNEL consumes them: a ``pallas_call``'s operand
+# is a whole array, so an index in front of it would be a copy (0.8 GB a
+# layer at Mellum2's sizes). They stay STACKED and the expert layer's kernels
+# index them themselves, by the layer's slot in its class's stack
+# (``ops/moe.py``).
 _HELD_LEAVES = ("we_gate_up", "we_down")
 
 
 @jax.named_scope("harvest/leaves")
-def _scan_leaves(layers: Mapping[str, jax.Array], take: Callable) -> tuple[dict, dict]:
-    """The stacked layer leaves as ``(xs, held)``: ``xs`` cut to the scanned
-    layers by ``take`` (the scan's per-layer operand), ``held`` whole."""
-    held = {k: layers[k] for k in _HELD_LEAVES if k in layers}
-    xs = {k: take(v) for k, v in layers.items() if k not in held}
-    return xs, held
+def _layer_leaves(stack: Mapping[str, jax.Array], s: jax.Array) -> dict:
+    """Layer ``s``'s leaves out of its class's WHOLE stack, by index — no
+    range of the stack is ever cut out ahead of the scan — with the
+    ``_HELD_LEAVES`` left stacked."""
+    return {k: v if k in _HELD_LEAVES
+            else jax.lax.dynamic_index_in_dim(v, s, 0, keepdims=False)
+            for k, v in stack.items()}
 
 
 def _attn_out(a: jax.Array, lp: Mapping[str, jax.Array], cfg: LMConfig) -> jax.Array:
@@ -1280,11 +1285,13 @@ def _scan_blocks(
       which ends with the VIRTUAL layer ``k`` — resid_pre of the first
       unscanned block (== the final resid_post when ``k == n_layers``),
       edited and captured like any other. A TRACED ``lo`` is one segment of
-      a longer job (``dynamic_slice`` on the stacked leaves, so one compiled
-      program serves every segment of a given width — no per-range
-      recompiles and no pre-split param copies); on a table of several
-      classes the segment lies inside one run and names its class ``cls``
-      (static). Its virtual layer is the job's business
+      a longer job: one compiled program serves every segment of a given
+      width (no per-range recompiles), and static or traced, a range's
+      layers take their leaves out of the class's whole stack by index
+      inside the scan's body (:func:`_layer_leaves`) — the range is never
+      cut out of the stack, which would copy its weights once a call. On a
+      table of several classes the segment lies inside one run and names its
+      class ``cls`` (static). Its virtual layer is the job's business
       (:func:`_seg_finish_impl`);
     - the carry: :func:`_fresh_carry`, or a segment's donated one;
     - ``pos`` / ``attend``: how attention is reached (:func:`_block`);
@@ -1334,23 +1341,18 @@ def _scan_blocks(
         """``n`` layers of class ``c`` from ``slot`` of its stack (static or
         traced), under one scan; a layer's id is its slot plus ``shift``
         (static 0 on a table of one class)."""
-        if isinstance(slot, int):
-            # TransformerLens-style stop_at_layer: scan only the blocks below the
-            # highest needed layer (the reference harvests with FULL forwards even
-            # for a mid-stack hook — reference buffer.py:81-89 — wasting every layer
-            # above it; at blocks.14 of 26 that is ~46% of the forward FLOPs)
-            stacked, held = _scan_leaves(stacks[c], lambda x: x[slot:slot + n])
-            at = jnp.arange(n, dtype=jnp.int32)
-            at = at + slot if slot else at
-        else:
-            stacked, held = _scan_leaves(
-                stacks[c], lambda x: jax.lax.dynamic_slice_in_dim(x, slot, n, axis=0))
-            at = slot + jnp.arange(n, dtype=jnp.int32)
+        # TransformerLens-style stop_at_layer: scan only the blocks below the
+        # highest needed layer (the reference harvests with FULL forwards even
+        # for a mid-stack hook — reference buffer.py:81-89 — wasting every layer
+        # above it; at blocks.14 of 26 that is ~46% of the forward FLOPs).
+        # The scan runs over the layers' slots ALONE and its body indexes the
+        # class's whole stack: a range of the stack cut out in front of the
+        # loop is a copy of every scanned weight, once a call.
+        at = slot + jnp.arange(n, dtype=jnp.int32)
 
-        def body(carry, xs):
+        def body(carry, s):
             resid, buf = carry
-            lp, s = xs
-            lp = {**lp, **held}
+            lp = _layer_leaves(stacks[c], s)
             i = s if isinstance(shift, int) and shift == 0 else s + shift
             resid = edited_resid(resid, i)
             if in_body is not None:
@@ -1368,7 +1370,7 @@ def _scan_blocks(
                 buf = _capture_into(buf, mlp_out, i, slots, _SITE_MLP)
             return (resid, buf), (emit(lp, seen) if emit else None)
 
-        return jax.lax.scan(body, carry, (stacked, at))
+        return jax.lax.scan(body, carry, at)
 
     if one_class:
         carry, ys = run(carry, 0, 0 if lo is None else lo, k)
@@ -1651,7 +1653,7 @@ class SegmentedHarvest:
     # segments bound the refresh bubble tighter (a quantum lands inside
     # whichever train step queues behind it) but each segment dispatch
     # costs host time; where the balance sits on a chip is not measured
-    # (ROADMAP S3).
+    # (``PERF.md`` §7; no ROADMAP item holds it).
     SEG_LAYERS = 3
 
     def __init__(

@@ -256,6 +256,13 @@ def one_device(monkeypatch):
     monkeypatch.setattr(jax, "device_count", lambda *a: 1)
 
 
+def _ouro_cfg() -> lm.LMConfig:
+    """The Ouro cells' subject LM (``benchmarks/configs/ouro2.6b-pair-*``)."""
+    return lm.LMConfig(
+        vocab_size=49_152, d_model=2048, n_layers=HOOK_LAYER, n_heads=16,
+        n_kv_heads=16, head_dim=128, d_ff=5632, query_pre_attn_scalar=128.0)
+
+
 @pytest.mark.parametrize("heads", ["ouro-16x128", "gemma2-2b-8/4x256"])
 def test_fused_attention_compiles_inside_the_harvest(chip, one_device, heads):
     """The refill's segment program (``_seg_scan_impl``, 3 blocks of a
@@ -264,12 +271,8 @@ def test_fused_attention_compiles_inside_the_harvest(chip, one_device, heads):
     4096 window inert) and at Gemma-2-2B's (8 Q / 4 KV x 256)."""
     from crosscoder_tpu.ops import flash_attention as fa
 
-    if heads.startswith("ouro"):
-        cfg = lm.LMConfig(
-            vocab_size=49_152, d_model=2048, n_layers=HOOK_LAYER, n_heads=16,
-            n_kv_heads=16, head_dim=128, d_ff=5632, query_pre_attn_scalar=128.0)
-    else:
-        cfg = lm.LMConfig.gemma2_2b().replace(n_layers=HOOK_LAYER)
+    cfg = (_ouro_cfg() if heads.startswith("ouro")
+           else lm.LMConfig.gemma2_2b().replace(n_layers=HOOK_LAYER))
     B, S = 4, 1024
     assert fa.supported(S, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, jnp.bfloat16)
     params = _abstract(jax.eval_shape(
@@ -286,6 +289,55 @@ def test_fused_attention_compiles_inside_the_harvest(chip, one_device, heads):
     # the kernel reads and writes the projections' own [B, S, H*hd] layout:
     # nothing head-major is ever built
     assert f"[{B},{cfg.n_heads},{S},{cfg.head_dim}]" not in text
+
+
+@pytest.mark.parametrize("table", ["one-class", "two-class"])
+def test_harvest_segment_reads_its_weights_where_they_lie(chip, one_device, table):
+    """A refill quantum that is a strict SUB-RANGE of its class's stack
+    copies none of it: the scan's body indexes the whole stack, so the
+    compiled ``_seg_scan_impl`` holds no array of the quantum's ``k`` layers
+    of a weight (what a ``dynamic_slice`` in front of the ``while`` made:
+    206 MB read and written a layer-call at Ouro's widths, 8.8 ms a step of
+    the Ouro cells), and its temporaries stay under ONE layer's leaves plus
+    the carries. At the Ouro cells' table (one class of 14, ``k`` 3) and on
+    a table of two classes whose second stack (4) is deeper than its quantum
+    (2) — the xing cell's shape of the problem, at small widths."""
+    import math
+    import re
+
+    if table == "one-class":
+        cfg, hook, (B, S), k, cls = _ouro_cfg(), HOOK_LAYER, (4, 1024), 3, None
+    else:
+        cfg = lm.LMConfig(
+            vocab_size=4096, d_model=1024, n_layers=6, n_heads=8, n_kv_heads=4,
+            head_dim=128, d_ff=4096, query_pre_attn_scalar=128.0,
+            heads_by_layer=(4, 4, 8, 8, 8, 8))
+        hook, (B, S), k, cls = 6, (1, 256), 2, 1
+        assert [len(c.layers) for c in lm.layer_classes(cfg)] == [2, 4]
+    params = _abstract(jax.eval_shape(
+        lambda key: lm.init_params(key, cfg), jax.random.key(0)), chip)
+    carries = (_sds((B, S, cfg.d_model), jnp.bfloat16, chip),
+               _sds((1, B, S, cfg.d_model), jnp.bfloat16, chip))
+    compiled = lm._seg_scan_impl.lower(
+        params, *carries, _sds((), jnp.int32, chip), cfg=cfg,
+        capture=lm._hook_layers(cfg, (f"blocks.{hook}.hook_resid_pre",)),
+        k=k, cls=cls,
+    ).compile()
+    stack = lm.class_stacks(params, cfg)[cls or 0]
+    assert all(v.shape[0] > k for v in stack.values())      # a strict sub-range
+    results = [(line, m.group(1)) for line in compiled.as_text().splitlines()
+               if (m := re.search(r" = (\w+\[[\d,]*\])", line))]
+    under_leaves = [line for line, shape in results
+                    if "harvest/leaves" in line and f"[{k}," in shape]
+    assert not under_leaves, under_leaves[:3]
+    cut = {"[" + ",".join(map(str, (k, *v.shape[1:]))) + "]"
+           for v in stack.values() if v.ndim == 3}
+    copies = [line for line, shape in results if shape[shape.index("["):] in cut]
+    assert not copies, copies[:3]
+    nbytes = lambda v: math.prod(v.shape) * v.dtype.itemsize   # noqa: E731
+    one_layer = sum(nbytes(v) // v.shape[0] for v in stack.values())
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < one_layer + sum(map(nbytes, carries)), (temp, one_layer)
 
 
 def test_fused_attention_window_pair_compiles(chip, one_device):

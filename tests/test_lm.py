@@ -431,37 +431,148 @@ def test_every_forward_traces_through_the_one_block(entry, monkeypatch):
     assert traced, f"{entry.__name__} spelled the block by hand"
 
 
-def test_segmented_harvest_matches_monolithic():
+def _two_shapes(heads: tuple[int, ...]) -> lm.LMConfig:
+    """The tiny config with layers of two SHAPES (2 or 4 query heads): two
+    classes of layers, one stack of leaves each."""
+    return lm.LMConfig.tiny(n_layers=len(heads)).replace(heads_by_layer=heads)
+
+
+# name -> (config, hooks, the layers [lo, lo + k) of each quantum a model)
+_SEG_CASES = {
+    "one-quantum": (lm.LMConfig.tiny(), ("blocks.2.hook_resid_pre",), [(0, 2)]),
+    # mixed sites + multi-layer: n_scan = 4 → quanta of (2, 2) layers at
+    # SEG_LAYERS = 3 (two near-equal sub-scans, not 3 + 1)
+    "mixed-sites-2+2": (
+        lm.LMConfig.tiny(),
+        ("blocks.1.hook_resid_pre", "blocks.3.hook_attn_out", "blocks.2.hook_mlp_out"),
+        [(0, 2), (2, 2)]),
+    # a traced ``lo`` STRICTLY inside the stack: layers [3, 5) of 8
+    "lo-inside-the-stack": (
+        lm.LMConfig.tiny(n_layers=8),
+        ("blocks.7.hook_resid_pre", "blocks.4.hook_mlp_out"),
+        [(0, 3), (3, 2), (5, 2)]),
+    # two classes, the second's stack (5) deeper than SEG_LAYERS: 1 | 3 + 2
+    "two-classes-deep-stack": (
+        _two_shapes((4, 2, 2, 2, 2, 2, 4)),
+        ("blocks.6.hook_resid_pre", "blocks.3.hook_attn_out"),
+        [(0, 1), (1, 3), (4, 2)]),
+    # runs of 1 and 2 layers three times over: the monolithic forward walks
+    # them as ONE outer scan, whose repeat (and so every slot) is traced
+    "periodic-outer-scan": (
+        _two_shapes((4, 2, 2) * 3),
+        ("blocks.9.hook_resid_pre", "blocks.4.hook_mlp_out"),
+        [(0, 1), (1, 2), (3, 1), (4, 2), (6, 1), (7, 2)]),
+}
+
+
+@pytest.mark.parametrize("case", _SEG_CASES)
+def test_segmented_harvest_matches_monolithic(case):
     """SegmentedHarvest (the refill pipeline's sub-forward dispatch quanta)
     computes the same stacked capture as run_with_cache_multi — same per-layer
-    op sequence, only the scan is cut into sub-scans. Covers mixed sublayer
-    sites, near-equal quanta (n_scan % SEG_LAYERS != 0), and the pacing count
-    contract."""
-    cfg = lm.LMConfig.tiny()
+    op sequence, only the scan is cut into sub-scans, each of which reads its
+    layers' leaves out of the class's whole stack at a TRACED slot. Covers
+    mixed sublayer sites, near-equal quanta (n_scan % SEG_LAYERS != 0), a
+    quantum strictly inside its stack, tables of several classes, and the
+    pacing count contract; the monolithic forward is itself a STATIC
+    ``n_scan < n_layers`` prefix wherever the hooks stop short of the depth,
+    and is held to the whole-depth forward's capture."""
+    cfg, hooks, quanta = _SEG_CASES[case]
     pa = lm.init_params(jax.random.key(11), cfg)
     pb = lm.init_params(jax.random.key(12), cfg)
     tokens = jax.numpy.asarray(
         np.random.default_rng(7).integers(0, cfg.vocab_size, size=(2, 12))
     )
-    for hooks in (
-        ("blocks.2.hook_resid_pre",),
-        # mixed sites + multi-layer: n_scan = 4 → quanta of (2, 2) layers at
-        # SEG_LAYERS = 3 (two near-equal sub-scans, not 3 + 1)
-        ("blocks.1.hook_resid_pre", "blocks.3.hook_attn_out",
-         "blocks.2.hook_mlp_out"),
-    ):
-        want = lm.run_with_cache_multi([pa, pb], tokens, cfg, hooks)
-        job = lm.SegmentedHarvest([pa, pb], tokens, cfg, hooks)
-        steps = 0
-        while job.step():
-            steps += 1
-        assert steps + 1 == job.n_steps == lm.SegmentedHarvest.count(cfg, hooks, 2)
-        np.testing.assert_allclose(
-            np.asarray(job.result(), np.float32), np.asarray(want, np.float32),
-            rtol=1e-5, atol=1e-5,
-        )
-        # result() after completion is idempotent; out_dtype is honored
-        assert job.result() is job.result()
-    job = lm.SegmentedHarvest([pa], tokens, cfg, ("blocks.1.hook_resid_pre",),
-                              out_dtype=jax.numpy.bfloat16)
-    assert job.result().dtype == jax.numpy.bfloat16
+    if case == "periodic-outer-scan":
+        assert lm._periodic(lm._runs(cfg, 0, cfg.n_layers)) == (0, 2, 3)
+    want = lm.run_with_cache_multi([pa, pb], tokens, cfg, hooks)
+    job = lm.SegmentedHarvest([pa, pb], tokens, cfg, hooks)
+    assert [(lo, hi - lo) for lo, hi in zip([0] + job._bounds, job._bounds)] == quanta
+    steps = 0
+    while job.step():
+        steps += 1
+    assert steps + 1 == job.n_steps == lm.SegmentedHarvest.count(cfg, hooks, 2)
+    np.testing.assert_allclose(
+        np.asarray(job.result(), np.float32), np.asarray(want, np.float32),
+        rtol=1e-5, atol=1e-5,
+    )
+    # result() after completion is idempotent
+    assert job.result() is job.result()
+    # ... and the forward that stops at the highest hooked layer (a static
+    # prefix of each stack) captures what the whole-depth forward captures
+    whole = [lm.forward(p, tokens, cfg, capture=hooks)[1] for p in (pa, pb)]
+    np.testing.assert_allclose(
+        np.asarray(want, np.float32),
+        np.stack([np.asarray(c[h], np.float32) for c in whole for h in hooks], axis=2),
+        rtol=1e-5, atol=1e-5,
+    )
+
+
+def test_segmented_harvest_honours_out_dtype():
+    cfg = lm.LMConfig.tiny()
+    tokens = jnp.asarray(np.random.default_rng(7).integers(0, cfg.vocab_size, size=(2, 12)))
+    job = lm.SegmentedHarvest(
+        [lm.init_params(jax.random.key(11), cfg)], tokens, cfg,
+        ("blocks.1.hook_resid_pre",), out_dtype=jnp.bfloat16)
+    assert job.result().dtype == jnp.bfloat16
+
+
+def _cuts_of_a_stack(closed, n_leaves: int) -> list:
+    """Equations of a traced forward that slice a STACKED layer leaf (one of
+    the jaxpr's first ``n_leaves`` inputs) anywhere but inside a layer scan's
+    body (a scan that holds no other scan): what cuts a range of layers out
+    of the stack ahead of the loop, i.e. copies it."""
+    from jax.core import jaxprs_in_params
+
+    def has_scan(jaxpr):
+        return any(e.primitive.name == "scan" or any(map(has_scan, jaxprs_in_params(e.params)))
+                   for e in jaxpr.eqns)
+
+    found = []
+
+    def walk(jaxpr, leaves, in_layer_scan):     # ``leaves``: ids (a Literal does not hash)
+        for e in jaxpr.eqns:
+            if (e.primitive.name in ("slice", "dynamic_slice", "gather")
+                    and not in_layer_scan and id(e.invars[0]) in leaves):
+                found.append(e)
+            for sub in jaxprs_in_params(e.params):
+                # operands reach a call's, a scan's or a cond's branch's
+                # inputs in order (a cond's first is the branch index)
+                ops = e.invars[len(e.invars) - len(sub.invars):]
+                inner = {id(v) for o, v in zip(ops, sub.invars) if id(o) in leaves}
+                walk(sub, inner, in_layer_scan or (
+                    e.primitive.name == "scan" and not has_scan(sub)))
+
+    walk(closed.jaxpr, {id(v) for v in closed.jaxpr.invars[:n_leaves]}, False)
+    return found
+
+
+@pytest.mark.parametrize("case", ["lo-inside-the-stack", "two-classes-deep-stack",
+                                  "periodic-outer-scan"])
+def test_no_range_of_the_stack_is_cut_out_ahead_of_the_layer_scan(case):
+    """Every forward reaches a layer's leaves ONE way: the layer scan's body
+    indexes the class's whole stack. No ``slice`` / ``dynamic_slice`` of a
+    stacked leaf stands outside that body — not at a segment's traced ``lo``,
+    not at a static prefix of the stack (``n_scan < n_layers``), not at the
+    periodic outer scan's traced repeat."""
+    cfg, hooks, quanta = _SEG_CASES[case]
+    params = lm.init_params(jax.random.key(0), cfg)
+    stacks = lm.class_stacks(params, cfg)
+    leaves = [v for stack in stacks for v in stack.values()]
+    rest = {k: v for k, v in params.items() if k != "layers"}
+    tokens = jnp.zeros((2, 12), jnp.int32)
+    capture = lm._hook_layers(cfg, hooks)
+
+    def traced(k, lo=None):     # blocks [lo, lo + k), or the static [0, k)
+        cls = lm._class_slots(cfg)[lo][0] if lo is not None and len(stacks) > 1 else None
+
+        def fn(*flat):
+            it = iter(flat)
+            p = {**rest, "layers": lm._from_stacks(
+                [{name: next(it) for name in stack} for stack in stacks])}
+            carry = lm._fresh_carry(p, tokens, cfg, len(capture))
+            return lm._scan_blocks(p, cfg, capture, carry, k, *it, cls=cls)[0]
+        return jax.make_jaxpr(fn)(*leaves, *([] if lo is None else [jnp.int32(lo)]))
+
+    for closed in [traced(cfg.n_layers - 1)] + [traced(k, lo) for lo, k in quanta if k > 1]:
+        cuts = _cuts_of_a_stack(closed, len(leaves))
+        assert not cuts, [str(e)[:200] for e in cuts[:3]]
