@@ -18,7 +18,25 @@ steps of a recurrence (a ``lax.scan``) carry the state from chunk to chunk;
 the state entering a chunk reaches its outputs through ``C`` and the decay
 from the chunk's start. Products take the inputs' dtype (bf16 on the chip)
 with float32 accumulation; decays, cumulative sums and the carried state
-are float32. XLA's form only: no kernel.
+are float32.
+
+Two forms of the scan, chosen in :func:`ssd_scan` when the program is
+traced, from what the code can observe (as ``ops/mhc`` and
+``ops/flash_attention`` choose theirs):
+
+- the kernel (:func:`enabled`, a :func:`supported` shape, no ``resets``):
+  ``pallas:ssd_scan`` walks the chunks as its sequential grid axis, a group
+  of heads a grid point, and carries each head's ``[P, N]`` state from chunk
+  to chunk in a float32 VMEM scratch. A chunk's x, B, C and dt are read once
+  and its y written once; no ``[chunks, H, P, N]`` state and no ``[Q, Q]``
+  decay reaches HBM (XLA's form writes ≈ 1 GB of them a layer-call at
+  Falcon-H1's widths);
+- XLA's form (everything else: the CPU, a mesh, an unsupported shape, the
+  paged runtime's document starts), also the oracle the kernel is pinned
+  against (tests/test_ssm_kernel.py).
+
+The choice is counted once a trace: ``harvest/ssm_scan_kernel_traces`` /
+``harvest/ssm_scan_xla_traces``. No environment gate, no config field.
 
 ``resets`` ([B, S] bool, True at a document's first token — the paged
 runtime's packed plane) zeroes the state carried INTO that token: ``L`` is
@@ -28,10 +46,36 @@ taps do not read the previous document.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 F32 = jnp.float32
+_LANES = 128
+# what the kernel's blocks, its state scratch and its temporaries may take
+# of VMEM (Falcon-H1's widths need ≈ 6.5 MiB: ``_vmem_bytes``)
+_VMEM_LIMIT_BYTES = 32 << 20
+
+# test-only: route the kernel through the Pallas interpreter (and let it
+# dispatch on the CPU backend) — same pattern as ops/mhc. Read at TRACE time.
+_INTERPRET = False
+
+
+def set_interpret(flag: bool) -> None:
+    global _INTERPRET
+    _INTERPRET = flag
+
+
+def enabled() -> bool:
+    """Whether the kernel may dispatch from this process: the interpreter
+    (CPU tests), or a TPU backend with exactly one device (a ``pallas_call``
+    is not partitioned by the SPMD partitioner)."""
+    return _INTERPRET or (
+        jax.default_backend() == "tpu" and jax.device_count() == 1
+    )
 
 
 def _segments(resets: jax.Array) -> jax.Array:
@@ -63,7 +107,21 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array, C: jax.Arr
     """The recurrence above by chunks: x ``[Bt, S, H, P]``, dt ``[Bt, S, H]``
     (after the softplus), A ``[H]`` (negative), B and C ``[Bt, S, G, N]``, D
     ``[H]`` → y ``[Bt, S, H, P]`` float32. A length that is not a multiple
-    of ``chunk`` is padded at the end (dt 0: no decay, no input) and cut."""
+    of ``chunk`` is padded at the end (dt 0: no decay, no input) and cut.
+    The kernel where it may run, else XLA's form (the module's docstring)."""
+    from crosscoder_tpu import obs
+
+    H, P = x.shape[2:]
+    G, N = B.shape[2:]
+    if resets is None and enabled() and supported(H, P, G, N, chunk, x.dtype):
+        obs.count("harvest/ssm_scan_kernel_traces")
+        return _ssd_scan_kernel(x, dt, A, B, C, D, chunk)
+    obs.count("harvest/ssm_scan_xla_traces")
+    return _ssd_scan_xla(x, dt, A, B, C, D, chunk, resets)
+
+
+def _ssd_scan_xla(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array, C: jax.Array,
+                  D: jax.Array, chunk: int, resets: jax.Array | None = None) -> jax.Array:
     Bt, S, H, P = x.shape
     G, N = B.shape[2:]
     R, Q = H // G, chunk
@@ -133,6 +191,150 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array, C: jax.Arr
                        preferred_element_type=F32) * from_start[..., None]
     y = y + D.astype(F32).reshape(G, R)[:, :, None] * xc.astype(F32)
     return y.reshape(Bt, c * Q, H, P)[:, :S]
+
+
+# ---------------------------------------------------------------------------
+# the kernel
+
+
+def _vmem_bytes(H: int, P: int, G: int, N: int, chunk: int, itemsize: int) -> int:
+    """The kernel's VMEM at these sizes: its blocks double-buffered (x's and
+    y's band of a group, B's and C's, dt), the carried states and the
+    cumulative sums, and a head's temporaries (the ``[Q, Q]`` decays, the
+    ``[P, Q]`` and ``[P, N]`` tiles, the sums a head a column)."""
+    Q, R = chunk, H // G
+    rows = -(-H // 8) * 8
+    lanes = -(-H // _LANES) * _LANES
+    blocks = R * P * Q * (itemsize + 4) + 2 * N * Q * itemsize + rows * Q * 4
+    scratch = R * P * N * 4 + rows * Q * 4
+    temps = 6 * Q * Q * 4 + 6 * P * Q * 4 + 3 * P * N * 4 + Q * lanes * 4
+    return 2 * blocks + scratch + temps
+
+
+def supported(H: int, P: int, G: int, N: int, chunk: int, dtype) -> bool:
+    """Shapes the kernel handles: heads and states of whole lanes, a chunk
+    of whole lane tiles, B's and C's bands on whole multiples of ``N`` after
+    x's, and the blocks, the state and the temporaries within the VMEM limit."""
+    if jnp.dtype(dtype) not in (jnp.dtype(jnp.bfloat16), jnp.dtype(F32)):
+        return False
+    if P % _LANES or N % _LANES or chunk % _LANES or H % G or (H * P) % N:
+        return False
+    return _vmem_bytes(H, P, G, N, chunk, jnp.dtype(dtype).itemsize) <= _VMEM_LIMIT_BYTES
+
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+_NT = (((1,), (1,)), ((), ()))          # a @ bᵀ
+_TN = (((0,), (0,)), ((), ()))          # aᵀ @ b
+
+
+def _cumsum(a: jax.Array) -> jax.Array:
+    """Inclusive cumulative sum of ``a [K, Q]`` along its lanes, as one
+    product with a triangle of ones at float32 precision: a default-precision
+    product would round ``a`` to bf16, an error of order one in an exponent
+    that reaches ≈ -400."""
+    Q = a.shape[1]
+    tri = (jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+           <= jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1))
+    return jnp.dot(a, tri.astype(F32), precision=_HIGHEST, preferred_element_type=F32)
+
+
+def _transpose(a: jax.Array) -> jax.Array:
+    """``a [K, Q]`` → ``[Q, K]`` float32, exactly: a product with the
+    identity at float32 precision (one nonzero term an entry)."""
+    Q = a.shape[1]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1))
+    return jax.lax.dot_general(eye.astype(F32), a, _NT, precision=_HIGHEST,
+                               preferred_element_type=F32)
+
+
+def _scan_kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, d_ref, y_ref, state_ref, rows_ref, *,
+                 heads: int, head_dim: int):
+    """One chunk of one group's ``heads`` heads (grid ``(batch, group,
+    chunk)``, the chunk axis sequential), TOKENS ON LANES: x's and y's band
+    ``[R·P, Q]``, B and C ``[N, Q]``, dt ``[H, Q]``. The intra-chunk product,
+    the entering state's part and D's, then the state carried to the next
+    chunk."""
+    g, k = pl.program_id(1), pl.program_id(2)
+    Q, P = x_ref.shape[1], head_dim
+    cdt = x_ref.dtype
+
+    @pl.when(k == 0)
+    def _():
+        state_ref[...] = jnp.zeros_like(state_ref)
+
+    rows_ref[...] = _cumsum(dt_ref[...] * a_ref[...])      # acs [H, Q], a head a row
+    # and a head a COLUMN, the same numbers: L's exponents are exact
+    # differences, 0 on its diagonal, as in XLA's form
+    cols = _transpose(rows_ref[...])                        # [Q, H]
+    Bc, Cc = b_ref[...], c_ref[...]                         # [N, Q]
+    cb_t = jax.lax.dot_general(Bc, Cc, _TN, preferred_element_type=F32)    # (C Bᵀ)ᵀ [s, l]
+    causal_t = (jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+                <= jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1))
+    lane = jax.lax.broadcasted_iota(jnp.int32, cols.shape, 1)
+    at_end = jax.lax.broadcasted_iota(jnp.int32, (1, Q), 1) == Q - 1
+
+    def head(h, carry):
+        hg = g * heads + h                                  # the head among all H
+        row = rows_ref[pl.ds(hg, 1), :]                     # acs [1, Q]: token l
+        col = jnp.sum(jnp.where(lane == hg, cols, 0.0), axis=1, keepdims=True)  # [Q, 1]: s
+        # [1, 1]: the chunk's whole decay (a reduction, whose layout broadcasts)
+        last = jnp.sum(jnp.where(at_end, row, 0.0), axis=1, keepdims=True)
+        band = pl.ds(pl.multiple_of(h * P, P), P)
+        xf = x_ref[band, :].astype(F32)                     # [P, Q]
+        xdt = xf * dt_ref[pl.ds(hg, 1), :]
+        l_t = jnp.exp(jnp.where(causal_t, row - col, -jnp.inf))           # L [l, s], transposed
+        y = jnp.dot(xdt.astype(cdt), (l_t * cb_t).astype(cdt), preferred_element_type=F32)
+        state = state_ref[h]                                # [P, N], entering this chunk
+        y = y + jnp.dot(state.astype(cdt), Cc, preferred_element_type=F32) * jnp.exp(row)
+        y_ref[band, :] = y + d_ref[hg] * xf
+        into = jax.lax.dot_general((xdt * jnp.exp(last - row)).astype(cdt), Bc, _NT,
+                                   preferred_element_type=F32)
+        state_ref[h] = jnp.exp(last) * state + into
+        return carry
+
+    # traced once, lowered once a head: the scheduler then overlaps one head's
+    # products with the next one's decays (0.37 → 0.22 ms a call at the
+    # cell's shape on a v5e: PERF.md §6)
+    jax.lax.fori_loop(0, heads, head, 0, unroll=True)
+
+
+def _ssd_scan_kernel(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array, C: jax.Array,
+                     D: jax.Array, chunk: int) -> jax.Array:
+    Bt, S, H, P = x.shape
+    G, N = B.shape[2:]
+    R, Q, di = H // G, chunk, H * P
+    # x | B | C side by side as the mixer's conv leaves them (where they are
+    # adjacent slices of one array, XLA folds the concatenation back into
+    # it), tokens minor: the layout XLA gives the mixer's elementwise parts,
+    # so that neither transpose is a copy there
+    v = jnp.swapaxes(jnp.concatenate([x.reshape(Bt, S, di), B.reshape(Bt, S, G * N),
+                                      C.reshape(Bt, S, G * N)], axis=-1), 1, 2)
+    dt = jnp.swapaxes(dt.astype(F32), 1, 2)                 # [Bt, H, S]
+    pad = -S % Q
+    if pad:
+        v, dt = (jnp.pad(a, ((0, 0), (0, 0), (0, pad))) for a in (v, dt))
+    bands = di // N                                         # B's first band of N
+    y = pl.pallas_call(
+        functools.partial(_scan_kernel, heads=R, head_dim=P),
+        grid=(Bt, G, (S + pad) // Q),
+        in_specs=[pl.BlockSpec((None, R * P, Q), lambda b, g, k: (b, g, k)),
+                  pl.BlockSpec((None, N, Q), lambda b, g, k: (b, bands + g, k)),
+                  pl.BlockSpec((None, N, Q), lambda b, g, k: (b, bands + G + g, k)),
+                  pl.BlockSpec((None, H, Q), lambda b, g, k: (b, 0, k)),
+                  pl.BlockSpec((H, 1), lambda b, g, k: (0, 0)),
+                  pl.BlockSpec(memory_space=pltpu.SMEM)],
+        out_specs=pl.BlockSpec((None, R * P, Q), lambda b, g, k: (b, g, k)),
+        out_shape=jax.ShapeDtypeStruct((Bt, di, S + pad), F32),
+        scratch_shapes=[pltpu.VMEM((R, P, N), F32),          # the group's carried states
+                        pltpu.VMEM((H, Q), F32)],            # acs, a head a row
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        name="ssd_scan",
+        interpret=_INTERPRET,
+    )(v, v, v, dt, A.astype(F32).reshape(H, 1), D.astype(F32))
+    return jnp.swapaxes(y[:, :, :S], 1, 2).reshape(Bt, S, H, P)
 
 
 def gated_rms_norm(y: jax.Array, z: jax.Array, w: jax.Array, groups: int,

@@ -662,9 +662,10 @@ def test_falcon_h1_harvest_segment_compiles(chip, one_device):
     """The sixth cell's refill quantum at its published widths, one 4096-token
     sequence a forward (``benchmarks/configs/falcon-h1-34b-pair-relu16k.json``;
     4 layers by 3: two quanta of 2): each block's GQA attention through the
-    fused kernel (20 / 4 heads of 128) beside the state-space mixer's XLA
-    form — the conv, the chunked scan over 32 chunks of 128 with its 32-step
-    recurrence (a ``while``), the gated norm — and the MLP of 21,504."""
+    fused kernel (20 / 4 heads of 128) beside the state-space mixer — the
+    conv, the chunked scan's kernel over 32 chunks of 128, the gated norm —
+    and the MLP of 21,504. The scan's kernel reads the conv's output and its
+    y is read where they lie: neither is copied into another layout."""
     import sys
     from pathlib import Path
 
@@ -689,6 +690,33 @@ def test_falcon_h1_harvest_segment_compiles(chip, one_device):
     text = compiled.as_text()
     calls = [line.split(" = ")[0] for line in text.splitlines() if "tpu_custom_call" in line]
     assert sum("fused_causal_attention" in c for c in calls) == 1
+    assert sum("ssd_scan" in c for c in calls) == 1
+    copies = [line.split(" = ")[1][:40] for line in text.splitlines() if " copy(" in line]
+    for shape in ("bf16[1,4096,5120]", "bf16[1,5120,4096]", "f32[1,4096,4096]"):
+        assert not [c for c in copies if c.startswith(shape)], (shape, copies)
     # the program's transients beside the two models' weights leave the
     # crosscoder step its room (ISSUE 39's reckoning: ≈ 2.7 GiB)
     assert total - compiled.memory_analysis().argument_size_in_bytes < 3 * 2**30, total
+
+
+def test_ssm_scan_kernel_compiles(chip, one_device):
+    """The state-space mixer's scan kernel alone at the sixth cell's shape
+    (S 4096, 32 heads of 128 in 2 groups, a state of 256, chunks of 128),
+    its blocks and the groups' carried states within its stated VMEM limit;
+    x, B, C handed over tokens minor, as the conv leaves them in the harvest."""
+    from crosscoder_tpu.ops import ssm
+
+    S, H, P, G, N, Q = 4096, 32, 128, 2, 256, 128
+    assert ssm.supported(H, P, G, N, Q, jnp.bfloat16)
+    di = H * P
+
+    def scan(xbc_t, dt, A, D):
+        xbc = jnp.swapaxes(xbc_t, 1, 2)
+        return ssm.ssd_scan(xbc[..., :di].reshape(1, S, H, P), dt, A,
+                            xbc[..., di:di + G * N].reshape(1, S, G, N),
+                            xbc[..., di + G * N:].reshape(1, S, G, N), D, Q)
+
+    text = _compiles(scan, _sds((1, di + 2 * G * N, S), jnp.bfloat16, chip),
+                     _sds((1, S, H), jnp.float32, chip), _sds((H,), jnp.float32, chip),
+                     _sds((H,), jnp.float32, chip))
+    assert "ssd_scan" in text

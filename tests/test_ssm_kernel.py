@@ -1,0 +1,199 @@
+"""The state-space mixer's scan kernel (crosscoder_tpu/ops/ssm.py,
+``pallas:ssd_scan``) through the Pallas interpreter, at small sizes on the
+CPU: against XLA's form of ``ssd_scan`` and against the float32 token-by-token
+recurrence (benchmarks/reference/falcon_h1_ref.py), the choice between the two
+forms and its counters, the mixer around it, and a planted fault (a cumulative
+sum rounded to bf16) that the comparison must catch."""
+
+import sys
+import types
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+
+from benchmarks.reference import falcon_h1_ref as ref   # noqa: E402
+from crosscoder_tpu import obs                           # noqa: E402
+from crosscoder_tpu.ops import ssm                       # noqa: E402
+
+# float32 inputs, the same mathematics in another order (the kernel's sums
+# are products with a triangle of ones; the recurrence is token by token):
+# seen at 4e-7 … 1.2e-6 relative; a bf16 rounding of the products reads 1e-3,
+# one of the cumulative sum's terms 1.3e-4
+RTOL = 1e-5
+P = N = Q = 128                     # whole lanes: the smallest shape the kernel takes
+
+
+@pytest.fixture
+def interpret():
+    ssm.set_interpret(True)
+    yield
+    ssm.set_interpret(False)
+
+
+@pytest.fixture
+def plane(tmp_path):
+    p = obs.acquire(types.SimpleNamespace(
+        obs="on", obs_dir=str(tmp_path / "obs"), checkpoint_dir=str(tmp_path)))
+    yield p
+    p.close()
+
+
+def _rel(got, want) -> float:
+    got, want = jnp.asarray(got, jnp.float32), jnp.asarray(want, jnp.float32)
+    return float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+
+
+def _inputs(S, bt, H, G, A, dt_mean, seed=0, quiet_after=None):
+    """x ``[bt, S, H, P]`` (zero from ``quiet_after`` on: what follows is the
+    carried state's alone), dt ≈ ``dt_mean`` after a softplus, A as given,
+    B and C ``[bt, S, G, N]``, D ``[H]``; float32."""
+    r = np.random.default_rng(seed)
+    f = lambda *s: jnp.asarray(r.normal(size=s), jnp.float32)    # noqa: E731
+    x = f(bt, S, H, P)
+    if quiet_after is not None:
+        x = x.at[:, quiet_after:].set(0.0)
+    dt = dt_mean / np.log(2.0) * jax.nn.softplus(f(bt, S, H))
+    return x, dt, jnp.asarray(A, jnp.float32), f(bt, S, G, N), f(bt, S, G, N), f(H)
+
+
+def _recurrence(x, dt, A, B, C, D):
+    with jax.default_matmul_precision("highest"):
+        return jnp.stack([ref.recurrence(x[b], dt[b], A, B[b], C[b], D)
+                          for b in range(x.shape[0])])
+
+
+CASES = {
+    # S, batch, H, G, A, mean dt
+    "one-group": (256, 1, 2, 1, [-1.0, -2.0], 0.1),
+    "two-groups-two-rows": (256, 2, 4, 2, [-1.0, -2.0, -3.0, -4.0], 0.1),
+    "off-the-chunk": (300, 2, 4, 2, [-1.0, -2.0, -3.0, -4.0], 0.1),   # padded and cut
+    "four-groups-one-chunk": (128, 1, 4, 4, [-0.5, -1.0, -2.0, -4.0], 0.1),
+}
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_the_kernel_against_xlas_form_and_the_recurrence(interpret, case):
+    S, bt, H, G, A, dt_mean = CASES[case]
+    x, dt, A, B, C, D = _inputs(S, bt, H, G, A, dt_mean, seed=S + H + G)
+    assert ssm.supported(H, P, G, N, Q, x.dtype)
+    got = ssm._ssd_scan_kernel(x, dt, A, B, C, D, Q)
+    assert got.shape == x.shape and got.dtype == jnp.float32
+    assert _rel(got, ssm._ssd_scan_xla(x, dt, A, B, C, D, Q)) < RTOL
+    assert _rel(got, _recurrence(x, dt, A, B, C, D)) < RTOL
+
+
+def test_a_state_carried_over_chunks_and_a_decay_to_minus_400(interpret):
+    """Four chunks, x zero after the first: every later output is the carried
+    state's (two slow heads keep it over all four); two fast heads' chunk
+    sums reach -277 and -568, so L's exponents span the float32 exp's range."""
+    x, dt, A, B, C, D = _inputs(512, 1, 4, 2, [-0.01, -0.05, -3.0, -6.0], 0.6, quiet_after=128)
+    sums = jnp.sum((dt * A)[0, :Q], axis=0)
+    assert float(sums[2]) < -200 and float(sums[3]) < -400
+    want = _recurrence(x, dt, A, B, C, D)
+    assert float(jnp.linalg.norm(want[:, 384:, :2])) > 1.0       # the state reached chunk 4
+    got = ssm._ssd_scan_kernel(x, dt, A, B, C, D, Q)
+    assert _rel(got, want) < RTOL
+    assert _rel(got[:, 128:], want[:, 128:]) < RTOL
+    assert _rel(got, ssm._ssd_scan_xla(x, dt, A, B, C, D, Q)) < RTOL
+
+
+def test_bf16_products_stay_by_xlas_form(interpret):
+    """bf16 inputs: the two forms round the same products' inputs, so they
+    agree far inside one bf16 rounding of the output (2^-9), which both are
+    from the float32 recurrence."""
+    x, dt, A, B, C, D = _inputs(300, 2, 4, 2, [-1.0, -2.0, -3.0, -4.0], 0.1, seed=3)
+    xb, Bb, Cb = (a.astype(jnp.bfloat16) for a in (x, B, C))
+    got = ssm._ssd_scan_kernel(xb, dt, A, Bb, Cb, D, Q)
+    assert _rel(got, ssm._ssd_scan_xla(xb, dt, A, Bb, Cb, D, Q)) < 1e-4
+    want = _recurrence(*(a.astype(jnp.float32) for a in (xb, dt, A, Bb, Cb, D)))
+    assert 1e-4 < _rel(got, want) < 1e-2
+
+
+@pytest.mark.parametrize("fault", ["terms", "sums"])
+def test_a_bf16_cumulative_sum_fails_the_comparison(interpret, monkeypatch, fault):
+    """The cumulative decay rounded to bf16 — its terms (what a product at
+    default precision does to ``dt·A``) or the sums themselves — leaves the
+    kernel off XLA's form by far more than the comparison's limit."""
+    x, dt, A, B, C, D = _inputs(512, 1, 4, 2, [-0.01, -0.05, -3.0, -6.0], 0.6, quiet_after=128)
+    want = ssm._ssd_scan_xla(x, dt, A, B, C, D, Q)
+    assert _rel(ssm._ssd_scan_kernel(x, dt, A, B, C, D, Q), want) < RTOL
+    real = ssm._cumsum
+    bf = lambda a: a.astype(jnp.bfloat16).astype(jnp.float32)   # noqa: E731
+    monkeypatch.setattr(ssm, "_cumsum", (lambda a: real(bf(a))) if fault == "terms"
+                        else (lambda a: bf(real(a))))
+    assert _rel(ssm._ssd_scan_kernel(x, dt, A, B, C, D, Q), want) > 10 * RTOL
+
+
+def test_resets_take_xlas_form_and_each_choice_is_counted(interpret, plane):
+    x, dt, A, B, C, D = _inputs(256, 2, 4, 2, [-1.0, -2.0, -3.0, -4.0], 0.1)
+    resets = jnp.zeros((2, 256), bool).at[:, 0].set(True).at[0, 77].set(True)
+    prims = lambda *a: [e.primitive.name for e in jax.make_jaxpr(       # noqa: E731
+        lambda *b: ssm.ssd_scan(*b, Q, *a))(x, dt, A, B, C, D).eqns]
+    assert "pallas_call" not in prims(resets)
+    assert plane.registry.get_count("harvest/ssm_scan_xla_traces") == 1
+    assert plane.registry.get_count("harvest/ssm_scan_kernel_traces") == 0
+    assert "pallas_call" in prims()
+    assert plane.registry.get_count("harvest/ssm_scan_kernel_traces") == 1
+    assert plane.registry.get_count("harvest/ssm_scan_xla_traces") == 1
+    # a start's masks are what XLA's form exists for: with one it is not the kernel's result
+    with_starts = ssm.ssd_scan(x, dt, A, B, C, D, Q, resets)
+    assert _rel(with_starts, ssm._ssd_scan_xla(x, dt, A, B, C, D, Q, resets)) == 0.0
+    assert _rel(ssm.ssd_scan(x, dt, A, B, C, D, Q), with_starts) > 1e-2
+
+
+def test_off_the_chip_and_at_unsupported_shapes_xlas_form_runs(plane):
+    x, dt, A, B, C, D = _inputs(256, 1, 2, 1, [-1.0, -2.0], 0.1)
+    assert not ssm.enabled()                                    # the CPU, no interpreter
+    ssm.ssd_scan(x, dt, A, B, C, D, Q)
+    ssm.set_interpret(True)
+    try:
+        ssm.ssd_scan(x, dt, A, B, C, D, 64)                     # a chunk of half a lane tile
+    finally:
+        ssm.set_interpret(False)
+    assert plane.registry.get_count("harvest/ssm_scan_xla_traces") == 2
+    assert plane.registry.get_count("harvest/ssm_scan_kernel_traces") == 0
+
+
+@pytest.mark.parametrize("shape", [
+    (4, 64, 2, 128, 128, jnp.bfloat16),        # a head narrower than the lanes
+    (4, 128, 2, 96, 128, jnp.bfloat16),        # a state narrower than the lanes
+    (4, 128, 2, 128, 64, jnp.bfloat16),        # a chunk of half a lane tile
+    (6, 128, 4, 128, 128, jnp.bfloat16),       # heads that do not split into the groups
+    (3, 128, 1, 256, 128, jnp.bfloat16),       # x's width not a whole number of B's bands
+    (4, 128, 2, 128, 128, jnp.float16),        # a dtype the kernel does not take
+    (256, 128, 2, 256, 128, jnp.bfloat16),     # a group's band past the VMEM limit
+])
+def test_supported_refuses(shape):
+    assert not ssm.supported(*shape)
+
+
+def test_supported_accepts_the_cell():
+    """Falcon-H1-34B's mixer (32 heads of 128 in 2 groups, a state of 256,
+    chunks of 128) within the stated limit, with room."""
+    assert ssm.supported(32, 128, 2, 256, 128, jnp.bfloat16)
+    assert ssm._vmem_bytes(32, 128, 2, 256, 128, 2) < ssm._VMEM_LIMIT_BYTES / 2
+
+
+def test_the_mixer_with_the_kernel_is_the_mixer_with_xlas_form(interpret):
+    """``lm._ssm`` on a normed input at lane-wide sizes, float32: the kernel
+    reads x, B and C as bands of the conv's output and hands y to the gated
+    norm, so the mixer's result is XLA's form's to float32 rounding."""
+    from benchmarks import manifest
+    from benchmarks.arch import falcon_h1 as fh
+    from crosscoder_tpu.models import lm
+
+    config = manifest.load_json(manifest.BENCH_DIR / "configs" / "falcon-h1-34b-pair-relu16k.json")
+    cfg = fh.lm_config(config, dict(vocab_size=257, d_model=64, n_layers=1, n_heads=4,
+                                    n_kv_heads=2, head_dim=16, d_ff=128, dtype="fp32"))
+    cfg = cfg.replace(ssm_heads=4, ssm_head_dim=P, ssm_state=N, ssm_groups=2, ssm_chunk=Q)
+    lp = {k: v[0] for k, v in lm.init_params(jax.random.key(0), cfg)["layers"].items()}
+    u = jax.random.normal(jax.random.key(1), (2, 200, cfg.d_model))
+    got = lm._ssm(u, lp, cfg)
+    ssm.set_interpret(False)
+    want = lm._ssm(u, lp, cfg)
+    assert _rel(got, want) < RTOL
