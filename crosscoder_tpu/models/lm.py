@@ -61,7 +61,13 @@ norm) reads the same normed input as attention and their sum joins the stream
 once; its µP multipliers are folded into the weights
 (:func:`fold_multiplier`), and the paged runtime restarts the mixer's state at
 each packed document (checked against ``benchmarks/reference/falcon_h1_ref.py``
-and transformers' ``FalconH1ForCausalLM`` by ``tests/test_falcon_h1.py``).
+and transformers' ``FalconH1ForCausalLM`` by ``tests/test_falcon_h1.py``). A
+sixth, the Nemotron-H tower (``LMConfig.nemotron_twotower_30b``), has layers
+of ONE sublayer: a layer is a mixer slot and an FFN slot, either of which may
+be empty (``NONE``), so a layer is the state-space mixer alone (``MAMBA``),
+attention alone (which rotates nothing) or the ungated relu² experts alone
+(``mlp_act``), each a class of its own (checked against
+``benchmarks/reference/nemotron_h_ref.py`` by ``tests/test_nemotron_h.py``).
 
 Gemma-2 architecture facts implemented (validated against the HF
 ``transformers`` Gemma2 implementation by ``tests/test_lm.py``): RMSNorm with
@@ -98,9 +104,12 @@ LMParams = dict[str, Any]
 
 
 # layer kinds, under the names public configs give them (``layer_types``,
-# ``mlp_layer_types``)
+# ``mlp_layer_types``): a layer's MIXER slot is attention of a kind, the
+# state-space mixer alone, or empty; its FFN slot dense, sparse or empty
 SLIDING, FULL = "sliding_attention", "full_attention"
+MAMBA = "mamba"
 DENSE, SPARSE = "dense", "sparse"
+NONE = "none"
 
 
 def _alternate(n_layers: int) -> tuple[str, ...]:
@@ -192,13 +201,28 @@ class LMConfig:
     head — its own part, then ``qk_rope_dim`` rotary dims whose key all heads
     share — and ``v_head_dim`` the value head.
 
-    ``ssm_heads`` > 0 makes every layer a PARALLEL HYBRID (Falcon-H1): a
-    Mamba-2 state-space mixer (:mod:`crosscoder_tpu.ops.ssm`; ``ssm_heads``
-    heads of ``ssm_head_dim`` over a state of ``ssm_state``, B and C shared
-    by each of ``ssm_groups`` groups of heads, a causal conv of
-    ``ssm_conv`` taps, scanned in chunks of ``ssm_chunk``) reads the same
-    normed input as attention, and their sum joins the stream once. ``mup``
-    is the model's :class:`Multipliers`, folded into its weights.
+    ``ssm_heads`` > 0 gives the model a Mamba-2 state-space mixer
+    (:mod:`crosscoder_tpu.ops.ssm`; ``ssm_heads`` heads of ``ssm_head_dim``
+    over a state of ``ssm_state``, B and C shared by each of ``ssm_groups``
+    groups of heads, a causal conv of ``ssm_conv`` taps, scanned in chunks
+    of ``ssm_chunk``). Where no layer's kind is ``MAMBA`` every layer is a
+    PARALLEL HYBRID (Falcon-H1): the mixer reads the same normed input as
+    attention, and their sum joins the stream once. ``mup`` is the model's
+    :class:`Multipliers`, folded into its weights.
+
+    A layer is a MIXER slot and an FFN slot, each behind its own norm, and
+    either may be empty (Nemotron-H: every layer ONE sublayer): ``layer_types``
+    gives the mixer slot — an attention kind, ``MAMBA`` (the state-space
+    mixer alone) or ``NONE`` — and ``mlp_types`` the FFN slot — ``DENSE``,
+    ``SPARSE`` or ``NONE``. An empty slot has no leaves and adds nothing.
+    ``mlp_act`` ``"gated"`` is the block style's gated MLP (dense, shared
+    and routed experts alike); ``"relu2"`` the ungated ``relu(x·W_up)² ·
+    W_down``. A kind whose :class:`Rope` rotates a share of 0 of the head
+    applies no rotation at all.
+
+    ``state_dict_names`` names the checkpoint's convention where the shape
+    of the config does not decide it (:func:`from_torch_state_dict`):
+    ``"nemotron_h"`` (the published ``model_type``) reads Nemotron-H's names.
     """
 
     vocab_size: int
@@ -247,6 +271,8 @@ class LMConfig:
     ssm_conv: int = 4
     ssm_chunk: int = 128
     mup: Multipliers = Multipliers()
+    mlp_act: str = "gated"
+    state_dict_names: str = ""
 
     def __post_init__(self) -> None:
         def fill(name, default, valid):
@@ -258,8 +284,20 @@ class LMConfig:
                     f"{self.n_layers} layers, got {table}")
             object.__setattr__(self, name, table)
 
-        fill("layer_types", _alternate(self.n_layers), (SLIDING, FULL))
-        fill("mlp_types", (DENSE,) * self.n_layers, (DENSE, SPARSE))
+        fill("layer_types", _alternate(self.n_layers), (SLIDING, FULL, MAMBA, NONE))
+        fill("mlp_types", (DENSE,) * self.n_layers, (DENSE, SPARSE, NONE))
+        if any(a == f == NONE for a, f in zip(self.layer_types, self.mlp_types)):
+            raise ValueError("a layer needs a mixer or an FFN; both slots are empty")
+        if (MAMBA in self.layer_types and not self.ssm) or (
+                NONE in self.layer_types + self.mlp_types
+                and (self.n_streams > 1 or self.block_style != "prenorm")):
+            raise ValueError("a MAMBA layer needs ssm_heads > 0, and an empty slot "
+                             "a pre-norm block of one stream")
+        if self.mlp_act not in ("gated", "relu2"):
+            raise ValueError(f"mlp_act must be gated|relu2, got {self.mlp_act!r}")
+        if self.state_dict_names not in ("", "nemotron_h"):
+            raise ValueError(f"state_dict_names must be '' or nemotron_h, "
+                             f"got {self.state_dict_names!r}")
         if self.block_style not in ("sandwich", "prenorm"):
             raise ValueError(f"block_style must be sandwich|prenorm, got {self.block_style!r}")
         if self.attn_gate not in ("none", "per_head"):
@@ -283,10 +321,10 @@ class LMConfig:
                 self.ssm_head_dim > 0 and self.ssm_state > 0 and self.ssm_conv > 0
                 and self.ssm_chunk > 0 and self.ssm_heads % self.ssm_groups == 0
                 and self.block_style == "prenorm" and self.n_streams == 1
-                and not self.latent and not self.sparse and self.heads_by_layer is None):
+                and not self.latent and self.heads_by_layer is None):
             raise ValueError(
                 "a state-space mixer (ssm_heads > 0) needs its head size, state, conv "
-                "taps and chunk, whole groups of heads, and a pre-norm dense block of "
+                "taps and chunk, whole groups of heads, and a pre-norm block of "
                 "one stream with plain attention")
         if self.mup != Multipliers() and (self.latent or self.sparse or self.n_streams > 1):
             raise ValueError("multipliers are folded into a dense block's plain "
@@ -323,8 +361,14 @@ class LMConfig:
 
     @property
     def ssm(self) -> bool:
-        """Whether every layer has a state-space mixer beside attention."""
+        """Whether the model has a state-space mixer (beside attention in
+        every layer, or alone in its ``MAMBA`` layers)."""
         return self.ssm_heads > 0
+
+    @property
+    def ssm_parallel(self) -> bool:
+        """Whether the mixer sits beside attention in every layer."""
+        return self.ssm and MAMBA not in self.layer_types
 
     @property
     def d_ssm(self) -> int:
@@ -510,6 +554,31 @@ class LMConfig:
         )
 
     @classmethod
+    def nemotron_twotower_30b(cls) -> "LMConfig":
+        """Nemotron-Labs-TwoTower-30B-A3B (NVIDIA; Nemotron-H): the tower the
+        config describes — 52 layers of ONE sublayer each behind one pre-norm
+        (``hybrid_override_pattern``: 23 Mamba-2 mixers of 64 heads of 64
+        over a state of 128 in 8 groups, 23 expert layers, 6 GQA attention
+        layers of 32 query / 2 key-value heads of 128 with no rotation); 128
+        sigmoid-scored ungated relu² experts of width 1856, top-6 chosen by
+        score plus a bias and gated by the unbiased scores times 2.5, beside
+        a shared one of 3712; untied head. The whole model: every expert
+        held; the denoiser tower is not modelled."""
+        layer_types, mlp_types = nemotron_h_table(
+            "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME")
+        return cls(
+            vocab_size=131_072, d_model=2688, n_layers=52, n_heads=32, n_kv_heads=2,
+            head_dim=128, d_ff=1856, rms_eps=1e-5, attn_softcap=0.0, final_softcap=0.0,
+            sliding_window=0, query_pre_attn_scalar=128.0, layer_types=layer_types,
+            mlp_types=mlp_types, block_style="prenorm", rope=((FULL, Rope(rotary_factor=0.0)),),
+            n_experts=128, experts_per_tok=6, d_expert=1856, norm_topk_prob=True,
+            tie_embeddings=False, d_shared_expert=3712, routed_scale=2.5,
+            router="sigmoid_bias", ssm_heads=64, ssm_head_dim=64, ssm_state=128,
+            ssm_groups=8, ssm_conv=4, ssm_chunk=128, mlp_act="relu2",
+            state_dict_names="nemotron_h",
+        )
+
+    @classmethod
     def tiny(cls, vocab_size: int = 257, n_layers: int = 4) -> "LMConfig":
         """Deterministic test-sized config (the 'fake LM' of SURVEY.md §4 —
         same hook semantics as the real model, no 2.6B-param download)."""
@@ -531,6 +600,17 @@ class LMConfig:
         return dataclasses.replace(self, **kw)
 
 
+def nemotron_h_table(pattern: str) -> tuple[tuple[str, ...], tuple[str, ...]]:
+    """``(layer_types, mlp_types)`` of a Nemotron-H ``hybrid_override_pattern``:
+    a layer a letter, each ONE sublayer — ``M`` the state-space mixer, ``*``
+    full attention, ``E`` the expert layer, ``-`` a dense MLP."""
+    slots = {"M": (MAMBA, NONE), "*": (FULL, NONE), "E": (NONE, SPARSE), "-": (NONE, DENSE)}
+    if set(pattern) - set(slots):
+        raise ValueError(f"hybrid_override_pattern {pattern!r}: letters are M, *, E, -")
+    mixers, ffns = zip(*(slots[c] for c in pattern))
+    return tuple(mixers), tuple(ffns)
+
+
 _NAMED_CONFIGS = {
     "gemma-2-2b": LMConfig.gemma2_2b,
     "gemma-2-2b-it": LMConfig.gemma2_2b,
@@ -548,6 +628,7 @@ _NAMED_CONFIGS = {
     "xing4.0-29b-a4b-base": LMConfig.xing4_0_29b,
     "falcon-h1-34b-base": LMConfig.falcon_h1_34b,
     "falcon-h1-34b-instruct": LMConfig.falcon_h1_34b,
+    "nemotron-labs-twotower-30b-a3b-base-bf16": LMConfig.nemotron_twotower_30b,
 }
 
 
@@ -567,24 +648,35 @@ class LayerClass(NamedTuple):
     """Layers whose leaves have one shape: one stack of leaves."""
 
     n_heads: int
-    mlp: str                    # DENSE | SPARSE
+    mlp: str                    # the FFN slot: DENSE | SPARSE | NONE
     layers: tuple[int, ...]     # the model's layer ids, ascending
-    kind: str | None            # the attention kind, where all its layers share one
+    kind: str | None            # the mixer slot's kind, where all its layers share one
+
+    @property
+    def attends(self) -> bool:
+        """Whether the mixer slot is attention (of one kind or several)."""
+        return _mixer_slot(self.kind) == "attention"
+
+
+def _mixer_slot(kind: str | None) -> str:
+    """A mixer kind as the shape of its leaves: attention of any kind is one."""
+    return kind if kind in (MAMBA, NONE) else "attention"
 
 
 @functools.lru_cache(maxsize=64)
 def layer_classes(cfg: LMConfig) -> tuple[LayerClass, ...]:
-    """The config's layers by the SHAPE of their leaves (query heads, MLP
-    kind), in order of first appearance. One class is the common case and
-    keeps the tree ``params["layers"][leaf]`` ``[L, ...]``; with more,
-    ``params["layers"]`` is a tuple of such dicts, one a class, each stacked
-    over its own layers."""
-    keys = [(cfg.heads_of(i), cfg.mlp_types[i]) for i in range(cfg.n_layers)]
+    """The config's layers by the SHAPE of their leaves (query heads, the
+    mixer slot, the FFN slot), in order of first appearance. One class is the
+    common case and keeps the tree ``params["layers"][leaf]`` ``[L, ...]``;
+    with more, ``params["layers"]`` is a tuple of such dicts, one a class,
+    each stacked over its own layers."""
+    keys = [(cfg.heads_of(i), _mixer_slot(cfg.layer_types[i]), cfg.mlp_types[i])
+            for i in range(cfg.n_layers)]
     out = []
     for key in dict.fromkeys(keys):
         layers = tuple(i for i, k in enumerate(keys) if k == key)
         kinds = {cfg.layer_types[i] for i in layers}
-        out.append(LayerClass(*key, layers, kinds.pop() if len(kinds) == 1 else None))
+        out.append(LayerClass(key[0], key[2], layers, kinds.pop() if len(kinds) == 1 else None))
     return tuple(out)
 
 
@@ -622,7 +714,12 @@ def init_params(key: jax.Array, cfg: LMConfig) -> LMParams:
     head is ``unembed`` [V, D]. A state-space mixer adds ``ssm_in`` [D, z | x
     | B | C | dt], ``ssm_conv_w`` [K, C] and ``ssm_conv_b``, the float32
     ``ssm_dt_bias``, ``ssm_A_log``, ``ssm_D`` [H], ``ssm_norm`` and
-    ``ssm_out``. A leaf the model multiplies (``cfg.mup``) is drawn so that
+    ``ssm_out``. An ungated (``mlp_act`` relu2) MLP has no gate leaf: the
+    experts' ``we_up`` [E_held, Fe, D] (out, in: the width Fe need not be
+    whole lanes, D is the minor axis) in place of ``we_gate_up``, no
+    ``ws_gate`` nor ``w_gate``. An empty slot has no leaves, its norm
+    included (``attn_norm`` is the mixer slot's, ``pre_ffw_norm`` the FFN
+    slot's). A leaf the model multiplies (``cfg.mup``) is drawn so that
     its FOLDED value is at the fixture's scale (:func:`fold_multiplier`).
     """
     dt = dtype_of(cfg.dtype)
@@ -647,8 +744,12 @@ def init_params(key: jax.Array, cfg: LMConfig) -> LMParams:
 
     def stack(cls: LayerClass, ks, key) -> dict:
         L, qd = len(cls.layers), cls.n_heads * cfg.head_dim
-        layers = {"attn_norm": unit((L, D), dt), "pre_ffw_norm": unit((L, D), dt)}
-        if cfg.latent:
+        layers = {}
+        if cls.kind != NONE:
+            layers["attn_norm"] = unit((L, D), dt)
+        if cls.mlp != NONE:
+            layers["pre_ffw_norm"] = unit((L, D), dt)
+        if cls.attends and cfg.latent:
             H, rq, rkv = cls.n_heads, cfg.q_lora_rank, cfg.kv_lora_rank
             dr, dv = cfg.qk_rope_dim, cfg.v_head_dim
             kl = jax.random.split(jax.random.fold_in(key, 103), 4)
@@ -660,13 +761,13 @@ def init_params(key: jax.Array, cfg: LMConfig) -> LMParams:
                 wk_nope=nrm(kl[1], (L, rkv, H * (cfg.head_dim - dr)), rkv ** -0.5),
                 wv=nrm(kl[2], (L, rkv, H * dv), rkv ** -0.5),
                 wo=nrm(ks[4], (L, H * dv, D), (H * dv) ** -0.5))
-        else:
+        elif cls.attends:
             layers.update(
                 wq=nrm(ks[1], (L, D, qd), D ** -0.5, "wq"),
                 wk=nrm(ks[2], (L, D, kd), D ** -0.5, "wk"),
                 wv=nrm(ks[3], (L, D, kd), D ** -0.5, "wv"),
                 wo=nrm(ks[4], (L, qd, D), qd ** -0.5, "wo"))
-        if cfg.ssm:
+        if cls.kind == MAMBA or (cls.attends and cfg.ssm_parallel):
             # the seeded fixture's mixer (the configuration file's
             # ``assumed.weights``): A_log = log(1..H) and dt_bias the inverse
             # softplus of a dt drawn log-uniform in [1e-3, 1e-1], as Mamba-2
@@ -700,10 +801,14 @@ def init_params(key: jax.Array, cfg: LMConfig) -> LMParams:
         if sandwich:
             layers["post_attn_norm"] = unit((L, D), dt)
             layers["post_ffw_norm"] = unit((L, D), dt)
+        gated = cfg.mlp_act == "gated"
         if cls.mlp == SPARSE:
             E, Fe = cfg.n_held, cfg.d_expert
             layers["router"] = nrm(ks[5], (L, D, cfg.n_experts), D ** -0.5)
-            layers["we_gate_up"] = nrm(ks[6], (L, E, D, 2 * Fe), D ** -0.5)
+            if gated:
+                layers["we_gate_up"] = nrm(ks[6], (L, E, D, 2 * Fe), D ** -0.5)
+            else:       # [out, in], as the checkpoint holds it (ops/moe.py)
+                layers["we_up"] = nrm(ks[6], (L, E, Fe, D), D ** -0.5)
             layers["we_down"] = nrm(ks[7], (L, E, Fe, D), Fe ** -0.5)
             if cfg.router == "sigmoid_bias":
                 # the fixture's choice bias: large enough to move choices (the
@@ -715,14 +820,16 @@ def init_params(key: jax.Array, cfg: LMConfig) -> LMParams:
             if cfg.d_shared_expert:
                 Fs = cfg.d_shared_expert
                 k_g, k_u, k_d = jax.random.split(jax.random.fold_in(key, 101), 3)
-                layers["ws_gate"] = nrm(k_g, (L, D, Fs), D ** -0.5)
+                if gated:
+                    layers["ws_gate"] = nrm(k_g, (L, D, Fs), D ** -0.5)
                 layers["ws_up"] = nrm(k_u, (L, D, Fs), D ** -0.5)
                 layers["ws_down"] = nrm(k_d, (L, Fs, D), Fs ** -0.5)
-        else:
-            layers["w_gate"] = nrm(ks[5], (L, D, F), D ** -0.5, "w_gate")
+        elif cls.mlp == DENSE:
+            if gated:
+                layers["w_gate"] = nrm(ks[5], (L, D, F), D ** -0.5, "w_gate")
             layers["w_up"] = nrm(ks[6], (L, D, F), D ** -0.5)
             layers["w_down"] = nrm(ks[7], (L, F, D), F ** -0.5, "w_down")
-        if cfg.attn_gate == "per_head":
+        if cls.attends and cfg.attn_gate == "per_head":
             layers["w_attn_gate"] = nrm(
                 jax.random.fold_in(key, 102), (L, D, cls.n_heads), D ** -0.5)
         return layers
@@ -779,27 +886,30 @@ def param_count(cfg: LMConfig) -> int:
     """Parameters this chip holds (of a sparse layer: its share of the experts)."""
     D, F = cfg.d_model, cfg.d_ff
     kd = cfg.n_kv_heads * cfg.head_dim
-    norms = 4 * D if cfg.block_style == "sandwich" else 2 * D
     n, W = cfg.n_streams, cfg.hc.width
     maps = 2 * (n * D * W + 3 + W) if n > 1 else 0
+    mats = 3 if cfg.mlp_act == "gated" else 2       # an MLP's matrices
     layers = 0
     for cls in layer_classes(cfg):
         qd = cls.n_heads * cfg.head_dim
+        slots = (cls.kind != NONE) + (cls.mlp != NONE)
+        norms = slots * (2 * D if cfg.block_style == "sandwich" else D)
+        mlp = attn = gate = ssm = 0
         if cls.mlp == SPARSE:
-            mlp = (D * cfg.n_experts + cfg.n_held * 3 * D * cfg.d_expert
-                   + 3 * D * cfg.d_shared_expert
+            mlp = (D * cfg.n_experts + cfg.n_held * mats * D * cfg.d_expert
+                   + mats * D * cfg.d_shared_expert
                    + (cfg.n_experts if cfg.router == "sigmoid_bias" else 0))
-        else:
-            mlp = 3 * D * F
-        if cfg.latent:
+        elif cls.mlp == DENSE:
+            mlp = mats * D * F
+        if cls.attends and cfg.latent:
             rq, rkv, vd = cfg.q_lora_rank, cfg.kv_lora_rank, cls.n_heads * cfg.v_head_dim
             attn = (D * rq + rq + rq * qd + D * (rkv + cfg.qk_rope_dim) + rkv
                     + rkv * (qd - cls.n_heads * cfg.qk_rope_dim) + rkv * vd + vd * D)
-        else:
+        elif cls.attends:
             attn = D * qd + 2 * D * kd + qd * D
-        gate = D * cls.n_heads if cfg.attn_gate == "per_head" else 0
-        ssm = 0
-        if cfg.ssm:
+        if cls.attends and cfg.attn_gate == "per_head":
+            gate = D * cls.n_heads
+        if cls.kind == MAMBA or (cls.attends and cfg.ssm_parallel):
             di, cd = cfg.d_ssm, cfg.d_ssm + 2 * cfg.ssm_groups * cfg.ssm_state
             ssm = (D * sum(cfg.ssm_proj_widths) + di * D + cfg.ssm_conv * cd + cd + di
                    + 3 * cfg.ssm_heads)
@@ -834,6 +944,8 @@ def rope_inv_freq(rope: Rope, head_dim: int) -> jax.Array:
     """The rotation frequencies of one attention kind: one a pair of the
     ``head_dim · rotary_factor`` leading dims that rotate."""
     d = int(head_dim * rope.rotary_factor)
+    if not d:           # a kind that rotates nothing
+        return jnp.zeros((0,), jnp.float32)
     if not rope.yarn_factor:
         return 1.0 / (rope.theta ** (jnp.arange(0, d // 2, dtype=jnp.float32) * 2.0 / d))
     # YaRN (HF ``_compute_yarn_parameters``), closed form, in float64
@@ -868,6 +980,8 @@ def _layer_kind(cfg: LMConfig, i: jax.Array, cls: LayerClass | None = None,
     Gemma-2 family) the RoPE side is static and only the mask is looked up;
     where the layer's class ``cls`` is of one attention kind (a table whose
     kinds differ in shape) everything is static and nothing is looked up."""
+    if cls is not None and not cls.attends:     # no attention: nothing to look up
+        return _LayerKind(i, np.bool_(False), None, 1.0, slot)
     if cls is not None and cls.kind is not None:
         rope = cfg.rope_of(cls.kind)
         return _LayerKind(i, np.bool_(cls.kind == SLIDING),
@@ -920,15 +1034,21 @@ def _qkv(
     kind: _LayerKind,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Project + RoPE: q [B,S,H,hd], k/v [B,S,KV,hd]. ``pos`` carries GLOBAL
-    positions so sequence-sharded callers rotate correctly."""
+    positions so sequence-sharded callers rotate correctly; a kind that
+    rotates nothing (no frequencies) emits no rotation at all."""
     B, S, _ = x.shape
     KV, hd = cfg.n_kv_heads, cfg.head_dim
     H = lp["wq"].shape[-1] // hd        # the layer's own (``heads_by_layer``)
     q = jnp.einsum("bsd,dq->bsq", x, lp["wq"], preferred_element_type=jnp.float32)
     k = jnp.einsum("bsd,dk->bsk", x, lp["wk"], preferred_element_type=jnp.float32)
     v = jnp.einsum("bsd,dk->bsk", x, lp["wv"], preferred_element_type=jnp.float32)
-    q = _rope(q.astype(x.dtype).reshape(B, S, H, hd), pos, kind.inv_freq, kind.rope_factor)
-    k = _rope(k.astype(x.dtype).reshape(B, S, KV, hd), pos, kind.inv_freq, kind.rope_factor)
+    rotates = kind.inv_freq.shape[-1] > 0
+    q = q.astype(x.dtype).reshape(B, S, H, hd)
+    if rotates:
+        q = _rope(q, pos, kind.inv_freq, kind.rope_factor)
+    k = k.astype(x.dtype).reshape(B, S, KV, hd)
+    if rotates:
+        k = _rope(k, pos, kind.inv_freq, kind.rope_factor)
     return q, k, v.astype(x.dtype).reshape(B, S, KV, hd)
 
 
@@ -1056,7 +1176,7 @@ def _attn_core(
 # layer at Mellum2's sizes). They stay STACKED and the expert layer's kernels
 # index them themselves, by the layer's slot in its class's stack
 # (``ops/moe.py``).
-_HELD_LEAVES = ("we_gate_up", "we_down")
+_HELD_LEAVES = ("we_gate_up", "we_up", "we_down")
 
 
 @jax.named_scope("harvest/leaves")
@@ -1154,25 +1274,42 @@ def _gated_mlp(x: jax.Array, w_gate: jax.Array, w_up: jax.Array, w_down: jax.Arr
     return jnp.einsum("bsf,fd->bsd", h, w_down, preferred_element_type=jnp.float32).astype(x.dtype)
 
 
+def _relu2_mlp(x: jax.Array, w_up: jax.Array, w_down: jax.Array) -> jax.Array:
+    """The ungated ``relu(x·W_up)² · W_down`` (Nemotron-H's ``relu2``)."""
+    up = jnp.einsum("bsd,df->bsf", x, w_up, preferred_element_type=jnp.float32)
+    h = jnp.square(jax.nn.relu(up)).astype(x.dtype)
+    return jnp.einsum("bsf,fd->bsd", h, w_down, preferred_element_type=jnp.float32).astype(x.dtype)
+
+
+def _dense_mlp(x: jax.Array, lp: Mapping[str, jax.Array], pre: str, cfg: LMConfig) -> jax.Array:
+    """The MLP whose leaves are ``{pre}_gate``, ``{pre}_up``, ``{pre}_down``
+    (``w``: a dense layer's; ``ws``: the shared expert's), gated or ungated
+    by the leaves it has."""
+    if f"{pre}_gate" in lp:
+        return _gated_mlp(x, lp[f"{pre}_gate"], lp[f"{pre}_up"], lp[f"{pre}_down"], cfg)
+    return _relu2_mlp(x, lp[f"{pre}_up"], lp[f"{pre}_down"])
+
+
 @jax.named_scope("harvest/block/mlp")
 def _mlp(x: jax.Array, lp: Mapping[str, jax.Array], cfg: LMConfig, slot: Any) -> jax.Array:
-    """The gated MLP of the layer's kind (by its leaves) on the normed
-    stream: the dense one, or the routed experts (``ops/moe.py``; their
-    leaves in ``lp`` are the STACKED ones, indexed by ``slot``) — this
-    chip's share of them where the config holds one — plus, where the
-    config has one, the shared expert on the same input."""
+    """The MLP of the layer's kind (by its leaves) on the normed stream: the
+    dense one, or the routed experts (``ops/moe.py``; their leaves in ``lp``
+    are the STACKED ones, indexed by ``slot``) — this chip's share of them
+    where the config holds one — plus, where the config has one, the shared
+    expert on the same input."""
     if "router" not in lp:
-        return _gated_mlp(x, lp["w_gate"], lp["w_up"], lp["w_down"], cfg)
+        return _dense_mlp(x, lp, "w", cfg)
     from crosscoder_tpu.ops import moe
 
+    gated = "we_gate_up" in lp
     m = moe.moe_mlp(
-        x, lp["router"], lp["we_gate_up"], lp["we_down"], slot,
+        x, lp["router"], lp["we_gate_up" if gated else "we_up"], lp["we_down"], slot,
         top_k=cfg.experts_per_tok, norm_topk_prob=cfg.norm_topk_prob,
         routed_scale=cfg.routed_scale, first_expert=cfg.first_expert,
-        router=cfg.router, router_bias=lp.get("router_bias"))
-    if "ws_gate" in lp:
+        router=cfg.router, router_bias=lp.get("router_bias"), gated=gated)
+    if "ws_up" in lp:
         with jax.named_scope("harvest/block/moe/shared"):
-            m = m + _gated_mlp(x, lp["ws_gate"], lp["ws_up"], lp["ws_down"], cfg)
+            m = m + _dense_mlp(x, lp, "ws", cfg)
     return m
 
 
@@ -1274,20 +1411,31 @@ def _block(
     the same normed input as attention and the two outputs' SUM is the
     first sublayer's contribution — what joins the stream, what
     ``hook_attn_out`` captures and ``edit_attn`` edits; ``resets`` (the
-    paged runtime's document starts) reach the mixer alone."""
-    u, maps_a = _read(resid, lp, cfg, "attn")
-    x = _norm(u, lp["attn_norm"], cfg)
-    attn_out = _attention(x, lp, cfg, kind, pos, attend)
-    if "ssm_in" in lp:
-        attn_out = _ssm(x, lp, cfg, resets) + attn_out
-    if edit_attn is not None:
-        attn_out = edit_attn(attn_out)
-    resid, attn_out = _write(resid, attn_out, maps_a, cfg)
-    u, maps_m = _read(resid, lp, cfg, "ffn")
-    mlp_out = _mlp_out(u, lp, cfg, kind.index if kind.slot is None else kind.slot)
-    if edit_mlp is not None:
-        mlp_out = edit_mlp(mlp_out)
-    resid, mlp_out = _write(resid, mlp_out, maps_m, cfg)
+    paged runtime's document starts) reach the mixer alone. The mixer slot
+    is whichever of the two the layer's leaves hold (the state-space mixer
+    alone in a ``MAMBA`` layer); a slot the layer does not have (no
+    ``attn_norm`` / ``pre_ffw_norm``) is not spelled at all — no norm, no
+    add — and its contribution is None."""
+    attn_out = mlp_out = None
+    u = resid
+    maps_a = maps_m = None
+    if "attn_norm" in lp:
+        u, maps_a = _read(resid, lp, cfg, "attn")
+        x = _norm(u, lp["attn_norm"], cfg)
+        if "wo" in lp:
+            attn_out = _attention(x, lp, cfg, kind, pos, attend)
+        if "ssm_in" in lp:
+            y = _ssm(x, lp, cfg, resets)
+            attn_out = y if attn_out is None else y + attn_out
+        if edit_attn is not None:
+            attn_out = edit_attn(attn_out)
+        resid, attn_out = _write(resid, attn_out, maps_a, cfg)
+    if "pre_ffw_norm" in lp:
+        u, maps_m = _read(resid, lp, cfg, "ffn")
+        mlp_out = _mlp_out(u, lp, cfg, kind.index if kind.slot is None else kind.slot)
+        if edit_mlp is not None:
+            mlp_out = edit_mlp(mlp_out)
+        resid, mlp_out = _write(resid, mlp_out, maps_m, cfg)
     return resid, attn_out, mlp_out, _Seen(u, (maps_a, maps_m) if cfg.n_streams > 1 else ())
 
 
@@ -1377,7 +1525,11 @@ def _hook_layers(cfg: LMConfig, hook_points: Sequence[str]) -> tuple[tuple[int, 
     (TransformerLens exposes these; the reference only ever uses
     ``resid_pre``, reference train.py:32): ``attn_out`` / ``mlp_out`` of
     layer L are the block's attention/MLP contributions as ADDED to the
-    stream — i.e. after Gemma-2's post-sublayer sandwich norms."""
+    stream — i.e. after Gemma-2's post-sublayer sandwich norms. Of layers of
+    one sublayer, ``attn_out`` is the MIXER slot's contribution (attention,
+    the state-space mixer, or both) and ``mlp_out`` the FFN slot's; a layer
+    without that slot captures zeros there (and refuses an edit:
+    :func:`_refuse_absent_slots`)."""
     pairs = []
     for hp in hook_points:
         layer, site = parse_hook_point(hp)
@@ -1399,6 +1551,18 @@ def _hook_layers(cfg: LMConfig, hook_points: Sequence[str]) -> tuple[tuple[int, 
             raise ValueError(f"hook layer {layer} out of range for {cfg.n_layers}-layer model")
         pairs.append((layer, code))
     return tuple(pairs)
+
+
+def _refuse_absent_slots(cfg: LMConfig, pairs: tuple[tuple[int, int], ...],
+                         hook_points: Sequence[str]) -> None:
+    """An edit of a sublayer the layer does not have would edit nothing:
+    refuse it by name."""
+    for (layer, code), hp in zip(pairs, hook_points):
+        slot = {_SITE_ATTN: ("mixer", cfg.layer_types), _SITE_MLP: ("FFN", cfg.mlp_types)}
+        if code in slot and slot[code][1][layer] == NONE:
+            raise ValueError(
+                f"edit at {hp!r}: layer {layer} has no {slot[code][0]} sublayer "
+                f"(its table entry is {cfg.layer_types[layer]!r} / {cfg.mlp_types[layer]!r})")
 
 
 def _scan_stop(pairs: tuple[tuple[int, int], ...]) -> int:
@@ -1583,9 +1747,10 @@ def _scan_blocks(
                 edit_mlp=functools.partial(edited, i=i, site=_SITE_MLP),
                 pos=pos, attend=attend, resets=resets,
             )
-            if _SITE_ATTN in captured_sites:
+            # (a slot the class does not have leaves its capture at zero)
+            if _SITE_ATTN in captured_sites and attn_out is not None:
                 buf = _capture_into(buf, attn_out, i, slots, _SITE_ATTN)
-            if _SITE_MLP in captured_sites:
+            if _SITE_MLP in captured_sites and mlp_out is not None:
                 buf = _capture_into(buf, mlp_out, i, slots, _SITE_MLP)
             return (resid, buf), (emit(lp, seen) if emit else None)
 
@@ -1684,6 +1849,7 @@ def forward(
     """
     cap_pairs = _hook_layers(cfg, capture)
     edit_pairs = _hook_layers(cfg, [e.hook_point for e in edits])
+    _refuse_absent_slots(cfg, edit_pairs, [e.hook_point for e in edits])
     edit_fns = tuple(e.fn for e in edits)
     zeros = None
     values = []
@@ -2295,24 +2461,29 @@ def tp_shardings(mesh, axis: str = "model", cfg: LMConfig | None = None) -> LMPa
     - norms: replicated (tiny).
 
     The leaves follow ``cfg`` (None: the Gemma-2 family's), one stack a
-    class of layers. Expert leaves have no layout here yet: a sparse config
-    on an axis larger than 1 is refused rather than mis-sharded.
+    class of layers (an empty slot: no leaves). Expert leaves have no layout
+    here yet: a sparse config on an axis larger than 1 is refused rather
+    than mis-sharded.
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     def ns(*spec):
         return NamedSharding(mesh, P(*spec))
 
-    def stack(sparse: bool) -> dict:
-        layers = {
-            "attn_norm": ns(None, None),
-            "pre_ffw_norm": ns(None, None),
-            "wq": ns(None, None, axis),
-            "wk": ns(None, None, axis),
-            "wv": ns(None, None, axis),
-            "wo": ns(None, axis, None),
-        }
-        if cfg is not None and cfg.latent:
+    def stack(cls: LayerClass | None) -> dict:
+        # the leaves init_params gives the class (None: the Gemma-2 family's)
+        attends = cls is None or cls.attends
+        mlp = DENSE if cls is None else cls.mlp
+        gated = cfg is None or cfg.mlp_act == "gated"
+        layers = {}
+        if cls is None or cls.kind != NONE:
+            layers["attn_norm"] = ns(None, None)
+        if mlp != NONE:
+            layers["pre_ffw_norm"] = ns(None, None)
+        if attends:
+            layers.update(wq=ns(None, None, axis), wk=ns(None, None, axis),
+                          wv=ns(None, None, axis), wo=ns(None, axis, None))
+        if attends and cfg is not None and cfg.latent:
             # the low-rank halves whole (small), the per-head halves by head
             del layers["wq"], layers["wk"]
             layers.update(
@@ -2328,36 +2499,37 @@ def tp_shardings(mesh, axis: str = "model", cfg: LMConfig | None = None) -> LMPa
         if cfg is None or cfg.block_style == "sandwich":
             layers["post_attn_norm"] = ns(None, None)
             layers["post_ffw_norm"] = ns(None, None)
-        if cfg is not None and cfg.attn_gate == "per_head":
+        if attends and cfg is not None and cfg.attn_gate == "per_head":
             layers["w_attn_gate"] = ns(None, None, axis)    # heads, as wq's
-        if cfg is not None and cfg.ssm:
+        if cls is not None and (cls.kind == MAMBA or (attends and cfg.ssm_parallel)):
             if mesh.shape[axis] > 1:
                 cfg.refuse_state(f"tensor parallelism on a {axis!r} axis of {mesh.shape[axis]}")
             layers.update({k: ns(None, None, None) for k in ("ssm_in", "ssm_conv_w", "ssm_out")})
             layers.update({k: ns(None, None) for k in (
                 "ssm_conv_b", "ssm_dt_bias", "ssm_A_log", "ssm_D", "ssm_norm")})
-        if sparse:
+        if mlp == SPARSE:
             if mesh.shape[axis] > 1:
                 raise NotImplementedError(
                     f"sparse-expert layers on a {axis!r} axis of {mesh.shape[axis]}: "
                     "expert parallelism (experts split over the axis, the token "
                     "exchange before and after them) is not implemented; "
                     "ops/moe.py computes every expert it holds on one device")
-            layers.update(router=ns(None, None, None),
-                          we_gate_up=ns(None, None, None, None),
-                          we_down=ns(None, None, None, None))
+            layers.update({"router": ns(None, None, None),
+                           "we_gate_up" if gated else "we_up": ns(None, None, None, None),
+                           "we_down": ns(None, None, None, None)})
             if cfg.router == "sigmoid_bias":
                 layers["router_bias"] = ns(None, None)
             if cfg.d_shared_expert:
-                layers.update(ws_gate=ns(None, None, None), ws_up=ns(None, None, None),
-                              ws_down=ns(None, None, None))
-        else:
-            layers.update(w_gate=ns(None, None, axis), w_up=ns(None, None, axis),
-                          w_down=ns(None, axis, None))
+                layers.update(ws_up=ns(None, None, None), ws_down=ns(None, None, None))
+                if gated:
+                    layers["ws_gate"] = ns(None, None, None)
+        elif mlp == DENSE:
+            layers.update(w_up=ns(None, None, axis), w_down=ns(None, axis, None))
+            if gated:
+                layers["w_gate"] = ns(None, None, axis)
         return layers
 
-    stacks = [stack(False)] if cfg is None else [
-        stack(c.mlp == SPARSE) for c in layer_classes(cfg)]
+    stacks = [stack(None)] if cfg is None else [stack(c) for c in layer_classes(cfg)]
     out = {"embed": ns(None, axis), "final_norm": ns(None), "layers": _from_stacks(stacks)}
     if cfg is not None and not cfg.tie_embeddings:
         out["unembed"] = ns(None, axis)
@@ -2546,7 +2718,8 @@ def from_torch_state_dict(
     shardings: LMParams | None = None,
 ) -> LMParams:
     """Convert an HF-transformers ``state_dict`` (Gemma2's names; for a
-    pre-norm or sparse ``cfg`` the names assumed below; Falcon-H1's own) to
+    pre-norm or sparse ``cfg`` the names assumed below; Falcon-H1's own; under
+    ``cfg.state_dict_names`` "nemotron_h", Nemotron-H's: :func:`_nemotron_h_params`) to
     our stacked layout, each leaf's multipliers folded in
     (:func:`fold_multiplier`).
 
@@ -2578,6 +2751,12 @@ def from_torch_state_dict(
             sh = sh[k]
         return _put_global(arr, sh)
 
+    if cfg.state_dict_names == "nemotron_h":
+        return _nemotron_h_params(cfg, get, leaf)
+    if NONE in cfg.layer_types + cfg.mlp_types:
+        raise NotImplementedError(
+            "layers with an empty slot load only by a named convention "
+            "(state_dict_names 'nemotron_h'); this config names none")
     classes = layer_classes(cfg)
     p = "model.layers.{}."
 
@@ -2722,6 +2901,76 @@ def from_torch_state_dict(
             hc_head_phi=leaf(("hc_head_phi",), get("model.hc_head_fn.weight").T, np.float32),
             hc_head_alpha=leaf(("hc_head_alpha",), get("model.hc_head_scale"), np.float32),
             hc_head_bias=leaf(("hc_head_bias",), get("model.hc_head_base"), np.float32))
+    return params
+
+
+def _nemotron_h_params(cfg: LMConfig, get: Callable, leaf: Callable) -> LMParams:
+    """:func:`from_torch_state_dict` of a model whose layers are ONE sublayer
+    each, from Nemotron-H's names (ASSUMED: no checkpoint was read here):
+    ``backbone.embeddings.weight``, ``backbone.layers.{i}.norm.weight`` (the
+    layer's one norm: its mixer slot's or its FFN slot's) and under
+    ``backbone.layers.{i}.mixer.`` the state-space mixer's ``in_proj``,
+    ``conv1d`` (``[C, 1, K]``, as ``[K, C]``), ``dt_bias``, ``A_log``, ``D``,
+    ``norm``, ``out_proj``; attention's ``{q,k,v,o}_proj``; the expert
+    layer's ``gate.{weight,e_score_correction_bias}``,
+    ``experts.{e}.{up,down}_proj`` (the whole model's; the rank's share is
+    kept) and ``shared_experts.{up,down}_proj``; a dense MLP's
+    ``{up,down}_proj``; ``backbone.norm_f.weight`` and ``lm_head.weight``."""
+    classes = layer_classes(cfg)
+    m = "backbone.layers.{}.mixer."
+
+    def stack_of(c: int, cls: LayerClass) -> dict:
+        where = ("layers",) if len(classes) == 1 else ("layers", c)
+
+        def stack(key: str, name: str, transpose: bool = False, as_dtype=None) -> jax.Array:
+            mats = [get((m + name).format(i)) for i in cls.layers]
+            return leaf((*where, key), np.stack([a.T if transpose else a for a in mats]),
+                        as_dtype)
+
+        norm = "attn_norm" if cls.kind != NONE else "pre_ffw_norm"
+        layers = {norm: leaf((*where, norm), np.stack(
+            [get(f"backbone.layers.{i}.norm.weight") for i in cls.layers]))}
+        if cls.kind == MAMBA:
+            layers.update(
+                ssm_in=stack("ssm_in", "in_proj.weight", True),
+                ssm_out=stack("ssm_out", "out_proj.weight", True),
+                ssm_conv_w=leaf((*where, "ssm_conv_w"), np.stack(
+                    [get((m + "conv1d.weight").format(i))[:, 0].T for i in cls.layers])),
+                ssm_conv_b=stack("ssm_conv_b", "conv1d.bias"),
+                ssm_norm=stack("ssm_norm", "norm.weight"))
+            for key, name in (("ssm_dt_bias", "dt_bias"), ("ssm_A_log", "A_log"), ("ssm_D", "D")):
+                layers[key] = stack(key, name, as_dtype=np.float32)
+        elif cls.attends:
+            layers.update({f"w{x}": stack(f"w{x}", f"{x}_proj.weight", True) for x in "qkvo"})
+        if cls.mlp == SPARSE:
+            held = range(cfg.first_expert, cfg.first_expert + cfg.n_held)
+
+            def experts(name: str) -> np.ndarray:      # -> [L, E_held, in, out]
+                return np.stack([np.stack([get((m + name).format(i, e)).T for e in held])
+                                 for i in cls.layers])
+
+            layers.update(
+                router=stack("router", "gate.weight", True),
+                router_bias=stack("router_bias", "gate.e_score_correction_bias",
+                                  as_dtype=np.float32),
+                # (the up projection kept [out, in], as held: ops/moe.py)
+                we_up=leaf((*where, "we_up"), np.swapaxes(
+                    experts("experts.{}.up_proj.weight"), -1, -2)),
+                we_down=leaf((*where, "we_down"), experts("experts.{}.down_proj.weight")),
+                ws_up=stack("ws_up", "shared_experts.up_proj.weight", True),
+                ws_down=stack("ws_down", "shared_experts.down_proj.weight", True))
+        elif cls.mlp == DENSE:
+            layers.update(w_up=stack("w_up", "up_proj.weight", True),
+                          w_down=stack("w_down", "down_proj.weight", True))
+        return layers
+
+    params = {
+        "embed": leaf(("embed",), get("backbone.embeddings.weight")),
+        "final_norm": leaf(("final_norm",), get("backbone.norm_f.weight")),
+        "layers": _from_stacks([stack_of(c, cls) for c, cls in enumerate(classes)]),
+    }
+    if not cfg.tie_embeddings:
+        params["unembed"] = leaf(("unembed",), get("lm_head.weight"))
     return params
 
 

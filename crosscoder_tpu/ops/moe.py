@@ -54,9 +54,16 @@ shape), as ``models/lm._attn_core`` chooses its attention:
   shape): rows sorted by expert, ``jax.lax.ragged_dot``. Also the oracle the
   kernels are pinned against (tests/test_moe.py).
 
+An expert may be UNGATED (``moe_mlp(gated=False)``, Nemotron-H's relu²):
+``relu(x·W_up)² · W_down``, its up projection held ``[L, E, F, D]``; both
+forms take it, the tile form through ``pallas:moe_up`` in place of
+``pallas:moe_gate_up``, with the expert's whole width as one block, so that
+its width need not be whole lanes.
+
 All round at the same places (the gated hidden and each expert's output to
 the model's dtype; the weighted combine in float32, rounded once). The
 choices are counted in the job's telemetry plane, once per trace:
+``harvest/moe_ungated_traces`` where the experts are ungated,
 ``harvest/moe_tiles_traces`` / ``harvest/moe_ragged_traces`` and, within the
 tile form, ``harvest/moe_combine_kernel_traces`` /
 ``harvest/moe_combine_xla_traces``; under a share
@@ -105,12 +112,17 @@ def enabled() -> bool:
     )
 
 
-def supported(d_model: int, d_expert: int, dtype) -> bool:
-    """Shapes the kernels handle: both widths whole lanes, and one expert's
-    gate+up block double-buffered within the raised VMEM limit."""
-    if d_model % _LANES or d_expert % _LANES:
+def supported(d_model: int, d_expert: int, dtype, gated: bool = True) -> bool:
+    """Shapes the kernels handle: the model's width whole lanes, and one
+    expert's first block (gate and up, or the ungated up) double-buffered
+    within the raised VMEM limit. The gated instances cut gate and up out of
+    one ``[D, 2·F]`` array, so their width is whole lanes too; the ungated
+    ones take each weight and their hidden rows as blocks as wide as the
+    array (1856 = 14.5 lane tiles at Nemotron-H's width), so theirs need
+    only whole sublane tiles."""
+    if d_model % _LANES or d_expert % (_LANES if gated else 16):
         return False
-    block = 2 * 2 * d_model * d_expert * jnp.dtype(dtype).itemsize
+    block = 2 * (1 + gated) * d_model * d_expert * jnp.dtype(dtype).itemsize
     return block <= _VMEM_LIMIT_BYTES // 2
 
 
@@ -177,18 +189,25 @@ def _combine(y_slots: jax.Array, gates: jax.Array) -> jax.Array:
     return out.astype(y_slots.dtype)
 
 
-def _experts_ragged(x, idx, gates, w_gate_up, w_down, layer, share=False):
+def _experts_ragged(x, idx, gates, w_gate_up, w_down, layer, share=False, gated=True):
     """The XLA form: sorted rows through ``ragged_dot``. Under a ``share``
     the absent rows lie past the last group, where ``ragged_dot`` multiplies
-    nothing; what it leaves there is replaced by 0."""
+    nothing; what it leaves there is replaced by 0. Ungated, ``w_gate_up``
+    is the up projection alone, ``[L, E, F, D]``, and the hidden is
+    ``relu(·)²``."""
     T, k = idx.shape
     w_gate_up, w_down = w_gate_up[layer], w_down[layer]
     F = w_down.shape[1]
     experts, order, sizes = _by_expert(idx, w_down.shape[0])
     inverse = _unsort(order, jnp.arange(T * k, dtype=jnp.int32))
     xs = x[order // k]                                          # [N, D]
+    if not gated:
+        w_gate_up = jnp.swapaxes(w_gate_up, -1, -2)
     gu = jax.lax.ragged_dot(xs, w_gate_up, sizes, preferred_element_type=jnp.float32)
-    h = (jax.nn.silu(gu[:, :F]) * gu[:, F:]).astype(x.dtype)
+    if gated:
+        h = (jax.nn.silu(gu[:, :F]) * gu[:, F:]).astype(x.dtype)
+    else:
+        h = jnp.square(jax.nn.relu(gu)).astype(x.dtype)
     y = jax.lax.ragged_dot(h, w_down, sizes, preferred_element_type=jnp.float32)
     if share:
         y = jnp.where((experts < w_down.shape[0])[:, None], y, 0.0)
@@ -205,10 +224,26 @@ def _gated_product(x, wg_ref, wu_ref, o_ref):
     o_ref[...] = (g * jax.nn.sigmoid(g) * u).astype(o_ref.dtype)
 
 
+def _relu2_product(x, wu_ref, o_ref):
+    # the up block [F, D], D minor: x · W_upᵀ
+    u = jax.lax.dot_general(x, wu_ref[...], (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    r = jnp.maximum(u, 0.0)
+    o_ref[...] = (r * r).astype(o_ref.dtype)
+
+
 def _gate_up_kernel(te_ref, nv_ref, ly_ref, x_ref, wg_ref, wu_ref, o_ref):
     @pl.when(pl.program_id(0) < nv_ref[0])
     def _():
         _gated_product(x_ref[...], wg_ref, wu_ref, o_ref)
+
+
+def _up_kernel(te_ref, nv_ref, ly_ref, x_ref, wu_ref, o_ref):
+    """The ungated instance (``pallas:moe_up``): ``relu(x·W_up)²``, the up
+    block as wide as the expert."""
+    @pl.when(pl.program_id(0) < nv_ref[0])
+    def _():
+        _relu2_product(x_ref[...], wu_ref, o_ref)
 
 
 def _down_kernel(te_ref, nv_ref, ly_ref, h_ref, w_ref, o_ref):
@@ -336,20 +371,21 @@ def _tile_layout(idx, n_experts, share=False):
     return tile_expert, n_valid, (order // k)[sorted_pos], _unsort(order, dest)
 
 
-def _experts_tiles(x, idx, gates, w_gate_up, w_down, layer, share=False):
+def _experts_tiles(x, idx, gates, w_gate_up, w_down, layer, share=False, gated=True):
     """The kernel form: expert-aligned row tiles; the weights stay stacked
     ``[L, E, ...]`` and a block is fetched by (layer, the tile's expert).
     Where the row-gather kernel takes the shape (``row_gather.supported``)
     ``moe_down`` writes its rows packed for it and the combine is that
     kernel (``Σ_s gates[t, s] · y[rows[t·k + s]]``, as :func:`_combine` of
     the gathered rows); elsewhere the rows are gathered and summed by XLA.
-    Under a ``share`` the rows go in and out by :func:`_held_rows`."""
+    Under a ``share`` the rows go in and out by :func:`_held_rows`. Ungated,
+    the first kernel is ``moe_up`` over the up projection alone."""
     from crosscoder_tpu import obs
 
     T, k = idx.shape
     _, E, F, D = w_down.shape
     if share and row_gather.held_supported(T, k, D, x.dtype):
-        return _held_rows(x, idx, gates, w_gate_up, w_down, layer)
+        return _held_rows(x, idx, gates, w_gate_up, w_down, layer, gated)
     if share:
         obs.count("harvest/moe_rows_in_xla_traces")
     tile_expert, n_valid, token, rows = _tile_layout(idx, E, share)
@@ -359,11 +395,15 @@ def _experts_tiles(x, idx, gates, w_gate_up, w_down, layer, share=False):
               else "harvest/moe_combine_xla_traces")
 
     prefetch = (tile_expert, n_valid, jnp.asarray(layer, jnp.int32).reshape(1))
-    h = _tile_call(
-        _gate_up_kernel, "moe_gate_up", prefetch, xs, (w_gate_up, w_gate_up),
-        [pl.BlockSpec((None, None, D, F), _w_block(0)),    # gate columns [0, F)
-         pl.BlockSpec((None, None, D, F), _w_block(1))],   # up columns [F, 2F)
-        F)
+    if gated:
+        h = _tile_call(
+            _gate_up_kernel, "moe_gate_up", prefetch, xs, (w_gate_up, w_gate_up),
+            [pl.BlockSpec((None, None, D, F), _w_block(0)),    # gate columns [0, F)
+             pl.BlockSpec((None, None, D, F), _w_block(1))],   # up columns [F, 2F)
+            F)
+    else:
+        h = _tile_call(_up_kernel, "moe_up", prefetch, xs, (w_gate_up,),
+                       [pl.BlockSpec((None, None, F, D), _w_block(0))], F)
     y = _tile_call(
         _down_kernel, "moe_down", prefetch, h, (w_down,),
         [pl.BlockSpec((None, None, F, D), _w_block(0))], D, packed=kernel)
@@ -407,6 +447,21 @@ def _gate_up_rows_kernel(te_ref, nv_ref, ly_ref, first_ref, tok_ref, x_ref,
     (packed, left in HBM) are fetched by DMA, one copy a row, the next tile's
     while this one is multiplied; one wait a tile by byte count. The grid is
     the real tiles alone."""
+    _rows_in(nv_ref, first_ref, tok_ref, x_ref, buf, x_tile, sem)
+    _gated_product(x_tile[...], wg_ref, wu_ref, o_ref)
+
+
+def _up_rows_kernel(te_ref, nv_ref, ly_ref, first_ref, tok_ref, x_ref,
+                    wu_ref, o_ref, buf, x_tile, sem):
+    """:func:`_up_kernel` that brings its own rows in, as
+    :func:`_gate_up_rows_kernel` does."""
+    _rows_in(nv_ref, first_ref, tok_ref, x_ref, buf, x_tile, sem)
+    _relu2_product(x_tile[...], wu_ref, o_ref)
+
+
+def _rows_in(nv_ref, first_ref, tok_ref, x_ref, buf, x_tile, sem):
+    """The rows kernels' fetch: this tile's rows of ``x`` into ``x_tile``
+    (the next tile's started meanwhile)."""
     tm, D = x_tile.shape
     half = D // 2
     W = half // _LANES                      # lane tiles of words a row
@@ -446,25 +501,30 @@ def _gate_up_rows_kernel(te_ref, nv_ref, ly_ref, first_ref, tok_ref, x_ref,
         x_tile[:, pl.ds(half + col, _LANES)] = hi.astype(x_tile.dtype)
         return carry
     jax.lax.fori_loop(0, W, unpack, 0)
-    _gated_product(x_tile[...], wg_ref, wu_ref, o_ref)
 
 
-def _gate_up_rows(tile_expert, n_valid, layer, first, token, x_packed, w_gate_up, dtype):
-    """``moe_gate_up`` over the real tiles, from ``x_packed`` (``row_gather.
-    packed``'s layout) and :func:`_held_layout`'s tables → ``[M, F]``."""
-    _, _, D, F = w_gate_up.shape
-    F //= 2
+def _gate_up_rows(tile_expert, n_valid, layer, first, token, x_packed, w_gate_up, dtype,
+                  gated=True):
+    """``moe_gate_up`` (ungated: ``moe_up``) over the real tiles, from
+    ``x_packed`` (``row_gather.packed``'s layout) and :func:`_held_layout`'s
+    tables → ``[M, F]``."""
+    if gated:
+        _, _, D, F = w_gate_up.shape
+        F //= 2
+    else:
+        _, _, F, D = w_gate_up.shape
     tm, W = TILE_ROWS, D // 2 // _LANES
     M = tile_expert.shape[0] * tm
+    n_w = 1 + gated
+    block = (None, None, D, F) if gated else (None, None, F, D)
 
     return pl.pallas_call(
-        _gate_up_rows_kernel,
+        _gate_up_rows_kernel if gated else _up_rows_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(n_valid[0],),     # the real tiles: a count the device holds
             in_specs=[pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec((None, None, D, F), _w_block(0)),
-                      pl.BlockSpec((None, None, D, F), _w_block(1))],
+                      *(pl.BlockSpec(block, _w_block(j)) for j in range(n_w))],
             out_specs=pl.BlockSpec((tm, F), lambda t, *_: (t, 0)),
             scratch_shapes=[pltpu.VMEM((2, tm * W, 1, _LANES), jnp.uint32),
                             pltpu.VMEM((tm, D), dtype),
@@ -474,12 +534,12 @@ def _gate_up_rows(tile_expert, n_valid, layer, first, token, x_packed, w_gate_up
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
-        name="moe_gate_up",
+        name="moe_gate_up" if gated else "moe_up",
         interpret=_INTERPRET,
-    )(tile_expert, n_valid, layer, first, token, x_packed, w_gate_up, w_gate_up)
+    )(tile_expert, n_valid, layer, first, token, x_packed, *(w_gate_up,) * n_w)
 
 
-def _held_rows(x, idx, gates, w_gate_up, w_down, layer):
+def _held_rows(x, idx, gates, w_gate_up, w_down, layer, gated=True):
     """:func:`_experts_tiles` under a share, at a shape the row kernels take
     (``row_gather.held_supported``): the buffers keep their static bound —
     every token may choose k held experts — and no row moves for a row that
@@ -496,7 +556,7 @@ def _held_rows(x, idx, gates, w_gate_up, w_down, layer):
     tile_expert, n_valid, first, token, pairs = _held_layout(idx, gates, E)
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
     h = _gate_up_rows(tile_expert, n_valid, layer, first, token,
-                      row_gather.packed(x, interpret=_INTERPRET), w_gate_up, x.dtype)
+                      row_gather.packed(x, interpret=_INTERPRET), w_gate_up, x.dtype, gated)
     y = _tile_call(
         _down_kernel, "moe_down", (tile_expert, n_valid, layer), h, (w_down,),
         [pl.BlockSpec((None, None, F, D), _w_block(0))], D, packed=True,
@@ -509,6 +569,7 @@ def moe_mlp(
     x: jax.Array, w_router: jax.Array, w_gate_up: jax.Array, w_down: jax.Array,
     layer=0, *, top_k: int, norm_topk_prob: bool, routed_scale: float = 1.0,
     first_expert: int = 0, router: str = "softmax", router_bias: jax.Array | None = None,
+    gated: bool = True,
 ) -> jax.Array:
     """The expert layer ``layer`` on the normed stream ``x [B, S, D]``:
     ``w_router [D, E]`` (that layer's), and the STACKED expert weights
@@ -518,7 +579,13 @@ def moe_mlp(
     in ``x``'s dtype. Where ``E_held < E`` the leaves are the experts
     ``[first_expert, first_expert + E_held)`` and the result is their part
     of the routed sum (the module's docstring). ``router`` is :func:`route`'s
-    ``kind`` and ``router_bias [E]`` that layer's choice bias."""
+    ``kind`` and ``router_bias [E]`` that layer's choice bias. ``gated``
+    False is the ungated expert ``relu(x·W_up)² · W_down``: ``w_gate_up`` is
+    then the up projection alone, held ``[L, E_held, F, D]`` (out, in): with
+    D the minor axis, a width F that is not whole lanes (Nemotron-H's 1856)
+    leaves the chip's layout of the leaf the one the kernel reads — as ``[D,
+    F]`` the compiler lays it out F-major and copies 1.28 GB a layer-call
+    back for the kernel."""
     from crosscoder_tpu import obs
 
     B, S, D = x.shape
@@ -533,12 +600,14 @@ def moe_mlp(
             obs.count("harvest/moe_held_traces")
             idx, gates = _held(idx, gates, first_expert, w_down.shape[1])
     with jax.named_scope("harvest/block/moe/experts"):
-        if enabled() and supported(D, w_down.shape[2], x.dtype):
+        if not gated:
+            obs.count("harvest/moe_ungated_traces")
+        if enabled() and supported(D, w_down.shape[2], x.dtype, gated):
             obs.count("harvest/moe_tiles_traces")
-            out = _experts_tiles(x2, idx, gates, w_gate_up, w_down, layer, share)
+            out = _experts_tiles(x2, idx, gates, w_gate_up, w_down, layer, share, gated)
         else:
             obs.count("harvest/moe_ragged_traces")
-            out = _experts_ragged(x2, idx, gates, w_gate_up, w_down, layer, share)
+            out = _experts_ragged(x2, idx, gates, w_gate_up, w_down, layer, share, gated)
     return out.reshape(B, S, D)
 
 

@@ -56,7 +56,8 @@ from jax.experimental.pallas import tpu as pltpu
 F32 = jnp.float32
 _LANES = 128
 # what the kernel's blocks, its state scratch and its temporaries may take
-# of VMEM (Falcon-H1's widths need ≈ 6.5 MiB: ``_vmem_bytes``)
+# of VMEM (Falcon-H1's widths need ≈ 6.5 MiB, Nemotron-H's ≈ 2.0 MiB:
+# ``_vmem_bytes``)
 _VMEM_LIMIT_BYTES = 32 << 20
 
 # test-only: route the kernel through the Pallas interpreter (and let it
@@ -212,12 +213,16 @@ def _vmem_bytes(H: int, P: int, G: int, N: int, chunk: int, itemsize: int) -> in
 
 
 def supported(H: int, P: int, G: int, N: int, chunk: int, dtype) -> bool:
-    """Shapes the kernel handles: heads and states of whole lanes, a chunk
-    of whole lane tiles, B's and C's bands on whole multiples of ``N`` after
-    x's, and the blocks, the state and the temporaries within the VMEM limit."""
+    """Shapes the kernel handles: a head's band of x and y whole sublane
+    tiles of the inputs' dtype (8 rows of float32, 16 of bf16: P 64 and P
+    128 alike — a head is a band of rows, tokens are the lanes), states of
+    whole lanes, a chunk of whole lane tiles, B's and C's bands on whole
+    multiples of ``N`` after x's, and the blocks, the state and the
+    temporaries within the VMEM limit."""
     if jnp.dtype(dtype) not in (jnp.dtype(jnp.bfloat16), jnp.dtype(F32)):
         return False
-    if P % _LANES or N % _LANES or chunk % _LANES or H % G or (H * P) % N:
+    sublanes = 32 // jnp.dtype(dtype).itemsize
+    if P % sublanes or N % _LANES or chunk % _LANES or H % G or (H * P) % N:
         return False
     return _vmem_bytes(H, P, G, N, chunk, jnp.dtype(dtype).itemsize) <= _VMEM_LIMIT_BYTES
 

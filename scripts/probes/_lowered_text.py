@@ -8,7 +8,7 @@ written out by one tree and compared with another tree's.
 ``write`` lowers, from the tree given (its ``crosscoder_tpu`` and its
 ``benchmarks``): the mellum2 cell's harvest programs (``_seg_start_impl``,
 ``_seg_scan_impl`` at widths 1, 2 and 4, ``_seg_finish_impl``,
-``_multi_cache_impl``), the Ouro cells', the Laguna cell's and the Xing cell's refill quanta
+``_multi_cache_impl``), the Ouro cells', the Laguna cell's, the Xing cell's and the Falcon-H1 cell's refill quanta
 (``_seg_scan_impl``, one program a class of layers) and both variants of the
 TopK 2^15 and the ReLU 2^14 step at the cells' sizes. ``compare`` holds two such directories against each
 other: the text outside the kernels byte for byte, and each Pallas kernel —
@@ -85,7 +85,8 @@ def write(root: str, out: str) -> None:
     for stem, arch_name, quanta in (
             ("ouro2.6b-pair-relu16k", "gemma2_block", ((None, 3),)),
             ("laguna-s2.1-pair-relu16k", "laguna", ((0, 1), (1, 3), (2, 1))),
-            ("xing4.0-pair-relu16k", "xing", ((0, 2), (1, 2)))):
+            ("xing4.0-pair-relu16k", "xing", ((0, 2), (1, 2))),
+            ("falcon-h1-34b-pair-relu16k", "falcon_h1", ((None, 2),))):
         conf = manifest.load_json(manifest.BENCH_DIR / "configs" / f"{stem}.json")
         acfg = importlib.import_module(f"benchmarks.arch.{arch_name}").lm_config(conf)
         ccc = conf["crosscoder"]

@@ -720,3 +720,54 @@ def test_ssm_scan_kernel_compiles(chip, one_device):
                      _sds((1, S, H), jnp.float32, chip), _sds((H,), jnp.float32, chip),
                      _sds((H,), jnp.float32, chip))
     assert "ssd_scan" in text
+
+
+@pytest.mark.parametrize("cls", [0, 1, 2], ids=["M-mixer", "E-experts", "star-attention"])
+def test_nemotron_h_harvest_segments_compile(chip, one_device, cls):
+    """The seventh cell's refill quanta at its published widths, two 4096-token
+    sequences a forward (``benchmarks/configs/
+    nemotron-twotower-30b-a3b-pair-relu16k.json``): one one-layer program a
+    class — a Mamba-2 mixer alone, its scan through ``pallas:ssd_scan`` at
+    head dim 64 (64 heads in 8 groups, a state of 128); the expert layer
+    alone, its 32 HELD ungated experts of 2688 x 1856 through ``moe_up`` and
+    ``moe_down`` with the expert's whole width a block; attention alone
+    (32 / 2 heads of 128) through the fused kernel, with no rotation. No
+    class holds another's leaves, and no norm or add stands for an empty
+    slot."""
+    import sys
+    from pathlib import Path
+
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+    from benchmarks import manifest
+    from benchmarks.arch import nemotron_h
+    from crosscoder_tpu.ops import flash_attention as fa
+    from crosscoder_tpu.ops import moe, ssm
+
+    cfg = nemotron_h.lm_config(manifest.load_json(
+        manifest.BENCH_DIR / "configs" / "nemotron-twotower-30b-a3b-pair-relu16k.json"))
+    B, S = 2, 4096
+    assert fa.supported(S, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, jnp.bfloat16)
+    assert moe.supported(cfg.d_model, cfg.d_expert, jnp.bfloat16, gated=False)
+    assert ssm.supported(cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state,
+                         cfg.ssm_chunk, jnp.bfloat16)
+    params = _abstract(jax.eval_shape(
+        lambda key: lm.init_params(key, cfg), jax.random.key(0)), chip)
+    compiled = lm._seg_scan_impl.lower(
+        params, _sds((B, S, cfg.d_model), jnp.bfloat16, chip),
+        _sds((1, B, S, cfg.d_model), jnp.bfloat16, chip),
+        _sds((), jnp.int32, chip), cfg=cfg,
+        capture=lm._hook_layers(cfg, ("blocks.9.hook_resid_pre",)), k=1, cls=cls,
+    ).compile()
+    _fits(compiled)
+    text = compiled.as_text()
+    calls = [line.split(" = ")[0] for line in text.splitlines() if "tpu_custom_call" in line]
+    kernels = {name: sum(name in c for c in calls) for name in (
+        "ssd_scan", "moe_up", "moe_gate_up", "moe_down", "fused_causal_attention")}
+    assert kernels == {"ssd_scan": cls == 0, "moe_up": cls == 1, "moe_gate_up": 0,
+                       "moe_down": cls == 1, "fused_causal_attention": cls == 2}, kernels
+    assert " conditional(" not in text
+    if cls == 1:        # the held experts reach the kernels whole
+        stacked = f"bf16[4,{cfg.n_held},"
+        assert not [line for line in text.splitlines()
+                    if stacked in line.split(" = ")[-1][:60]
+                    and (" copy(" in line or "dynamic-slice(" in line)]

@@ -644,3 +644,89 @@ def test_a_sigmoid_routed_held_share_matches_the_plain_loop(form, request):
         h = jax.nn.silu(x[0] @ w_gate_up[0, e, :, :F]) * (x[0] @ w_gate_up[0, e, :, F:])
         want = want + g[:, None] * (h @ w_down[0, e])
     np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want), rtol=2e-4, atol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# the ungated relu² expert (Nemotron-H): ``relu(x·W_up)² · W_down``, its up
+# block and hidden rows as wide as the expert, whole lane tiles or not
+
+FU = 192        # 1.5 lane tiles, as Nemotron-H's 1856 is 14.5
+
+
+def _ungated_layer(seed, width=FU):
+    ks = jax.random.split(jax.random.key(seed), 3)
+    return (jax.random.normal(ks[0], (D, E)) * D ** -0.5,
+            jax.random.normal(ks[1], (L, E, width, D)) * D ** -0.5,         # [out, in]
+            jax.random.normal(ks[2], (L, E, width, D)) * width ** -0.5)
+
+
+def _relu2_loop(x, w_router, w_up, w_down, layer, first, n_held, scale, bias):
+    """Each held expert on every token, masked by its gate (the plain loop)."""
+    with jax.default_matmul_precision("highest"):
+        idx, gates = moe.route(x, w_router, K, True, scale, "sigmoid_bias", bias)
+        out = jnp.zeros_like(x)
+        for e in range(first, first + n_held):
+            g = jnp.sum(jnp.where(idx == e, gates, 0.0), -1)
+            h = jnp.square(jax.nn.relu(x @ w_up[layer, e - first].T))
+            out = out + g[:, None] * (h @ w_down[layer, e - first])
+        return out
+
+
+@pytest.mark.parametrize("held", [E, 4], ids=["every-expert", "half-held"])
+@pytest.mark.parametrize("form", ["ragged", "tiles"])
+def test_the_ungated_expert_at_a_width_off_the_lanes_matches_the_plain_loop(
+        form, held, request, tmp_path):
+    """Both forms at width 192 under the sigmoid router, every expert held or
+    experts [4, 8): the tile form's ``moe_up`` and ``moe_down`` take the
+    expert's whole width as one block."""
+    if form == "tiles":
+        request.getfixturevalue("interpret")
+    w_router, w_up, w_down = _ungated_layer(5)
+    first = E - held
+    w_up, w_down = w_up[:, first:], w_down[:, first:]
+    x, bias = _x(5, 300, False), _bias(5)
+    assert moe.supported(D, FU, x.dtype, gated=False) and not moe.supported(D, FU, x.dtype)
+    plane = obs.acquire(types.SimpleNamespace(
+        obs="on", obs_dir=str(tmp_path / "obs"), checkpoint_dir=str(tmp_path)))
+    try:
+        got = moe.moe_mlp(x, w_router, w_up, w_down, jnp.int32(1), top_k=K,
+                          norm_topk_prob=True, routed_scale=2.5, first_expert=first,
+                          router="sigmoid_bias", router_bias=bias, gated=False)
+        reg = plane.registry
+        assert reg.get_count("harvest/moe_ungated_traces") == 1
+        assert reg.get_count(f"harvest/moe_{form}_traces") == 1
+    finally:
+        plane.close()
+    want = _relu2_loop(x[0], w_router, w_up, w_down, 1, first, held, 2.5, bias)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want), rtol=0, atol=2.5 * ATOL)
+
+
+def test_the_ungated_held_tiles_fetch_their_own_rows(interpret):
+    """``moe_up`` fetching x's rows by DMA (bf16, D a multiple of 256) against
+    the plain ``moe_up`` on XLA's gather: the real tiles' results are the same
+    bits; a gated kernel on the same up block would not be."""
+    from jax.experimental import pallas as pl
+
+    x, _, (local, held_gates) = _rows_in_case(300, False, 0)
+    w_up = (jax.random.normal(jax.random.key(3), (L, 2, FU, DC)) * DC ** -0.5).astype(jnp.bfloat16)
+    layer = jnp.ones((1,), jnp.int32)
+    tile_expert, n_valid, tile_first, token, _ = moe._held_layout(local, held_gates, 2)
+    got = moe._gate_up_rows(tile_expert, n_valid, layer, tile_first, token,
+                            row_gather.packed(x, interpret=True), w_up, x.dtype, gated=False)
+    te, nv, gathered, _ = moe._tile_layout(local, 2, True)
+    want = moe._tile_call(moe._up_kernel, "moe_up", (te, nv, layer), x[gathered], (w_up,),
+                          [pl.BlockSpec((None, None, FU, DC), moe._w_block(0))], FU)
+    real = int(n_valid[0]) * moe.TILE_ROWS
+    assert got.shape == (want.shape[0], FU)
+    np.testing.assert_array_equal(np.asarray(got[:real], np.float32),
+                                  np.asarray(want[:real], np.float32))
+    assert (np.asarray(want[:real], np.float32) >= 0).all()       # relu², not a gate
+
+
+@pytest.mark.parametrize("d_model,d_expert,gated,want", [
+    (2688, 1856, False, True), (2688, 1856, True, False), (2304, 896, True, True),
+    (2688, 1850, False, False), (2600, 1856, False, False)],
+    ids=["nemotron-ungated", "gated-off-the-lanes", "mellum2", "off-the-sublanes",
+         "model-off-the-lanes"])
+def test_supported_by_form(d_model, d_expert, gated, want):
+    assert moe.supported(d_model, d_expert, jnp.bfloat16, gated) == want

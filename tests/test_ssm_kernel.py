@@ -48,13 +48,13 @@ def _rel(got, want) -> float:
     return float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
 
 
-def _inputs(S, bt, H, G, A, dt_mean, seed=0, quiet_after=None):
-    """x ``[bt, S, H, P]`` (zero from ``quiet_after`` on: what follows is the
+def _inputs(S, bt, H, G, A, dt_mean, seed=0, quiet_after=None, p=P):
+    """x ``[bt, S, H, p]`` (zero from ``quiet_after`` on: what follows is the
     carried state's alone), dt ≈ ``dt_mean`` after a softplus, A as given,
     B and C ``[bt, S, G, N]``, D ``[H]``; float32."""
     r = np.random.default_rng(seed)
     f = lambda *s: jnp.asarray(r.normal(size=s), jnp.float32)    # noqa: E731
-    x = f(bt, S, H, P)
+    x = f(bt, S, H, p)
     if quiet_after is not None:
         x = x.at[:, quiet_after:].set(0.0)
     dt = dt_mean / np.log(2.0) * jax.nn.softplus(f(bt, S, H))
@@ -160,7 +160,7 @@ def test_off_the_chip_and_at_unsupported_shapes_xlas_form_runs(plane):
 
 
 @pytest.mark.parametrize("shape", [
-    (4, 64, 2, 128, 128, jnp.bfloat16),        # a head narrower than the lanes
+    (16, 40, 2, 128, 128, jnp.bfloat16),       # a head not a whole bf16 sublane tile
     (4, 128, 2, 96, 128, jnp.bfloat16),        # a state narrower than the lanes
     (4, 128, 2, 128, 64, jnp.bfloat16),        # a chunk of half a lane tile
     (6, 128, 4, 128, 128, jnp.bfloat16),       # heads that do not split into the groups
@@ -177,6 +177,32 @@ def test_supported_accepts_the_cell():
     chunks of 128) within the stated limit, with room."""
     assert ssm.supported(32, 128, 2, 256, 128, jnp.bfloat16)
     assert ssm._vmem_bytes(32, 128, 2, 256, 128, 2) < ssm._VMEM_LIMIT_BYTES / 2
+
+
+def test_supported_accepts_nemotron_hs_heads_of_64():
+    """Nemotron-H's mixer (64 heads of 64 in 8 groups, a state of 128, chunks
+    of 128): a head is a band of 64 rows, four bf16 sublane tiles; P 64 and
+    P 40 in float32 (five tiles of 8 rows) too."""
+    assert ssm.supported(64, 64, 8, 128, 128, jnp.bfloat16)
+    assert ssm.supported(16, 40, 2, 128, 128, jnp.float32)
+    assert ssm._vmem_bytes(64, 64, 8, 128, 128, 2) < ssm._VMEM_LIMIT_BYTES / 8
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_the_kernel_at_head_dim_64_and_eight_heads_a_group(interpret, dtype):
+    """P 64, two groups of eight heads (Nemotron-H's eight a group), a
+    length off the chunk: against XLA's form (float32: to rounding; bf16
+    inputs: the same products' inputs rounded alike) and the recurrence."""
+    A = -np.arange(1, 17, dtype=np.float32) / 4
+    x, dt, A, B, C, D = _inputs(300, 2, 16, 2, A, 0.1, seed=64, p=64)
+    assert ssm.supported(16, 64, 2, N, Q, dtype)
+    x, B, C = (a.astype(dtype) for a in (x, B, C))
+    got = ssm._ssd_scan_kernel(x, dt, A, B, C, D, Q)
+    assert got.shape == x.shape and got.dtype == jnp.float32
+    want = ssm._ssd_scan_xla(x, dt, A, B, C, D, Q)
+    assert _rel(got, want) < (RTOL if dtype == jnp.float32 else 1e-4)
+    if dtype == jnp.float32:
+        assert _rel(got, _recurrence(x, dt, A, B, C, D)) < RTOL
 
 
 def test_the_mixer_with_the_kernel_is_the_mixer_with_xlas_form(interpret):

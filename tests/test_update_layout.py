@@ -59,12 +59,24 @@ def same(a, b):
 
 @pytest.fixture
 def own_disk_tier():
-    """The compile cache's process-global state, put back after the test."""
+    """The compile cache's process-global state, put back after the test;
+    JAX's persistent compilation cache off meanwhile. An earlier test file
+    in the same worker may have turned that cache on (tests/test_compile_cache.py),
+    and on XLA:CPU an executable it serves serializes again WITHOUT its
+    functions' code: a disk-tier entry written from one then fails when it
+    runs (``NOT_FOUND: ... Function convert_bitcast_fusion not found``)."""
+    from jax._src import compilation_cache as jax_cache
+
     names = ("_AOT_CACHE", "_COST_CACHE", "_COST_PENDING", "_COLLECTIVES")
     with compile_cache._LOCK:
         saved = [dict(getattr(compile_cache, n)) for n in names]
         disk, verify = compile_cache._DISK, compile_cache._VERIFY
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax_cache.reset_cache()
     yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    jax_cache.reset_cache()
     with compile_cache._LOCK:
         for n, old in zip(names, saved):
             getattr(compile_cache, n).clear()
