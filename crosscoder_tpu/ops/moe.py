@@ -251,13 +251,14 @@ def _down_kernel(te_ref, nv_ref, ly_ref, h_ref, w_ref, o_ref):
     def _():
         y = jnp.dot(h_ref[...], w_ref[...], preferred_element_type=jnp.float32)
         if o_ref.dtype == jnp.uint32:       # packed rows, for the combine kernel
-            half = y.shape[1] // 2
-            W = half // _LANES              # lane tiles of words a row
+            W = row_gather.word_tiles(y.shape[1])   # lane tiles of words a row
+            half = W * _LANES
             if _INTERPRET:      # the interpreter has no rule for the pack op
                 words = row_gather.pack_rows(y.astype(h_ref.dtype))
             else:               # one instruction a word: rounds as astype does
                 words = pltpu.pack_elementwise(
-                    [y[:, :half], y[:, half:]], packed_dtype=jnp.bfloat16)
+                    [y[:, :half], row_gather.high_half(y[:, half:], half)],
+                    packed_dtype=jnp.bfloat16)
             for c in range(W):
                 # lane tile c of every row of the tile, to that row's c-th tile
                 o_ref[pl.ds(c, y.shape[0], stride=W), 0, :] = (
@@ -276,7 +277,7 @@ def _tile_call(kernel, name, prefetch, rows, weights, w_specs, n_out,
                packed=False, n_grid=None):
     """One kernel over the row tiles: ``rows [M, K]`` × the weight block of
     each tile's expert → ``[M, n_out]``, or with ``packed`` the same rows as
-    :func:`row_gather.pack_rows` lays them out, ``[M · n_out / 256, 1, 128]`` uint32.
+    :func:`row_gather.pack_rows` lays them out, ``[M · ⌈n_out/256⌉, 1, 128]`` uint32.
     ``prefetch`` is (tile → expert, number of real tiles, layer); a tile past
     the last real one maps to that one's blocks (nothing is fetched or
     written for it) — or, with ``n_grid`` (a count the device holds), is not
@@ -289,7 +290,7 @@ def _tile_call(kernel, name, prefetch, rows, weights, w_specs, n_out,
         return (jnp.minimum(t, nv[0] - 1), 0)
 
     if packed:
-        words = n_out // 2 // _LANES
+        words = row_gather.word_tiles(n_out)
         out_spec = pl.BlockSpec((tm * words, 1, _LANES),
                                 lambda *a: (*row_block(*a), 0))
         out_shape = jax.ShapeDtypeStruct((M * words, 1, _LANES), jnp.uint32)
@@ -463,8 +464,9 @@ def _rows_in(nv_ref, first_ref, tok_ref, x_ref, buf, x_tile, sem):
     """The rows kernels' fetch: this tile's rows of ``x`` into ``x_tile``
     (the next tile's started meanwhile)."""
     tm, D = x_tile.shape
-    half = D // 2
-    W = half // _LANES                      # lane tiles of words a row
+    W = row_gather.word_tiles(D)            # lane tiles of words a row
+    half = W * _LANES
+    Wh = D // _LANES - W                    # of them with a high half (W - 1: padded)
     t, nv = pl.program_id(0), nv_ref[0]
     slot = jax.lax.rem(t, 2)
     last = tok_ref.shape[0] - 1
@@ -494,13 +496,16 @@ def _rows_in(nv_ref, first_ref, tok_ref, x_ref, buf, x_tile, sem):
     def _():
         fetch(t + 1, 1 - slot)
 
-    def unpack(c, carry):       # lane tile c of every row, to its two column halves
+    def unpack(c, carry, high=True):    # lane tile c of every row, to its two column halves
         lo, hi = row_gather.unpack_words(tiles[pl.ds(slot * tm * W + c, tm, stride=W), :])
         col = pl.multiple_of(c * _LANES, _LANES)
         x_tile[:, pl.ds(col, _LANES)] = lo.astype(x_tile.dtype)
-        x_tile[:, pl.ds(half + col, _LANES)] = hi.astype(x_tile.dtype)
+        if high:
+            x_tile[:, pl.ds(half + col, _LANES)] = hi.astype(x_tile.dtype)
         return carry
-    jax.lax.fori_loop(0, W, unpack, 0)
+    jax.lax.fori_loop(0, Wh, unpack, 0)
+    if Wh < W:                  # the last tile's high half is padding: never written
+        unpack(Wh, 0, high=False)
 
 
 def _gate_up_rows(tile_expert, n_valid, layer, first, token, x_packed, w_gate_up, dtype,
@@ -513,7 +518,7 @@ def _gate_up_rows(tile_expert, n_valid, layer, first, token, x_packed, w_gate_up
         F //= 2
     else:
         _, _, F, D = w_gate_up.shape
-    tm, W = TILE_ROWS, D // 2 // _LANES
+    tm, W = TILE_ROWS, row_gather.word_tiles(D)
     M = tile_expert.shape[0] * tm
     n_w = 1 + gated
     block = (None, None, D, F) if gated else (None, None, F, D)

@@ -14,10 +14,12 @@ PR 30), so the rows are fetched by DMA, one copy a row.
 **The row format** (:func:`pack_rows`). Rows of a 2-D bf16 array lie
 interleaved in (16, 128) tiles — two rows a 32-bit word — and Mosaic refuses
 a one-row slice of such a tile. A gatherable matrix is therefore kept as
-32-bit words of two bf16 columns (column ``j`` low, column ``j + D/2``
-high), a row's ``D/256`` lane tiles on an UNTILED axis: ``uint32 [R · D/256,
-1, 128]``. A copy addresses a row as ``D/256`` consecutive entries of the
-leading axis; loads go through a ``reshape`` view in whole (8, 128) tiles.
+32-bit words of two bf16 columns, a row's ``W = ⌈D/256⌉`` lane tiles of
+words on an UNTILED axis: ``uint32 [R · W, 1, 128]``, word ``j`` holding
+column ``j`` low and column ``W·128 + j`` high — 0 past ``D``: where ``D/128``
+is odd the last tile's high half is padding, and no reader writes it out.
+A copy addresses a row as ``W`` consecutive entries of the leading axis;
+loads go through a ``reshape`` view in whole (8, 128) tiles.
 
 **Token-major** (:func:`weighted_sum`, :func:`dots`): a grid over tiles of
 tokens; the ``[T·k]`` row table is scalar-prefetched; a tile's ``k·tt`` row
@@ -85,15 +87,30 @@ def enabled() -> bool:
     )
 
 
+def word_tiles(d: int) -> int:
+    """Lane tiles of words a packed row of ``d`` columns takes: ``⌈d/256⌉``."""
+    return -(-d // (2 * LANES))
+
+
 def pack_rows(y):
-    """bf16 ``[R, D]`` → uint32 ``[R, D/2]``: column ``j`` in the low half
-    of word ``j`` and column ``j + D/2`` in its high half, so that both
-    halves of a row unpack to whole lane tiles. A row is then ``D/256``
-    lane tiles of 32-bit words, a unit a DMA can address: rows of a
-    ``[M, D]`` array lie interleaved in (8, 128) tiles (bf16: two rows a
-    word), and Mosaic refuses a one-row slice of such a tile."""
-    half = y.shape[-1] // 2
-    return _words(y[..., :half], y[..., half:])
+    """bf16 ``[R, D]`` → uint32 ``[R, W·128]``, ``W`` = :func:`word_tiles`:
+    column ``j`` in the low half of word ``j`` and column ``W·128 + j`` in
+    its high half (0 past ``D``), so that both halves of a row unpack to
+    whole lane tiles. A row is then ``W`` lane tiles of 32-bit words, a unit
+    a DMA can address: rows of a ``[M, D]`` array lie interleaved in (8,
+    128) tiles (bf16: two rows a word), and Mosaic refuses a one-row slice
+    of such a tile."""
+    half = word_tiles(y.shape[-1]) * LANES
+    return _words(y[..., :half], high_half(y[..., half:], half))
+
+
+def high_half(hi, half):
+    """The columns of a row's high halves, zeros appended up to ``half``
+    (none where ``D`` is a multiple of 256: no op is added)."""
+    if hi.shape[-1] == half:
+        return hi
+    pad = jnp.zeros((*hi.shape[:-1], half - hi.shape[-1]), hi.dtype)
+    return jnp.concatenate([hi, pad], axis=-1)
 
 
 def _words(lo, hi):
@@ -108,14 +125,26 @@ _PACK_ROWS = 256            # rows of one tile of the pack kernel
 
 
 def _pack_kernel(lo_ref, hi_ref, o_ref, *, interpret):
-    rows, cols = lo_ref.shape
-    W = cols // LANES
+    _pack_store(lambda: lo_ref[...], lambda: hi_ref[...], o_ref, interpret)
+
+
+def _pack_halves_kernel(y_ref, o_ref, *, interpret):
+    """:func:`_pack_kernel` of one array at a ``D`` whose halves are not
+    whole lanes: the first ``W·128`` columns low, the rest high, padded."""
+    half = word_tiles(y_ref.shape[1]) * LANES
+    _pack_store(lambda: y_ref[:, :half],
+                lambda: high_half(y_ref[:, half:].astype(jnp.float32), half), o_ref, interpret)
+
+
+def _pack_store(lo, hi, o_ref, interpret):
+    """``lo``, ``hi``: the tile's two halves, loaded when called."""
     if interpret:               # the interpreter has no rule for the pack op
-        words = _words(lo_ref[...], hi_ref[...])
+        words = _words(lo(), hi())
     else:                       # one instruction a word: rounds as astype does
         words = pltpu.pack_elementwise(
-            [lo_ref[...].astype(jnp.float32), hi_ref[...].astype(jnp.float32)],
-            packed_dtype=jnp.bfloat16)
+            [lo().astype(jnp.float32), hi().astype(jnp.float32)], packed_dtype=jnp.bfloat16)
+    rows, cols = words.shape
+    W = cols // LANES
     for c in range(W):
         # lane tile c of every row of the tile, to that row's c-th tile
         o_ref[pl.ds(c, rows, stride=W), 0, :] = words[:, c * LANES:(c + 1) * LANES]
@@ -130,19 +159,23 @@ def packed(lo, hi=None, *, interpret=False):
     ``W_dec``, PERF.md §6, PR 32), so where the rows tile evenly a kernel
     writes it, a lane tile a strided store as ``moe_down`` does."""
     if hi is None:
-        cols = lo.shape[1] // 2
+        cols = word_tiles(lo.shape[1]) * LANES
         args, maps = (lo, lo), (lambda i: (i, 0), lambda i: (i, 1))
     else:
         cols = lo.shape[1]
         args, maps = (lo, hi), (lambda i: (i, 0), lambda i: (i, 0))
     R, W = lo.shape[0], cols // LANES
-    if R % _PACK_ROWS or cols % LANES:
+    if R % _PACK_ROWS or lo.shape[1] % LANES:
         words = pack_rows(lo) if hi is None else _words(lo, hi)
         return words.reshape(-1, 1, LANES)
+    kernel, in_specs = _pack_kernel, [pl.BlockSpec((_PACK_ROWS, cols), m) for m in maps]
+    if hi is None and 2 * cols != lo.shape[1]:      # the high half padded: rows whole
+        kernel, args = _pack_halves_kernel, (lo,)
+        in_specs = [pl.BlockSpec((_PACK_ROWS, lo.shape[1]), lambda i: (i, 0))]
     return pl.pallas_call(
-        functools.partial(_pack_kernel, interpret=interpret),
+        functools.partial(kernel, interpret=interpret),
         grid=(R // _PACK_ROWS,),
-        in_specs=[pl.BlockSpec((_PACK_ROWS, cols), m) for m in maps],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((_PACK_ROWS * W, 1, LANES), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((R * W, 1, LANES), jnp.uint32),
         compiler_params=pltpu.CompilerParams(
@@ -713,17 +746,21 @@ def grouped_sums(idx, cv, cd, g, x, n_out, *, name, interpret=False):
 
 def unpack_words(words):
     """Packed words → the float32 values of their low and high halves (a
-    row's column ``j`` and ``j + D/2``)."""
+    row's column ``j`` and ``W·128 + j``)."""
     return (jax.lax.bitcast_convert_type(words << 16, jnp.float32),
             jax.lax.bitcast_convert_type(words & jnp.uint32(0xFFFF0000), jnp.float32))
 
 
 def held_supported(n_tokens: int, top_k: int, d: int, dtype) -> bool:
-    """Shapes :func:`held_sums` handles: :func:`supported`'s, with the
-    ``[T·k]`` pair table WHOLE within SMEM — it cannot be cut into slices of
-    the batch, since where a slice's pairs lie is known only on the device."""
+    """Shapes :func:`held_sums` handles: bf16 rows of whole lanes (halves of
+    whole lanes or not: the high half may be padded), at least one group of
+    tokens, and the ``[T·k]`` pair table WHOLE within SMEM — it cannot be cut
+    into slices of the batch, since where a slice's pairs lie is known only
+    on the device."""
+    if jnp.dtype(dtype) != jnp.bfloat16 or d % LANES or n_tokens < GROUP:
+        return False
     pairs = -(-n_tokens * top_k // PAIRS) * PAIRS
-    return supported(n_tokens, top_k, d, dtype) and 4 * pairs <= _SMEM_TABLE_BYTES
+    return 4 * pairs <= _SMEM_TABLE_BYTES
 
 
 def _held_kernel(starts_ref, live_ref, rows_ref, tok_ref, w_ref, y_ref, o_ref,
@@ -742,8 +779,9 @@ def _held_kernel(starts_ref, live_ref, rows_ref, tok_ref, w_ref, y_ref, o_ref,
     copies started among them: 0.57 — PERF.md §6, PR 34)."""
     C = PAIRS
     dt, d = o_ref.shape
-    half = d // 2
-    W = half // LANES                           # lane tiles of words a row
+    W = word_tiles(d)                           # lane tiles of words a row
+    half = W * LANES
+    Wh = d // LANES - W                         # of them with a high half (W - 1: padded)
     i = pl.program_id(0)
     s0, s1 = starts_ref[i], starts_ref[i + 1]   # the tile's sorted pairs
     n_live = live_ref[0]
@@ -794,23 +832,27 @@ def _held_kernel(starts_ref, live_ref, rows_ref, tok_ref, w_ref, y_ref, o_ref,
             [jnp.where(hit, part, 0.0).astype(jnp.bfloat16) for part in (w1, w2, w3)],
             axis=0)                                             # [3·dt, C]
 
-        def lane_tile(q, carry):
+        def lane_tile(q, carry, high=True):
             lo, hi = unpack_words(tiles[pl.ds(slot * C * W + q, C, stride=W), :])  # [C, 128]
-            for k, part in ((q, lo), (W + q, hi)):
+            for k, part in ((q, lo), (W + q, hi))[:1 + high]:
                 p = jnp.dot(sel, part.astype(jnp.bfloat16),
                             preferred_element_type=jnp.float32)
                 acc[k] += p[:dt] + p[dt:2 * dt] + p[2 * dt:]
             return carry
-        return jax.lax.fori_loop(0, W, lane_tile, carry, unroll=2 * W <= _UNROLLED_BODIES)
+        carry = jax.lax.fori_loop(0, Wh, lane_tile, carry, unroll=2 * W <= _UNROLLED_BODIES)
+        return carry if Wh == W else lane_tile(Wh, carry, high=False)
 
     jax.lax.fori_loop(0, n, visit, 0)
 
-    def leave(q, carry):        # round once
+    def leave(q, carry, high=True):     # round once
         col = pl.multiple_of(q * LANES, LANES)
         o_ref[:, pl.ds(col, LANES)] = acc[q].astype(o_ref.dtype)
-        o_ref[:, pl.ds(half + col, LANES)] = acc[W + q].astype(o_ref.dtype)
+        if high:
+            o_ref[:, pl.ds(half + col, LANES)] = acc[W + q].astype(o_ref.dtype)
         return carry
-    jax.lax.fori_loop(0, W, leave, 0)
+    jax.lax.fori_loop(0, Wh, leave, 0)
+    if Wh < W:                  # the last tile's high half is padding: not summed, not written
+        leave(Wh, 0, high=False)
 
 
 def held_sums(rows, tokens, weights, n_live, y_packed, n_tokens, d, *, name,
@@ -825,7 +867,7 @@ def held_sums(rows, tokens, weights, n_live, y_packed, n_tokens, d, *, name,
     Float32 weights, float32 sums of the bf16 rows, one rounding."""
     P = rows.shape[0]
     C = PAIRS
-    W = d // 2 // LANES
+    W = word_tiles(d)
     dt = min(_MAX_TOKENS, n_tokens // GROUP * GROUP)
     n_tiles = pl.cdiv(n_tokens, dt)
     live = jnp.arange(P, dtype=jnp.int32) < n_live[0]

@@ -771,3 +771,13 @@ def test_nemotron_h_harvest_segments_compile(chip, one_device, cls):
         assert not [line for line in text.splitlines()
                     if stacked in line.split(" = ")[-1][:60]
                     and (" copy(" in line or "dynamic-slice(" in line)]
+        # at d 2688 (21 lane tiles: the packed rows' last high half is padding)
+        # the held rows move by DMA: x packed, the combine over the held pairs,
+        # and none of XLA's row buffers over the static bound is left
+        for name in ("rows_pack", "expert_combine"):
+            assert any(name in c for c in calls), name
+        D, T = cfg.d_model, B * S
+        M = (T * cfg.experts_per_tok + cfg.n_held * 127) // 128 * 128
+        for gathered in (f"bf16[{M},{D}]", f"bf16[{T * cfg.experts_per_tok},{D}]",
+                         f"f32[{T},{cfg.experts_per_tok},{D}]"):
+            assert gathered not in text, gathered

@@ -303,25 +303,36 @@ def test_a_held_share_matches_the_plain_loop_over_the_held_experts(form, skew, t
     np.testing.assert_allclose(np.asarray(total), np.asarray(whole), rtol=0, atol=5 * ATOL)
 
 
-@pytest.mark.parametrize("skew", [False, True], ids=["even", "skewed"])
-def test_both_forms_agree_under_a_share_with_the_combine_kernel(skew, interpret):
+@pytest.mark.parametrize("skew,d_model,gated", [
+    pytest.param(False, DC, True, id="even"), pytest.param(True, DC, True, id="skewed"),
+    pytest.param(False, 384, True, id="even-d384-odd-lane-tiles"),
+    pytest.param(True, 384, True, id="skewed-d384-odd-lane-tiles"),
+    pytest.param(False, 384, False, id="even-d384-odd-lane-tiles-ungated"),
+    pytest.param(True, 384, False, id="skewed-d384-odd-lane-tiles-ungated")])
+def test_both_forms_agree_under_a_share_with_the_combine_kernel(skew, d_model, gated, interpret):
     """bf16 rows at a shape the row kernels take, a share of 2 of 8 experts:
     the tile form (x's rows fetched for the real tiles, the combine over the
     held pairs alone) against ``_experts_ragged`` on the same held routing;
-    the tolerance is ``test_bf16_tile_form_with_the_combine_kernel_matches_ragged``'s."""
+    the tolerance is ``test_bf16_tile_form_with_the_combine_kernel_matches_ragged``'s.
+    At D 384 (three lane tiles: a packed row's last high half is padding) gated
+    and ungated, as Nemotron-H's 2688 is 21."""
     tokens = 300
     ks = jax.random.split(jax.random.key(23), 3)
     w_router = _layer(23, skew)[0]
-    x = jax.random.normal(ks[2], (tokens, DC), jnp.bfloat16)
+    x = jax.random.normal(ks[2], (tokens, d_model), jnp.bfloat16)
     idx, gates = moe.route(_x(23, tokens, skew)[0], w_router, K, True, 2.5)
+    up, width = ((L, 2, d_model, 2 * F), F) if gated else ((L, 2, FU, d_model), FU)
     for first in (0, 4):
-        w_gate_up = (jax.random.normal(ks[0], (L, 2, DC, 2 * F)) * DC ** -0.5).astype(jnp.bfloat16)
-        w_down = (jax.random.normal(ks[1], (L, 2, F, DC)) * F ** -0.5).astype(jnp.bfloat16)
+        w_gate_up = (jax.random.normal(ks[0], up) * d_model ** -0.5).astype(jnp.bfloat16)
+        w_down = (jax.random.normal(ks[1], (L, 2, width, d_model)) * width ** -0.5
+                  ).astype(jnp.bfloat16)
         local, held_gates = moe._held(idx, gates, first, 2)
         assert int(local.max()) == 2 and float(held_gates[local == 2].sum()) == 0.0
-        assert row_gather.held_supported(tokens, K, DC, x.dtype)
-        got = moe._experts_tiles(x, local, held_gates, w_gate_up, w_down, jnp.int32(1), True)
-        want = moe._experts_ragged(x, local, held_gates, w_gate_up, w_down, jnp.int32(1), True)
+        assert row_gather.held_supported(tokens, K, d_model, x.dtype)
+        got = moe._experts_tiles(x, local, held_gates, w_gate_up, w_down, jnp.int32(1), True,
+                                 gated)
+        want = moe._experts_ragged(x, local, held_gates, w_gate_up, w_down, jnp.int32(1), True,
+                                   gated)
         got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
         assert np.isfinite(got).all()
         assert np.abs(got - want).max() <= 2.5 * 6.3e-2
@@ -361,26 +372,29 @@ def _plain_gate_up(x, tile_expert, n_valid, token, w_gate_up, layer):
          pl.BlockSpec((None, None, d, f), moe._w_block(1))], f)
 
 
-def _rows_in_case(tokens, skew, first, n_held=2, seed=31):
+def _rows_in_case(tokens, skew, first, n_held=2, seed=31, d_model=DC):
     ks = jax.random.split(jax.random.key(seed), 2)
-    x = jax.random.normal(ks[0], (tokens, DC), jnp.bfloat16)
-    w_gate_up = (jax.random.normal(ks[1], (L, n_held, DC, 2 * F)) * DC ** -0.5).astype(jnp.bfloat16)
+    x = jax.random.normal(ks[0], (tokens, d_model), jnp.bfloat16)
+    w_gate_up = (jax.random.normal(ks[1], (L, n_held, d_model, 2 * F)) * d_model ** -0.5
+                 ).astype(jnp.bfloat16)
     idx, gates = moe.route(_x(seed, tokens, skew)[0], _layer(seed, skew)[0], K, True)
     return x, w_gate_up, moe._held(idx, gates, first, n_held)
 
 
-@pytest.mark.parametrize("tokens,skew,first", [
-    (300, False, 0), (64, False, 2), (300, True, 0), (300, True, 4), (64, True, 4)],
+@pytest.mark.parametrize("tokens,skew,first,d_model", [
+    (300, False, 0, DC), (64, False, 2, DC), (300, True, 0, DC), (300, True, 4, DC),
+    (64, True, 4, DC), (300, True, 4, 384)],
     ids=["300tok-even", "64tok-even", "300tok-no-row-held", "300tok-nearly-every-row",
-         "64tok-nearly-every-row"])
-def test_the_held_tiles_fetch_their_own_rows(tokens, skew, first, interpret):
+         "64tok-nearly-every-row", "300tok-nearly-every-row-d384-odd-lane-tiles"])
+def test_the_held_tiles_fetch_their_own_rows(tokens, skew, first, d_model, interpret):
     """``moe_gate_up`` fetching x's rows by DMA for the real tiles alone
     against the plain kernel on XLA's gather of every tile's rows: the real
     tiles' results are the same bits (the same bf16 rows meet the same
     products); under the skewed router rank 0 holds no routed row (one tile
     counts as real all the same, so that tiled row 0 is written) and rank 2
-    nearly every one (more real tiles than one: the double buffer turns)."""
-    x, w_gate_up, (local, held_gates) = _rows_in_case(tokens, skew, first)
+    nearly every one (more real tiles than one: the double buffer turns). At
+    D 384 a packed row's last high half is padding, never unpacked."""
+    x, w_gate_up, (local, held_gates) = _rows_in_case(tokens, skew, first, d_model=d_model)
     layer = jnp.ones((1,), jnp.int32)
     tile_expert, n_valid, tile_first, token, _ = moe._held_layout(local, held_gates, 2)
     held = int((np.asarray(local) < 2).sum())
@@ -509,10 +523,12 @@ def test_every_expert_held_is_the_whole_layer_op_for_op(tmp_path):
         plane.close()
 
 
-def test_which_way_the_held_rows_moved_is_counted_once_per_trace(tmp_path, interpret):
-    """Under a share, at a shape the row kernels take, the rows go in and out
-    by DMA (three counters, once a trace); float32 rows keep XLA's gathers
-    over the static bound, and say so."""
+@pytest.mark.parametrize("d_model", [DC, 384], ids=["d256", "d384-odd-lane-tiles"])
+def test_which_way_the_held_rows_moved_is_counted_once_per_trace(d_model, tmp_path, interpret):
+    """Under a share, at a shape the row kernels take — D a multiple of 256
+    or an odd number of lane tiles — the rows go in and out by DMA (three
+    counters, once a trace); float32 rows keep XLA's gathers over the static
+    bound, and say so."""
     cfg = CrossCoderConfig(obs="on", obs_dir=str(tmp_path / "obs"), log_backend="null")
     plane = obs.acquire(cfg)
     names = ("held", "held_combine", "rows_in_kernel", "rows_in_xla",
@@ -520,12 +536,12 @@ def test_which_way_the_held_rows_moved_is_counted_once_per_trace(tmp_path, inter
     counts = lambda: [plane.registry.get_count(f"harvest/moe_{c}_traces")   # noqa: E731
                       for c in names]
     try:
-        x, _ = _whole_layer(jnp.bfloat16, DC)
+        x, _ = _whole_layer(jnp.bfloat16, d_model)
         w_router, _, _ = _layer(5, False)
         ks = jax.random.split(jax.random.key(6), 2)
-        w_gate_up = jax.random.normal(ks[0], (L, 2, DC, 2 * F), jnp.bfloat16)
-        w_down = jax.random.normal(ks[1], (L, 2, F, DC), jnp.bfloat16)
-        router = jnp.zeros((DC, E), jnp.bfloat16).at[:D].set(w_router.astype(jnp.bfloat16))
+        w_gate_up = jax.random.normal(ks[0], (L, 2, d_model, 2 * F), jnp.bfloat16)
+        w_down = jax.random.normal(ks[1], (L, 2, F, d_model), jnp.bfloat16)
+        router = jnp.zeros((d_model, E), jnp.bfloat16).at[:D].set(w_router.astype(jnp.bfloat16))
         f = jax.jit(lambda x: moe.moe_mlp(x, router, w_gate_up, w_down, 0, top_k=K,
                                           norm_topk_prob=True, first_expert=2))
         for _ in range(3):

@@ -145,14 +145,18 @@ def _held_case(T, k, D, R, share, seed=0):
 
 @pytest.mark.parametrize("T,k,D,share", [
     (300, 3, 256, 0.125), (64, 3, 256, 0.5), (300, 10, 768, 0.125), (300, 3, 256, 0.0),
-    (300, 3, 256, 0.97), (72, 4, 512, 1.0), (256, 8, 256, 1 / 64)],
+    (300, 3, 256, 0.97), (72, 4, 512, 1.0), (256, 8, 256, 1 / 64),
+    (300, 6, 384, 0.25), (300, 6, 2688, 0.25)],
     ids=["300tok-an-eighth", "64tok-half", "k10-three-lane-tiles", "no-row-held",
-         "nearly-every-row", "every-row-a-tile-count-not-whole", "tiles-without-a-pair"])
+         "nearly-every-row", "every-row-a-tile-count-not-whole", "tiles-without-a-pair",
+         "d384-odd-lane-tiles", "d2688-the-nemotron-width"])
 def test_held_sums_match_the_gathered_sum_over_the_held_slots(T, k, D, share):
     assert rg.held_supported(T, k, D, jnp.bfloat16)
     y, pairs, held, want = _held_case(T, k, D, R=64, share=share)
     n = int(pairs[3][0])
     assert n == int(held.sum()) and (share not in (0.0, 1.0) or n == int(share) * T * k)
+    if D % 256:     # dead pairs behind the live ones, and tokens no live pair names
+        assert 0 < n < T * k and not np.asarray(held).any(axis=1).all()
     got = jax.jit(lambda *a: rg.held_sums(*a, T, D, name="t", interpret=True))(
         *pairs, rg.packed(y))
     assert got.shape == (T, D) and got.dtype == jnp.bfloat16
@@ -199,7 +203,9 @@ def test_held_sums_fail_on_a_swapped_table_entry():
     ((8192, 10, 3072, F32), False),
     ((16384, 10, 3072, jnp.bfloat16), False),     # the pair table passes SMEM, and is not cut
     ((8, 10, 3072, jnp.bfloat16), False),
-], ids=["cell", "float32", "smem", "tokens"])
+    ((8192, 6, 2688, jnp.bfloat16), True),        # the nemotron cell: 21 lane tiles, 192 KiB
+    ((8192, 6, 2600, jnp.bfloat16), False),       # a row is not whole lanes
+], ids=["cell", "float32", "smem", "tokens", "nemotron-cell", "not-whole-lanes"])
 def test_held_supported(args, want):
     assert rg.held_supported(*args) is want
 
@@ -446,3 +452,23 @@ def test_the_pack_kernel_writes_the_words_xla_writes(pair):
     # rows that do not tile (R % 256) take XLA's form of the same words
     np.testing.assert_array_equal(np.asarray(rg.packed(*(x[:72] for x in args))),
                                   np.asarray(got)[:72 * want.shape[1] // 128])
+
+
+@pytest.mark.parametrize("D", [256, 384, 2688], ids=["d256-whole-halves", "d384", "d2688"])
+@pytest.mark.parametrize("R", [512, 72], ids=["kernel", "xla"])
+def test_packed_rows_round_trip(D, R):
+    """The gatherable form of bf16 rows unpacks to the same rows, bit for
+    bit, whether ``D/128`` is even or odd (``⌈D/256⌉`` lane tiles of words,
+    the last high half zeros); the pack kernel (rows in whole tiles) and
+    XLA (rows that are not) write the same words."""
+    y = jax.random.normal(jax.random.key(8), (R, D), jnp.bfloat16)
+    W = rg.word_tiles(D)
+    assert W == -(-D // 256) and (D % 256 or 2 * W * 128 == D)
+    words = rg.packed(y, interpret=True)
+    assert words.dtype == jnp.uint32 and words.shape == (R * W, 1, 128)
+    words = words.reshape(R, W * 128)
+    np.testing.assert_array_equal(np.asarray(words), np.asarray(rg.pack_rows(y)))
+    lo, hi = rg.unpack_words(words)
+    back = jnp.concatenate([lo, hi], axis=1).astype(jnp.bfloat16)
+    np.testing.assert_array_equal(np.asarray(back[:, :D], np.float32), np.asarray(y, np.float32))
+    assert not np.asarray(back[:, D:], np.float32).any()        # the padding is zeros
