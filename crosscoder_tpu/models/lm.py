@@ -1231,8 +1231,9 @@ def _ssm(x: jax.Array, lp: Mapping[str, jax.Array], cfg: LMConfig,
     """The state-space mixer on the normed stream [B, S, D], as added to the
     stream (its multipliers are in its weights: :func:`fold_multiplier`):
     one input projection to z | x | B | C | dt, the causal conv over x | B |
-    C, the chunked scan, the gate and the grouped RMSNorm, the output
-    projection (:mod:`crosscoder_tpu.ops.ssm`). ``resets`` ([B, S] bool, the
+    C, the chunked scan (the two as one kernel where the scan's kernel runs),
+    the gate and the grouped RMSNorm, the output projection
+    (:mod:`crosscoder_tpu.ops.ssm`). ``resets`` ([B, S] bool, the
     paged runtime's document starts) zeroes the state carried into a start;
     None on a padded forward, whose rows are one document each."""
     from crosscoder_tpu import obs
@@ -1243,16 +1244,27 @@ def _ssm(x: jax.Array, lp: Mapping[str, jax.Array], cfg: LMConfig,
     H, P, G, N = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state
     di, gn = cfg.d_ssm, G * N
     zxbcdt = jnp.einsum("bsd,dw->bsw", x, lp["ssm_in"], preferred_element_type=jnp.float32)
-    z = zxbcdt[..., :di].astype(x.dtype)
-    with jax.named_scope("harvest/block/ssm/conv"):
-        xbc = ssm.causal_conv(zxbcdt[..., di: 2 * di + 2 * gn].astype(x.dtype),
-                              lp["ssm_conv_w"], lp["ssm_conv_b"], resets)
+    proj = zxbcdt.astype(x.dtype)
+    z = proj[..., :di]
+    # the scan's kernel computes the conv itself, reading x | B | C where the
+    # projection leaves them; elsewhere (the CPU, a mesh, document starts)
+    # XLA runs the conv and ``ssd_scan`` chooses the scan's form
+    in_kernel = ssm.kernel_takes(H, P, G, N, cfg.ssm_chunk, x.dtype, resets, cfg.ssm_conv)
+    if not in_kernel:
+        with jax.named_scope("harvest/block/ssm/conv"):
+            xbc = ssm.causal_conv(proj[..., di: 2 * di + 2 * gn], lp["ssm_conv_w"],
+                                  lp["ssm_conv_b"], resets)
     with jax.named_scope("harvest/block/ssm/scan"):
         dt = jax.nn.softplus(zxbcdt[..., 2 * di + 2 * gn:] + lp["ssm_dt_bias"])
-        y = ssm.ssd_scan(
-            xbc[..., :di].reshape(Bt, S, H, P), dt, -jnp.exp(lp["ssm_A_log"]),
-            xbc[..., di: di + gn].reshape(Bt, S, G, N), xbc[..., di + gn:].reshape(Bt, S, G, N),
-            lp["ssm_D"], cfg.ssm_chunk, resets)
+        A = -jnp.exp(lp["ssm_A_log"])
+        if in_kernel:
+            y = ssm.conv_ssd_scan(proj, lp["ssm_conv_w"], lp["ssm_conv_b"], dt, A,
+                                  lp["ssm_D"], G, N, cfg.ssm_chunk)
+        else:
+            y = ssm.ssd_scan(
+                xbc[..., :di].reshape(Bt, S, H, P), dt, A,
+                xbc[..., di: di + gn].reshape(Bt, S, G, N),
+                xbc[..., di + gn:].reshape(Bt, S, G, N), lp["ssm_D"], cfg.ssm_chunk, resets)
     with jax.named_scope("harvest/block/ssm/gate_norm"):
         y = ssm.gated_rms_norm(y.reshape(Bt, S, di), z, lp["ssm_norm"], G,
                                cfg.rms_eps).astype(x.dtype)

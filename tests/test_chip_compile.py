@@ -781,3 +781,38 @@ def test_nemotron_h_harvest_segments_compile(chip, one_device, cls):
         for gathered in (f"bf16[{M},{D}]", f"bf16[{T * cfg.experts_per_tok},{D}]",
                          f"f32[{T},{cfg.experts_per_tok},{D}]"):
             assert gathered not in text, gathered
+
+
+def test_nemotron_h_mixer_conv_runs_inside_the_scan_kernel(chip, one_device):
+    """The seventh cell's mixer quantum: ``pallas:ssd_scan`` computes the
+    causal conv itself and reads x | B | C where the input projection leaves
+    them — its first three operands are that bf16 output, tokens minor, by a
+    bitcast — so neither the conv's float32 copy of x | B | C nor its output
+    transposed for the kernel is an op of the program."""
+    import sys
+    from pathlib import Path
+
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+    from benchmarks import manifest
+    from benchmarks.arch import nemotron_h
+
+    cfg = nemotron_h.lm_config(manifest.load_json(
+        manifest.BENCH_DIR / "configs" / "nemotron-twotower-30b-a3b-pair-relu16k.json"))
+    B, S = 2, 4096
+    params = _abstract(jax.eval_shape(
+        lambda key: lm.init_params(key, cfg), jax.random.key(0)), chip)
+    text = lm._seg_scan_impl.lower(
+        params, _sds((B, S, cfg.d_model), jnp.bfloat16, chip),
+        _sds((1, B, S, cfg.d_model), jnp.bfloat16, chip),
+        _sds((), jnp.int32, chip), cfg=cfg,
+        capture=lm._hook_layers(cfg, ("blocks.9.hook_resid_pre",)), k=1, cls=0,
+    ).compile().as_text()
+    cd = cfg.d_ssm + 2 * cfg.ssm_groups * cfg.ssm_state
+    for shape in (f"f32[{B},{S},{cd}]", f"bf16[{B},{cd},{S}]"):
+        assert shape not in text, shape
+    ops = {line.split(" = ")[0].strip(): line for line in text.splitlines() if " = " in line}
+    (call,) = [line for line in ops.values() if "ssd_scan" in line and "custom-call(" in line]
+    operands = call.split("custom-call(")[1].split(")")[0].split(", ")[:3]
+    width = sum(cfg.ssm_proj_widths)
+    for name in operands:
+        assert f"= bf16[{B},{width},{S}]" in ops[name] and " bitcast(" in ops[name], ops[name]

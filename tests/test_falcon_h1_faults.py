@@ -123,6 +123,10 @@ def plant(fault: str, cfg, params: dict):
                    (ssm, "causal_conv", _resets_ignored(ssm.causal_conv, 3))]
     elif fault != "none":
         raise KeyError(fault)
+    if any(name in ("causal_conv", "ssd_scan") for _, name, _ in patches):
+        # where the scan's kernel runs it computes the conv itself: a fault
+        # planted in XLA's conv or scan holds where XLA's form runs
+        patches.append((ssm, "enabled", lambda: False))
     return cfg, params, patches
 
 

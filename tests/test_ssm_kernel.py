@@ -3,7 +3,9 @@
 CPU: against XLA's form of ``ssd_scan`` and against the float32 token-by-token
 recurrence (benchmarks/reference/falcon_h1_ref.py), the choice between the two
 forms and its counters, the mixer around it, and a planted fault (a cumulative
-sum rounded to bf16) that the comparison must catch."""
+sum rounded to bf16) that the comparison must catch; the kernel with the
+causal conv inside it against XLA's ``causal_conv`` then scan, and two planted
+faults of the conv (a previous chunk not carried, taps in the wrong order)."""
 
 import sys
 import types
@@ -167,6 +169,8 @@ def test_off_the_chip_and_at_unsupported_shapes_xlas_form_runs(plane):
     (3, 128, 1, 256, 128, jnp.bfloat16),       # x's width not a whole number of B's bands
     (4, 128, 2, 128, 128, jnp.float16),        # a dtype the kernel does not take
     (256, 128, 2, 256, 128, jnp.bfloat16),     # a group's band past the VMEM limit
+    (4, 128, 2, 128, 128, jnp.bfloat16, 129),  # a conv that reaches back past a chunk
+    (80, 128, 2, 256, 128, jnp.bfloat16, 4),   # the scan fits, not with the conv's VMEM
 ])
 def test_supported_refuses(shape):
     assert not ssm.supported(*shape)
@@ -177,6 +181,17 @@ def test_supported_accepts_the_cell():
     chunks of 128) within the stated limit, with room."""
     assert ssm.supported(32, 128, 2, 256, 128, jnp.bfloat16)
     assert ssm._vmem_bytes(32, 128, 2, 256, 128, 2) < ssm._VMEM_LIMIT_BYTES / 2
+
+
+def test_supported_accepts_both_mixer_cells_with_the_conv():
+    """Falcon-H1-34B's and Nemotron-H's mixers with their four-tap conv in
+    the kernel, within the stated limit, with room."""
+    assert ssm.supported(32, 128, 2, 256, 128, jnp.bfloat16, 4)
+    assert ssm.supported(64, 64, 8, 128, 128, jnp.bfloat16, 4)
+    assert ssm._vmem_bytes(32, 128, 2, 256, 128, 2, 4) < 0.6 * ssm._VMEM_LIMIT_BYTES
+    assert ssm._vmem_bytes(64, 64, 8, 128, 128, 2, 4) < ssm._VMEM_LIMIT_BYTES / 4
+    # where only the scan fits, the mixer runs XLA's conv and then the scan's kernel
+    assert ssm.supported(80, 128, 2, 256, 128, jnp.bfloat16)
 
 
 def test_supported_accepts_nemotron_hs_heads_of_64():
@@ -205,21 +220,137 @@ def test_the_kernel_at_head_dim_64_and_eight_heads_a_group(interpret, dtype):
         assert _rel(got, _recurrence(x, dt, A, B, C, D)) < RTOL
 
 
-def test_the_mixer_with_the_kernel_is_the_mixer_with_xlas_form(interpret):
-    """``lm._ssm`` on a normed input at lane-wide sizes, float32: the kernel
-    reads x, B and C as bands of the conv's output and hands y to the gated
-    norm, so the mixer's result is XLA's form's to float32 rounding."""
+def _mixer_layer(arch: str, config: str, **sizes):
+    """One mixer layer's leaves of a float32 model of ``arch`` at small sizes,
+    its mixer at ``sizes``, and a normed input ``[2, 200, d]``."""
+    import importlib
+
     from benchmarks import manifest
-    from benchmarks.arch import falcon_h1 as fh
     from crosscoder_tpu.models import lm
 
-    config = manifest.load_json(manifest.BENCH_DIR / "configs" / "falcon-h1-34b-pair-relu16k.json")
-    cfg = fh.lm_config(config, dict(vocab_size=257, d_model=64, n_layers=1, n_heads=4,
-                                    n_kv_heads=2, head_dim=16, d_ff=128, dtype="fp32"))
-    cfg = cfg.replace(ssm_heads=4, ssm_head_dim=P, ssm_state=N, ssm_groups=2, ssm_chunk=Q)
+    config = manifest.load_json(manifest.BENCH_DIR / "configs" / config)
+    cfg = importlib.import_module(f"benchmarks.arch.{arch}").lm_config(
+        config, dict(vocab_size=257, d_model=64, n_layers=1, n_heads=4, n_kv_heads=2,
+                     head_dim=16, d_ff=128, dtype="fp32"))
+    cfg = cfg.replace(**sizes)
     lp = {k: v[0] for k, v in lm.init_params(jax.random.key(0), cfg)["layers"].items()}
-    u = jax.random.normal(jax.random.key(1), (2, 200, cfg.d_model))
+    return cfg, lp, jax.random.normal(jax.random.key(1), (2, 200, cfg.d_model))
+
+
+def _the_mixer_in_both_forms(cfg, lp, u) -> float:
+    from crosscoder_tpu.models import lm
+
     got = lm._ssm(u, lp, cfg)
     ssm.set_interpret(False)
     want = lm._ssm(u, lp, cfg)
-    assert _rel(got, want) < RTOL
+    return _rel(got, want)
+
+
+def test_the_mixer_with_the_kernel_is_the_mixer_with_xlas_form(interpret):
+    """``lm._ssm`` on a normed input at lane-wide sizes, float32: the kernel
+    reads x, B and C as bands of the projection, computes the conv and hands
+    y to the gated norm, so the mixer's result is XLA's form's to float32
+    rounding."""
+    cfg, lp, u = _mixer_layer("falcon_h1", "falcon-h1-34b-pair-relu16k.json", ssm_heads=4,
+                              ssm_head_dim=P, ssm_state=N, ssm_groups=2, ssm_chunk=Q)
+    assert _the_mixer_in_both_forms(cfg, lp, u) < RTOL
+
+
+def test_the_nemotron_mixer_with_the_kernel_is_the_mixer_with_xlas_form(interpret):
+    """The same at Nemotron-H's head dim 64, eight heads a group."""
+    cfg, lp, u = _mixer_layer("nemotron_h", "nemotron-twotower-30b-a3b-pair-relu16k.json",
+                              ssm_heads=16, ssm_head_dim=64, ssm_state=N, ssm_groups=2,
+                              ssm_chunk=Q)
+    assert _the_mixer_in_both_forms(cfg, lp, u) < RTOL
+
+
+# ---------------------------------------------------------------------------
+# the causal conv inside the kernel
+
+CONV_CASES = {
+    # S, batch, H, head dim, G: Nemotron-H's 64 with eight heads a group,
+    # Falcon-H1's 128; several chunks (the previous chunk's tokens reach the
+    # next one's first taps) and lengths off the chunk (padded and cut)
+    "nemotron-five-chunks": (640, 2, 16, 64, 2),
+    "nemotron-off-the-chunk": (300, 1, 16, 64, 2),
+    "falconh1-three-chunks": (384, 1, 4, 128, 2),
+    "falconh1-off-the-chunk": (200, 2, 4, 128, 2),
+}
+
+
+def _projection(S, bt, H, p, G, dtype, seed=0):
+    """The mixer's projection as it lies, z | x | B | C | dt ``[bt, S, 2·di +
+    2·G·N + H]`` in ``dtype`` (x | B | C before the conv), the conv's four
+    taps and bias, dt after a softplus, A and D."""
+    r = np.random.default_rng(seed)
+    f = lambda *s: jnp.asarray(r.normal(size=s), jnp.float32)    # noqa: E731
+    di, cd = H * p, H * p + 2 * G * N
+    v = f(bt, S, di + cd + H).astype(dtype)
+    dt = 0.1 / np.log(2.0) * jax.nn.softplus(f(bt, S, H))
+    A = -jnp.arange(1, H + 1, dtype=jnp.float32) / 4
+    return v, 0.5 * f(4, cd), 0.1 * f(cd), dt, A, f(H)
+
+
+def _xlas_conv_then_scan(v, w, b, dt, A, D, G):
+    bt, S = v.shape[:2]
+    H, di = A.shape[0], w.shape[1] - 2 * G * N
+    xbc = ssm.causal_conv(v[..., di: di + w.shape[1]], w, b)
+    return ssm._ssd_scan_xla(xbc[..., :di].reshape(bt, S, H, di // H), dt, A,
+                             xbc[..., di: di + G * N].reshape(bt, S, G, N),
+                             xbc[..., di + G * N:].reshape(bt, S, G, N), D, Q)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", CONV_CASES)
+def test_the_kernel_with_the_conv_against_xlas_conv_then_scan(interpret, case, dtype):
+    """The conv's taps, SiLU and rounding in the kernel are ``causal_conv``'s
+    (bf16: the same bf16 conv output, so the two forms agree as far as the
+    scan alone does), over the whole length and over the first chunk, whose
+    first taps read the causal zeros."""
+    S, bt, H, p, G = CONV_CASES[case]
+    v, w, b, dt, A, D = _projection(S, bt, H, p, G, dtype, seed=S + p)
+    assert ssm.supported(H, p, G, N, Q, dtype, w.shape[0])
+    got = ssm.conv_ssd_scan(v, w, b, dt, A, D, G, N, Q)
+    assert got.shape == (bt, S, H, p) and got.dtype == jnp.float32
+    want = _xlas_conv_then_scan(v, w, b, dt, A, D, G)
+    limit = RTOL if dtype == jnp.float32 else 1e-4
+    assert _rel(got, want) < limit
+    assert _rel(got[:, :Q], want[:, :Q]) < limit
+
+
+@pytest.mark.parametrize("fault", ["previous_chunk_dropped", "taps_reversed"])
+def test_a_conv_fault_fails_the_comparison(interpret, monkeypatch, fault):
+    """The previous chunk's tokens not carried (every chunk's first taps read
+    zeros), or the taps applied in the reverse order, leave the kernel off
+    XLA's form by far more than the comparison's limit."""
+    v, w, b, dt, A, D = _projection(384, 1, 16, 64, 2, jnp.float32, seed=5)
+    want = _xlas_conv_then_scan(v, w, b, dt, A, D, 2)
+    assert _rel(ssm.conv_ssd_scan(v, w, b, dt, A, D, 2, N, Q), want) < RTOL
+    real = ssm._conv
+    if fault == "previous_chunk_dropped":
+        planted = lambda raw, prev, wb: real(raw, jnp.zeros_like(prev), wb)        # noqa: E731
+    else:
+        planted = lambda raw, prev, wb: real(                                     # noqa: E731
+            raw, prev, [*wb[-2::-1], wb[-1]])
+    monkeypatch.setattr(ssm, "_conv", planted)
+    assert _rel(ssm.conv_ssd_scan(v, w, b, dt, A, D, 2, N, Q), want) > 10 * RTOL
+
+
+def test_the_conv_in_the_kernel_is_counted_where_it_runs(interpret, plane):
+    """``harvest/ssm_conv_kernel_traces`` once a trace of the mixer on the
+    kernel's path, with its scan's count; none with document starts or on
+    the CPU, where XLA's conv and scan run."""
+    from crosscoder_tpu.models import lm
+
+    cfg, lp, u = _mixer_layer("falcon_h1", "falcon-h1-34b-pair-relu16k.json", ssm_heads=4,
+                              ssm_head_dim=P, ssm_state=N, ssm_groups=2, ssm_chunk=Q)
+    reg = plane.registry
+    counts = lambda: tuple(reg.get_count(f"harvest/ssm_{c}_traces")       # noqa: E731
+                           for c in ("conv_kernel", "scan_kernel", "scan_xla"))
+    prims = lambda *a: {e.primitive.name for e in jax.make_jaxpr(         # noqa: E731
+        lambda u: lm._ssm(u, lp, cfg, *a))(u).eqns}
+    assert "pallas_call" in prims() and counts() == (1, 1, 0)
+    resets = jnp.zeros(u.shape[:2], bool).at[:, 0].set(True)
+    assert "pallas_call" not in prims(resets) and counts() == (1, 1, 1)
+    ssm.set_interpret(False)
+    assert "pallas_call" not in prims() and counts() == (1, 1, 2)
