@@ -12,8 +12,8 @@ to stderr for the whole run:
 - **step**: the bare train step on device-resident batches (round-1's
   headline; BASELINE.json config 1 — dict 2^15, batch 4096, bf16).
 - **matrix**: the sparse tier at the training-step level — activation
-  {relu, topk dense, topk pallas, topk+sparse_decode, topk+sparse_bwd,
-  batchtopk (dense + pallas)} × dict {2^15, 2^16, 2^17} (BASELINE.json
+  {relu, topk dense, topk pallas, topk+sparse_bwd, batchtopk (dense +
+  pallas)} × dict {2^15, 2^16, 2^17} (BASELINE.json
   config 2 is TopK k=32 @ 2^15). Kernel-heavy legs also report a
   fwd/bwd split (``fwd_ms``/``bwd_ms`` of the model loss alone) — the
   sparse backward plane (cfg.sparse_bwd) only changes bwd_ms, so the
@@ -219,7 +219,7 @@ def bench_step(cfg, n_steps: int, warmup: int = 3) -> dict:
 @contextlib.contextmanager
 def _env(overrides: dict):
     """Set env vars for one bench leg (the kernel opt-in gates —
-    CROSSCODER_SPARSE_GRAD_PALLAS etc. are read at trace time), restoring
+    CROSSCODER_BATCHTOPK_PALLAS etc. are read at trace time), restoring
     the previous values on exit so legs can't leak into each other."""
     saved = {k: os.environ.get(k) for k in overrides}
     os.environ.update(overrides)
@@ -288,8 +288,9 @@ def _kernel_parity(dict_size: int) -> dict:
     the timed variants:
 
     - pallas TopK output == dense ``lax.top_k`` scatter, bit-exact;
-    - sparse-decode loss == dense-decode loss (same math re-associated, so
-      tolerance is a few fp32 ulps, max-abs-diff recorded).
+    - the TopK step's loss in the form the backend picks (``"auto"``) ==
+      the dense decode's (``factored_decode="off"``): the same math
+      re-associated, so the tolerance is a few fp32 ulps.
     """
     import numpy as np
 
@@ -307,9 +308,9 @@ def _kernel_parity(dict_size: int) -> dict:
     out_d = jax.jit(lambda x: act_ops._topk_dense(x, k))(h)
     topk_ok = bool(jax.device_get(jax.jit(lambda a, b: (a == b).all())(out_p, out_d)))
 
-    cfg_d = _make_cfg(dict_size=dict_size, activation="topk", topk_k=k,
+    cfg_s = _make_cfg(dict_size=dict_size, activation="topk", topk_k=k,
                       l1_coeff=0.0, batch_size=256)
-    cfg_s = cfg_d.replace(sparse_decode=True)
+    cfg_d = cfg_s.replace(factored_decode="off")
     params = cc.init_params(jax.random.key(3), cfg_d)
     x = jax.random.normal(jax.random.key(8), (256, cfg_d.n_sources, cfg_d.d_in),
                           jnp.bfloat16)
@@ -321,47 +322,12 @@ def _kernel_parity(dict_size: int) -> dict:
     entry = {
         "dict_size": dict_size,
         "topk_pallas_bitexact": topk_ok,
-        "sparse_decode_l2_rel_diff": float(np.format_float_scientific(
+        "topk_form_l2_rel_diff": float(np.format_float_scientific(
             sparse_rel, precision=3, unique=False)),
         "parity_ok": bool(topk_ok and sparse_rel < 1e-4),
     }
     log(f"[parity] {entry}")
     return entry
-
-
-def _encoder_hbm_bytes(cfg) -> dict:
-    """Predicted step HBM traffic, fused vs dense encoder — the PR 5
-    compile-span HLO cost analysis ("bytes accessed" of the compiled
-    bare model loss+grad) applied to the A/B the fused megakernel
-    claims: same FLOPs, [B, dict] pre-acts never round-tripping HBM.
-    Reported beside wall time so the bytes win is first-class in BENCH
-    output, not an inference from step_ms."""
-    from crosscoder_tpu.models import crosscoder as cc
-
-    def bytes_of(c) -> float:
-        # abstract operands only: .lower() accepts ShapeDtypeStruct
-        # pytrees, and a real 2^17-dict param set would add GBs of HBM
-        # pressure right after the timed leg ran
-        params = jax.eval_shape(lambda key: cc.init_params(key, c),
-                                jax.random.key(0))
-        x = jax.ShapeDtypeStruct(
-            (c.batch_size, c.n_sources, c.d_in), jnp.bfloat16)
-
-        def loss(p, xb):
-            return cc.training_loss(p, xb, 0.0, c, with_metrics=False)[0]
-
-        from crosscoder_tpu.utils import compile_cache
-
-        compiled = jax.jit(jax.grad(loss)).lower(params, x).compile()
-        return compile_cache.extract_cost(compiled)["bytes_accessed"]
-
-    fused_b = bytes_of(cfg)
-    dense_b = bytes_of(cfg.replace(fused_encoder="off",
-                                   quant_encoder=False))
-    out = {"hbm_bytes_fused": fused_b, "hbm_bytes_dense": dense_b}
-    if dense_b > 0:
-        out["hbm_bytes_ratio"] = round(fused_b / dense_b, 4)
-    return out
 
 
 def section_matrix() -> list[dict]:
@@ -383,9 +349,6 @@ def section_matrix() -> list[dict]:
          "dense", {}),
         ("topk_pallas", dict(activation="topk", topk_k=32, l1_coeff=0.0),
          "pallas", {}),
-        ("topk_sparse_decode",
-         dict(activation="topk", topk_k=32, l1_coeff=0.0, sparse_decode=True),
-         "auto", {}),
         # the sparse backward plane: identical forward to topk_pallas +
         # factored tier, backward through ops/row_gather.py where its
         # kernels are live (one TPU device) — step_ms vs topk_pallas is the
@@ -394,25 +357,6 @@ def section_matrix() -> list[dict]:
          dict(activation="topk", topk_k=32, l1_coeff=0.0, sparse_bwd="on",
               factored_decode="on"),
          "pallas", {}),
-        # the fused encoder→TopK megakernel (PR "melt the dense floor"):
-        # identical math to topk_sparse_bwd with the encode+TopK+sparsify
-        # chain fused so [B, dict] pre-acts never hit HBM — step_ms vs
-        # topk_sparse_bwd and vs relu is the headline (ROADMAP item-2
-        # target: TopK <= 1.0x ReLU at dict 2^16/2^17); the
-        # encoder_hbm_* fields carry the HLO cost-analysis bytes A/B
-        ("topk_fused",
-         dict(activation="topk", topk_k=32, l1_coeff=0.0, sparse_bwd="on",
-              factored_decode="on", fused_encoder="on"),
-         "pallas", {"CROSSCODER_SPARSE_GRAD_PALLAS": "1",
-                    "CROSSCODER_FUSED_TOPK_PALLAS": "1"}),
-        # + the int8 block-scaled in-kernel encoder matmul (the
-        # --quant-encoder quality gate rides this leg: selection
-        # agreement vs the exact fused leg)
-        ("topk_fused_int8",
-         dict(activation="topk", topk_k=32, l1_coeff=0.0, sparse_bwd="on",
-              factored_decode="on", fused_encoder="on", quant_encoder=True),
-         "pallas", {"CROSSCODER_SPARSE_GRAD_PALLAS": "1",
-                    "CROSSCODER_FUSED_TOPK_PALLAS": "1"}),
         ("batchtopk", dict(activation="batchtopk", topk_k=32, l1_coeff=0.0),
          "auto", {}),
         # BatchTopK through the chunked Pallas global-threshold kernels
@@ -421,13 +365,6 @@ def section_matrix() -> list[dict]:
         ("batchtopk_pallas",
          dict(activation="batchtopk", topk_k=32, l1_coeff=0.0),
          "auto", {"CROSSCODER_BATCHTOPK_PALLAS": "1"}),
-        # fused BatchTopK: global bisection + emit recomputed over
-        # streamed encoder tiles (FLOPs ~3x the single matmul, HBM bytes
-        # ~1 masked write instead of ~7 [B, dict] round-trips)
-        ("batchtopk_fused",
-         dict(activation="batchtopk", topk_k=32, l1_coeff=0.0,
-              fused_encoder="on"),
-         "auto", {"CROSSCODER_FUSED_TOPK_PALLAS": "1"}),
         ("jumprelu", dict(activation="jumprelu", l1_coeff=0.0), "auto", {}),
         # AuxK step cost: aux_dead_steps=1 keeps the dead set non-empty so
         # aux-on steps include the full aux path (approx_max_k ranking
@@ -463,14 +400,13 @@ def section_matrix() -> list[dict]:
          dict(activation="topk", topk_k=32, l1_coeff=0.0, aux_k=256,
               aux_dead_steps=1, aux_mask_every=0, sparse_bwd="on",
               factored_decode="on"),
-         "pallas", {"CROSSCODER_SPARSE_GRAD_PALLAS": "1"}),
+         "pallas", {}),
     ]
     # legs that also report the fwd/bwd model-loss split (compiles two
     # extra programs per entry, so only where the split answers a
     # question: the sparse-backward A/B pair and the dense floor)
     split_fwd_bwd = {"topk_pallas", "topk_sparse_bwd", "jumprelu",
-                     "batchtopk", "batchtopk_pallas", "topk_fused",
-                     "topk_fused_int8", "batchtopk_fused"}
+                     "batchtopk", "batchtopk_pallas"}
     steps = int(os.environ.get("BENCH_MATRIX_STEPS", 16))
     dicts = tuple(
         int(x) for x in os.environ.get(
@@ -526,19 +462,6 @@ def section_matrix() -> list[dict]:
                                 "skipped": "batchtopk kernel unsupported at "
                                            "this width"})
                     continue
-            if cfg.fused_encoder == "on":
-                # forced-fused legs must actually time the megakernel,
-                # not its dense fallback
-                from crosscoder_tpu.ops import fused_encoder_topk as fek
-
-                qb = cfg.quant_block if cfg.quant_encoder else 0
-                if not fek.supported(cfg.batch_size,
-                                     cfg.n_sources * cfg.d_in, dict_size,
-                                     cfg.topk_k, jnp.bfloat16, qb):
-                    out.append({"variant": label, "dict_size": dict_size,
-                                "skipped": "fused kernel unsupported at "
-                                           "this shape"})
-                    continue
             act_ops.set_topk_impl(impl)
             try:
                 with _env(env):
@@ -546,12 +469,6 @@ def section_matrix() -> list[dict]:
                     entry = {"variant": label, "dict_size": dict_size, **r}
                     if label in split_fwd_bwd:
                         entry.update(bench_fwd_bwd(cfg, steps))
-                    if cfg.fused_encoder == "on":
-                        try:
-                            entry.update(_encoder_hbm_bytes(cfg))
-                        except Exception as e:   # cost analysis is best-effort
-                            entry["hbm_bytes_error"] = (
-                                f"{type(e).__name__}: {str(e)[:120]}")
             except Exception as e:     # one OOM must not kill the bench
                 entry = {"variant": label, "dict_size": dict_size,
                          "error": f"{type(e).__name__}: {str(e)[:200]}"}

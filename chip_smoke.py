@@ -233,7 +233,6 @@ def resolved_tiers(cfg: Any) -> dict:
         "topk": "pallas" if kernel else "xla",
         "factored_decode": cc.use_factored_decode(cfg, cfg.batch_size),
         "sparse_bwd": cc.use_sparse_bwd(cfg, cfg.batch_size),
-        "fused_encoder": cc.use_fused_encoder(cfg, cfg.batch_size),
     }
 
 
@@ -447,7 +446,6 @@ def serve_phase(sizes: Sizes, lm_params: list, trained: dict) -> None:
     every answer against ``lm.run_with_cache_multi`` + ``encode_topk_diff``."""
     import numpy as np
 
-    from crosscoder_tpu.models import crosscoder as cc
     from crosscoder_tpu.ops import paged_attention as pa
     from crosscoder_tpu.serve import InferenceEngine
     from crosscoder_tpu.serve.smoke import oracle, serve_batch
@@ -458,8 +456,7 @@ def serve_phase(sizes: Sizes, lm_params: list, trained: dict) -> None:
                           norm_factors=trained["norm"])
     say(f"serve: dict {cfg.dict_size} topk {cfg.topk_k}, buckets "
         f"{eng.buckets}, page {cfg.page_size}; tiers "
-        f"{{'paged_attention': '{'pallas' if pa.kernel_enabled() else 'xla'}',"
-        f" 'fused_encoder': {cc.use_fused_encoder(cfg, nb)}}}")
+        f"{{'paged_attention': '{'pallas' if pa.kernel_enabled() else 'xla'}'}}")
     t0 = time.perf_counter()
     n_warm = eng.warmup()
     warm_s = time.perf_counter() - t0

@@ -10,14 +10,11 @@ the traced jaxpr:
 
 - **knob-off identity** — a knob that is present-but-off lowers the
   byte-identical program (generalizes the scattered asserts of
-  ``tests/test_quant.py`` / ``test_obs.py`` / ``test_fused_encoder_topk.py``
-  into one parametrized sweep, which those tests now wrap);
+  ``tests/test_quant.py`` / ``test_obs.py`` into one parametrized sweep,
+  which those tests now wrap);
 - **no-s8-when-quant-off** / **no-f64-anywhere** — dtype hygiene;
 - **donation honored** — every donated train-state leaf carries an
   input/output alias (``tf.aliasing_output``) in the lowered signature;
-- **fused-no-dense-preacts** — with the fused encoder live, no
-  ``[B, dict]``-shaped tensor exists anywhere in the program (the PR 6
-  bytes-deleted claim, verified statically per variant);
 - **no-host-transfers** — no infeed/outfeed/send/recv/host-callback
   inside the step;
 - **no large captured constants** — closed-over concrete arrays above a
@@ -27,12 +24,6 @@ the traced jaxpr:
 Rules here are pure functions of :class:`StepContext` data so the
 mutation self-tests (``mutations.py``) can prove each rule fires on a
 seeded violation without recompiling anything.
-
-Probe geometry note: the fused ``[B, dict]`` scan needs every
-distinguished dimension distinct (``B != n·d != dict != k``), otherwise
-legitimate tiles alias the forbidden shape — e.g. the fused kernel's
-``[R, cw]`` VMEM workspace at ``R=32, cw=512`` is indistinguishable from
-a ``[B=32, dict=512]`` pre-act matrix.
 """
 
 from __future__ import annotations
@@ -67,9 +58,6 @@ class VariantMeta:
 
     n_donated_leaves: int = 0
     quant_off: bool = True                  # no int8 may appear
-    forbid_dense_shape: tuple[int, int] | None = None   # (B, dict) if fused
-    serve_step: bool = False                # a serve-plane encode lowering,
-                                            # not a train step (own rules)
 
 
 @dataclass
@@ -171,16 +159,15 @@ def step_jaxpr_consts(cfg) -> list[tuple[int, str]]:
 
 
 @contextlib.contextmanager
-def _interpret_kernels(flag: bool):
-    """Flip every step-path kernel module's interpret latch, restoring on
-    exit — the CPU stand-in that makes 'kernel live' variants lowerable."""
-    from crosscoder_tpu.ops import (fused_encoder_topk, row_gather,
-                                    topk_pallas)
+def _kernels_dead():
+    """Every step-path kernel module's interpreter latch off, restored on
+    exit: an "auto" tier then resolves dead on the CPU."""
+    from crosscoder_tpu.ops import row_gather, topk_pallas
 
-    mods = (fused_encoder_topk, row_gather, topk_pallas)
+    mods = (row_gather, topk_pallas)
     prev = [m._INTERPRET for m in mods]
     for m in mods:
-        m.set_interpret(flag)
+        m.set_interpret(False)
     try:
         yield
     finally:
@@ -231,29 +218,24 @@ KNOB_OFF_LATTICE: tuple[tuple[str, dict[str, Any]], ...] = (
                        checkpoint_dir="/tmp/ckpt")),
 )
 
-# the sparse/fused tiers: "off" vs a dead "auto" (no kernel live) must be
-# byte-identical — the knob's PRESENCE costs nothing
+# the sparse backward plane: "off" vs a dead "auto" (no kernel live) must
+# be byte-identical — the knob's PRESENCE costs nothing
 _SPARSE_SHAPE = dict(d_in=128, dict_size=256, batch_size=32, topk_k=8,
                      l1_coeff=0.0)
-# all distinguished dims distinct (see module docstring): B=192, n·d=256,
-# dict=1024, k=8
-_FUSED_SHAPE = dict(d_in=128, dict_size=1024, batch_size=192, topk_k=8,
-                    l1_coeff=0.0)
 
 
-def build_step_context(full: bool = True) -> StepContext:
-    """Lower the variant lattice. ``full=False`` skips the interpret-mode
-    fused-live variant (the slowest lowering) for quick iterations."""
+def build_step_context() -> StepContext:
+    """Lower the variant lattice."""
     ctx = StepContext()
 
-    def add(label, cfg, **meta_kw):
+    def add(label, cfg):
         text, n_leaves = lower_step(cfg)
         ctx.texts[label] = text
-        ctx.meta[label] = VariantMeta(n_donated_leaves=n_leaves, **meta_kw)
+        ctx.meta[label] = VariantMeta(n_donated_leaves=n_leaves)
         ctx.jaxpr_consts[label] = []
         return label
 
-    with _interpret_kernels(False):
+    with _kernels_dead():
         add("base", _cfg())
         ctx.jaxpr_consts["base"] = step_jaxpr_consts(_cfg())
         for label, overrides in KNOB_OFF_LATTICE:
@@ -277,37 +259,11 @@ def build_step_context(full: bool = True) -> StepContext:
             path = art.save(f"{td}/TUNED.json")
             add("off:tuned", apply_tuned(_cfg(), path))
             ctx.identity_pairs.append(("base", "off:tuned", "tuned"))
-        for act in ("topk", "batchtopk"):
-            a = add(f"{act}:fused_off",
-                    _cfg(activation=act, fused_encoder="off", **_SPARSE_SHAPE))
-            b = add(f"{act}:fused_auto_dead",
-                    _cfg(activation=act, fused_encoder="auto", **_SPARSE_SHAPE))
-            ctx.identity_pairs.append((a, b, f"fused_encoder[{act}]"))
         a = add("topk:sparse_off",
                 _cfg(activation="topk", sparse_bwd="off", **_SPARSE_SHAPE))
         b = add("topk:sparse_auto_dead",
                 _cfg(activation="topk", sparse_bwd="auto", **_SPARSE_SHAPE))
         ctx.identity_pairs.append((a, b, "sparse_bwd"))
-
-    if full:
-        with _interpret_kernels(True):
-            cfg = _cfg(activation="topk", fused_encoder="on", sparse_bwd="on",
-                       **_FUSED_SHAPE)
-            add("topk:fused_live", cfg,
-                forbid_dense_shape=(cfg.batch_size, cfg.dict_size))
-            # the serve plane's device program: encode→TopK→diff on captured
-            # hooks with the fused kernel live — like the train step it must
-            # never materialize the [B, dict] pre-act matrix
-            # (hlo-serve-no-dense-preacts)
-            from crosscoder_tpu.serve import step as serve_step
-
-            scfg = _cfg(activation="topk", fused_encoder="on",
-                        sparse_bwd="on", serve="on", **_FUSED_SHAPE)
-            ctx.texts["serve:encode_fused"] = serve_step.lower_encode_text(scfg)
-            ctx.meta["serve:encode_fused"] = VariantMeta(
-                serve_step=True,
-                forbid_dense_shape=(scfg.batch_size, scfg.dict_size))
-            ctx.jaxpr_consts["serve:encode_fused"] = []
     return ctx
 
 
@@ -497,49 +453,6 @@ def _check_donation(ctx: StepContext) -> list[Finding]:
     return out
 
 
-def _check_fused_no_dense(ctx: StepContext) -> list[Finding]:
-    out = []
-    for label, text in ctx.texts.items():
-        shape = ctx.meta[label].forbid_dense_shape
-        if shape is None or ctx.meta[label].serve_step:
-            continue
-        b, h = shape
-        pat = re.compile(rf"tensor<(?:\d+x)*{b}x{h}x(?:f32|bf16|f16)>")
-        hits = pat.findall(text)
-        if hits:
-            out.append(Finding(
-                rule="hlo-fused-no-dense-preacts", location=label,
-                message=f"{len(hits)} [B={b}, dict={h}] tensors in a "
-                        f"fused-encoder-live step — the pre-act matrix "
-                        f"the fusion exists to never materialize",
-            ))
-    return out
-
-
-def _check_serve_no_dense(ctx: StepContext) -> list[Finding]:
-    """The serve encode step inherits the fused tier's memory contract:
-    with the kernel live, the lowered serve program must carry no
-    ``[B, dict]`` float tensor — the whole point of serving through the
-    fusion is that per-request cost scales with ``[B, k]``, not the
-    dictionary width (docs/SERVING.md)."""
-    out = []
-    for label, text in ctx.texts.items():
-        shape = ctx.meta[label].forbid_dense_shape
-        if shape is None or not ctx.meta[label].serve_step:
-            continue
-        b, h = shape
-        pat = re.compile(rf"tensor<(?:\d+x)*{b}x{h}x(?:f32|bf16|f16)>")
-        hits = pat.findall(text)
-        if hits:
-            out.append(Finding(
-                rule="hlo-serve-no-dense-preacts", location=label,
-                message=f"{len(hits)} [B={b}, dict={h}] tensors in the "
-                        f"fused-live serve encode step — the dense pre-act "
-                        f"matrix must never materialize on the request path",
-            ))
-    return out
-
-
 def _check_host_transfers(ctx: StepContext) -> list[Finding]:
     out = []
     for label, text in ctx.texts.items():
@@ -582,9 +495,6 @@ HLO_RULES: list[Rule] = [
     Rule("hlo-donation-honored",
          "every donated train-state leaf has an input/output alias",
          _is_step_ctx, _check_donation),
-    Rule("hlo-fused-no-dense-preacts",
-         "fused-encoder-live variants contain no [B, dict] tensor",
-         _is_step_ctx, _check_fused_no_dense),
     Rule("hlo-no-host-transfers",
          "no infeed/outfeed/send/recv/callback inside the step",
          _is_step_ctx, _check_host_transfers),
@@ -606,9 +516,6 @@ HLO_RULES: list[Rule] = [
     Rule("hlo-serve-off-identity",
          "the serving plane never changes the train-step lowering",
          _is_step_ctx, _check_serve_off),
-    Rule("hlo-serve-no-dense-preacts",
-         "the fused-live serve encode step carries no [B, dict] tensor",
-         _is_step_ctx, _check_serve_no_dense),
     Rule("hlo-tuned-config-identity",
          "a default-knob TUNED.json never changes the step lowering",
          _is_step_ctx, _check_tuned_identity),

@@ -114,14 +114,6 @@ def _mut_tuned() -> StepContext:
     return ctx
 
 
-def _mut_serve_dense() -> StepContext:
-    ctx = _step_ctx()
-    ctx.meta["base"] = VariantMeta(n_donated_leaves=1, serve_step=True,
-                                   forbid_dense_shape=(192, 1024))
-    ctx.texts["base"] += "  %p = stablehlo.dot : tensor<192x1024xf32>\n"
-    return ctx
-
-
 def _mut_s8() -> StepContext:
     ctx = _step_ctx()
     ctx.texts["base"] += "  %q = stablehlo.convert : tensor<32x8xi8>\n"
@@ -137,14 +129,6 @@ def _mut_f64() -> StepContext:
 def _mut_donation() -> StepContext:
     ctx = _step_ctx()
     ctx.meta["base"] = VariantMeta(n_donated_leaves=3)   # only 1 alias present
-    return ctx
-
-
-def _mut_dense_preacts() -> StepContext:
-    ctx = _step_ctx()
-    ctx.meta["base"] = VariantMeta(n_donated_leaves=1,
-                                   forbid_dense_shape=(192, 1024))
-    ctx.texts["base"] += "  %p = stablehlo.dot : tensor<192x1024xf32>\n"
     return ctx
 
 
@@ -174,14 +158,14 @@ def _call(**kw) -> CapturedCall:
 
 
 def _mut_probe_coverage() -> PallasContext:
-    # only one family probed; the other six are missing
+    # only one family probed; the other five are missing
     return PallasContext(calls=[_call()])
 
 
 def _pallas_ctx(call: CapturedCall) -> PallasContext:
     calls = [_call(kernel=f) for f in
              ("topk", "sparsify", "batchtopk", "quant",
-              "paged_attention", "fused_encoder_topk")]
+              "paged_attention")]
     calls.append(call)
     return PallasContext(calls=calls)
 
@@ -284,11 +268,9 @@ MUTATIONS: dict[str, Callable[[], Any]] = {
     "hlo-fleet-off-identity": _mut_fleet,
     "hlo-serve-off-identity": _mut_serve,
     "hlo-tuned-config-identity": _mut_tuned,
-    "hlo-serve-no-dense-preacts": _mut_serve_dense,
     "hlo-no-s8-when-quant-off": _mut_s8,
     "hlo-no-f64": _mut_f64,
     "hlo-donation-honored": _mut_donation,
-    "hlo-fused-no-dense-preacts": _mut_dense_preacts,
     "hlo-no-host-transfers": _mut_host_transfer,
     "jaxpr-no-large-captured-consts": _mut_large_const,
     "pallas-probe-coverage": _mut_probe_coverage,

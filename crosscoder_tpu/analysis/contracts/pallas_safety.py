@@ -1,8 +1,8 @@
-"""Static safety analyzer for seven ops/ Pallas kernel families.
+"""Static safety analyzer for six ops/ Pallas kernel families.
 
 Every kernel family that moves its blocks through BlockSpecs alone (topk,
-sparsify, batchtopk, quant, paged_attention, flash_attention,
-fused_encoder_topk; not ops/moe and ops/row_gather, whose kernels place
+sparsify, batchtopk, quant, paged_attention, flash_attention; not
+ops/moe and ops/row_gather, whose kernels place
 rows by their own DMAs and are held by tests/test_chip_compile.py and
 their oracles) is probed once at a canonical
 supported shape with a recording ``pallas_call`` shim: the probe runs the
@@ -58,7 +58,6 @@ KERNEL_BUDGETS = {
     "quant": 12 << 20,
     "paged_attention": 13 << 20,
     "flash_attention": 13 << 20,
-    "fused_encoder_topk": 13 << 20,
 }
 
 ALLOWED_SCRATCH_DTYPES = ("float32", "int32")
@@ -327,14 +326,6 @@ def run_kernel_probes() -> PallasContext:
         q = jnp.asarray(rng.normal(size=(2, S, H, hd)).astype(np.float32))
         k = jnp.asarray(rng.normal(size=(2, S, KV, hd)).astype(np.float32))
         fa.flash_attention(q, k, k, scale=0.09, softcap=50.0, window=200)
-    with probe("fused_encoder_topk"):
-        from crosscoder_tpu.ops import fused_encoder_topk as fek
-        B, nd, H, k = 48, 256, 1024, 8
-        x2 = jnp.asarray(rng.normal(size=(B, nd)).astype(np.float32))
-        W2 = jnp.asarray(rng.normal(size=(nd, H)).astype(np.float32) * 0.05)
-        b = jnp.asarray(rng.normal(size=(H,)).astype(np.float32))
-        assert fek.supported(B, nd, H, k, x2.dtype, 0)
-        fek.fused_topk_encode(x2, W2, b, k)
     return ctx
 
 

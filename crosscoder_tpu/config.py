@@ -96,9 +96,6 @@ class CrossCoderConfig:
                                     # bf16 pre-acts tie there (topk proper
                                     # breaks ties by index and keeps
                                     # exactly k per row)
-    sparse_decode: bool = False     # topk only: decode via the k active rows
-                                    # (gather + custom-vjp) instead of the
-                                    # dense [B,H]x[H,n,d] matmul
     factored_decode: str = "auto"   # topk + Pallas tier: decode FORWARD
                                     # through the k active rows (sparsify
                                     # kernel + the rows). "auto" is
@@ -129,38 +126,6 @@ class CrossCoderConfig:
                                     # parity and A/Bs); "off" never.
                                     # Requires l1_coeff == 0 (the factored
                                     # tier's soundness gate).
-    fused_encoder: str = "auto"     # fused encoder→TopK megakernel
-                                    # (ops/fused_encoder_topk.py;
-                                    # docs/SCALING.md "Fused encoder→
-                                    # TopK"): the encoder matmul streams
-                                    # dictionary tiles through VMEM and
-                                    # top-k-reduces them in-kernel, so
-                                    # the [B, dict] pre-act matrix never
-                                    # round-trips HBM. topk: rides the
-                                    # sparse-backward full-step scope
-                                    # (requires factored tier + sparse_bwd
-                                    # live; AuxK steps keep the dense
-                                    # encode — the h-residual escape
-                                    # hatch). batchtopk: fused global-
-                                    # bisection count-then-emit. "auto" =
-                                    # on when the kernel is live (TPU +
-                                    # CROSSCODER_FUSED_TOPK_PALLAS=1 or
-                                    # CROSSCODER_PALLAS=all, or interpret
-                                    # mode) and shapes are supported;
-                                    # "on"/"off" force. Zero-cost off
-                                    # (step-HLO identity).
-    quant_encoder: bool = False     # fused tier only: int8 block-scaled
-                                    # encoder matmul inside the fused
-                                    # kernel (per-block scales along the
-                                    # contraction axis, ops/quant.py
-                                    # layout) — ~0.5x weight-stream
-                                    # bytes at a small selection-
-                                    # agreement cost. Opt-in behind the
-                                    # bench quality gate (the
-                                    # --quant-grads discipline):
-                                    # docs/SCALING.md has the procedure.
-                                    # quant_block must divide
-                                    # n_sources·d_in.
     jumprelu_theta: float = 0.001   # initial JumpReLU threshold
     jumprelu_bandwidth: float = 0.001  # STE bandwidth for the threshold gradient
     l0_coeff: float = 0.0           # jumprelu only: coefficient on the
@@ -453,7 +418,7 @@ class CrossCoderConfig:
     serve: str = "off"              # off | on: the online model-diffing
                                     # request path (serve/engine.py): token
                                     # streams admitted via ContinuousBatcher
-                                    # into paged LM harvest slots, fused
+                                    # into paged LM harvest slots,
                                     # encoder→TopK on the captured hooks,
                                     # per-request top-k latents + decoder-
                                     # norm diff scores returned — only
@@ -677,10 +642,6 @@ class CrossCoderConfig:
                     "densely, while the seq-parallel harvest shards it "
                     "over the mesh — pick one"
                 )
-        if self.sparse_decode and self.activation != "topk":
-            raise ValueError(
-                f"sparse_decode requires activation='topk', got {self.activation!r}"
-            )
         _check_choice("factored_decode", self.factored_decode,
                       ("auto", "on", "off"))
         if self.factored_decode == "on" and self.activation != "topk":
@@ -708,68 +669,6 @@ class CrossCoderConfig:
                 "through (vals, idx), which a nonzero weighted-L1 "
                 "objective needs"
             )
-        if self.sparse_bwd == "on" and self.sparse_decode:
-            raise ValueError(
-                "sparse_bwd='on' is incompatible with sparse_decode: the "
-                "sparse backward extends the factored Pallas tier, not the "
-                "legacy gather decode (which has its own custom VJP)"
-            )
-        _check_choice("fused_encoder", self.fused_encoder,
-                      ("auto", "on", "off"))
-        if self.fused_encoder == "on":
-            if self.activation not in ("topk", "batchtopk"):
-                raise ValueError(
-                    f"fused_encoder='on' requires activation='topk' or "
-                    f"'batchtopk' (the kernel IS a fused TopK/BatchTopK "
-                    f"selection), got {self.activation!r}"
-                )
-            if self.activation == "topk":
-                if self.sparse_bwd == "off":
-                    raise ValueError(
-                        "fused_encoder='on' with activation='topk' requires "
-                        "sparse_bwd != 'off': the fused forward hands "
-                        "(vals, idx) to the sparse backward plane — without "
-                        "it the backward would need the dense pre-acts the "
-                        "fusion exists to never materialize"
-                    )
-                if self.l1_coeff != 0:
-                    raise ValueError(
-                        "fused_encoder='on' with activation='topk' requires "
-                        "l1_coeff=0 (the factored/sparse tier it rides "
-                        "carries no gradient path through (vals, idx))"
-                    )
-                if self.sparse_decode:
-                    raise ValueError(
-                        "fused_encoder='on' is incompatible with "
-                        "sparse_decode: the fused tier extends the factored "
-                        "Pallas tier, not the legacy gather decode"
-                    )
-        if self.quant_encoder:
-            if self.fused_encoder == "off":
-                raise ValueError(
-                    "quant_encoder requires fused_encoder != 'off': the "
-                    "int8 block-scaled matmul lives INSIDE the fused "
-                    "kernel; with the fused tier off the knob would "
-                    "silently do nothing"
-                )
-            if self.activation != "topk":
-                raise ValueError(
-                    f"quant_encoder requires activation='topk': the int8 "
-                    f"path lives in the fused TopK kernel only (BatchTopK "
-                    f"stacks quantization error into a GLOBAL order "
-                    f"statistic and stays exact), got {self.activation!r}"
-                )
-            nd = self.n_sources * self.d_in
-            if self.quant_block % 128 or nd % self.quant_block:
-                divisors = [b for b in (128, 256, 384, 512)
-                            if nd % b == 0]
-                raise ValueError(
-                    f"quant_encoder: quant_block {self.quant_block} must be "
-                    f"a multiple of 128 dividing n_sources*d_in = {nd} (the "
-                    f"in-kernel int8 dot slices the contraction axis per "
-                    f"block); try one of "
-                    f"{divisors or 'a lane-aligned divisor'}"
-                )
         if self.l0_coeff > 0 and self.activation != "jumprelu":
             raise ValueError(
                 f"l0_coeff requires activation='jumprelu' (the rectangle-"

@@ -209,61 +209,6 @@ def cached_apply(cfg: CrossCoderConfig, kind: str = "forward"):
 
 
 # ---------------------------------------------------------------------------
-# sparse TopK decode (no reference counterpart — the reference's decode is
-# always the dense [B,H]x[H,n,d] matmul, reference crosscoder.py:82-89,
-# which at TopK(k=32) multiplies ~0.1% nonzeros).
-#
-# Measured guidance (TPU v5e, k 32, batch 4096, full train step —
-# artifacts/BENCH_r03_local.json matrix): at dict 2^15 the DENSE decode
-# wins (76.7 vs 95.0 ms/step) because at B·k/H ≈ 4 hits per latent every
-# W_dec row is read anyway, the dense matmul is a compute-bound MXU op,
-# and XLA's row gather runs well below HBM bandwidth. Against the plain
-# dense path this gather wins at 2^17 (251.0 vs 278.3 ms/step) — but
-# round-3's width-chunked Pallas TopK moved the goalposts: the
-# kernel+dense-decode step is faster still at every dict (208.3 ms at
-# 2^17), so cfg.sparse_decode now only pays on shapes the kernel's
-# supported() gate rejects. Default stays False.
-
-
-@jax.custom_vjp
-def _sparse_decode_product(vals: jax.Array, idx: jax.Array, W_dec: jax.Array) -> jax.Array:
-    """``Σ_j vals[b,j] · W_dec[idx[b,j]]`` → ``[B, n, d]`` fp32.
-
-    Forward gathers only the k active decoder rows per example (bandwidth
-    ~B·k·n·d instead of the dense matmul's B·H FLOP column). The backward
-    computes ``dW_dec`` by scattering the k values into a dense ``[B, H]``
-    one-hot-weighted matrix and running a dense matmul — on TPU the MXU
-    matmul over mostly-zeros beats a ``[B,k,n,d]``-sized scatter-add with
-    row collisions by a wide margin.
-    """
-    w = jnp.take(W_dec, idx, axis=0)                       # [B, k, n, d]
-    return jnp.einsum("bk,bknd->bnd", vals, w, preferred_element_type=jnp.float32)
-
-
-def _sparse_decode_fwd(vals, idx, W_dec):
-    return _sparse_decode_product(vals, idx, W_dec), (vals, idx, W_dec)
-
-
-def _sparse_decode_bwd(res, g):
-    vals, idx, W_dec = res
-    g = g.astype(jnp.float32)
-    w = jnp.take(W_dec, idx, axis=0)                       # recomputed (residual would be B·k·n·d)
-    d_vals = jnp.einsum("bnd,bknd->bk", g, w.astype(jnp.float32)).astype(vals.dtype)
-    # dense-scatter trick for dW_dec: f_dense[b, idx[b,j]] = vals[b,j]
-    B, k = vals.shape
-    rows = jnp.arange(B)[:, None]
-    f_dense = jnp.zeros((B, W_dec.shape[0]), dtype=vals.dtype)
-    f_dense = f_dense.at[rows, idx].add(vals, mode="drop")
-    dW_dec = jnp.einsum(
-        "bh,bnd->hnd", f_dense, g, preferred_element_type=jnp.float32
-    ).astype(W_dec.dtype)
-    return d_vals, None, dW_dec
-
-
-_sparse_decode_product.defvjp(_sparse_decode_fwd, _sparse_decode_bwd)
-
-
-# ---------------------------------------------------------------------------
 # factored TopK decode (Pallas tier, round-5): forward through the k active
 # rows only, backward through the SAME dense matmuls as the dense path.
 #
@@ -514,178 +459,6 @@ def _sparse_topk_step_bwd(k, rows, res, g):
 _sparse_topk_step.defvjp(_sparse_topk_step_fwd, _sparse_topk_step_bwd)
 
 
-# ---------------------------------------------------------------------------
-# fused encoder→TopK tier (cfg.fused_encoder; ops/fused_encoder_topk.py):
-# the _sparse_topk_step forward with the dense encode + TopK + sparsify
-# chain replaced by ONE Pallas kernel that streams encoder tiles through
-# VMEM and folds them into a running per-row top-k — the [B, H] pre-act
-# matrix never exists in HBM. The BACKWARD is _sparse_topk_step's
-# verbatim: its residuals are (x, vals, idx, W_enc, W_dec), none of which
-# the fusion removes, so the two tiers share one bwd implementation and
-# the (vals, idx) contract is pinned by construction. AuxK steps need the
-# pre-acts as a differentiable residual for the aux ranking — the
-# ``h``-residual escape hatch: they stay on _sparse_topk_from_h's dense
-# encode (see get_losses).
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _fused_topk_step(
-    x: jax.Array, W_enc: jax.Array, b_enc: jax.Array, W_dec: jax.Array,
-    k: int, quant_block: int = 0,
-) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """``(recon [B,n,d] f32 (no b_dec), vals [B,k], idx [B,k])`` with the
-    encode+TopK+sparsify chain fused into one kernel (``quant_block`` > 0
-    routes the in-kernel int8 block-scaled matmul — cfg.quant_encoder)."""
-    from crosscoder_tpu.ops import fused_encoder_topk as fek
-
-    B = x.shape[0]
-    vals, idx = fek.fused_topk_encode(
-        x.reshape(B, -1), W_enc.reshape(-1, W_enc.shape[-1]), b_enc, k,
-        quant_block=quant_block,
-    )
-    w = jnp.take(W_dec, idx, axis=0)                       # [B, k, n, d]
-    recon = jnp.einsum("bk,bknd->bnd", vals, w,
-                       preferred_element_type=jnp.float32)
-    return recon, vals, idx
-
-
-def _fused_topk_step_fwd(x, W_enc, b_enc, W_dec, k, quant_block):
-    out = _fused_topk_step(x, W_enc, b_enc, W_dec, k, quant_block)
-    _, vals, idx = out
-    # the _sparse_topk_step residual tuple exactly (see its fwd)
-    return out, (x, vals, idx, W_enc, W_dec, jnp.zeros((0,), b_enc.dtype))
-
-
-def _fused_topk_step_bwd(k, quant_block, res, g):
-    # gradients are the sparse plane's verbatim: the kernel only changed
-    # how (vals, idx) were PRODUCED, not what they mean
-    return _sparse_topk_step_bwd(k, False, res, g)
-
-
-_fused_topk_step.defvjp(_fused_topk_step_fwd, _fused_topk_step_bwd)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _fused_batchtopk_encode(
-    x: jax.Array, W_enc: jax.Array, b_enc: jax.Array, k: int
-) -> jax.Array:
-    """Masked BatchTopK activations ``f [B, H]`` with the encoder matmul
-    and the global-threshold bisection fused over streamed tiles
-    (ops/fused_encoder_topk.fused_batchtopk_encode_raw) — bit-identical
-    to ``activations.batchtopk(pre_acts(params, x), k)``. The custom VJP
-    reproduces the dense path's gradients exactly: straight-through on
-    the survivors, then the ordinary encoder-einsum VJP."""
-    from crosscoder_tpu.ops import fused_encoder_topk as fek
-
-    B = x.shape[0]
-    return fek.fused_batchtopk_encode_raw(
-        x.reshape(B, -1), W_enc.reshape(-1, W_enc.shape[-1]), b_enc, k,
-    )
-
-
-def _fused_batchtopk_encode_fwd(x, W_enc, b_enc, k):
-    f = _fused_batchtopk_encode(x, W_enc, b_enc, k)
-    return f, (x, W_enc, f, jnp.zeros((0,), b_enc.dtype))
-
-
-def _fused_batchtopk_encode_bwd(k, res, g):
-    x, W_enc, f, b_tok = res
-    # dense chain: f = hp·stop_grad(mask) → dh = g·mask (mask ⟺ f > 0);
-    # h = (hf + b).astype(x.dtype) → dhf = dh in f32; then the einsum VJP
-    dh = jnp.where(f > 0, g, 0).astype(jnp.float32)        # [B, H]
-    db_enc = jnp.sum(dh, axis=0).astype(b_tok.dtype)
-    dW_enc = jnp.einsum(
-        "bnd,bh->ndh", x.astype(jnp.float32), dh,
-        preferred_element_type=jnp.float32,
-    ).astype(W_enc.dtype)
-    dx = jnp.einsum(
-        "bh,ndh->bnd", dh, W_enc.astype(jnp.float32),
-        preferred_element_type=jnp.float32,
-    ).astype(x.dtype)
-    return dx, dW_enc, db_enc
-
-
-_fused_batchtopk_encode.defvjp(_fused_batchtopk_encode_fwd,
-                               _fused_batchtopk_encode_bwd)
-
-
-_FUSED_DEMOTION_WARNED: set[str] = set()
-
-
-def _warn_fused_demoted(reason: str) -> None:
-    """``fused_encoder='on'`` fell back to the dense encode — the silent
-    no-op class the dispatch layer exists to prevent, so say it once per
-    (process, reason) on stderr. Config validation can't catch these:
-    they depend on env/backend resolution ("auto" knobs) only known at
-    trace time."""
-    if reason in _FUSED_DEMOTION_WARNED:
-        return
-    _FUSED_DEMOTION_WARNED.add(reason)
-    import sys
-
-    print(
-        f"[crosscoder_tpu] fused_encoder='on' demoted to the dense "
-        f"encode: {reason}",
-        file=sys.stderr, flush=True,
-    )
-
-
-def use_fused_encoder(cfg: CrossCoderConfig, batch: int | None = None) -> bool:
-    """Dispatch for the fused encoder→TopK tier (``cfg.fused_encoder``).
-
-    "off" never. For ``topk`` the fused forward hands (vals, idx)
-    straight to the sparse backward plane, so it rides the
-    ``_sparse_topk_step`` scope: the factored tier AND
-    :func:`use_sparse_bwd` must be live (AuxK steps additionally fall
-    back at the trace site — the ``h``-residual escape hatch). For
-    ``batchtopk`` it needs only training mode (a calibrated fixed
-    threshold is eval — the emit sweep alone, no bisection to fuse).
-    "auto" additionally requires the kernel to be live (TPU +
-    ``CROSSCODER_FUSED_TOPK_PALLAS=1`` / umbrella, or interpret mode)
-    and a kernel-supported shape; "on" forces, with the ops layer's
-    dense fallback covering unsupported shapes. An "on" that a
-    prerequisite tier demotes anyway (e.g. ``sparse_bwd='auto'``
-    resolving off) warns once on stderr instead of silently no-opping.
-    """
-    if cfg.fused_encoder == "off":
-        return False
-    forced = cfg.fused_encoder == "on"
-    if cfg.activation == "topk":
-        if not (use_factored_decode(cfg) and use_sparse_bwd(cfg, batch)):
-            if forced:
-                _warn_fused_demoted(
-                    "activation='topk' needs the factored tier and the "
-                    "sparse backward plane live (use_factored_decode/"
-                    "use_sparse_bwd resolved off — check dict_size and "
-                    "whether the row kernels are live: rows_live)"
-                )
-            return False
-    elif cfg.activation == "batchtopk":
-        if cfg.batchtopk_threshold > 0:
-            if forced:
-                _warn_fused_demoted(
-                    "batchtopk_threshold > 0 is eval mode — a calibrated "
-                    "fixed threshold has no bisection to fuse"
-                )
-            return False
-    else:
-        return False
-    if cfg.fused_encoder == "on":
-        return True
-    from crosscoder_tpu.ops import fused_encoder_topk as fek
-
-    if not fek.kernel_enabled():
-        return False
-    # the int8 path is topk-only (validated in config) — batchtopk's
-    # support probe must not gate on quant geometry it will never use
-    qb = (cfg.quant_block
-          if cfg.quant_encoder and cfg.activation == "topk" else 0)
-    return batch is None or fek.supported(
-        batch, cfg.n_sources * cfg.d_in, cfg.dict_size, cfg.topk_k,
-        dtype_of(cfg.enc_dtype), qb,
-    )
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def _sparse_topk_from_h(
     h: jax.Array, W_dec: jax.Array, k: int
@@ -785,7 +558,7 @@ def rows_live(cfg: CrossCoderConfig, batch: int | None) -> bool:
     ``dh`` for two 5.8 ms products and gain nothing (PERF.md §6, PR 32).
     ``get_losses`` adds what only it can see: no AuxK consumer of ``h`` on
     this step, and parameters already in the compute dtype."""
-    if cfg.activation != "topk" or cfg.sparse_decode or cfg.l1_coeff != 0:
+    if cfg.activation != "topk" or cfg.l1_coeff != 0:
         return False
     if batch is None or cfg.sparse_bwd == "off":
         return False
@@ -809,7 +582,7 @@ def use_sparse_bwd(cfg: CrossCoderConfig, batch: int | None = None) -> bool:
     matmuls beat.
     Soundness: the factored tier's l1_coeff == 0 gate.
     """
-    if cfg.activation != "topk" or cfg.sparse_decode:
+    if cfg.activation != "topk":
         return False
     if cfg.sparse_bwd == "off" or cfg.l1_coeff != 0:
         return False
@@ -846,7 +619,7 @@ def use_factored_decode(cfg: CrossCoderConfig, batch: int | None = None) -> bool
     -8 ms at 2^17, +6 ms at 2^16).
     Soundness gate: l1_coeff must be 0 (see _factored_topk_forward).
     """
-    if cfg.activation != "topk" or cfg.sparse_decode:
+    if cfg.activation != "topk":
         return False
     mode = cfg.factored_decode
     if mode == "off" or cfg.l1_coeff != 0:
@@ -866,32 +639,6 @@ def use_factored_decode(cfg: CrossCoderConfig, batch: int | None = None) -> bool
     # forced sparse backward at sub-2^17 dicts doesn't silently noop
     return (mode == "on" or cfg.sparse_bwd == "on"
             or cfg.dict_size >= 131072 or rows_live(cfg, batch))
-
-
-def topk_vals_idx(params: Params, x: jax.Array, cfg: CrossCoderConfig) -> tuple[jax.Array, jax.Array]:
-    """TopK encode in factored form: ``(vals [B,k], idx [B,k])``.
-
-    Gradients flow to ``W_enc``/``b_enc`` through the ``take_along_axis``
-    gather (its VJP is the scatter the dense TopK mask implements); ``idx``
-    is treated as a constant of the backward pass, the standard
-    straight-through treatment (same as ops.activations.topk).
-    """
-    h = pre_acts(params, x)
-    hp = act_ops.relu(h)
-    _, idx = jax.lax.top_k(hp, cfg.topk_k)
-    vals = jnp.take_along_axis(hp, jax.lax.stop_gradient(idx), axis=-1)
-    return vals, idx
-
-
-def sparse_topk_forward(params: Params, x: jax.Array, cfg: CrossCoderConfig) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """TopK encode + sparse decode: ``(recon [B,n,d] fp32, vals, idx)``.
-
-    Numerically the dense path's reconstruction restricted to its nonzero
-    terms — equal up to fp32 summation order.
-    """
-    vals, idx = topk_vals_idx(params, x, cfg)
-    recon = _sparse_decode_product(vals, idx, params["W_dec"])
-    return recon + params["b_dec"].astype(jnp.float32), vals, idx
 
 
 def get_losses(
@@ -930,15 +677,13 @@ def get_losses(
             and params["W_dec"].dtype == x.dtype == jnp.bfloat16)
     batch = x.shape[0] if rows else None
     factored = use_factored_decode(cfg, batch)
-    sparse = (cfg.sparse_decode and cfg.activation == "topk") or factored
     l0_penalty: jax.Array | float = 0.0
     h = None            # pre-acts, kept when a later consumer (the
                         # JumpReLU L0 penalty, the AuxK ranking) needs
                         # them — shared explicitly rather than trusting
                         # CSE to dedupe a second encode matmul
     sparse_bwd = factored and use_sparse_bwd(cfg, batch)
-    fused = use_fused_encoder(cfg, x.shape[0])
-    rows = rows and sparse_bwd and not fused
+    rows = rows and sparse_bwd
     if cfg.activation == "topk":
         from crosscoder_tpu import obs
 
@@ -952,23 +697,13 @@ def get_losses(
         # sparse backward plane, full-step scope: encode + TopK + factored
         # decode under ONE custom vjp — none of the three dense backward
         # matmuls survives. Forward numerics are the factored tier's
-        # exactly (same einsum/kernel/gather chain). The fused tier
-        # (cfg.fused_encoder) swaps that forward for the encoder→TopK
-        # megakernel — same (vals, idx) contract, same backward, no [B, H]
-        # pre-act matrix in HBM; aux-active steps fall through to the
-        # (h, W_dec) scope below (the h-residual escape hatch — the aux
-        # ranking consumes the pre-acts).
-        if fused:
-            qb = cfg.quant_block if cfg.quant_encoder else 0
-            recon_f32, vals, idx = _fused_topk_step(
-                x, params["W_enc"], params["b_enc"], params["W_dec"],
-                cfg.topk_k, qb,
-            )
-        else:
-            recon_f32, vals, idx = _sparse_topk_step(
-                x, params["W_enc"], params["b_enc"], params["W_dec"],
-                cfg.topk_k, rows,
-            )
+        # exactly (same einsum/kernel/gather chain). Aux-active steps fall
+        # through to the (h, W_dec) scope below (the h-residual escape
+        # hatch — the aux ranking consumes the pre-acts).
+        recon_f32, vals, idx = _sparse_topk_step(
+            x, params["W_enc"], params["b_enc"], params["W_dec"],
+            cfg.topk_k, rows,
+        )
         recon = (recon_f32 + params["b_dec"].astype(jnp.float32)).astype(x.dtype)
         f = None
     elif factored:
@@ -981,24 +716,6 @@ def get_losses(
         recon_f32, vals, idx = tier(h, params["W_dec"], cfg.topk_k)
         recon = (recon_f32 + params["b_dec"].astype(jnp.float32)).astype(x.dtype)
         f = None
-    elif sparse:
-        # factored TopK path: decode touches only the k active rows; the
-        # rounding of recon through the compute dtype matches the dense
-        # decode's output cast so both paths see the same loss numerics
-        recon_f32, vals, idx = sparse_topk_forward(params, x, cfg)
-        recon = recon_f32.astype(x.dtype)
-        f = None
-    elif cfg.activation == "batchtopk" and fused and not aux_active:
-        # fused BatchTopK: encoder matmul + global-threshold bisection +
-        # emit over streamed VMEM tiles (the pre-acts are recomputed per
-        # bisection pass instead of round-tripping [B, H] through HBM);
-        # f is bit-identical to the dense chain, gradients are the dense
-        # straight-through VJP. AuxK steps keep the dense encode (the
-        # aux ranking needs h — same escape hatch as the topk tier).
-        f = _fused_batchtopk_encode(
-            x, params["W_enc"], params["b_enc"], cfg.topk_k
-        )
-        recon = decode(params, f)
     elif cfg.activation == "jumprelu" and cfg.l0_coeff > 0:
         h = pre_acts(params, x)
         f = act_ops.apply(h, cfg, params)
@@ -1031,7 +748,7 @@ def get_losses(
             total_dec_norm = jnp.sum(dec_norms, axis=-1)      # [H]
         if not need_l1:
             l1_loss = jnp.zeros((), jnp.float32)
-        elif sparse:
+        elif factored:
             # identical to the dense weighted L1: inactive latents contribute 0
             w_active = jnp.take(total_dec_norm, idx)          # [B, k]
             l1_loss = jnp.mean(jnp.sum(vals.astype(jnp.float32) * w_active, axis=-1))
@@ -1059,7 +776,7 @@ def get_losses(
         # current, or a revived latent would keep receiving aux gradient
         # for up to aux_every steps after coming back.
         d_hidden = params["W_dec"].shape[0]
-        if sparse:
+        if factored:
             hits = jnp.zeros((d_hidden,), jnp.int32).at[idx.reshape(-1)].add(
                 (vals.reshape(-1) > 0).astype(jnp.int32), mode="drop"
             )
@@ -1075,8 +792,8 @@ def get_losses(
         # 79 ms, bench matrix): it materialized [B, H] fp32 and paid the
         # k=256 sort. Which near-top dead latent gets the aux gradient is
         # heuristic anyway; values are re-GATHERED from the pre-acts so
-        # the encoder's gradient path is exact (same straight-through
-        # treatment as topk_vals_idx), and non-dead slots (when fewer
+        # the encoder's gradient path is exact (the straight-through
+        # treatment of ops.activations.topk), and non-dead slots (when fewer
         # dead than aux_k exist) are zeroed by the mask gather.
         h_all = h if h is not None else pre_acts(params, x)
         neg = jnp.asarray(jnp.finfo(h_all.dtype).min, h_all.dtype)
@@ -1091,13 +808,12 @@ def get_losses(
         avals = jnp.take_along_axis(h_all, aidx, axis=-1)
         avals = jnp.where(jnp.take(dead_mask, aidx), avals, 0)
         e = jax.lax.stop_gradient(xf - rf)                # [B, n, d] fp32
-        # dense decode of the scattered aux activations: at aux_k ≈ 8k the
-        # per-example row gather (_sparse_decode_product) materializes
+        # dense decode of the scattered aux activations: at aux_k ≈ 8k a
+        # per-example gather of the aux_k decoder rows materializes
         # [B, aux_k, n, d] — ~10 GB of HBM traffic at bench shapes
         # (measured 391 ms/step vs ~145 dense) — while B·aux_k/H ≈ 32
         # hits per dictionary row means every W_dec row is read anyway:
-        # three MXU matmuls (fwd + the two VJPs) win outright, the same
-        # trade the sparse_decode notes above document for the main path.
+        # three MXU matmuls (fwd + the two VJPs) win outright.
         if use_sparse_aux(cfg, x.shape[0]):
             # sparse backward reuse (cfg.sparse_bwd): identical dense
             # forward, backward through the O(B·aux_k) scatter/gather
@@ -1146,7 +862,7 @@ def get_losses(
         var_per_source = jnp.sum(jnp.square(centered), axis=-1)  # [B, n]
         ev_per_source = 1.0 - l2_per_source / (var_per_source + eps)  # [B, n]
 
-        if sparse:
+        if factored:
             l0_loss = jnp.mean(jnp.sum((vals > 0).astype(jnp.float32), axis=-1))
         else:
             l0_loss = jnp.mean(jnp.sum((f > 0).astype(jnp.float32), axis=-1))

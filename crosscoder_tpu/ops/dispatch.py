@@ -12,8 +12,10 @@ fused attention, the expert kernels, and (PR 32) the sparse backward plane:
 its sorted-pair scatter was refused by the chip's compiler, and its place
 is taken by ``ops/row_gather.py``, which dispatches like those
 (``models/crosscoder.rows_live``: TopK, ``l1_coeff == 0``, one TPU device,
-a supported shape). The GATE shape itself is shared so a policy change (new
-backend, global kill-switch) lands in one place.
+a supported shape). Three gates remain: BatchTopK's global-threshold mask,
+the paged-attention harvest and the int8 quantized matmuls. The GATE shape
+itself is shared so a policy change (new backend, global kill-switch) lands
+in one place.
 
 Gate resolution (first ``hw_kernel_enabled`` call logs the full table to
 stderr, once per process, so a run's kernel posture is always in its
@@ -45,7 +47,6 @@ UMBRELLA_ENV = "CROSSCODER_PALLAS"
 # see it)
 KNOWN_GATES = (
     "CROSSCODER_BATCHTOPK_PALLAS",
-    "CROSSCODER_FUSED_TOPK_PALLAS",
     "CROSSCODER_PAGED_ATTN_PALLAS",
     "CROSSCODER_QUANT_PALLAS",
 )

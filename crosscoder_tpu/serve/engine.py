@@ -24,7 +24,7 @@ The request loop that turns a trained crosscoder into a service:
    packed by ``pack_chunk``, per-document ragged attention, captures
    bitwise-equal to the padded path at valid positions.
 4. **encode** — :func:`crosscoder_tpu.serve.step.encode_topk_diff`:
-   fused encoder→TopK on the captured activations + decoder-norm diff
+   encoder→TopK on the captured activations + decoder-norm diff
    scores; only three ``[B, k]`` arrays leave the device.
 5. **extend** — a live request (``submit(..., keep=True)``) appends
    follow-up tokens via :meth:`PageTable.extend`: the prefix keeps its
@@ -377,7 +377,7 @@ class InferenceEngine:
         each) plus the two stage wall times."""
         import jax
 
-        from crosscoder_tpu.models import crosscoder, lm
+        from crosscoder_tpu.models import lm
         from crosscoder_tpu.utils import compile_cache
 
         cfg = self.cfg
@@ -396,9 +396,8 @@ class InferenceEngine:
 
             lengths = jnp.asarray(chunk.lengths)
             norm = jnp.asarray(self._norm)
-            fused = crosscoder.use_fused_encoder(cfg, b)
             statics = dict(enc_dtype=cfg.enc_dtype, k=cfg.topk_k,
-                           fused=fused, pair=self._pair)
+                           pair=self._pair)
             key = ("serve_encode", b, tuple(caps.shape), str(caps.dtype),
                    self._cc_sig, tuple(sorted(statics.items())))
 

@@ -68,7 +68,7 @@ def oracle(eng, cfg, lm_cfg, lm_params, cc_params, tokens, lengths):
     answer the serving path must reproduce bit-for-bit."""
     import jax.numpy as jnp
 
-    from crosscoder_tpu.models import crosscoder, lm
+    from crosscoder_tpu.models import lm
     from crosscoder_tpu.serve import step as serve_step
 
     caps = lm.run_with_cache_multi(
@@ -76,7 +76,6 @@ def oracle(eng, cfg, lm_cfg, lm_params, cc_params, tokens, lengths):
     vals, idx, diff = serve_step.encode_topk_diff(
         cc_params, caps, jnp.asarray(lengths, jnp.int32),
         jnp.asarray(eng._norm), enc_dtype=cfg.enc_dtype, k=cfg.topk_k,
-        fused=crosscoder.use_fused_encoder(cfg, tokens.shape[0]),
         pair=eng._pair)
     return np.asarray(vals), np.asarray(idx), np.asarray(diff)
 

@@ -60,7 +60,6 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
 from crosscoder_tpu.config import CrossCoderConfig
-from crosscoder_tpu.models import crosscoder as cc
 from crosscoder_tpu.models import stacked
 from crosscoder_tpu.obs import trace
 from crosscoder_tpu.parallel import mesh as mesh_lib
@@ -412,13 +411,6 @@ class FleetScheduler:
 
     # -- compiled steps (AOT, keyed through variant_key(tenant=...)) ----
 
-    def _enc_tag(self, cfg: CrossCoderConfig, key: tuple) -> str:
-        # mirror of Trainer._wrap_step's encoder-tier resolution
-        if not (key[1] and cfg.aux_k > 0) and cc.use_fused_encoder(
-                cfg, cfg.batch_size):
-            return "fused-int8" if cfg.quant_encoder else "fused"
-        return "dense"
-
     def _batch_struct(self, cfg: CrossCoderConfig) -> jax.ShapeDtypeStruct:
         dtype = jnp.bfloat16 if self._raw_serving else jnp.float32
         return jax.ShapeDtypeStruct(
@@ -449,10 +441,7 @@ class FleetScheduler:
                 out_shardings=(co.stacked_shardings, None),
                 donate_argnums=(0,),
             )
-            label = compile_cache.variant_key(
-                *key, enc=self._enc_tag(co.cfg, key),
-                tenant=f"{co.tag}x{n}",
-            )
+            label = compile_cache.variant_key(*key, tenant=f"{co.tag}x{n}")
             state = co.state
 
             def build():
@@ -482,9 +471,7 @@ class FleetScheduler:
                 out_shardings=(b.shardings, None),
                 donate_argnums=(0,),
             )
-            label = compile_cache.variant_key(
-                *key, enc=self._enc_tag(cfg, key), tenant=b.tag,
-            )
+            label = compile_cache.variant_key(*key, tenant=b.tag)
             state = b.state
 
             def build():

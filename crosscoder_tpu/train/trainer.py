@@ -653,17 +653,6 @@ class Trainer:
     def step_counter(self) -> int:
         return int(self.state.step)
 
-    def _variant_label(self, key: tuple[bool, bool, bool]) -> str:
-        """The canonical compile-event label for one step variant,
-        including the encoder tier traced into it (trace-time static):
-        aux-on steps keep the dense encode (the h-residual escape
-        hatch), so the enc tag follows the aux key."""
-        enc = "dense"
-        if not (key[1] and self.cfg.aux_k > 0) and cc.use_fused_encoder(
-                self.cfg, self.cfg.batch_size):
-            enc = "fused-int8" if self.cfg.quant_encoder else "fused"
-        return compile_cache.variant_key(*key, enc=enc)
-
     def _compile_scope(self, mesh=None):
         """``(mesh topology, step-knob projection hash)`` — the scope
         half of this trainer's persistent compile-cache keys; ``None``
@@ -687,7 +676,7 @@ class Trainer:
         what it always called."""
         if self._obs is None and not compile_cache.disk_enabled():
             return fn
-        label = self._variant_label(key)
+        label = compile_cache.variant_key(*key)
         scope = self._compile_scope()
         if self._obs is not None:
             return self._obs.observe_step(label, fn, disk_scope=scope)
@@ -1201,7 +1190,7 @@ class Trainer:
                 sharding=NamedSharding(mesh, PartitionSpec()))
             scope = self._compile_scope(mesh)
             for key in keys:
-                label = self._variant_label(key)
+                label = compile_cache.variant_key(*key)
                 dk = compile_cache.observed_digest(
                     label, scope, (state_sh, batch, scale))
                 if dk is None or disk is None or disk.has(dk):

@@ -67,7 +67,7 @@ def contracts_gate(cfg: Any, knobs: dict[str, Any] | None = None
 
     ctx = hlo_rules.StepContext()
     text, n_leaves = hlo_rules.lower_step(cfg)
-    quant_off = not (cfg.quant_encoder or cfg.quant_grads)
+    quant_off = not cfg.quant_grads
     ctx.texts["tune:candidate"] = text
     ctx.meta["tune:candidate"] = hlo_rules.VariantMeta(
         n_donated_leaves=n_leaves, quant_off=quant_off)

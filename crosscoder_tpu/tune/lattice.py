@@ -56,8 +56,7 @@ _OFF_CRITICAL = 0.1
 # is host/data-plane and shares the step executable (the zero-cost-off
 # contract); candidates are projected onto this set to key the AOT memo.
 STEP_FIELDS = frozenset({
-    "activation", "topk_k", "sparse_decode", "factored_decode",
-    "sparse_bwd", "fused_encoder", "quant_encoder", "quant_grads",
+    "activation", "topk_k", "factored_decode", "sparse_bwd", "quant_grads",
     "quant_block", "batch_size", "dict_size", "d_in", "n_models",
     "hook_points", "enc_dtype", "master_dtype", "l1_coeff", "l0_coeff",
     "aux_k", "aux_every", "remat", "grad_clip", "shard_sources",

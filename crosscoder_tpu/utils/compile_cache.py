@@ -60,15 +60,13 @@ _CLAIM_TTL_S = float(os.environ.get("CROSSCODER_COMPILE_CACHE_CLAIM_TTL_S",
 
 
 def variant_key(metrics: bool, aux: bool, refresh: bool, *,
-                enc: str = "dense", tenant: str = "") -> str:
+                tenant: str = "") -> str:
     """Canonical compile-event key for one train-step variant.
 
     ``(metrics, aux, refresh)`` is the Trainer's compiled-variant cache
-    tuple; ``enc`` names the encoder tier actually traced into the
-    variant ("dense", "fused", "fused-int8" — cfg.fused_encoder /
-    cfg.quant_encoder resolved at build time), so compile telemetry and
-    the HLO cost-analysis report distinguish a fused step from a dense
-    one instead of aliasing them under one label. ``tenant`` is the
+    tuple. The text keeps ``enc=dense``, the one encoder a step has, so
+    compile logs and the disk tier's keys read as they always have.
+    ``tenant`` is the
     fleet scheduler's compile-bucket tag (train/fleet.py): a stacked
     cohort or a heterogeneous tenant signature appends its bucket name
     so per-tenant compile events stay distinguishable; solo-trainer
@@ -78,7 +76,7 @@ def variant_key(metrics: bool, aux: bool, refresh: bool, *,
     """
     tag = f", tenant={tenant}" if tenant else ""
     return (f"train_step(metrics={metrics}, aux={aux}, "
-            f"refresh={refresh}, enc={enc}{tag})")
+            f"refresh={refresh}, enc=dense{tag})")
 
 
 def enable() -> str | None:

@@ -6,7 +6,7 @@ the shipped tree and exits nonzero on any error-severity finding:
 
 - HLO/jaxpr contracts — lowers the real train step across the knob
   lattice and checks zero-cost-off identity, dtype bans, donation,
-  fused-encoder memory shape, host transfers, captured constants;
+  host transfers, captured constants;
 - Pallas kernel safety — captures every ``pallas_call`` in ops/ via
   interpret-mode probes and checks BlockSpec/grid consistency, VMEM
   budgets, index-map OOB on tails, grid-axis write races, scratch dtypes;
@@ -88,7 +88,7 @@ def build_report(args: argparse.Namespace):
         report.merge(pallas)
     if not args.skip_hlo:
         print("analyze: HLO knob-lattice sweep ...", file=sys.stderr)
-        report.merge(run_rules(HLO_RULES, build_step_context(full=True),
+        report.merge(run_rules(HLO_RULES, build_step_context(),
                                allow))
     return report
 

@@ -9,8 +9,9 @@ written out by one tree and compared with another tree's.
 ``benchmarks``): the mellum2 cell's harvest programs (``_seg_start_impl``,
 ``_seg_scan_impl`` at widths 1, 2 and 4, ``_seg_finish_impl``,
 ``_multi_cache_impl``), the Ouro cells', the Laguna cell's, the Xing cell's and the Falcon-H1 cell's refill quanta
-(``_seg_scan_impl``, one program a class of layers) and both variants of the
-TopK 2^15 and the ReLU 2^14 step at the cells' sizes. ``compare`` holds two such directories against each
+(``_seg_scan_impl``, one program a class of layers), both variants of the
+TopK 2^15 and the ReLU 2^14 step at the cells' sizes, and the TopK cell's serve
+encode (``encode_topk_diff``) at its largest bucket. ``compare`` holds two such directories against each
 other: the text outside the kernels byte for byte, and each Pallas kernel —
 which travels as MLIR bytecode in its call's ``backend_config``, source
 locations and absolute file names included — parsed and printed WITHOUT
@@ -21,6 +22,7 @@ one that is equal only without them compiles once more.
 from __future__ import annotations
 
 import base64
+import inspect
 import os
 import re
 import sys
@@ -44,6 +46,7 @@ def write(root: str, out: str) -> None:
     from crosscoder_tpu.models import lm
     from crosscoder_tpu.ops import activations as act_ops
     from crosscoder_tpu.parallel import mesh as mesh_lib
+    from crosscoder_tpu.serve import step as serve_step
     from crosscoder_tpu.train import schedules
     from crosscoder_tpu.train.state import init_train_state, make_optimizer
     from crosscoder_tpu.train.trainer import make_train_step
@@ -115,6 +118,19 @@ def write(root: str, out: str) -> None:
             progs[f"{name}_step_{'full' if metrics else 'bare'}"] = step.lower(
                 state, jax.ShapeDtypeStruct((4096, ccfg.n_sources, ccfg.d_in), jnp.bfloat16),
                 jax.ShapeDtypeStruct((ccfg.n_sources,), jnp.float32))
+        if name == "topk32k":
+            # the serve encode at the cell's largest bucket (serve_max_batch 8, seq 1024);
+            # a tree whose encode still took a tier argument is given the dense one
+            statics = dict(enc_dtype=ccfg.enc_dtype, k=ccfg.topk_k,
+                           pair=serve_step.diff_pair(ccfg.n_sources, ccfg.n_models))
+            if "fused" in inspect.signature(serve_step.encode_topk_diff).parameters:
+                statics["fused"] = False
+            params = jax.tree_util.tree_map(
+                lambda a: sds(a.shape, a.dtype),
+                jax.eval_shape(lambda k: cc.init_params(k, ccfg), jax.random.key(0)))
+            progs["topk32k_serve_encode"] = serve_step.encode_topk_diff.lower(
+                params, sds((8, 1024, ccfg.n_sources, ccfg.d_in), jnp.bfloat16),
+                sds((8,), jnp.int32), sds((ccfg.n_sources,), jnp.float32), **statics)
     os.makedirs(out, exist_ok=True)
     Path(out, "ROOT").write_text(str(Path(root).resolve()))
     for name, lowered in progs.items():

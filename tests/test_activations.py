@@ -5,6 +5,7 @@ only, crosscoder.py:76-77)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from crosscoder_tpu.config import CrossCoderConfig
 from crosscoder_tpu.models import crosscoder as cc
@@ -218,3 +219,34 @@ def test_jumprelu_l0_coeff_trains_sparsity():
     l0_without = run(0.0)
     # measured: ~49 vs ~66 active latents after 400 steps
     assert l0_with < 0.85 * l0_without, (l0_with, l0_without)
+
+
+@pytest.mark.parametrize("dtype", ["fp32", "bf16"])
+def test_batchtopk_step_bitexact_incl_ties(dtype):
+    """The BatchTopK step's activations — ``get_losses``' dense encode — to
+    the bit against a NumPy restatement, with ties: integer operands make
+    every pre-act sum exact in f32, the cast to bf16 rounds many of them
+    together, and one latent copies another's encoder column and bias, so
+    the two agree on every row (a dictionary of 1000, not whole
+    lane tiles)."""
+    rng = np.random.default_rng(6)
+    B, d, H, k = 48, 64, 1000, 8
+    cfg = CrossCoderConfig(d_in=d, dict_size=H, batch_size=B, activation="batchtopk",
+                           topk_k=k, l1_coeff=0.0, enc_dtype=dtype, log_backend="null")
+    jdt = jnp.bfloat16 if dtype == "bf16" else jnp.float32
+    W = rng.integers(-2, 3, size=(2, d, H))
+    W[:, :, 500] = W[:, :, 9]
+    x = rng.integers(-3, 4, size=(B, 2, d))
+    b = rng.integers(-2, 3, size=(H,))
+    b[500] = b[9]
+    params = dict(cc.init_params(jax.random.key(0), cfg, dtype=jnp.float32),
+                  W_enc=jnp.asarray(W, jdt), b_enc=jnp.asarray(b, jnp.float32))
+    h = (np.einsum("bnd,ndh->bh", x, W) + b).astype(np.float32).astype(jdt)
+    want = _batchtopk_sort_oracle(np.asarray(h, np.float32), k)
+    got = np.asarray(cc.encode(params, jnp.asarray(x, jdt), cfg), np.float32)
+    np.testing.assert_array_equal(got, want)
+    hp = np.maximum(np.asarray(h, np.float32), 0)
+    assert (hp == np.sort(hp.ravel())[::-1][k * B - 1]).sum() > 1     # a tied threshold
+    np.testing.assert_array_equal(got[:, 500], got[:, 9])
+    losses = cc.get_losses(params, jnp.asarray(x, jnp.float32), cfg)
+    assert float(losses.l0_loss) == pytest.approx((want > 0).sum(-1).mean(), rel=1e-6)

@@ -91,11 +91,21 @@ def test_aux_loss_zero_when_nothing_dead():
     assert float(losses.aux_loss) == 0.0
 
 
-@pytest.mark.parametrize("activation,sparse", [
+@pytest.mark.parametrize("activation,factored", [
     ("relu", False), ("topk", False), ("topk", True), ("batchtopk", False),
 ])
-def test_fired_matches_dense_activity(activation, sparse):
-    cfg = _cfg(activation=activation, sparse_decode=sparse, topk_k=4)
+def test_fired_matches_dense_activity(activation, factored, monkeypatch):
+    """``factored``: the TopK step through the factored decode (the TopK
+    kernel in the interpreter), whose ``fired`` is the scatter of the k
+    active latents' hits rather than a reduction over dense activations."""
+    cfg = _cfg(activation=activation, topk_k=4)
+    if factored:
+        from crosscoder_tpu.ops import topk_pallas
+
+        monkeypatch.setattr(topk_pallas, "_INTERPRET", True)
+        # the kernel's narrowest row is 256 latents
+        cfg = cfg.replace(factored_decode="on", dict_size=256)
+        assert cc.use_factored_decode(cfg)
     rng = np.random.default_rng(6)
     params = cc.init_params(jax.random.key(7), cfg, dtype=jnp.float32)
     x = jnp.asarray(

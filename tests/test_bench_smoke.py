@@ -76,7 +76,7 @@ def test_bench_compact_summary_is_small_and_gated():
                        "acts_per_sec_chip": 150000.0, "step_ms": 27.3,
                        "loss_finite": True, "n_devices": 1,
                        "workload": "w" * 80})
-        for v in ("topk_dense", "topk_pallas", "topk_sparse_decode",
+        for v in ("topk_dense", "topk_pallas", "jumprelu",
                   "topk_sparse_bwd", "batchtopk", "batchtopk_pallas"):
             matrix.append({"variant": v, "dict_size": d,
                            "acts_per_sec_chip": 140000.0, "step_ms": 29.0,

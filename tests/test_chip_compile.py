@@ -161,7 +161,7 @@ def test_topk_step_compiles_in_its_row_form(chip, topo, one_device):
     cfg = CrossCoderConfig(log_backend="null", d_in=2048, dict_size=2**15,
                            activation="topk", topk_k=K, l1_coeff=0.0)
     assert cc.rows_live(cfg, BATCH) and cc.use_factored_decode(cfg, BATCH)
-    assert cc.use_sparse_bwd(cfg, BATCH) and not cc.use_fused_encoder(cfg, BATCH)
+    assert cc.use_sparse_bwd(cfg, BATCH)
     text = _compiled_train_step(
         cfg, mesh_lib.make_mesh(devices=topo.devices[:1]), with_metrics=False).as_text()
     calls = [line.split(" = ")[0] for line in text.splitlines() if "tpu_custom_call" in line]
@@ -204,8 +204,7 @@ def test_serve_pair_compiles(chip):
         lambda k: cc.init_params(k, cfg, jnp.float32), jax.random.key(0)), chip)
     encode = serve_step.encode_topk_diff.lower(
         params, _sds((b, S, 2, 2304), jnp.bfloat16, chip), i32(b),
-        _sds((2,), jnp.float32, chip), enc_dtype="bf16", k=K,
-        fused=cc.use_fused_encoder(cfg, b), pair=(0, 1),
+        _sds((2,), jnp.float32, chip), enc_dtype="bf16", k=K, pair=(0, 1),
     ).compile()
     _fits(encode)
 
@@ -229,16 +228,6 @@ def test_topk_family_compiles(chip, width):
     _compiles(jax.grad(lambda x: topk_pallas.topk(x, K).astype(jnp.float32).sum()), h)
     _compiles(lambda x: topk_pallas.sparsify(x, K), h)
     _compiles(lambda x: topk_pallas.batchtopk(x, K), h)
-
-
-def test_fused_encoder_family_compiles(chip):
-    from crosscoder_tpu.ops import fused_encoder_topk as fek
-
-    x = _sds((BATCH, ND), jnp.bfloat16, chip)
-    W = _sds((ND, 2**15), jnp.bfloat16, chip)
-    b = _sds((2**15,), jnp.bfloat16, chip)
-    _compiles(lambda x, W, b: fek.fused_topk_encode(x, W, b, K), x, W, b)
-    _compiles(lambda x, W, b: fek.fused_batchtopk_encode_raw(x, W, b, K), x, W, b)
 
 
 def test_quant_family_compiles(chip, gates_open):

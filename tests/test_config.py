@@ -50,6 +50,21 @@ def test_reference_cfg_json_loads(tmp_path):
         assert out[k] == v or out[k] == float(v)
 
 
+def test_removed_encoder_keys_land_in_extras(tmp_path):
+    """A config saved when the TopK step had a fused encoder tier and a
+    gathered decode still loads: the three keys no field reads any more are
+    kept, verbatim, as foreign keys."""
+    old = {"activation": "topk", "topk_k": 32, "l1_coeff": 0.0,
+           "fused_encoder": "auto", "quant_encoder": False, "sparse_decode": True}
+    p = tmp_path / "cfg.json"
+    p.write_text(json.dumps(old))
+    cfg = CrossCoderConfig.from_json(p)
+    assert cfg.extras == {"fused_encoder": "auto", "quant_encoder": False,
+                          "sparse_decode": True}
+    assert (cfg.activation, cfg.topk_k) == ("topk", 32)
+    assert {k: cfg.to_dict()[k] for k in cfg.extras} == cfg.extras
+
+
 def test_parse_hook_point():
     assert parse_hook_point("blocks.14.hook_resid_pre") == (14, "resid_pre")
     assert parse_hook_point("blocks.6.hook_resid_post") == (6, "resid_post")

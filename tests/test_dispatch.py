@@ -37,8 +37,8 @@ def test_per_kernel_env_overrides_umbrella(monkeypatch):
     assert not dispatch.resolve_gate("CROSSCODER_QUANT_PALLAS")
     assert dispatch.resolve_gate("CROSSCODER_PAGED_ATTN_PALLAS")
     monkeypatch.setenv(dispatch.UMBRELLA_ENV, "off")
-    monkeypatch.setenv("CROSSCODER_FUSED_TOPK_PALLAS", "1")
-    assert dispatch.resolve_gate("CROSSCODER_FUSED_TOPK_PALLAS")
+    monkeypatch.setenv("CROSSCODER_BATCHTOPK_PALLAS", "1")
+    assert dispatch.resolve_gate("CROSSCODER_BATCHTOPK_PALLAS")
     assert not dispatch.resolve_gate("CROSSCODER_QUANT_PALLAS")
 
 

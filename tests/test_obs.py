@@ -763,7 +763,7 @@ def test_harvest_store_and_serve_programs_carry_scope_names():
         cc_params, jax.ShapeDtypeStruct((4, 8, cfg.n_sources, cfg.d_in), np.float32),
         jax.ShapeDtypeStruct((4,), np.int32),
         jax.ShapeDtypeStruct((cfg.n_sources,), np.float32),
-        enc_dtype=cfg.enc_dtype, k=cfg.topk_k, fused=False,
+        enc_dtype=cfg.enc_dtype, k=cfg.topk_k,
         pair=serve_step.diff_pair(cfg.n_sources, cfg.n_models),
     ).as_text(debug_info=True)
     assert "serve/encode_topk_diff" in text and "cc/encode" in text

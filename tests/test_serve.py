@@ -45,6 +45,66 @@ def _padded(docs, seq_len):
 
 
 # ---------------------------------------------------------------------------
+# the device step alone against NumPy
+
+
+@pytest.mark.parametrize("B,k,n_models,n_hooks,enc_dtype", [
+    (192, 8, 2, 1, "fp32"), (192, 8, 2, 1, "bf16"),     # the widest bucket of old
+    (8, 8, 2, 1, "fp32"), (8, 8, 2, 1, "bf16"),         # the cells' serve_max_batch
+    (1, 8, 2, 1, "bf16"),                               # one request
+    (8, 32, 2, 2, "bf16"),                              # two hooks a model: pair (0, 2)
+])
+def test_encode_topk_diff_matches_numpy(B, k, n_models, n_hooks, enc_dtype):
+    """``(vals, idx, diff)`` of ``serve.step.encode_topk_diff`` against a NumPy
+    top-k of ``relu(x·W_enc + b_enc)`` at each request's last valid token.
+    Small integer operands make every pre-act sum exact, and the second half
+    of the dictionary copies the first (encoder columns and biases), so every
+    value is tied at least twice; ReLU ties the rest at zero. Ties go to the
+    lowest latent index, as ``lax.top_k`` orders them."""
+    import jax
+    import jax.numpy as jnp
+    from ml_dtypes import bfloat16
+
+    from crosscoder_tpu.config import CrossCoderConfig
+    from crosscoder_tpu.models import crosscoder
+    from crosscoder_tpu.serve.step import diff_pair, encode_topk_diff
+
+    n, H, S = n_models * n_hooks, 1024, 8
+    d = 256 // n
+    hooks = tuple(f"blocks.{i}.hook_resid_pre" for i in range(n_hooks))
+    cfg = CrossCoderConfig(d_in=d, dict_size=H, n_models=n_models, hook_points=hooks,
+                           activation="topk", topk_k=k, l1_coeff=0.0,
+                           enc_dtype=enc_dtype, log_backend="null")
+    assert cfg.n_sources == n
+    rng = np.random.default_rng(B + k + n)
+    dt = bfloat16 if enc_dtype == "bf16" else np.float32
+    W = np.tile(rng.integers(-1, 2, size=(n, d, H // 2)), 2)
+    b = np.tile(rng.integers(-2, 3, size=(H // 2,)), 2)
+    caps = rng.integers(-1, 2, size=(B, S, n, d))
+    lengths = rng.integers(1, S + 1, size=(B,))
+    norm = np.asarray([0.5, 2.0, 1.0, 4.0][:n], np.float32)
+    params = dict(crosscoder.init_params(jax.random.key(2), cfg, dtype=jnp.float32),
+                  W_enc=jnp.asarray(W, jnp.float32), b_enc=jnp.asarray(b, jnp.float32))
+    pair = diff_pair(n, n_models)
+    vals, idx, diff = encode_topk_diff(
+        params, jnp.asarray(caps.astype(dt)), jnp.asarray(lengths, jnp.int32),
+        jnp.asarray(norm), enc_dtype=enc_dtype, k=k, pair=pair)
+
+    x = caps[np.arange(B), lengths - 1] * norm[:, None]          # exact: powers of two
+    h = (np.einsum("bnd,ndh->bh", x, W) + b).astype(np.float32).astype(dt)
+    hp = np.maximum(h.astype(np.float32), 0)
+    order = np.stack([np.lexsort((np.arange(H), -row))[:k] for row in hp])
+    norms = np.linalg.norm(np.asarray(params["W_dec"], np.float64), axis=-1)
+    rel = norms[:, pair[1]] / (norms[:, pair[0]] + norms[:, pair[1]] + 1e-12)
+    top = np.take_along_axis(hp, order, axis=1)
+    assert ((np.diff(top, axis=1) == 0) & (top[:, 1:] > 0)).any()   # ties are chosen
+    np.testing.assert_array_equal(np.asarray(idx), order)
+    np.testing.assert_array_equal(np.asarray(vals, np.float32),
+                                  np.take_along_axis(hp, order, axis=1))
+    np.testing.assert_allclose(np.asarray(diff), rel[order], rtol=1e-6)
+
+
+# ---------------------------------------------------------------------------
 # parity vs the offline padded oracle
 
 

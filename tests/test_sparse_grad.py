@@ -289,8 +289,6 @@ def test_config_rejects_bad_sparse_bwd():
              factored_decode="auto")
     with pytest.raises(ValueError, match="l1_coeff=0"):
         _cfg(sparse_bwd="on", l1_coeff=1.0)
-    with pytest.raises(ValueError, match="sparse_decode"):
-        _cfg(sparse_bwd="on", sparse_decode=True)
 
 
 # ---------------------------------------------------------------------------
